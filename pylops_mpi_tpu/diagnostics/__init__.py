@@ -2,9 +2,7 @@
 
 The reference ships no runtime introspection at all; after three perf
 rounds this repo had many tuned kernels and zero visibility into where
-time, bytes and iterations actually go (VERDICT round 5: a 900 s
-harvest stage burned a rare ~20-minute TPU window producing nothing).
-Four modules make the folklore first-class:
+time, bytes and iterations actually go. Four modules make the folklore first-class:
 
 - :mod:`~pylops_mpi_tpu.diagnostics.trace` — structured span tracer
   (context-manager API, nested spans, thread-safe ring buffer) emitting
@@ -23,9 +21,8 @@ Four modules make the folklore first-class:
   donated/fused hot path carries zero host callbacks when disabled.
 - :mod:`~pylops_mpi_tpu.diagnostics.profiler` — ``jax.profiler``
   trace-capture hooks plus the deadline-aware stage runner and the
-  central per-stage wall-budget table consumed by the harvest ladder
-  (``bench.py``, ``benchmarks/tpu_probe_loop.py``,
-  ``benchmarks/rehearse_ladder.py``).
+  central per-stage wall-budget table (tuner searches, benchmark
+  components, watched multi-host phases, serving batches).
 
 Fleet observability (ISSUE 10) adds the cross-process half:
 

@@ -1,30 +1,22 @@
-"""Profiler hooks and the deadline-aware harvest-stage runner.
-
-Two jobs, both born from VERDICT round 5 ("a 900 s harvest stage
-burned a rare ~20-minute TPU window producing nothing"):
+"""Profiler hooks and the deadline-aware stage runner.
 
 1. :func:`profile_capture` — ``jax.profiler`` trace-capture around a
    region (the XLA/device-level view the host-side span tracer cannot
-   give), gated by an env dir so any harvest stage can be captured
-   without code changes.
+   give), gated by an env dir so any region can be captured without
+   code changes.
 
 2. :class:`DeadlineRunner` + :data:`STAGE_BUDGETS` — the central
-   per-stage wall-budget table for the harvest ladder (previously the
-   900 s-class limits were duplicated inline across ``bench.py``,
-   ``benchmarks/tpu_probe_loop.py`` and
-   ``benchmarks/rehearse_ladder.py``) and a runner that (a) caps each
-   stage's timeout at ``min(budget, window remaining)``, (b) records
-   whether a killed stage still BANKED a partial artifact (the
-   ``_run_json_cmd`` salvage), and (c) SKIPS stages the remaining
-   window cannot fit — yielding the window instead of eating it.
+   per-stage wall-budget table (tuner searches, benchmark components,
+   watched multi-host phases, serving batches) and a runner that (a)
+   caps each stage's timeout at ``min(budget, window remaining)``, (b)
+   records whether a killed stage still BANKED a partial artifact, and
+   (c) SKIPS stages the remaining window cannot fit.
 
 STANDALONE-LOADABLE BY DESIGN: module-level imports are stdlib only
-and there are no relative imports, so the probe daemon's jax-free
-parent process loads this file directly via
-``importlib.util.spec_from_file_location`` (see
-``benchmarks/tpu_probe_loop.py::_profiler_mod``) without pulling the
-package (and jax) into the long-lived supervisor. Trace emission is
-lazy and guarded for the same reason.
+and there are no relative imports, so a jax-free supervisor process
+can load this file directly via
+``importlib.util.spec_from_file_location`` without pulling the package
+(and jax) in. Trace emission is lazy and guarded for the same reason.
 """
 
 from __future__ import annotations
@@ -39,73 +31,44 @@ __all__ = ["STAGE_BUDGETS", "stage_budget", "DeadlineRunner",
 
 
 # ------------------------------------------------------------ budget table
-# Per-stage wall budgets, seconds. ONE table, two columns:
-#   "tpu"      — the live-window budget the probe daemon enforces
-#                (previously the PROBE_*_TIMEOUT inline defaults in
-#                tpu_probe_loop.py);
-#   "rehearse" — the CPU-rehearsal enforcement budget
-#                (previously rehearse_ladder.py's BUDGETS dict).
-# Env override names are unchanged (PROBE_<STAGE>_TIMEOUT, with the
-# historical "flagship_" prefix dropped: PROBE_SMALL_TIMEOUT etc.), so
-# existing harvest configs keep working.
-STAGE_BUDGETS: Dict[str, Dict[str, Optional[int]]] = {
-    "selfcheck":      {"tpu": 900,  "rehearse": 600},
-    # the autotuner sweep (python -m pylops_mpi_tpu.tuning): runs
-    # EARLY in the ladder so later stages replay measured plans; also
-    # the per-search budget tuning.search enforces in-process
+# Per-stage wall budgets, seconds. Env override names:
+# PROBE_<STAGE>_TIMEOUT (BENCH_COMPONENT_TIMEOUT for "component").
+STAGE_BUDGETS: Dict[str, int] = {
+    # the autotuner sweep (python -m pylops_mpi_tpu.tuning); also the
+    # per-search budget tuning.search enforces in-process
     # (PYLOPS_MPI_TPU_TUNE_BUDGET overrides for a single search)
-    "tune":           {"tpu": 600,  "rehearse": 240},
-    "flagship_small": {"tpu": 900,  "rehearse": 600},
-    "fft_planar":     {"tpu": 700,  "rehearse": 600},
-    "flagship_full":  {"tpu": 3000, "rehearse": 2400},
-    "flagship_mid":   {"tpu": 1200, "rehearse": 1200},
-    "overlap":        {"tpu": 600,  "rehearse": 600},
-    # hierarchical-vs-flat race (round 11): per-fabric byte + timing
-    # rows on the hybrid mesh; cheap, slotted right after overlap
-    "hier":           {"tpu": 300,  "rehearse": 300},
-    "bisect":         {"tpu": 1200, "rehearse": 900},
-    "breakdown":      {"tpu": 900,  "rehearse": 700},
-    "diag":           {"tpu": 900,  "rehearse": 700},
-    # bench-child internal budgets (bench.py consumes these directly):
-    # the pre-headline selfcheck subprocess and the per-component cap
-    "bench_selfcheck": {"tpu": 600, "rehearse": 600},
-    "component":       {"tpu": 150, "rehearse": 150},
+    "tune":           600,
+    # per-config cap of benchmarks/bench_components.py
+    "component":      150,
     # elastic-runtime watched phases (resilience/elastic.py
     # watched_call deadlines; PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT
     # overrides globally, PROBE_<STAGE>_TIMEOUT per stage):
     # blocking jax.distributed bring-up, blocking multi-host
     # checkpoint save/load, and the CI chaos leg's whole
     # kill/recover suite
-    "multihost_init":  {"tpu": 300, "rehearse": 120},
-    "checkpoint_io":   {"tpu": 600, "rehearse": 300},
-    "multihost_chaos": {"tpu": 900, "rehearse": 600},
+    "multihost_init":  300,
+    "checkpoint_io":   600,
+    "multihost_chaos": 900,
     # serving-daemon stages (serving/queue.py dispatcher wraps every
     # packed batch solve in a DeadlineRunner with this budget; the CI
     # serve-forever smoke uses serve_smoke as its job timeout)
-    "serve_batch":     {"tpu": 120, "rehearse": 60},
-    "serve_smoke":     {"tpu": 900, "rehearse": 600},
+    "serve_batch":     120,
+    "serve_smoke":     900,
 }
-
-_ENV_NAMES = {
-    "bench_selfcheck": "BENCH_SELFCHECK_TIMEOUT",
-    "component": "BENCH_COMPONENT_TIMEOUT",
-}
-
 
 def _env_name(stage: str) -> str:
-    if stage in _ENV_NAMES:
-        return _ENV_NAMES[stage]
-    return "PROBE_" + stage.replace("flagship_", "").upper() + "_TIMEOUT"
+    if stage == "component":
+        return "BENCH_COMPONENT_TIMEOUT"
+    return "PROBE_" + stage.upper() + "_TIMEOUT"
 
 
-def stage_budget(stage: str, rehearse: bool = False,
-                 env: Optional[Dict] = None) -> int:
-    """Wall budget (seconds) for one harvest stage: the env override
-    (``PROBE_<STAGE>_TIMEOUT`` / ``BENCH_*_TIMEOUT``) when set and
-    parseable, else the table column for the flavor. Unknown stages
-    raise — a typo'd stage name must not silently get some default."""
+def stage_budget(stage: str, env: Optional[Dict] = None) -> int:
+    """Wall budget (seconds) for one stage: the env override
+    (``PROBE_<STAGE>_TIMEOUT`` / ``BENCH_COMPONENT_TIMEOUT``) when set
+    and parseable, else the table entry. Unknown stages raise — a
+    typo'd stage name must not silently get some default."""
     if stage not in STAGE_BUDGETS:
-        raise KeyError(f"unknown harvest stage {stage!r}; known: "
+        raise KeyError(f"unknown stage {stage!r}; known: "
                        f"{sorted(STAGE_BUDGETS)}")
     env = os.environ if env is None else env
     raw = env.get(_env_name(stage))
@@ -114,7 +77,7 @@ def stage_budget(stage: str, rehearse: bool = False,
             return int(raw)
         except ValueError:
             pass  # malformed override: fall through to the table
-    return STAGE_BUDGETS[stage]["rehearse" if rehearse else "tpu"]
+    return STAGE_BUDGETS[stage]
 
 
 # --------------------------------------------------------- deadline runner
@@ -131,13 +94,13 @@ class StageRecord(dict):
 
 
 class DeadlineRunner:
-    """Run harvest stages against a hard window deadline.
+    """Run stages against a hard window deadline.
 
     ``fn`` passed to :meth:`run` receives the EFFECTIVE timeout
-    (seconds) and returns ``(result, err)`` in the
-    ``bench._run_json_cmd`` convention — ``result`` may be a salvaged
-    partial line when the child was killed at the timeout (detected
-    here via its ``salvaged_after_timeout`` stamp). The runner:
+    (seconds) and returns ``(result, err)`` — exactly one of the two
+    is None; ``result`` may be a salvaged partial when the stage was
+    cut at the timeout (detected here via its
+    ``salvaged_after_timeout`` stamp). The runner:
 
     - caps each stage at ``min(budget, remaining window)`` (a stage
       never eats past the deadline);

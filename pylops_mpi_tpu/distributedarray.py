@@ -298,18 +298,21 @@ class DistributedArray:
         out = cls(global_shape=x.shape, mesh=mesh, partition=partition,
                   axis=axis, local_shapes=local_shapes, mask=mask,
                   dtype=dtype)
-        if host_src and not out._even:
-            # Uneven split from a host array: cast to the canonical
-            # dtype first (half the traffic when x64 is off), then pack
-            # to the padded physical layout with the native (C++) host
-            # runtime in one threaded pass instead of tracing per-shard
-            # pad+concat.
-            from . import native
-            phys = native.pack_padded(np.asarray(x, dtype=dtype), out._axis,
-                                      out._axis_sizes, out._s_phys)
-            out._arr = out._place(jnp.asarray(phys))
+        if host_src:
+            # host arrays go straight to the devices that own each
+            # shard (device_put of a NumPy array with a sharding slices
+            # on the host) — never through a full copy on device 0
+            x = np.asarray(x, dtype=dtype)
+            if not out._even:
+                # uneven split: pack to the padded physical layout with
+                # the native (C++) host runtime in one threaded pass
+                # instead of tracing per-shard pad+concat
+                from . import native
+                x = native.pack_padded(x, out._axis, out._axis_sizes,
+                                       out._s_phys)
+            out._arr = out._place(x)
         else:
-            out._arr = out._place(out._from_global(jnp.asarray(x)))
+            out._arr = out._place(out._from_global(x))
         return out
 
     def asarray(self) -> np.ndarray:
@@ -774,7 +777,7 @@ class DistributedArray:
         valid_tab = jnp.asarray(sizes, dtype=jnp.int32)
         out_valid_tab = jnp.asarray(out_sizes, dtype=jnp.int32)
         from .parallel.collectives import halo_slab
-        from .jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
 
         def _iota(shape):

@@ -19,7 +19,6 @@ import os
 from typing import List, Optional, Sequence
 
 import numpy as np
-import jax
 import jax.numpy as jnp
 
 from ..distributedarray import DistributedArray, Partition
@@ -141,18 +140,17 @@ class MPIBlockDiag(MPILinearOperator):
         if len(odims) != 1:
             return None
         other = odims.pop()
-        shapes = {op.A.shape for op in self.ops}
+        mats = [op.A_source for op in self.ops]
+        shapes = {m.shape for m in mats}
         if len(shapes) != 1 or len(self.ops) % int(self.mesh.devices.size) != 0:
             return None
         self._batched_k = int(np.prod(other)) if other else 1
-        A = jnp.stack([op.A for op in self.ops])  # (nblk, m, n)
-        if self.compute_dtype is not None:
-            from ._precision import check_compute_dtype
-            check_compute_dtype(self.compute_dtype, A.dtype,
-                                "MPIBlockDiag")
-            A = A.astype(self.compute_dtype)
-        from ..parallel.mesh import axis_sharding
-        return jax.device_put(A, axis_sharding(self.mesh, 3, 0))
+        from ._precision import check_compute_dtype
+        check_compute_dtype(self.compute_dtype,
+                            np.result_type(*{m.dtype for m in mats}),
+                            "MPIBlockDiag")
+        from ..parallel.mesh import stack_sharded
+        return stack_sharded(mats, self.mesh, self.compute_dtype)
 
     # block (column-batched) inputs reuse the SAME batched einsum with a
     # widened trailing contraction — no per-column Python loop
@@ -281,7 +279,7 @@ class MPIBlockDiag(MPILinearOperator):
         if not self.has_fused_normal or x.ndim == 2:
             return super().normal_matvec(x)
         from jax.sharding import PartitionSpec as P
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from .pallas_kernels import normal_matvec_supported
         if self._ffi_normal_usable() \
                 and np.dtype(x.dtype) == np.dtype(self._batched.dtype):

@@ -244,7 +244,7 @@ class _StencilOperator(MPILinearOperator):
         inner = int(np.prod(dims[1:])) if len(dims) > 1 else 1
         if x._axis_sizes != tuple(r * inner for r in rows_tab):
             return None  # bespoke layout: implicit path handles it
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax import lax
         from jax.sharding import PartitionSpec as PSpec
         from ..parallel.collectives import halo_slab

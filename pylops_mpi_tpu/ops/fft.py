@@ -464,7 +464,7 @@ class _MPIBaseFFTND(MPILinearOperator):
         transform, all-to-all back."""
         if dft.resolved_mode() == "planar":
             return self._matvec_aligned_planar(x)
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
 
         axes = [int(a) for a in self.axes]
@@ -572,7 +572,7 @@ class _MPIBaseFFTND(MPILinearOperator):
     def _rmatvec_aligned(self, x: DistributedArray) -> DistributedArray:
         if dft.resolved_mode() == "planar":
             return self._rmatvec_aligned_planar(x)
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
 
         axes = [int(a) for a in self.axes]
@@ -714,7 +714,7 @@ class _MPIBaseFFTND(MPILinearOperator):
         materialized for it); returns the flat (yr, yi) data-side
         planes. Mirrors the complex kernel of :meth:`_matvec_aligned`
         stage for stage."""
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
         from ..parallel.collectives import plane_all_to_all
 
@@ -843,7 +843,7 @@ class _MPIBaseFFTND(MPILinearOperator):
         """Planar adjoint pencil on flat physical plane buffers;
         returns a 1-tuple (real-model operators) or 2-tuple of flat
         model-side planes. Mirrors :meth:`_rmatvec_aligned`."""
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
         from ..parallel.collectives import plane_all_to_all
 

@@ -33,7 +33,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
-from ..jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from ..distributedarray import DistributedArray, Partition

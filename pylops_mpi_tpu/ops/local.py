@@ -182,13 +182,29 @@ class MatrixMult(LocalOperator):
     """Dense GEMM block — feeds the MXU. Analog of ``pylops.MatrixMult``."""
 
     def __init__(self, A, otherdims: Tuple[int, ...] = (), dtype=None):
-        A = jnp.asarray(A)
-        self.A = A
+        # a host (NumPy) matrix stays on the host until this block is
+        # applied on its own: the distributed operators that batch
+        # their blocks (MPIBlockDiag, MPIVStack) read ``A_source`` and
+        # place each shard on the device that owns it, so no
+        # per-block copy ever lands on the default device
+        self.A_source = A if isinstance(A, np.ndarray) else jnp.asarray(A)
+        A = self.A_source
         self.otherdims = tuple(otherdims)
         nother = int(np.prod(self.otherdims)) if self.otherdims else 1
         dims = (A.shape[1] * nother,)
         dimsd = (A.shape[0] * nother,)
-        super().__init__(dims, dimsd, dtype=dtype or A.dtype)
+        super().__init__(dims, dimsd, dtype=dtype
+                         or jax.dtypes.canonicalize_dtype(A.dtype))
+
+    @property
+    def A(self) -> jax.Array:
+        """The matrix as a device array (placed on first use — eagerly
+        even when that first use is under a trace, so a concrete
+        array is cached, never a tracer)."""
+        if isinstance(self.A_source, np.ndarray):
+            with jax.ensure_compile_time_eval():
+                self.A_source = jnp.asarray(self.A_source)
+        return self.A_source
 
     def _matvec(self, x):
         if self.otherdims:

@@ -44,7 +44,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from ..distributedarray import DistributedArray, Partition, local_split

@@ -20,12 +20,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
-try:
-    from jax.experimental import pallas as pl
-    from jax.experimental.pallas import tpu as pltpu
-    _HAS_PALLAS = True
-except Exception:  # pragma: no cover
-    _HAS_PALLAS = False
+from jax.experimental import pallas as pl
 
 __all__ = ["first_derivative_centered", "second_derivative",
            "stencil_taps", "batched_normal_matvec",
@@ -33,10 +28,7 @@ __all__ = ["first_derivative_centered", "second_derivative",
 
 
 def pallas_available() -> bool:
-    if not _HAS_PALLAS:
-        return False
-    plat = jax.default_backend()
-    return plat in ("tpu", "cpu")
+    return jax.default_backend() in ("tpu", "cpu")
 
 
 def _interpret() -> bool:
@@ -213,7 +205,7 @@ def normal_matvec_supported(A: jax.Array) -> bool:
     """Pallas path requires real floating blocks (complex dots fall back
     to the generic two-sweep path) for which a Mosaic-legal row tile
     fits the VMEM budget — otherwise the generic path must be used."""
-    if not (_HAS_PALLAS and pallas_available() and A.ndim == 3
+    if not (pallas_available() and A.ndim == 3
             and not jnp.iscomplexobj(A)):
         return False
     return _tile_args(A)[0] is not None

@@ -219,7 +219,7 @@ class MPISparseMatrixMult(MPILinearOperator):
     def _rmatvec_ring(self, prod: jax.Array) -> jax.Array:
         """Explicit ring adjoint: rotate the (values, cols) bundle,
         fold the resident slice into this device's x-block."""
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from ..parallel.collectives import ring_pass
         from jax.sharding import PartitionSpec as PSpec
 

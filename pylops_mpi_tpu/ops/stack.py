@@ -119,23 +119,22 @@ class MPIVStack(MPILinearOperator):
         mats, adjs = [], []
         for op in self.ops:
             if isinstance(op, MatrixMult) and not op.otherdims:
-                mats.append(op.A)
+                mats.append(op.A_source)
                 adjs.append(False)
             elif (isinstance(op, _Adjoint) and isinstance(op.A, MatrixMult)
                     and not op.A.otherdims):
-                mats.append(op.A.A)
+                mats.append(op.A.A_source)
                 adjs.append(True)
             else:
                 return None, False
         if (len(set(adjs)) != 1 or len({m.shape for m in mats}) != 1
                 or len(mats) % int(self.mesh.devices.size) != 0):
             return None, False
-        A = jnp.stack(mats)  # (nblk, m, n)
-        if self.compute_dtype is not None:
-            check_compute_dtype(self.compute_dtype, A.dtype, "MPIVStack")
-            A = A.astype(self.compute_dtype)
-        from ..parallel.mesh import axis_sharding
-        return jax.device_put(A, axis_sharding(self.mesh, 3, 0)), adjs[0]
+        check_compute_dtype(self.compute_dtype,
+                            np.result_type(*{m.dtype for m in mats}),
+                            "MPIVStack")
+        from ..parallel.mesh import stack_sharded
+        return stack_sharded(mats, self.mesh, self.compute_dtype), adjs[0]
 
     # block (column-batched) inputs add a trailing index to the SAME
     # batched einsums — one widened GEMM, no per-column Python loop
@@ -184,7 +183,7 @@ class MPIVStack(MPILinearOperator):
         final all-gather restores the replicated (BROADCAST) layout."""
         import jax.numpy as _jnp
         from jax import lax
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
 
         A, adj = self._batched, self._batched_adj
@@ -245,7 +244,7 @@ class MPIVStack(MPILinearOperator):
         ring reduces within each slice first, so the outer DCN stage
         moves ``P_ici``-times-fewer, larger messages."""
         import jax.numpy as _jnp
-        from ..jaxcompat import shard_map
+        from jax import shard_map
         from jax.sharding import PartitionSpec as PSpec
         from ..parallel.collectives import (hier_all_gather,
                                             hier_psum_scatter)

@@ -32,6 +32,7 @@ __all__ = [
     "set_default_mesh",
     "local_device_count",
     "best_grid_2d",
+    "stack_sharded",
 ]
 
 # The default axis name for 1-D sharding ("shard-parallel"); mirrors the
@@ -203,3 +204,31 @@ def axis_sharding(mesh: Mesh, ndim: int, axis: int,
     spec = [None] * ndim
     spec[axis] = axis_name
     return NamedSharding(mesh, P(*spec))
+
+
+def stack_sharded(mats: Sequence, mesh: Mesh, dtype=None) -> jax.Array:
+    """``stack(mats)`` as one ``(nblk, m, n)`` array block-sharded over
+    ``mesh`` (``len(mats)`` divisible by the device count), stored at
+    ``dtype`` when given.
+
+    Host (NumPy) blocks are stacked per shard and sent straight to the
+    device that owns them: no device ever holds more than its own
+    share, and nothing passes through device 0 — a deployment-sized
+    operator (gigabytes per chip) does not fit twice. Device blocks
+    are stacked where they are and resharded."""
+    sharding = axis_sharding(mesh, 3, 0)
+    if not all(isinstance(m, np.ndarray) for m in mats):
+        import jax.numpy as jnp
+        A = jnp.stack([jnp.asarray(m) for m in mats])
+        return jax.device_put(A if dtype is None else A.astype(dtype),
+                              sharding)
+    if dtype is None:
+        dtype = np.result_type(*{m.dtype for m in mats})
+    dtype = jax.dtypes.canonicalize_dtype(dtype)
+    mats = list(mats)
+    shape = (len(mats),) + tuple(mats[0].shape)
+    shards = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        part = np.stack(mats[idx[0]], dtype=dtype, casting="unsafe")
+        shards.append(jax.device_put(part, dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)

@@ -17,9 +17,7 @@ half of that contract:
   catch this, because the *stuck* worker's beat thread keeps running.
   :func:`watched_call` runs such a phase in a worker thread with a
   deadline from the central :data:`~pylops_mpi_tpu.diagnostics.\
-profiler.STAGE_BUDGETS` table (the same machinery the harvest ladder's
-  :class:`~pylops_mpi_tpu.diagnostics.profiler.DeadlineRunner` uses)
-  and raises a classified :class:`WatchdogTimeout` instead of blocking
+profiler.STAGE_BUDGETS` table and raises a classified :class:`WatchdogTimeout` instead of blocking
   — the worker exits nonzero, the supervisor reaps it and relaunches
   the job on the surviving host set.
 
@@ -244,18 +242,17 @@ def watchdog_enabled() -> bool:
 
 def watchdog_timeout(stage: str, default: Optional[float] = None) -> float:
     """Deadline for one watched stage: the global override
-    ``PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT`` when set, else the stage's row
-    in the central ``STAGE_BUDGETS`` table (``tpu`` column), else
-    ``default`` (300 s)."""
+    ``PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT`` when set, else the stage's
+    entry in the central ``STAGE_BUDGETS`` table, else ``default``
+    (300 s)."""
     raw = os.environ.get("PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT")
     if raw:
         try:
             return float(raw)
         except ValueError:
             pass
-    row = STAGE_BUDGETS.get(stage)
-    if row and row.get("tpu"):
-        return float(row["tpu"])
+    if stage in STAGE_BUDGETS:
+        return float(STAGE_BUDGETS[stage])
     return 300.0 if default is None else float(default)
 
 

@@ -6,10 +6,9 @@ may lag the pod scheduler, a preempted peer may rejoin seconds late.
 The reference stack leans on ``mpiexec`` to re-run the world; here one
 controller process must absorb transient faults itself. This module is
 the ONE retry/backoff implementation, used by
-:func:`pylops_mpi_tpu.parallel.mesh.initialize_multihost` and by the
-harvest ladder's stage spawn (``benchmarks/tpu_probe_loop.py``) — both
-places where the failure is transient-by-construction and a bounded
-retry is the difference between a lost window and a banked result.
+:func:`pylops_mpi_tpu.parallel.mesh.initialize_multihost` — where the
+failure is transient-by-construction and a bounded retry is the
+difference between a lost job and a running one.
 
 Retries are **bounded** (``PYLOPS_MPI_TPU_RETRIES``, default 3 extra
 attempts) with doubling backoff from

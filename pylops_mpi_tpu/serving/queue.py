@@ -275,14 +275,12 @@ class Dispatcher(threading.Thread):
 
     def __init__(self, pool: WarmPool, queue: AdmissionQueue, *,
                  window_s: Optional[float] = None,
-                 rehearse: bool = False,
                  on_batch: Optional[Callable[[Dict], None]] = None):
         super().__init__(name="pylops-serve-dispatch", daemon=True)
         self.pool = pool
         self.queue = queue
         self.window_s = batch_window_s() if window_s is None \
             else max(0.0, float(window_s))
-        self.rehearse = bool(rehearse)
         self.on_batch = on_batch
         self._halt = threading.Event()
         self._inflight = threading.Event()
@@ -322,7 +320,7 @@ class Dispatcher(threading.Thread):
         runner = DeadlineRunner(
             deadline_ts=min(deadlines) if deadlines else None,
             min_stage_s=0)
-        budget = stage_budget("serve_batch", rehearse=self.rehearse)
+        budget = stage_budget("serve_batch")
         fam = batch[0].family
 
         def _solve(_eff_timeout):

@@ -66,13 +66,11 @@ class SolveDaemon:
 
     def __init__(self, pool: WarmPool, *,
                  window_s: Optional[float] = None,
-                 queue_bound: Optional[int] = None,
-                 rehearse: bool = False):
+                 queue_bound: Optional[int] = None):
         self.pool = pool
         self.queue = AdmissionQueue(bound=queue_bound)
         self.dispatcher = Dispatcher(pool, self.queue,
-                                     window_s=window_s,
-                                     rehearse=rehearse)
+                                     window_s=window_s)
         self._started = False
 
     def start(self, prewarm: bool = False) -> "SolveDaemon":

@@ -18,8 +18,7 @@ not assumed):
   override the tuner).
 
 ``python -m pylops_mpi_tpu.tuning`` sweeps the flagship shapes
-offline and banks a cache artifact; the TPU harvest ladder runs it as
-the early ``tune`` stage. See ``docs/tuning.md``.
+offline and banks a cache artifact. See ``docs/tuning.md``.
 """
 
 from .plan import (Plan, get_plan, tune_mode, tune_enabled, plan_key,

@@ -3,14 +3,11 @@
 Measures the flagship plan spaces shape-by-shape and banks the
 winners into a JSON plan cache (``--out``, or
 ``PYLOPS_MPI_TPU_TUNE_CACHE``), so later sessions with
-``PYLOPS_MPI_TPU_TUNE=on`` replay hardware-measured plans for free.
-The TPU harvest ladder runs this as its early ``tune`` stage
-(``benchmarks/tpu_probe_loop.py``); the CI tuning leg seeds its cache
-with ``--quick`` before running the suites.
+``PYLOPS_MPI_TPU_TUNE=on`` replay measured plans for free. The CI
+tuning leg seeds its cache with ``--quick`` before running the suites.
 
 Output contract: progress goes to stderr; the LAST stdout line is one
-compact JSON summary (the ``bench._run_json_cmd`` salvage
-convention), stamped per-family with the winning params and their
+compact JSON summary, stamped per-family with the winning params and their
 provenance. ``--defaults`` banks cost-model picks without timing a
 single trial (a cheap way to pre-seed a cache that exactly matches
 today's behavior).
@@ -279,8 +276,7 @@ def main(argv=None) -> int:
     ap.add_argument("--defaults", action="store_true",
                     help="bank cost-model picks without measuring")
     ap.add_argument("--ladder", action="store_true",
-                    help="harvest-ladder mode: quick shapes off-TPU, "
-                         "full shapes on hardware")
+                    help="quick shapes off-TPU, full shapes on a TPU")
     ap.add_argument("--family", action="append", default=None,
                     help="limit to one family (repeatable)")
     ap.add_argument("--repeats", type=int, default=3)

@@ -7,8 +7,8 @@ model). This module is the storage layer of the autotuner:
 
 - **Location** — ``PYLOPS_MPI_TPU_TUNE_CACHE`` names the JSON file;
   when unset the cache is **process-local memory only** (nothing is
-  ever written to disk behind the user's back — the offline CLI and
-  the harvest ``tune`` stage pass an explicit path).
+  ever written to disk behind the user's back — the offline CLI
+  passes an explicit path).
 - **Schema-versioned** — the file carries ``{"schema": N, "plans":
   {key: entry}}``; a version mismatch is treated as a miss for every
   key (logged as a structured trace event), never an exception.

@@ -5,8 +5,7 @@ with the analytic seed (``space.rank`` → ``diagnostics/costmodel``),
 then TIME the top-k with the package's benchmark timers
 (``utils/benchmark.time_callable`` — same sync discipline as the
 ``@benchmark`` decorator) inside a :class:`DeadlineRunner` budget
-(``STAGE_BUDGETS["tune"]``), so tuning can never eat a harvest
-window. Every trial is emitted as a structured ``tuning.trial`` trace
+(``STAGE_BUDGETS["tune"]``), so a search is bounded. Every trial is emitted as a structured ``tuning.trial`` trace
 event — the replay proof ("zero timing trials on the second run")
 counts exactly these events.
 
@@ -45,14 +44,14 @@ def _env_int(name: str, default: int) -> int:
         return default
 
 
-def tune_budget_s(platform: Optional[str] = None) -> int:
+def tune_budget_s() -> int:
     """Wall budget for ONE search (seconds):
     ``PYLOPS_MPI_TPU_TUNE_BUDGET`` when set, else the central
-    ``STAGE_BUDGETS["tune"]`` table (``rehearse`` column off-TPU)."""
+    ``STAGE_BUDGETS["tune"]`` entry."""
     b = _env_int("PYLOPS_MPI_TPU_TUNE_BUDGET", 0)
     if b > 0:
         return b
-    return stage_budget("tune", rehearse=(platform != "tpu"))
+    return stage_budget("tune")
 
 
 def tune_topk() -> int:
@@ -102,7 +101,7 @@ def measure_candidates(space: _space.TuningSpace, ctx: Dict,
     cands = _trial_list(space, ctx)
     dflt = _space.default_params(space, ctx)
     if budget_s is None:
-        budget_s = tune_budget_s(ctx.get("platform"))
+        budget_s = tune_budget_s()
     if runner is None:
         runner = DeadlineRunner(deadline_ts=time.time() + budget_s,
                                 min_stage_s=1)

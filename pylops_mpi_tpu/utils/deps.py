@@ -5,8 +5,10 @@ between MPI, CUDA-aware MPI and NCCL backends at import time
 (``NCCL_PYLOPS_MPI``, ``PYLOPS_MPI_CUDA_AWARE``). The TPU build has one
 backend — XLA collectives — so the seam carries different switches:
 
-- ``PYLOPS_MPI_TPU_PLATFORM``: force ``jax_platforms`` (e.g. ``cpu``
-  for the 8-virtual-device simulation) before first backend use.
+- ``PYLOPS_MPI_TPU_PLATFORM``: request a ``jax_platforms`` value (e.g.
+  ``cpu`` for the 8-virtual-device simulation) before first backend
+  use. A request, never a fallback: when the platform asked for cannot
+  initialise, JAX raises.
 - ``PYLOPS_MPI_TPU_X64``: enable float64 (defaults to JAX's setting;
   TPUs prefer f32/bf16).
 - ``BENCH_PYLOPS_MPI`` / ``BENCH_PYLOPS_MPI_TPU``: benchmark kill-switch
@@ -87,9 +89,10 @@ jax_enabled = True  # the only engine; mirrors deps.nccl_enabled's role
 # you add a knob — or better, register a tuning space
 # (pylops_mpi_tpu/tuning/space.py) instead of adding one.
 KNOBS = [
-    ("PYLOPS_MPI_TPU_PLATFORM", "cpu|tpu|…", "unset (auto)",
+    ("PYLOPS_MPI_TPU_PLATFORM", "cpu|tpu|…", "unset (what JAX finds)",
      "utils/deps.py",
-     "force the JAX platform before first backend use"),
+     "request a JAX platform before first backend use (a request, "
+     "never a fallback)"),
     ("PYLOPS_MPI_TPU_X64", "0|1", "0", "utils/deps.py",
      "enable float64 (TPUs prefer f32/bf16)"),
     ("PYLOPS_MPI_TPU_MATMUL_PRECISION", "highest|default|…", "highest",
@@ -139,11 +142,8 @@ KNOBS = [
      "fused-solver executable cache capacity"),
     ("PYLOPS_MPI_TPU_FFT_MODE", "auto|xla|matmul|planar", "auto",
      "ops/dft.py",
-     "local-FFT engine seam (planar = complex-free plane pairs)"),
-    ("PYLOPS_MPI_TPU_FFTLESS_RUNTIMES", "csv of runtime substrings",
-     "built-in list", "ops/dft.py",
-     "runtimes known to lack the fft custom-call (auto avoids XLA "
-     "FFT there)"),
+     "local-FFT engine seam (auto = xla; planar = complex-free plane "
+     "pairs)"),
     ("PYLOPS_MPI_TPU_DFT_BASE", "int", "128 on TPU / 16 on CPU",
      "ops/dft.py", "mixed-radix GEMM base of the matmul DFT engine"),
     ("PYLOPS_MPI_TPU_FFI_COMPLEX", "0|1", "1", "ops/blockdiag.py",
@@ -174,7 +174,7 @@ KNOBS = [
     ("PYLOPS_MPI_TPU_RETRIES", "int>=0", "3",
      "resilience/retry.py (parallel/mesh.py, benchmarks)",
      "bounded retries for transient host-side faults (multihost "
-     "init, harvest stage spawn)"),
+     "init)"),
     ("PYLOPS_MPI_TPU_RETRY_BACKOFF", "seconds", "0.5",
      "resilience/retry.py",
      "initial retry backoff (doubling, capped at 30 s)"),
@@ -345,12 +345,15 @@ KNOBS = [
      "per entry, schema-versioned, atomic, flock'd read-merge-write; "
      "rank 0 writes, other ranks read); unset under AOT=on keeps the "
      "bank process-local in memory"),
-    ("PYLOPS_MPI_TPU_COMPILE_CACHE", "directory", "unset (off)",
+    ("PYLOPS_MPI_TPU_COMPILE_CACHE", "directory",
+     "unset (entry scripts: <checkout>/.jax_cache; library: off)",
      "aot/compile_cache.py (package import)",
      "JAX persistent compilation cache dir — the fallback compile "
      "tier for programs the AOT bank does not serialize (closure "
      "operators, preconditioned solves, ISTA/FISTA); shared per CI "
-     "job, rank-0-writes/others-read on multi-host"),
+     "job, rank-0-writes/others-read on multi-host. "
+     "JAX_COMPILATION_CACHE_DIR, when set, stands instead and is "
+     "never overridden"),
     ("PYLOPS_MPI_TPU_AUTODIFF", "off|on", "off",
      "utils/deps.py (solvers/basic.py, solvers/block.py, autodiff/*)",
      "differentiable-solver tier: on lets traced (jax.grad/jvp) "

@@ -28,7 +28,8 @@ import jax
 
 __all__ = ["collective_report", "assert_no_full_gather",
            "parse_hlo_collectives", "complex_dtype_lines",
-           "assert_complex_free", "compiled_hlo", "count_ops",
+           "assert_complex_free", "compiled_hlo", "strip_provenance",
+           "count_ops",
            "assert_max_converts", "donation_report", "assert_donation",
            "count_collectives", "assert_ring_schedule",
            "host_callback_lines", "count_host_callbacks",
@@ -152,6 +153,23 @@ def compiled_hlo(fn, *args, **kwargs) -> str:
     needed) — the shared entry for every pin below."""
     jfn = fn if hasattr(fn, "lower") else jax.jit(fn)
     return jfn.lower(*args, **kwargs).compile().as_text()
+
+
+# where a program came from, not what it computes: the module name,
+# per-op metadata, and the stack-frame index tables this jaxlib prints
+# under the module header (file, function and frame names — two
+# programs traced from differently NAMED Python functions differ there
+# and nowhere else)
+_PROVENANCE = re.compile(
+    r'HloModule\s+\S+|metadata=\{[^}]*\}|, module_name="[^"]*"'
+    r'|^(?:FileNames|FunctionNames|FileLocations|StackFrames)\n'
+    r'(?:\d+ .*\n)*', re.M)
+
+
+def strip_provenance(hlo: str) -> str:
+    """``hlo`` without its provenance — what the "same program,
+    bit for bit" pins compare."""
+    return _PROVENANCE.sub("", hlo)
 
 
 def count_ops(hlo: str, opcode: str, shape_re: Optional[str] = None,

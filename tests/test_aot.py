@@ -470,34 +470,95 @@ def test_prewarm_without_aot_still_compiles(monkeypatch):
 
 
 # ------------------------------------------------ compilation cache
-def test_compile_cache_enable_and_restore(tmp_path):
-    """``maybe_enable_compile_cache`` points jax's persistent cache at
-    the configured dir (idempotently); config is restored afterwards
-    so the rest of the suite is unaffected."""
+# The one placement rule (aot/compile_cache.py): JAX_COMPILATION_CACHE_DIR
+# stands untouched, else PYLOPS_MPI_TPU_COMPILE_CACHE, else the entry
+# script's <checkout>/.jax_cache default, else nothing.
+@pytest.fixture
+def cache_config(monkeypatch):
+    """Clean cache env + jax config restored afterwards, so the rest
+    of the suite keeps whatever cache the run was started with."""
     import jax
     from pylops_mpi_tpu.aot import compile_cache as cc
-    old_dir = jax.config.jax_compilation_cache_dir
-    old_min = jax.config.jax_persistent_cache_min_compile_time_secs
-    old_enabled = cc._enabled_dir
-    try:
-        got = cc.maybe_enable_compile_cache(str(tmp_path))
-        assert got == str(tmp_path)
-        assert jax.config.jax_compilation_cache_dir == str(tmp_path)
-        assert jax.config.jax_persistent_cache_min_compile_time_secs \
-            == 0.0
-        assert cc.maybe_enable_compile_cache(str(tmp_path)) \
-            == str(tmp_path)   # idempotent
-    finally:
-        jax.config.update("jax_compilation_cache_dir", old_dir)
-        jax.config.update("jax_persistent_cache_min_compile_time_secs",
-                          old_min)
-        cc._enabled_dir = old_enabled
+    saved = (jax.config.jax_compilation_cache_dir,
+             jax.config.jax_persistent_cache_min_compile_time_secs,
+             jax.config.jax_persistent_cache_min_entry_size_bytes,
+             cc._enabled_dir)
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    monkeypatch.delenv("PYLOPS_MPI_TPU_COMPILE_CACHE", raising=False)
+    cc._enabled_dir = None
+    yield cc
+    jax.config.update("jax_compilation_cache_dir", saved[0])
+    jax.config.update("jax_persistent_cache_min_compile_time_secs",
+                      saved[1])
+    jax.config.update("jax_persistent_cache_min_entry_size_bytes",
+                      saved[2])
+    cc._enabled_dir = saved[3]
 
 
-def test_compile_cache_unset_is_noop():
-    from pylops_mpi_tpu.aot import compile_cache as cc
-    assert cc.compile_cache_dir() is None
-    assert cc.maybe_enable_compile_cache() is None
+def test_compile_cache_unset_is_noop(cache_config):
+    import jax
+    before = jax.config.jax_compilation_cache_dir
+    assert cache_config.compile_cache_dir() is None
+    assert cache_config.maybe_enable_compile_cache() is None
+    assert jax.config.jax_compilation_cache_dir == before
+
+
+def test_compile_cache_entry_default(cache_config, tmp_path):
+    """Neither variable set: the entry scripts' default is used, and
+    the thresholds are lowered so fast compiles are banked too."""
+    import jax
+    d = str(tmp_path / ".jax_cache")
+    assert cache_config.compile_cache_dir(d) == d
+    assert cache_config.maybe_enable_compile_cache(d) == d
+    assert jax.config.jax_compilation_cache_dir == d
+    assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
+    assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    assert cache_config.maybe_enable_compile_cache(d) == d  # idempotent
+
+
+def test_compile_cache_package_knob_beats_default(cache_config, tmp_path,
+                                                  monkeypatch):
+    import jax
+    knob, d = str(tmp_path / "knob"), str(tmp_path / ".jax_cache")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_COMPILE_CACHE", knob)
+    assert cache_config.maybe_enable_compile_cache(d) == knob
+    assert jax.config.jax_compilation_cache_dir == knob
+
+
+def test_compile_cache_jax_variable_stands(cache_config, tmp_path,
+                                           monkeypatch):
+    """JAX_COMPILATION_CACHE_DIR set: it is reported as the directory
+    in use and nothing here overrides jax_compilation_cache_dir — not
+    the package knob, not an entry script's default."""
+    import jax
+    outside = str(tmp_path / "outside")
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", outside)
+    monkeypatch.setenv("PYLOPS_MPI_TPU_COMPILE_CACHE",
+                       str(tmp_path / "knob"))
+    before = jax.config.jax_compilation_cache_dir
+    got = cache_config.maybe_enable_compile_cache(
+        str(tmp_path / ".jax_cache"))
+    assert got == outside
+    assert jax.config.jax_compilation_cache_dir == before
+    assert not (tmp_path / ".jax_cache").exists()
+    assert not (tmp_path / "knob").exists()
+
+
+def test_one_cache_dir_setter_in_the_repo():
+    """``jax_compilation_cache_dir`` is set in one place only."""
+    import glob
+    root = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    paths = glob.glob(os.path.join(root, "*.py"))
+    for sub in ("pylops_mpi_tpu", "benchmarks", "examples"):
+        paths += glob.glob(os.path.join(root, sub, "**", "*.py"),
+                           recursive=True)
+    hits = []
+    for path in paths:
+        with open(path) as f:
+            if '"jax_compilation_cache_dir"' in f.read():
+                hits.append(os.path.relpath(path, root))
+    assert hits == [os.path.join("pylops_mpi_tpu", "aot",
+                                 "compile_cache.py")]
 
 
 # ------------------------------------------------ supervisor wiring

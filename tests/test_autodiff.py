@@ -11,7 +11,6 @@ fused CG/CGLS matches the unrolled scan-tape oracle to ≤1e-5 in f64;
 """
 
 import os
-import re
 
 import numpy as np
 import pytest
@@ -31,8 +30,7 @@ from pylops_mpi_tpu.solvers import clear_fused_cache
 from pylops_mpi_tpu.solvers.basic import _cg_fused, _cgls_fused
 from pylops_mpi_tpu.utils import deps, hlo
 
-_STRIP = re.compile(
-    r'(HloModule\s+\S+|metadata=\{[^}]*\}|, module_name="[^"]*")')
+_strip = hlo.strip_provenance
 
 
 @pytest.fixture(autouse=True)
@@ -246,11 +244,19 @@ def test_unrolled_matches_fused_forward(rng):
                        atol=1e-10)
     OpL, _, yL = _ls_problem(rng)
     x0L = _zeros(OpL, np.float64)
-    xfL = pmt.cgls(OpL, yL, x0L, niter=25, damp=1e-3, tol=0.0,
-                   fused=True)[0]
-    xuL = unrolled_cgls(OpL, yL, x0L, niter=25, damp=1e-3)
-    assert np.allclose(xuL.asarray(), xfL.asarray(), rtol=1e-10,
-                       atol=1e-10)
+    # early (the recurrences still agree to rounding) and converged.
+    # In between — around the 40-unknown system's finite-termination
+    # point — the Krylov iterates amplify last-ulp differences in
+    # reduction order between scan and while_loop (measured on jax
+    # 0.9.0: 4e-16 at 5 iterations, 2e-14 at 10, 9e-6 at 25, back to
+    # 5e-14 at 60), so an iterate taken there pins rounding, not the
+    # recurrence.
+    for niter in (10, 60):
+        xfL = pmt.cgls(OpL, yL, x0L, niter=niter, damp=1e-3, tol=0.0,
+                       fused=True)[0]
+        xuL = unrolled_cgls(OpL, yL, x0L, niter=niter, damp=1e-3)
+        assert np.allclose(xuL.asarray(), xfL.asarray(), rtol=1e-10,
+                           atol=1e-10)
 
 
 def test_implicit_cg_gradient_matches_unrolled(rng):
@@ -426,10 +432,10 @@ def test_autodiff_off_hlo_bit_identical(rng):
     for env in ("off", "on"):
         os.environ["PYLOPS_MPI_TPU_AUTODIFF"] = env
         clear_fused_cache()
-        assert _STRIP.sub("", hlo.compiled_hlo(f, y, x0, 0.0)) \
-            == _STRIP.sub("", base_f)
-        assert _STRIP.sub("", hlo.compiled_hlo(g, y, x0, 0.0)) \
-            == _STRIP.sub("", base_g)
+        assert _strip(hlo.compiled_hlo(f, y, x0, 0.0)) \
+            == _strip(base_f)
+        assert _strip(hlo.compiled_hlo(g, y, x0, 0.0)) \
+            == _strip(base_g)
         os.environ.pop("PYLOPS_MPI_TPU_AUTODIFF")
     # concrete host entries never intercept even with the knob on
     os.environ["PYLOPS_MPI_TPU_AUTODIFF"] = "on"

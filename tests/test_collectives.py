@@ -11,7 +11,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from pylops_mpi_tpu.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from pylops_mpi_tpu.parallel import collectives as C

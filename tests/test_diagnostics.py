@@ -322,6 +322,21 @@ def test_peak_tables_match_bench():
     assert costmodel.peak_flops("v4", "f32_highest") == 275e12 / 6
 
 
+def test_bench_unknown_device_kind_is_an_error():
+    """A TPU the peak tables do not hold gets no assumed peak."""
+    import bench
+    from types import SimpleNamespace
+    v5e = SimpleNamespace(device_kind="TPU v5 lite", platform="tpu")
+    assert bench._peak_flops_per_chip(v5e, "bf16") == 197e12
+    assert bench._peak_flops_per_chip(v5e, "f32_highest") == 197e12 / 6
+    assert bench._peak_hbm_gbps(v5e) == 819.0
+    odd = SimpleNamespace(device_kind="TPU v99", platform="tpu")
+    with pytest.raises(ValueError, match="TPU v99"):
+        bench._peak_flops_per_chip(odd)
+    with pytest.raises(ValueError, match="TPU v99"):
+        bench._peak_hbm_gbps(odd)
+
+
 # --------------------------------------------------------------- telemetry
 def test_telemetry_off_by_default():
     assert not telemetry.telemetry_enabled()
@@ -484,35 +499,25 @@ def test_cpu_sim_cgls_emits_full_chrome_trace(monkeypatch, tmp_path,
 
 # ------------------------------------------- budgets and deadline runner
 def test_stage_budget_table_and_overrides(monkeypatch):
-    assert profiler.stage_budget("flagship_full") == 3000
-    assert profiler.stage_budget("flagship_full", rehearse=True) == 2400
-    assert profiler.stage_budget("breakdown", rehearse=True) == 700
-    monkeypatch.setenv("PROBE_FULL_TIMEOUT", "123")
-    assert profiler.stage_budget("flagship_full") == 123
-    monkeypatch.setenv("PROBE_FULL_TIMEOUT", "not-a-number")
-    assert profiler.stage_budget("flagship_full") == 3000
+    assert profiler.stage_budget("tune") == 600
+    assert profiler.stage_budget("component") == 150
+    monkeypatch.setenv("PROBE_TUNE_TIMEOUT", "123")
+    assert profiler.stage_budget("tune") == 123
+    monkeypatch.setenv("PROBE_TUNE_TIMEOUT", "not-a-number")
+    assert profiler.stage_budget("tune") == 600
+    monkeypatch.setenv("BENCH_COMPONENT_TIMEOUT", "77")
+    assert profiler.stage_budget("component") == 77
     with pytest.raises(KeyError):
         profiler.stage_budget("no_such_stage")
 
 
-def test_budget_table_consumed_by_bench_and_probe_loop(monkeypatch):
-    """The 900 s-class limits live in ONE place: bench.py and the
-    probe daemon both resolve through the central table."""
-    import bench
-    mod = bench._profiler_mod()
-    assert mod is not None
-    assert mod.STAGE_BUDGETS == profiler.STAGE_BUDGETS
-    assert bench._stage_budget("bench_selfcheck", 0) == \
-        profiler.stage_budget("bench_selfcheck")
-    import sys
-    bdir = os.path.join(os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))), "benchmarks")
-    monkeypatch.syspath_prepend(bdir)
-    import tpu_probe_loop
-    assert tpu_probe_loop._budget("breakdown") == \
-        profiler.stage_budget("breakdown")
-    monkeypatch.setenv("PROBE_BREAKDOWN_TIMEOUT", "77")
-    assert tpu_probe_loop._budget("breakdown") == 77
+def test_budget_table_is_one_flat_column():
+    """One budget per stage: no second column for a CPU rehearsal of a
+    harvest ladder that no longer exists."""
+    assert profiler.STAGE_BUDGETS
+    assert all(isinstance(v, int) for v in profiler.STAGE_BUDGETS.values())
+    from pylops_mpi_tpu.tuning.search import tune_budget_s
+    assert tune_budget_s() == profiler.stage_budget("tune")
 
 
 def test_deadline_runner_runs_and_records():

@@ -1,9 +1,8 @@
 """Fleet observability suite (ISSUE 10): the metrics registry (zero-
 cost off, HLO pins, heartbeat embedding, atomic snapshots), cross-
 worker trace aggregation (clock alignment, per-collective skew +
-straggler attribution, killed-worker hardening), the diagnostics CLI,
-the supervisor's ``job_report.json``, and the bench regression
-sentinel.
+straggler attribution, killed-worker hardening), the diagnostics CLI and
+the supervisor's ``job_report.json``.
 
 The quick tests drive synthetic traces and jax-free ``python -c``
 workers; the real 2-process supervised smoke lives in the
@@ -425,77 +424,6 @@ def test_job_report_written_on_terminal_failure(tmp_path):
     assert [f["kind"] for f in doc["failures"]] == ["exit"]
 
 
-# ---------------------------------------------------- regression sentinel
-def _flagship_row():
-    return json.load(open(os.path.join(ROOT, "BENCH_r05.json")))["parsed"]
-
-
-def _run_sentinel(row, *extra):
-    import tempfile
-    with tempfile.NamedTemporaryFile("w", suffix=".json",
-                                     delete=False) as f:
-        json.dump(row, f)
-        path = f.name
-    try:
-        p = subprocess.run(
-            [sys.executable, os.path.join(ROOT, "bench.py"),
-             "--sentinel-artifact", path, *extra],
-            capture_output=True, text=True, cwd=ROOT)
-        line = json.loads(p.stdout.strip().splitlines()[-1])
-        return p.returncode, line
-    finally:
-        os.unlink(path)
-
-
-def test_sentinel_clean_flagship_passes():
-    rc, line = _run_sentinel(_flagship_row())
-    assert rc == 0 and line["regressed"] is False
-    assert line["sentinel"]["status"] == "ok"
-    assert line["sentinel"]["n_history"] >= 1
-
-
-def test_sentinel_trips_on_20pct_slowdown():
-    row = dict(_flagship_row())
-    row["value"] = row["value"] * 0.80
-    rc, line = _run_sentinel(row)
-    assert rc == 1 and line["regressed"] is True
-    assert line["sentinel"]["status"] == "regressed"
-    assert line["sentinel"]["ratio"] == pytest.approx(0.8, abs=0.01)
-
-
-def test_sentinel_tolerance_knob():
-    row = dict(_flagship_row())
-    row["value"] = row["value"] * 0.80
-    rc, line = _run_sentinel(row, "--sentinel-tol", "0.30")
-    assert rc == 0 and line["regressed"] is False
-
-
-def test_sentinel_new_bucket_is_no_history():
-    row = dict(_flagship_row())
-    row["metric"] = "CGLS iters/sec (some brand-new methodology)"
-    rc, line = _run_sentinel(row)
-    assert rc == 0 and line["sentinel"]["status"] == "no-history"
-
-
-def test_sentinel_compact_line_stamp(monkeypatch):
-    """In-process: the compact-line builder stamps ``regressed`` (and
-    sheds the detail dict first under the 2 KB cap)."""
-    import importlib.util
-    spec = importlib.util.spec_from_file_location(
-        "_bench_sentinel", os.path.join(ROOT, "bench.py"))
-    bench = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(bench)
-    row = dict(_flagship_row())
-    row["value"] = row["value"] * 0.5
-    verdict = bench._sentinel_check(row, bench._load_bench_history(),
-                                    0.15)
-    assert verdict["regressed"] is True
-    row["sentinel"] = verdict
-    compact = bench._compact_line(row)
-    assert compact["regressed"] is True
-    assert len(json.dumps(compact)) <= 2000
-
-
 # ------------------------------------------------- fleet-smoke acceptance
 @pytest.mark.slow
 def test_fleet_smoke_aggregation_names_straggler(tmp_path):
@@ -516,7 +444,7 @@ def test_fleet_smoke_aggregation_names_straggler(tmp_path):
            "XLA_FLAGS": " ".join(
                f for f in os.environ.get("XLA_FLAGS", "").split()
                if "force_host_platform_device_count" not in f)}
-    budget = stage_budget("multihost_chaos", rehearse=True)
+    budget = stage_budget("multihost_chaos")
     r = launch_job([os.path.join(ROOT, "tests", "fleet_obs_worker.py")],
                    2, heartbeat_interval=0.4, job_timeout_s=budget,
                    env=env, logdir=logdir)

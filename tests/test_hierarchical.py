@@ -33,7 +33,7 @@ from jax.sharding import PartitionSpec as PSpec
 
 import pylops_mpi_tpu as pmt
 from pylops_mpi_tpu import DistributedArray, MPIMatrixMult
-from pylops_mpi_tpu.jaxcompat import shard_map
+from jax import shard_map
 from pylops_mpi_tpu.parallel import collectives as C
 from pylops_mpi_tpu.parallel.mesh import make_mesh, make_mesh_hybrid
 from pylops_mpi_tpu.diagnostics import costmodel, metrics

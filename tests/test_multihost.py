@@ -43,10 +43,8 @@ def test_two_process_distributed_solve():
     r = launch_job([WORKER, "{port}", "{rank}"], 2,
                    max_relaunches=0,
                    heartbeat_interval=1.0,
-                   grace_s=stage_budget("multihost_init",
-                                        rehearse=True),
-                   job_timeout_s=stage_budget("multihost_chaos",
-                                              rehearse=True),
+                   grace_s=stage_budget("multihost_init"),
+                   job_timeout_s=stage_budget("multihost_chaos"),
                    env=env)
     assert r.ok, (r.failures,
                   {k: v[-3000:] for k, v in r.outputs.items()})

@@ -23,7 +23,7 @@ import jax.numpy as jnp
 
 import pylops_mpi_tpu as pmt
 from pylops_mpi_tpu import DistributedArray, MPIMatrixMult, MPIFFTND
-from pylops_mpi_tpu.jaxcompat import shard_map
+from jax import shard_map
 from jax.sharding import PartitionSpec as PSpec
 from pylops_mpi_tpu.parallel import collectives as C
 from pylops_mpi_tpu.parallel.mesh import make_mesh

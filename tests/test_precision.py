@@ -6,8 +6,8 @@ Three properties are pinned here, in CI, instead of asserted in prose:
    fused CGLS program may widen each A tile at the GEMM operand — at
    most 2 tile-shaped converts per iteration (matvec + rmatvec) inside
    the while body — and the solver's model/residual vectors are NEVER
-   rounded to bf16 (the recurrence contamination behind the round-5
-   ``bf16_race`` 40× cliff, BENCH_r05.json).
+   rounded to bf16 (per-iteration vector rounding contaminates the
+   Krylov recurrence).
 2. **Donation**: the fused solver entries donate the model vector; the
    compiled program must carry an ``input_output_alias`` for it and no
    ``copy`` of the donated parameter.

@@ -606,7 +606,7 @@ def test_serve_knobs_registered_and_budgets_present():
         assert knob in names, f"{knob} missing from deps.KNOBS"
     assert "serve_batch" in STAGE_BUDGETS
     assert "serve_smoke" in STAGE_BUDGETS
-    assert stage_budget("serve_batch", rehearse=True) == 60
+    assert stage_budget("serve_batch") == 120
 
 
 def test_window_knob_parsing(monkeypatch):
@@ -736,7 +736,7 @@ def test_serve_forever_smoke_survives_worker_kill(tmp_path, rng):
             spool.request_drain(spool_dir)
             drained.append(done)
 
-    budget = stage_budget("serve_smoke", rehearse=True)
+    budget = stage_budget("serve_smoke")
     r = serving.serve_job(
         [os.path.join(ROOT, "tests", "serving_worker.py")], 2,
         spool_dir=spool_dir, max_relaunches=2,

@@ -167,7 +167,7 @@ def test_watchdog_nested_runs_direct(monkeypatch):
 def test_watchdog_timeout_resolution(monkeypatch):
     # stage budget row ("tpu" column) is the default deadline
     assert watchdog_timeout("multihost_init") == \
-        STAGE_BUDGETS["multihost_init"]["tpu"]
+        STAGE_BUDGETS["multihost_init"]
     monkeypatch.setenv("PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT", "7.5")
     assert watchdog_timeout("multihost_init") == 7.5
     assert watchdog_timeout("checkpoint_io") == 7.5  # global override
@@ -176,9 +176,7 @@ def test_watchdog_timeout_resolution(monkeypatch):
 def test_new_stages_in_budget_table():
     for stage in ("multihost_init", "checkpoint_io", "multihost_chaos"):
         assert stage in STAGE_BUDGETS
-        assert stage_budget(stage) == STAGE_BUDGETS[stage]["tpu"]
-        assert stage_budget(stage, rehearse=True) == \
-            STAGE_BUDGETS[stage]["rehearse"]
+        assert stage_budget(stage) == STAGE_BUDGETS[stage]
 
 
 def test_unknown_watchdog_mode_warns_once(monkeypatch):
@@ -494,7 +492,7 @@ def test_chaos_kill_recover_resume(tmp_path):
                 w.proc.send_signal(signal.SIGSTOP)
                 stopped.append(time.monotonic())
 
-    budget = stage_budget("multihost_chaos", rehearse=True)
+    budget = stage_budget("multihost_chaos")
     r = launch_job([os.path.join(ROOT, "tests", "elastic_worker.py")],
                    2, heartbeat_interval=hb, stale_factor=2.0,
                    on_poll=on_poll, job_timeout_s=budget, env=env)
@@ -560,7 +558,7 @@ def test_chaos_inplace_kill_recover(tmp_path):
                     w.proc.send_signal(signal.SIGKILL)
                     killed.append(w.slot)
 
-    budget = stage_budget("multihost_chaos", rehearse=True)
+    budget = stage_budget("multihost_chaos")
     r = launch_job([os.path.join(ROOT, "tests", "elastic_worker.py")],
                    2, heartbeat_interval=0.4, stale_factor=2.0,
                    on_poll=on_poll, job_timeout_s=budget, env=env,
@@ -620,7 +618,7 @@ def test_chaos_kill_mid_reshard_falls_back(tmp_path):
                     w.proc.send_signal(signal.SIGKILL)
                     killed.append(w.slot)
 
-    budget = stage_budget("multihost_chaos", rehearse=True)
+    budget = stage_budget("multihost_chaos")
     r = launch_job([os.path.join(ROOT, "tests", "elastic_worker.py")],
                    2, heartbeat_interval=0.4, stale_factor=2.0,
                    on_poll=on_poll, job_timeout_s=budget, env=env,
@@ -676,7 +674,7 @@ def test_chaos_kill_mid_spill_falls_back(tmp_path):
                     w.proc.send_signal(signal.SIGKILL)
                     killed.append(w.slot)
 
-    budget = stage_budget("multihost_chaos", rehearse=True)
+    budget = stage_budget("multihost_chaos")
     r = launch_job([os.path.join(ROOT, "tests", "elastic_worker.py")],
                    2, heartbeat_interval=0.4, stale_factor=2.0,
                    on_poll=on_poll, job_timeout_s=budget, env=env,
