@@ -186,10 +186,9 @@ PAGES = {
          "pylops_mpi_tpu.diagnostics.telemetry",
          ["telemetry_enabled", "telemetry_signature", "iteration",
           "history", "clear_history"]),
-        ("Profiler hooks and harvest budgets",
+        ("Stage budgets and the deadline runner",
          "pylops_mpi_tpu.diagnostics.profiler",
-         ["stage_budget", "DeadlineRunner", "profile_capture",
-          "profile_dir"]),
+         ["stage_budget", "DeadlineRunner"]),
         ("Fleet metrics registry",
          "pylops_mpi_tpu.diagnostics.metrics",
          ["metrics_mode", "metrics_enabled", "metrics_file",

@@ -8,7 +8,9 @@ time, bytes and iterations actually go. Four modules make the folklore first-cla
   (context-manager API, nested spans, thread-safe ring buffer) emitting
   Chrome-trace-event JSONL, gated by ``PYLOPS_MPI_TPU_TRACE``; wired
   through every operator ``matvec``/``rmatvec``, the hand-scheduled
-  collectives, and the solver entry points.
+  collectives, and the solver entry points. The same spans always
+  land on the profiler's clock (``pmt.*`` named scopes under a jit
+  trace, ``TraceAnnotation`` outside it).
 - :mod:`~pylops_mpi_tpu.diagnostics.costmodel` — per-op cost registry
   (FLOPs, HBM bytes, ICI bytes per apply) generalizing the comm-volume
   model previously private to ``ops/matrixmult.py``'s auto-select,
@@ -19,10 +21,10 @@ time, bytes and iterations actually go. Four modules make the folklore first-cla
   ``while_loop``\\ s via ``jax.debug.callback``; off by default, with
   an HLO pin (``utils/hlo.py::assert_no_host_callbacks``) proving the
   donated/fused hot path carries zero host callbacks when disabled.
-- :mod:`~pylops_mpi_tpu.diagnostics.profiler` — ``jax.profiler``
-  trace-capture hooks plus the deadline-aware stage runner and the
-  central per-stage wall-budget table (tuner searches, benchmark
-  components, watched multi-host phases, serving batches).
+- :mod:`~pylops_mpi_tpu.diagnostics.profiler` — the deadline-aware
+  stage runner and the central per-stage wall-budget table (tuner
+  searches, benchmark components, watched multi-host phases, serving
+  batches).
 
 Fleet observability (ISSUE 10) adds the cross-process half:
 
@@ -56,8 +58,7 @@ from .costmodel import (OpCost, estimate, register_cost, roofline,
                         device_peaks)
 from .telemetry import (telemetry_enabled, iteration, history,
                         clear_history, telemetry_signature)
-from .profiler import (STAGE_BUDGETS, stage_budget, DeadlineRunner,
-                       profile_capture)
+from .profiler import STAGE_BUDGETS, stage_budget, DeadlineRunner
 from .metrics import (metrics_mode, metrics_enabled, inc, set_gauge,
                       observe, timer, snapshot, clear_metrics,
                       write_snapshot, read_snapshot)
@@ -78,5 +79,5 @@ __all__ = [
     "peak_hbm_gbps", "peak_ici_gbps", "device_peaks",
     "telemetry_enabled", "iteration", "history", "clear_history",
     "telemetry_signature",
-    "STAGE_BUDGETS", "stage_budget", "DeadlineRunner", "profile_capture",
+    "STAGE_BUDGETS", "stage_budget", "DeadlineRunner",
 ]

@@ -1,16 +1,15 @@
-"""Profiler hooks and the deadline-aware stage runner.
+"""The deadline-aware stage runner.
 
-1. :func:`profile_capture` — ``jax.profiler`` trace-capture around a
-   region (the XLA/device-level view the host-side span tracer cannot
-   give), gated by an env dir so any region can be captured without
-   code changes.
+:class:`DeadlineRunner` + :data:`STAGE_BUDGETS` — the central
+per-stage wall-budget table (tuner searches, benchmark components,
+watched multi-host phases, serving batches) and a runner that (a)
+caps each stage's timeout at ``min(budget, window remaining)``, (b)
+records whether a killed stage still BANKED a partial artifact, and
+(c) SKIPS stages the remaining window cannot fit.
 
-2. :class:`DeadlineRunner` + :data:`STAGE_BUDGETS` — the central
-   per-stage wall-budget table (tuner searches, benchmark components,
-   watched multi-host phases, serving batches) and a runner that (a)
-   caps each stage's timeout at ``min(budget, window remaining)``, (b)
-   records whether a killed stage still BANKED a partial artifact, and
-   (c) SKIPS stages the remaining window cannot fit.
+(The device-level view is ``jax.profiler.trace(dir)`` around any
+region: the program's spans land in it by the rule of
+``diagnostics/trace.py``.)
 
 STANDALONE-LOADABLE BY DESIGN: module-level imports are stdlib only
 and there are no relative imports, so a jax-free supervisor process
@@ -27,7 +26,7 @@ import time
 from typing import Callable, Dict, List, Optional
 
 __all__ = ["STAGE_BUDGETS", "stage_budget", "DeadlineRunner",
-           "StageRecord", "profile_capture", "profile_dir"]
+           "StageRecord"]
 
 
 # ------------------------------------------------------------ budget table
@@ -196,58 +195,6 @@ class DeadlineRunner:
             "remaining_s": (None if self.deadline_ts is None
                             else round(self.remaining(), 1)),
         }
-
-
-# ------------------------------------------------------------ jax.profiler
-def profile_dir() -> Optional[str]:
-    """``PYLOPS_MPI_TPU_PROFILE_DIR`` — when set, the solvers' /
-    bench's :func:`profile_capture` regions actually capture; unset
-    (default) they are no-ops."""
-    return os.environ.get("PYLOPS_MPI_TPU_PROFILE_DIR") or None
-
-
-class _NoopCapture:
-    def __enter__(self):
-        return self
-
-    def __exit__(self, *exc):
-        return False
-
-
-def profile_capture(name: str, logdir: Optional[str] = None):
-    """Context manager: capture a ``jax.profiler`` trace of the region
-    into ``logdir`` (default: ``$PYLOPS_MPI_TPU_PROFILE_DIR/<name>``;
-    no-op when neither is set, or when the profiler cannot start —
-    e.g. a second concurrent capture). TensorBoard/XProf-compatible;
-    this is the DEVICE-side complement of the host-side span tracer
-    (``diagnostics/trace.py``)."""
-    base = logdir or profile_dir()
-    if not base:
-        return _NoopCapture()
-    path = os.path.join(base, name) if logdir is None else logdir
-
-    class _Capture:
-        def __enter__(self):
-            self._on = False
-            try:
-                import jax.profiler
-                os.makedirs(path, exist_ok=True)
-                jax.profiler.start_trace(path)
-                self._on = True
-            except Exception:
-                pass  # profiling must never break the workload
-            return self
-
-        def __exit__(self, *exc):
-            if self._on:
-                try:
-                    import jax.profiler
-                    jax.profiler.stop_trace()
-                except Exception:
-                    pass
-            return False
-
-    return _Capture()
 
 
 # convenience for scripts that bank runner reports next to artifacts

@@ -12,26 +12,40 @@ previously went to stdout/logging (``resolve_chunks`` fallbacks, SUMMA
 schedule selection) land here as instant events, so they ride in the
 JSONL artifact instead of scrolling away.
 
-Gating — ``PYLOPS_MPI_TPU_TRACE``:
+Two sinks, one rule (:func:`span`, which :func:`op_span` ends in):
 
-- ``off`` (default): every entry point returns a shared no-op; the
-  only cost is one env lookup per call. Nothing is ever added to a
-  traced program, so compiled HLO is BIT-IDENTICAL to untraced runs
-  (the exact-equality overlap/precision suites pin this).
+1. **The profiler's clock, always.** Under a jax trace the span enters
+   ``jax.named_scope("pmt." + name)``: trace-time only, nothing at run
+   time, and every op lowered inside carries the name in its HLO
+   ``op_name``, which a device trace prints (``pmt.MPIBlockDiag.matvec``
+   inside a fused solver's ``while_loop``, ``pmt.collective.ring_pass``
+   …). Outside a jax trace it enters
+   ``jax.profiler.TraceAnnotation("pmt." + name, **ids)`` (``ids``: the
+   ``int``/``str`` tags), a host span in whatever ``jax.profiler``
+   session is running and a flag test in C++ when none is. Named scopes
+   are provenance (``metadata={op_name=…}``), never instructions:
+   compare programs through ``utils/hlo.py::strip_provenance``. JAX's
+   compilation-cache key ignores metadata, so a cache filled before the
+   scopes existed serves unnamed programs.
+2. **The ring buffer, gated** by ``PYLOPS_MPI_TPU_TRACE``:
+
+- ``off`` (default): no event is recorded and no flush handler is
+  installed; the cost per call is one env lookup and one annotation
+  object (sink 1).
 - ``spans``: operator / collective / solver spans and structured
-  events are recorded.
+  events are recorded (same names without the ``pmt.`` prefix).
 - ``full``: additionally enables the in-loop solver telemetry
   (:mod:`.telemetry` — per-iteration residual norms via
   ``jax.debug.callback``; the only mode that changes compiled
   programs).
 
-Timestamp semantics: spans record HOST wall-clock (``perf_counter_ns``
-relative to process start). A span around code running under a ``jit``
-trace measures *trace time*, not device time — such spans are tagged
-``"jax_tracing": true``; they still carry the schedule metadata
-(shapes, chunk counts, byte estimates), which is their real payload.
-Device-side timing belongs to :mod:`.profiler`'s ``jax.profiler``
-capture.
+Timestamp semantics of the ring buffer: spans record HOST wall-clock
+(``perf_counter_ns`` relative to process start). A span around code
+running under a ``jit`` trace measures *trace time*, not device time —
+such spans are tagged ``"jax_tracing": true``; they still carry the
+schedule metadata (shapes, chunk counts, byte estimates), which is
+their real payload. Device-side timing comes from sink 1: take a
+``jax.profiler.trace(dir)`` around the region.
 
 Events are Chrome trace-event dicts (``ph`` ``X``/``i``/``C``), one
 JSON object per line when dumped (``dump(path)``); set
@@ -143,13 +157,11 @@ def _jsonable(v):
 
 def _jax_tracing() -> bool:
     """True when called under an active jax trace (jit/shard_map/vmap
-    tracing pass) — spans recorded there measure trace time, and are
-    tagged so readers never mistake them for device time."""
-    try:
-        import jax.core
-        return not jax.core.trace_state_clean()
-    except Exception:
-        return False
+    tracing pass) — ring-buffer spans recorded there measure trace
+    time, and are tagged so readers never mistake them for device
+    time; on the profiler's clock they become named scopes."""
+    import jax
+    return not jax.core.trace_ctx.is_top_level()
 
 
 def _ensure_flush_handlers() -> None:
@@ -197,33 +209,51 @@ def _atexit_dump() -> None:
             pass  # a failed flush must never mask the real exit status
 
 
-class _NoopSpan:
-    """Shared do-nothing context manager — the entire cost of tracing
-    when ``PYLOPS_MPI_TPU_TRACE=off`` (beyond the mode lookup)."""
+PREFIX = "pmt."
 
-    __slots__ = ()
+
+def _annotation(name: str, tags: Dict, tracing: bool):
+    """Sink 1: the context manager that puts ``name`` on the
+    profiler's clock — a named scope under a jax trace, a
+    ``TraceAnnotation`` carrying the ``int``/``str`` tags outside it."""
+    import jax
+    if tracing:
+        return jax.named_scope(PREFIX + name)
+    return jax.profiler.TraceAnnotation(
+        PREFIX + name,
+        **{k: v for k, v in tags.items() if type(v) in (int, str)})
+
+
+class _ProfilerSpan:
+    """What :func:`span` returns in ``off`` mode: sink 1 alone."""
+
+    __slots__ = ("_prof",)
+
+    def __init__(self, prof):
+        self._prof = prof
 
     def __enter__(self):
+        self._prof.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._prof.__exit__(*exc)
         return False
 
     def tag(self, **tags):
         return self
 
 
-_NOOP = _NoopSpan()
-
-
-class _Span:
-    """One open span: records a Chrome ``ph="X"`` (complete) event on
-    exit, carrying its nesting depth and parent name so span trees can
-    be rebuilt from the flat buffer (``span_tree``)."""
+class _Span(_ProfilerSpan):
+    """One open span: besides sink 1, records a Chrome ``ph="X"``
+    (complete) event on exit, carrying its nesting depth and parent
+    name so span trees can be rebuilt from the flat buffer
+    (``span_tree``)."""
 
     __slots__ = ("name", "args", "t0", "_depth", "_parent", "_tid")
 
-    def __init__(self, name: str, args: Dict):
+    def __init__(self, name: str, args: Dict, prof):
+        super().__init__(prof)
         self.name = name
         self.args = args
         self.t0 = 0.0
@@ -249,9 +279,11 @@ class _Span:
         with _LOCK:
             _OPEN[id(self)] = self
             _ensure_flush_handlers()  # flush even if we never close
+        self._prof.__enter__()
         return self
 
     def __exit__(self, *exc):
+        self._prof.__exit__(*exc)
         t1 = _now_us()
         stack = getattr(_tls, "stack", ())
         if stack and stack[-1] is self:
@@ -270,38 +302,46 @@ class _Span:
 
 
 def span(name: str, cat: str = "span", **tags):
-    """Open a traced span (context manager). No-op when tracing is
-    off. ``tags`` become the Chrome event's ``args``; tags are
+    """Open a span (context manager) on the profiler's clock — the
+    module docstring's rule — and, unless tracing is ``off``, in the
+    ring buffer. ``tags`` become the Chrome event's ``args``; tags are
     JSON-sanitized so arbitrary shapes/dtypes/meshes are safe to
     pass. Spans nest: each records its depth and parent name."""
-    if trace_mode() == "off":
-        return _NOOP
+    return _open(name, cat, tags, trace_mode())
+
+
+def _open(name: str, cat: str, tags: Dict, mode: str):
+    tracing = _jax_tracing()
+    prof = _annotation(name, tags, tracing)
+    if mode == "off":
+        return _ProfilerSpan(prof)
     args = {k: _jsonable(v) for k, v in tags.items()}
-    if _jax_tracing():
+    if tracing:
         args["jax_tracing"] = True
     args["cat"] = cat
-    return _Span(name, args)
+    return _Span(name, args, prof)
 
 
 def op_span(op, which: str):
     """Span for one operator apply — the wiring point used by
     ``MPILinearOperator.matvec``/``rmatvec``. Tags: operator class,
     operator shape, dtype, mesh axis names, and (when the operator
-    carries them) overlap mode / schedule / grid. Returns the shared
-    no-op when tracing is off so the eager hot path pays only the mode
-    lookup."""
-    if trace_mode() == "off":
-        return _NOOP
-    tags = {"op": type(op).__name__, "shape": getattr(op, "shape", None),
-            "dtype": getattr(op, "dtype", None)}
-    mesh = getattr(op, "mesh", None)
-    if mesh is not None:
-        tags["mesh_axes"] = getattr(mesh, "axis_names", None)
-    for extra in ("overlap", "schedule", "grid", "compute_dtype"):
-        v = getattr(op, extra, None)
-        if v is not None:
-            tags[extra] = v
-    return span(f"{type(op).__name__}.{which}", cat="operator", **tags)
+    carries them) overlap mode / schedule / grid. With tracing off
+    the tags have no reader and are not gathered."""
+    mode = trace_mode()
+    tags = {}
+    if mode != "off":
+        tags = {"op": type(op).__name__,
+                "shape": getattr(op, "shape", None),
+                "dtype": getattr(op, "dtype", None)}
+        mesh = getattr(op, "mesh", None)
+        if mesh is not None:
+            tags["mesh_axes"] = getattr(mesh, "axis_names", None)
+        for extra in ("overlap", "schedule", "grid", "compute_dtype"):
+            v = getattr(op, extra, None)
+            if v is not None:
+                tags[extra] = v
+    return _open(f"{type(op).__name__}.{which}", "operator", tags, mode)
 
 
 def event(name: str, cat: str = "event", **tags) -> None:

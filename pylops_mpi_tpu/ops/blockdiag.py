@@ -292,25 +292,27 @@ class MPIBlockDiag(MPILinearOperator):
             from .pallas_kernels import batched_normal_matvec as kernel
         else:  # mismatched-dtype x, or complex without the FFI kernel
             return super().normal_matvec(x)
-        A = self._batched
-        nblk, m, n = A.shape
-        X = x.array.reshape(nblk, n)
-        axis = self.mesh.axis_names[0]
-        U, Q = shard_map(kernel, mesh=self.mesh,
-                         in_specs=(P(axis), P(axis)),
-                         out_specs=(P(axis), P(axis)),
-                         check_vma=False)(A, X)
-        u = DistributedArray(global_shape=self.shape[1], mesh=self.mesh,
-                             partition=x.partition, axis=0,
-                             local_shapes=self.local_shapes_m,
-                             mask=self.mask, dtype=U.dtype)
-        u[:] = U.reshape(-1)
-        q = DistributedArray(global_shape=self.shape[0], mesh=self.mesh,
-                             partition=x.partition, axis=0,
-                             local_shapes=self.local_shapes_n,
-                             mask=self.mask, dtype=Q.dtype)
-        q[:] = Q.reshape(-1)
-        return u, q
+        from ..diagnostics import trace
+        with trace.op_span(self, "normal_matvec"):
+            A = self._batched
+            nblk, m, n = A.shape
+            X = x.array.reshape(nblk, n)
+            axis = self.mesh.axis_names[0]
+            U, Q = shard_map(kernel, mesh=self.mesh,
+                             in_specs=(P(axis), P(axis)),
+                             out_specs=(P(axis), P(axis)),
+                             check_vma=False)(A, X)
+            u = DistributedArray(global_shape=self.shape[1], mesh=self.mesh,
+                                 partition=x.partition, axis=0,
+                                 local_shapes=self.local_shapes_m,
+                                 mask=self.mask, dtype=U.dtype)
+            u[:] = U.reshape(-1)
+            q = DistributedArray(global_shape=self.shape[0], mesh=self.mesh,
+                                 partition=x.partition, axis=0,
+                                 local_shapes=self.local_shapes_n,
+                                 mask=self.mask, dtype=Q.dtype)
+            q[:] = Q.reshape(-1)
+            return u, q
 
 
 class MPIStackedBlockDiag(MPIStackedLinearOperator):

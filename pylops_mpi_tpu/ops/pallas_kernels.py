@@ -138,6 +138,7 @@ def stencil_taps(slab: jax.Array, taps, w: int,
         out_specs=pl.BlockSpec((out_rows, tile), lambda j: (0, j)),
         out_shape=jax.ShapeDtypeStruct((out_rows, cols), slab.dtype),
         interpret=_interpret(),
+        name="pmt_taps",
     )(slab2)
     return y2.reshape((out_rows,) + shp[1:])
 
@@ -281,5 +282,6 @@ def batched_normal_matvec(A: jax.Array, X: jax.Array):
         out_shape=[jax.ShapeDtypeStruct((nblk, 1, n), out_dtype),
                    jax.ShapeDtypeStruct((nblk, m, 1), out_dtype)],
         interpret=_interpret(),
+        name="pmt_normal_stream" if stream else "pmt_normal",
     )(A, X[:, None, :])
     return u[:, 0, :], q[:, :, 0]

@@ -40,7 +40,7 @@ from __future__ import annotations
 import os
 import threading
 import time
-from dataclasses import dataclass
+from dataclasses import dataclass, field
 from typing import Dict, Optional, Sequence, Tuple
 
 import numpy as np
@@ -161,6 +161,8 @@ class BlockOutcome:
     k: int                        # real fill
     bucket: int                   # compiled width actually run
     wall_s: float
+    # seconds in each of the pool's stages: stage_in, solve, pull
+    stage_s: Dict[str, float] = field(default_factory=dict)
 
 
 def _column_statuses(kold: np.ndarray, tol: float) -> Tuple[str, ...]:
@@ -223,35 +225,44 @@ class WarmPool:
         return tuple(sorted(self._families))
 
     # ------------------------------------------------------------ solve
-    def solve(self, name: str, Y: np.ndarray) -> BlockOutcome:
+    def solve(self, name: str, Y: np.ndarray,
+              batch: Optional[int] = None) -> BlockOutcome:
         """Solve ``Y``'s ``k`` columns as one padded block program of
         the next-larger bucket width. ``Y`` is ``(N, k)`` (a 1-D ``y``
-        is treated as ``k=1``)."""
+        is treated as ``k=1``). Three stage spans — ``serve.stage_in``,
+        ``serve.solve``, ``serve.pull`` — carry ``batch``, the
+        dispatcher's batch number, and their seconds come back in
+        ``BlockOutcome.stage_s``."""
         from ..solvers.block import block_cg, block_cgls
         spec = self.family(name)
-        Y = np.asarray(Y, dtype=np.dtype(spec.dtype))
-        if Y.ndim == 1:
-            Y = Y[:, None]
-        N, k = Y.shape
-        if N != spec.nrows:
-            raise ValueError(
-                f"family {name!r} expects data length {spec.nrows}, "
-                f"got {N}")
-        bucket = bucket_for(k, self._buckets)
-        if k > bucket:
-            raise ValueError(
-                f"fill {k} exceeds the largest bucket {bucket}; "
-                "dispatch at most k_max columns per batch")
-        if bucket > k:
-            Y = np.concatenate(
-                [Y, np.zeros((N, bucket - k), dtype=Y.dtype)], axis=1)
-        yb = DistributedArray(global_shape=(N, bucket),
-                              dtype=np.dtype(spec.dtype))
-        yb[:] = Y
-        t0 = time.perf_counter()
-        with self._lock, _trace.span("serve.pool_solve", cat="serving",
+        ids = {} if batch is None else {"batch": batch}
+        t0 = time.monotonic()
+        with _trace.span("serve.stage_in", cat="serving", **ids):
+            Y = np.asarray(Y, dtype=np.dtype(spec.dtype))
+            if Y.ndim == 1:
+                Y = Y[:, None]
+            N, k = Y.shape
+            if N != spec.nrows:
+                raise ValueError(
+                    f"family {name!r} expects data length {spec.nrows}, "
+                    f"got {N}")
+            bucket = bucket_for(k, self._buckets)
+            if k > bucket:
+                raise ValueError(
+                    f"fill {k} exceeds the largest bucket {bucket}; "
+                    "dispatch at most k_max columns per batch")
+            if bucket > k:
+                Y = np.concatenate(
+                    [Y, np.zeros((N, bucket - k), dtype=Y.dtype)], axis=1)
+            yb = DistributedArray(global_shape=(N, bucket),
+                                  dtype=np.dtype(spec.dtype))
+            yb[:] = Y
+        t1 = time.monotonic()
+        # the wrappers return host values (``int(iiter)``, the cost
+        # history), so the answer is ready on the device at span exit
+        with self._lock, _trace.span("serve.solve", cat="serving",
                                      family=name, fill=k, bucket=bucket,
-                                     solver=spec.solver):
+                                     solver=spec.solver, **ids):
             if spec.solver == "cg":
                 xb, iiter, cost = block_cg(
                     spec.operator, yb, niter=spec.niter, tol=spec.tol,
@@ -261,15 +272,19 @@ class WarmPool:
                 xb, _istop, iiter, kold, _r2, _cost = block_cgls(
                     spec.operator, yb, niter=spec.niter,
                     damp=spec.damp, tol=spec.tol, M=spec.M)
-        wall = time.perf_counter() - t0
+        t2 = time.monotonic()
+        with _trace.span("serve.pull", cat="serving", **ids):
+            x = np.asarray(xb.array)[:, :k]
+            statuses = _column_statuses(kold, spec.tol)[:k]
+        t3 = time.monotonic()
         self.warmed.add((name, bucket))
         _WARMED_SIGS.add((spec.signature(), bucket))
         _metrics.inc("serve.pool.solves")
         _metrics.observe("serve.batch.fill", k / bucket)
-        x = np.asarray(xb.array)[:, :k]
-        statuses = _column_statuses(kold, spec.tol)[:k]
         return BlockOutcome(x=x, iiter=int(iiter), statuses=statuses,
-                            k=k, bucket=bucket, wall_s=wall)
+                            k=k, bucket=bucket, wall_s=t2 - t1,
+                            stage_s={"stage_in": t1 - t0, "solve": t2 - t1,
+                                     "pull": t3 - t2})
 
     # ---------------------------------------------------------- prewarm
     def prewarm(self, names: Optional[Sequence[str]] = None,

@@ -290,7 +290,13 @@ class Dispatcher(threading.Thread):
         self.forced = 0
         self.failed = 0
         self.wait_samples: deque = deque(maxlen=4096)
+        self.admit_samples: deque = deque(maxlen=4096)
         self.fill_samples: deque = deque(maxlen=4096)
+        # cumulative seconds of the loop's six stages, taken at the
+        # boundaries of the spans of the same names (``serve.<stage>``)
+        self.stage_s: Dict[str, float] = dict.fromkeys(
+            ("collect", "pack", "stage_in", "solve", "pull", "resolve"),
+            0.0)
         self._t_solving = 0.0
         self._t_started = time.monotonic()
 
@@ -301,19 +307,33 @@ class Dispatcher(threading.Thread):
 
     def run(self) -> None:
         while not self._halt.is_set():
-            batch, forced = self.queue.collect(
-                self.pool.k_max, self.window_s,
-                margin_s=self._margin_s())
+            # the number the next batch's spans share (``batch`` stat)
+            n = self.batches + 1
+            t0 = time.monotonic()
+            with _trace.span("serve.collect", cat="serving", batch=n):
+                batch, forced = self.queue.collect(
+                    self.pool.k_max, self.window_s,
+                    margin_s=self._margin_s())
+            t1 = time.monotonic()
+            self.stage_s["collect"] += t1 - t0
             if not batch:
                 continue
+            self.admit_samples.extend(t1 - r.t_mono for r in batch)
             self._inflight.set()
             try:
-                self._dispatch(batch, forced)
+                with _trace.span("serve.batch", cat="serving", batch=n,
+                                 k=len(batch), bucket=bucket_for(
+                                     len(batch), self.pool.buckets)):
+                    self._dispatch(batch, forced, n)
             finally:
                 self._inflight.clear()
 
-    def _dispatch(self, batch: List[SolveRequest], forced: bool) -> None:
-        Y, bucket = pack(batch, self.pool.buckets)
+    def _dispatch(self, batch: List[SolveRequest], forced: bool,
+                  n: int) -> None:
+        t0 = time.monotonic()
+        with _trace.span("serve.pack", cat="serving", batch=n):
+            Y, bucket = pack(batch, self.pool.buckets)
+        self.stage_s["pack"] += time.monotonic() - t0
         k = len(batch)
         deadlines = [r.deadline_ts for r in batch
                      if r.deadline_ts is not None]
@@ -324,7 +344,7 @@ class Dispatcher(threading.Thread):
         fam = batch[0].family
 
         def _solve(_eff_timeout):
-            return self.pool.solve(fam, Y), None
+            return self.pool.solve(fam, Y, batch=n), None
 
         rec = runner.run("serve_batch", _solve, budget)
         now_mono = time.monotonic()
@@ -351,30 +371,35 @@ class Dispatcher(threading.Thread):
                 r.ticket._fail(RuntimeError(
                     f"request {r.request_id}: {reason}"))
             return
+        for stage, sec in outcome.stage_s.items():
+            self.stage_s[stage] += sec
         self._t_solving += outcome.wall_s
         self._ewma_wall = outcome.wall_s if self._ewma_wall == 0 \
             else 0.7 * self._ewma_wall + 0.3 * outcome.wall_s
         rate = k / outcome.wall_s if outcome.wall_s > 0 else 0.0
         _metrics.set_gauge("serve.solves_per_sec", rate)
-        for j, r in enumerate(batch):
-            r.ticket._resolve({
-                "x": outcome.x[:, j],
-                "iiter": outcome.iiter,
-                "status": outcome.statuses[j],
-                "wait_s": waits[j],
-                "batch_k": k,
-                "bucket": bucket,
-            })
-        _trace.event("serve.batch", cat="serving", family=fam, fill=k,
-                     bucket=bucket, forced=forced,
-                     wall_s=round(outcome.wall_s, 4))
-        if self.on_batch is not None:
-            try:
-                self.on_batch({"family": fam, "fill": k,
-                               "bucket": bucket, "forced": forced,
-                               "wall_s": outcome.wall_s})
-            except Exception:
-                pass
+        t0 = time.monotonic()
+        with _trace.span("serve.resolve", cat="serving", batch=n):
+            for j, r in enumerate(batch):
+                r.ticket._resolve({
+                    "x": outcome.x[:, j],
+                    "iiter": outcome.iiter,
+                    "status": outcome.statuses[j],
+                    "wait_s": waits[j],
+                    "batch_k": k,
+                    "bucket": bucket,
+                })
+            _trace.event("serve.batch", cat="serving", family=fam, fill=k,
+                         bucket=bucket, forced=forced,
+                         wall_s=round(outcome.wall_s, 4))
+            if self.on_batch is not None:
+                try:
+                    self.on_batch({"family": fam, "fill": k,
+                                   "bucket": bucket, "forced": forced,
+                                   "wall_s": outcome.wall_s})
+                except Exception:
+                    pass
+        self.stage_s["resolve"] += time.monotonic() - t0
 
     # ------------------------------------------------------------ stats
     def _quantile(self, samples: List[float], q: float) -> float:
@@ -386,9 +411,13 @@ class Dispatcher(threading.Thread):
 
     def stats(self) -> Dict:
         """The backpressure/autoscaling report: queue depth, admission
-        counters, batch fill, solves/sec (solve-wall basis), and
-        p50/p99 time-in-queue over the recent window."""
+        counters, batch fill, solves/sec (solve-wall basis), p50/p99
+        over the recent window of admission → batch resolved
+        (``wait_*``, the solve included) and of admission → leaving
+        the queue (``admit_wait_*``, the solve excluded), and the
+        cumulative seconds of the loop's six stages (``stage_s``)."""
         waits = list(self.wait_samples)
+        admits = list(self.admit_samples)
         fills = list(self.fill_samples)
         return {
             "queue_depth": self.queue.depth(),
@@ -404,6 +433,9 @@ class Dispatcher(threading.Thread):
                                if self._t_solving > 0 else 0.0),
             "wait_p50_s": self._quantile(waits, 0.50),
             "wait_p99_s": self._quantile(waits, 0.99),
+            "admit_wait_p50_s": self._quantile(admits, 0.50),
+            "admit_wait_p99_s": self._quantile(admits, 0.99),
+            "stage_s": dict(self.stage_s),
         }
 
     def idle(self) -> bool:

@@ -55,11 +55,10 @@ backend — XLA collectives — so the seam carries different switches:
   device list as D slices of I devices each when deciding which mesh
   axes are ICI vs DCN.
 - ``PYLOPS_MPI_TPU_TRACE`` / ``PYLOPS_MPI_TPU_TELEMETRY`` /
-  ``PYLOPS_MPI_TPU_TRACE_FILE`` / ``PYLOPS_MPI_TPU_PROFILE_DIR`` /
+  ``PYLOPS_MPI_TPU_TRACE_FILE`` /
   ``PYLOPS_MPI_TPU_METRICS`` (``_FILE``, ``_INTERVAL``): the
   observability seams (rounds 9/10) — structured span tracing, in-loop
-  solver telemetry, ``jax.profiler`` capture and the fleet metrics
-  registry. Resolved by :mod:`pylops_mpi_tpu.diagnostics` (see
+  solver telemetry and the fleet metrics registry. Resolved by :mod:`pylops_mpi_tpu.diagnostics` (see
   ``docs/observability.md``), not here, so the jax-free scripts can
   read them standalone.
 """
@@ -188,9 +187,6 @@ KNOBS = [
     ("PYLOPS_MPI_TPU_TELEMETRY", "auto|on|off", "auto",
      "diagnostics/telemetry.py",
      "in-loop solver telemetry gate under TRACE=full"),
-    ("PYLOPS_MPI_TPU_PROFILE_DIR", "path", "unset",
-     "diagnostics/profiler.py",
-     "jax.profiler capture dir for profile_capture regions"),
     ("PYLOPS_MPI_TPU_TUNE", "off|on|auto", "off",
      "tuning/plan.py (ops/*, parallel/collectives.py)",
      "autotuner seam: on replays cached/cost-model plans, auto also "

@@ -12,6 +12,7 @@ table + deadline-aware runner.
 import json
 import math
 import os
+import re
 
 import numpy as np
 import pytest
@@ -437,9 +438,10 @@ def test_hlo_callback_pin_catches_telemetry_on(monkeypatch, rng):
 
 
 def test_spans_mode_leaves_hlo_bit_identical(monkeypatch, rng):
-    """`spans` tracing is host-side only: the compiled program text is
-    IDENTICAL to the untraced build (only `full`/telemetry may change
-    programs, and those retrace via the cache key)."""
+    """`spans` tracing is host-side only: the compiled program, less
+    its provenance (source lines, scope names), is IDENTICAL to the
+    untraced build (only `full`/telemetry may change programs, and
+    those retrace via the cache key)."""
     from pylops_mpi_tpu.solvers.basic import _cgls_fused
     Op, y, _ = _mk_blockdiag(rng)
     x0 = pmt.DistributedArray.to_dist(
@@ -455,7 +457,8 @@ def test_spans_mode_leaves_hlo_bit_identical(monkeypatch, rng):
     off_text = compile_text()
     monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
     spans_text = compile_text()
-    assert off_text == spans_text
+    assert hlo.strip_provenance(off_text) \
+        == hlo.strip_provenance(spans_text)
 
 
 def test_fused_cache_keys_on_telemetry(monkeypatch, rng):
@@ -574,10 +577,158 @@ def test_deadline_runner_survives_raising_stage():
     assert not rec["ok"] and "stage raised" in rec["error"]
 
 
-def test_profile_capture_noop_without_env(monkeypatch):
-    monkeypatch.delenv("PYLOPS_MPI_TPU_PROFILE_DIR", raising=False)
-    with profiler.profile_capture("nothing"):
-        pass  # no crash, no capture
+# ------------------------------------- the profiler's clock (sink 1)
+def _profiled(tmp_path, body):
+    """Run ``body()`` under a ``jax.profiler`` session on the CPU and
+    return the ``pmt.*`` host events per thread line, in start order,
+    as ``(name, start_ns, end_ns, stats)``."""
+    import glob
+    from jax.profiler import ProfileData
+    opts = jax.profiler.ProfileOptions()
+    opts.python_tracer_level = 0
+    jax.profiler.start_trace(str(tmp_path), profiler_options=opts)
+    try:
+        body()
+    finally:
+        jax.profiler.stop_trace()
+    path, = glob.glob(os.path.join(str(tmp_path), "plugins", "profile",
+                                   "*", "*.xplane.pb"))
+    lines = []
+    for plane in ProfileData.from_file(path).planes:
+        if not plane.name.startswith("/host:CPU"):
+            continue
+        for line in plane.lines:
+            ev = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name,
+                         dict(e.stats)) for e in line.events
+                        if e.name.startswith("pmt."))
+            if ev:
+                lines.append([(n, s, e, st) for s, e, n, st in ev])
+    return lines
+
+
+def test_spans_land_on_the_profilers_clock_with_tracing_off(tmp_path, rng):
+    """With ``PYLOPS_MPI_TPU_TRACE`` unset, three requests through a
+    tiny daemon and one ``pmt.cgls`` leave the dispatcher's stage
+    spans and the solver's span in the profiler's own trace."""
+    import time
+    from pylops_mpi_tpu.serving import FamilySpec, SolveDaemon, WarmPool
+    Op, y, _ = _mk_blockdiag(rng)
+    pool = WarmPool(buckets=(1, 2, 4))
+    pool.register(FamilySpec(name="f", operator=Op, solver="cgls",
+                             niter=3, tol=0.0))
+    daemon = SolveDaemon(pool)
+    daemon.start(prewarm=True)
+    pmt.cgls(Op, y, niter=3, tol=0.0)
+    cols = rng.standard_normal((3, Op.shape[0])).astype(np.float32)
+
+    def body():
+        time.sleep(0.12)        # a poll of collect starts in the session
+        for t in [daemon.submit("f", c) for c in cols]:
+            t.wait(timeout=120)
+        pmt.cgls(Op, y, niter=3, tol=0.0)
+
+    try:
+        lines = _profiled(tmp_path, body)
+    finally:
+        daemon.drain(timeout=30)
+    assert trace.get_events() == []          # the ring buffer stayed off
+    disp, = [ln for ln in lines
+             if any(n == "pmt.serve.batch" for n, *_ in ln)]
+    (_, b0, b1, bstats), = [ev for ev in disp if ev[0] == "pmt.serve.batch"]
+    assert bstats["k"] == 3 and bstats["bucket"] == 4
+    inside = [ev for ev in disp if ev[0].startswith("pmt.serve.")
+              and b0 <= ev[1] and ev[2] <= b1 and ev[0] != "pmt.serve.batch"]
+    assert [n for n, *_ in inside] == [
+        "pmt.serve." + s
+        for s in ("pack", "stage_in", "solve", "pull", "resolve")]
+    assert {st["batch"] for *_, st in inside} == {bstats["batch"]}
+    before = [ev for ev in disp if ev[0] == "pmt.serve.collect"
+              and ev[2] <= b0]
+    assert before and before[-1][3]["batch"] == bstats["batch"]
+    assert any(n == "pmt.solver.cgls" for ln in lines if ln is not disp
+               for n, *_ in ln)
+
+
+def test_named_scopes_are_provenance_only(monkeypatch, rng):
+    """The compiled fused CGLS carries the operator scopes in
+    ``op_name``, and less its provenance it is the program built with
+    the scopes patched out."""
+    import contextlib
+    from pylops_mpi_tpu.solvers.basic import _cgls_fused
+    Op, y, _ = _mk_blockdiag(rng)
+    x0 = pmt.DistributedArray.to_dist(
+        np.zeros(Op.shape[1], dtype=np.float32))
+
+    def compile_text():
+        return hlo.compiled_hlo(
+            lambda y, x, damp, tol: _cgls_fused(Op, y, x, damp, tol,
+                                                niter=3),
+            y, x0, 0.0, 0.0)
+
+    named = compile_text()
+    for scope in ("pmt.MPIBlockDiag.matvec", "pmt.MPIBlockDiag.rmatvec"):
+        assert any(scope in m for m in
+                   re.findall(r'op_name="([^"]*)"', named)), scope
+    monkeypatch.setattr(jax, "named_scope",
+                        lambda name: contextlib.nullcontext())
+    bare = compile_text()
+    assert "pmt." not in bare
+    assert hlo.strip_provenance(named) == hlo.strip_provenance(bare)
+
+
+def test_annotation_takes_int_and_str_tags_only(monkeypatch):
+    """Outside a jax trace the span is a ``TraceAnnotation`` under the
+    ``pmt.`` prefix; tags that are neither ``int`` nor ``str`` are
+    dropped, not stringified."""
+    seen = []
+
+    class Recorder:
+        def __init__(self, name, **kw):
+            seen.append((name, kw))
+
+        def __enter__(self):
+            return self
+
+        def __exit__(self, *exc):
+            return False
+
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", Recorder)
+    with trace.span("serve.batch", cat="serving", batch=7, family="f",
+                    shape=(4, 4), tol=0.5, forced=True, dtype=np.float32):
+        pass
+    assert seen == [("pmt.serve.batch", {"batch": 7, "family": "f"})]
+
+
+def test_one_sweep_path_is_scoped(rng):
+    """``MPIBlockDiag.normal_matvec`` is a site the operator wiring
+    does not reach: the fused kernel's ops carry its scope."""
+    from pylops_mpi_tpu.solvers.basic import _cgls_fused_normal
+    Op, y, _ = _mk_blockdiag(rng)
+    if not Op.has_fused_normal:
+        pytest.skip("no fused normal kernel on this backend")
+    x0 = pmt.DistributedArray.to_dist(
+        np.zeros(Op.shape[1], dtype=np.float32))
+    text = hlo.compiled_hlo(
+        lambda y, x, damp, tol: _cgls_fused_normal(Op, y, x, damp, tol,
+                                                   niter=3),
+        y, x0, 0.0, 0.0)
+    assert any("pmt.MPIBlockDiag.normal_matvec" in m
+               for m in re.findall(r'op_name="([^"]*)"', text))
+
+
+def test_off_mode_installs_no_flush_handler(monkeypatch, tmp_path):
+    """``off`` keeps its meaning for the ring buffer: no event, and no
+    exit-flush handler even with a trace file named."""
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE_FILE", str(tmp_path / "t"))
+    monkeypatch.setattr(trace, "_atexit_registered", False)
+    with trace.span("quiet", cat="serving", batch=1) as sp:
+        sp.tag(later=2)
+        with trace.op_span(pmt.MPIBlockDiag.__new__(pmt.MPIBlockDiag),
+                           "matvec"):
+            trace.event("nor.this")
+    assert trace.get_events() == []
+    assert trace._OPEN == {}
+    assert trace._atexit_registered is False
 
 
 # --------------------------------------------------------- bench roofline

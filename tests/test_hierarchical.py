@@ -2,7 +2,6 @@
 ``PYLOPS_MPI_TPU_HIERARCHICAL`` + ``PYLOPS_MPI_TPU_FABRIC``).
 
 Four families of pins, per the hierarchical contract:
-
 - **oracles** (ISSUE 11 satellite): operator results on
   ``make_mesh_hybrid(dcn_size=2)`` with 8 virtual devices are
   BIT-IDENTICAL to the flat 8-device mesh for SUMMA, the pencil FFTs,
@@ -23,7 +22,6 @@ Four families of pins, per the hierarchical contract:
   env pins still win.
 """
 
-import re
 
 import numpy as np
 import pytest
@@ -43,8 +41,7 @@ P = len(jax.devices())
 
 pytestmark = pytest.mark.skipif(P != 8, reason="hierarchical pins assume 8")
 
-_STRIP = (lambda s: re.sub(
-    r'(HloModule\s+\S+|metadata=\{[^}]*\}|, module_name="[^"]*")', "", s))
+_STRIP = H.strip_provenance
 
 
 @pytest.fixture

@@ -364,6 +364,31 @@ def test_poisoned_column_isolated(rng, monkeypatch):
                                    rtol=0, atol=1e-5)
 
 
+def test_stats_split_the_wait_and_the_stages(rng):
+    """``admit_wait`` ends where the request leaves the queue, so it
+    excludes the solve that ``wait`` includes; the six stages' seconds
+    fit inside the daemon's lifetime."""
+    spec = _make_family(rng, solver="cgls", niter=5)
+    pool = WarmPool(buckets=(1, 4))
+    pool.register(spec)
+    daemon = SolveDaemon(pool)
+    t0 = time.monotonic()
+    daemon.start(prewarm=True)
+    ys = rng.standard_normal((6, spec.nrows)).astype(np.float32)
+    for t in [daemon.submit(spec.name, y) for y in ys]:
+        t.wait(timeout=120)
+    st = daemon.stats()
+    life = time.monotonic() - t0
+    daemon.drain(timeout=30)
+    assert st["solves"] == 6
+    assert 0 <= st["admit_wait_p50_s"] <= st["wait_p50_s"]
+    assert st["admit_wait_p99_s"] <= st["wait_p99_s"]
+    assert set(st["stage_s"]) == {"collect", "pack", "stage_in", "solve",
+                                  "pull", "resolve"}
+    assert all(v > 0 for v in st["stage_s"].values())
+    assert sum(st["stage_s"].values()) <= life
+
+
 def test_daemon_requires_start_and_drains_clean(rng):
     pool = WarmPool(buckets=(1,))
     pool.register(_make_family(rng))

@@ -3,8 +3,6 @@ row shards, ring-vs-scatter adjoint parity, cost model ∝ nnz, the
 tuner's sparse-vs-dense tier pick, and the tier-off HLO pin.
 """
 
-import re
-
 import numpy as np
 import pytest
 import jax
@@ -17,9 +15,6 @@ from pylops_mpi_tpu.ops.matrixmult import MPIMatrixMult
 from pylops_mpi_tpu.ops.sparse import (MPISparseMatrixMult,
                                        auto_sparse_matmult)
 from pylops_mpi_tpu.utils import hlo
-
-_STRIP = re.compile(
-    r'(HloModule\s+\S+|metadata=\{[^}]*\}|, module_name="[^"]*")')
 
 
 def _sparse_problem(rng, N=37, M=53, density=0.08, cmplx=False):
@@ -172,4 +167,4 @@ def test_tier_off_hlo_bit_identical(rng, monkeypatch):
 
     ha = hlo.compiled_hlo(lambda v: auto.matvec(v).array, x)
     hd = hlo.compiled_hlo(lambda v: direct.matvec(v).array, x)
-    assert _STRIP.sub("", ha) == _STRIP.sub("", hd)
+    assert hlo.strip_provenance(ha) == hlo.strip_provenance(hd)
