@@ -8,8 +8,10 @@ reference that lives in this file and imports nothing from
 
 A. the flagship at a deployment's size — ``pmt.cgls`` on
    ``MPIBlockDiag([MatrixMult(b) ...])`` at N=4096 with 128 f32 blocks
-   (8 GB) per chip: two-sweep, ``normal=True`` (one-sweep Pallas
-   kernel) and ``normal=True`` over bf16 block storage;
+   (8 GB) per chip: ``normal=False`` (two sweeps), ``normal=True``
+   (one-sweep Pallas kernel), no ``normal`` at all (the operator's
+   answer: the kernel on a TPU, two sweeps on the CPU) and
+   ``normal=True`` over bf16 block storage;
 B. the solve service — ``SolveDaemon`` over a ``WarmPool`` holding the
    Stage A operator, one full K=16 bucket and one ragged bucket;
 C. the roll-call of every hand-scheduled or Pallas-backed operator,
@@ -559,12 +561,18 @@ def stage_a_and_b(mesh, sz, seed, compiles, ir_root):
     rows = {}
 
     def solve(name, op, tol, normal):
+        # normal=None is the caller who says nothing: on a TPU the
+        # operator answers for the one-sweep kernel, on the CPU it
+        # compiles the classic program. Each row lowers a program of
+        # its own to read: the default must not borrow the one an
+        # explicit row left in the solver cache
+        pmt.clear_fused_cache()
         with IrDump(ir_root) as ir:
             x, setup_s, run_s = timed_solve(
                 compiles, op, y, niter=niter,
-                **({"normal": True} if normal else {}))
+                **({} if normal is None else {"normal": normal}))
         n_mosaic = ir.count(MOSAIC, within="stablehlo.while")
-        check((n_mosaic > 0) == (normal and on_tpu),
+        check((n_mosaic > 0) == (normal is not False and on_tpu),
               f"{name}: {n_mosaic} Mosaic calls in the solver program on "
               f"{jax.default_backend()} (normal={normal})")
         e = rel_err(x, x_ref)
@@ -576,6 +584,7 @@ def stage_a_and_b(mesh, sz, seed, compiles, ir_root):
 
     solve("two_sweep_f32", Op, F32_TOL, normal=False)
     solve("normal_f32", Op, F32_TOL, normal=True)
+    solve("default_f32", Op, F32_TOL, normal=None)
 
     stage_b = serve(Op, sz, niter, Y_np, X_ref, compiles)
     log(f"B: {stage_b}")

@@ -176,6 +176,13 @@ class MPILinearOperator:
         q = self.matvec(x)
         return self.rmatvec(q), q
 
+    def prefers_fused_normal(self, x: VectorLike) -> bool:
+        """What ``cgls(normal=None)`` asks: would ``normal_matvec(x)``
+        run a compiled one-sweep kernel that beats ``matvec`` +
+        ``rmatvec`` for THIS vector? Only then is the one-sweep CGLS
+        recurrence worth its extra carry. The generic pair never is."""
+        return False
+
     # ----------------------------------------------------------- algebra
     def dot(self, x):
         """Operator-operator, operator-scalar or operator-vector product

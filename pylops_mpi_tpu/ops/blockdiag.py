@@ -69,6 +69,11 @@ class MPIBlockDiag(MPILinearOperator):
         (default) — fused when available, unless the autotuner
         (``PYLOPS_MPI_TPU_TUNE=on|auto``) has a measured plan saying
         otherwise. An explicit value always beats the tuner.
+        ``cgls(normal=None)`` follows it through
+        :meth:`prefers_fused_normal`: ``"two_sweep"`` (given or tuned)
+        keeps the default solve classic; otherwise the solve takes the
+        one-sweep schedule where the fused kernel is Mosaic on a TPU
+        with a row tile the chip has shown faster than two sweeps.
     """
 
     def __init__(self, ops: Sequence[LocalOperator],
@@ -265,6 +270,42 @@ class MPIBlockDiag(MPILinearOperator):
         return (normal_matvec_supported(self._batched)
                 or self._ffi_normal_usable())
 
+    def _normal_kernel_for(self, x: DistributedArray):
+        """The one-sweep kernel ``normal_matvec(x)`` runs, or ``None``
+        when it takes the generic two sweeps."""
+        # the fused kernels are vector-form: block (column-batched)
+        # inputs take the generic two-sweep path, whose widened einsums
+        # carry the column axis natively
+        if not self.has_fused_normal or x.ndim == 2:
+            return None
+        from .pallas_kernels import normal_matvec_supported
+        if self._ffi_normal_usable() \
+                and np.dtype(x.dtype) == np.dtype(self._batched.dtype):
+            # the native kernel handles real AND complex blocks
+            from ..native.ffi import fused_normal
+            return fused_normal
+        if (normal_matvec_supported(self._batched)
+                and not jnp.issubdtype(x.dtype, jnp.complexfloating)):
+            # complex vectors would be silently truncated by the real
+            # Pallas kernel — only the real path may use it
+            from .pallas_kernels import batched_normal_matvec
+            return batched_normal_matvec
+        return None  # mismatched-dtype x, or complex without the FFI kernel
+
+    def prefers_fused_normal(self, x) -> bool:
+        """Yes only where ``normal_matvec(x)`` is the Mosaic kernel on a
+        TPU, fed a real vector of the kernel's accumulation dtype, with
+        a row tile the chip has shown faster than two sweeps
+        (``pallas_kernels.normal_matvec_pays``). The CPU's native FFI
+        kernel and Pallas in interpret mode answer no: there the
+        one-sweep path stays an explicit ``cgls(normal=True)``."""
+        from .pallas_kernels import batched_normal_matvec, normal_matvec_pays
+        if self._normal_kernel_for(x) is not batched_normal_matvec:
+            return False
+        acc = jnp.promote_types(self._batched.dtype, jnp.float32)
+        return (np.dtype(x.dtype) == np.dtype(acc)
+                and normal_matvec_pays(self._batched))
+
     def normal_matvec(self, x: DistributedArray):
         """``(u, q) = (OpᴴOp x, Op x)`` with ONE memory sweep of the
         block matrices when batched: on TPU the Pallas
@@ -273,25 +314,11 @@ class MPIBlockDiag(MPILinearOperator):
         does the same against DRAM (measured 1.6x the two-sweep
         einsum pair at the 4096² flagship block). Falls back to
         matvec+rmatvec otherwise."""
-        # the fused kernels are vector-form: block (column-batched)
-        # inputs take the generic two-sweep path, whose widened einsums
-        # carry the column axis natively
-        if not self.has_fused_normal or x.ndim == 2:
+        kernel = self._normal_kernel_for(x)
+        if kernel is None:
             return super().normal_matvec(x)
         from jax.sharding import PartitionSpec as P
         from jax import shard_map
-        from .pallas_kernels import normal_matvec_supported
-        if self._ffi_normal_usable() \
-                and np.dtype(x.dtype) == np.dtype(self._batched.dtype):
-            # the native kernel handles real AND complex blocks
-            from ..native.ffi import fused_normal as kernel
-        elif (normal_matvec_supported(self._batched)
-              and not jnp.issubdtype(x.dtype, jnp.complexfloating)):
-            # complex vectors would be silently truncated by the real
-            # Pallas kernel — only the real path may use it
-            from .pallas_kernels import batched_normal_matvec as kernel
-        else:  # mismatched-dtype x, or complex without the FFI kernel
-            return super().normal_matvec(x)
         from ..diagnostics import trace
         with trace.op_span(self, "normal_matvec"):
             A = self._batched

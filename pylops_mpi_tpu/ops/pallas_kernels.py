@@ -24,7 +24,8 @@ from jax.experimental import pallas as pl
 
 __all__ = ["first_derivative_centered", "second_derivative",
            "stencil_taps", "batched_normal_matvec",
-           "normal_matvec_supported", "pallas_available"]
+           "normal_matvec_supported", "normal_matvec_pays",
+           "pallas_available"]
 
 
 def pallas_available() -> bool:
@@ -210,6 +211,30 @@ def normal_matvec_supported(A: jax.Array) -> bool:
             and not jnp.iscomplexobj(A)):
         return False
     return _tile_args(A)[0] is not None
+
+
+def _tile_beats_two_sweeps(tm: int, n: int, itemsize: int) -> bool:
+    """Whether the chip has shown the one-sweep kernel with a
+    ``(tm, n)`` row tile of ``itemsize``-byte elements faster than the
+    XLA matvec + rmatvec pair it replaces. A grid step costs about
+    0.45 us beside its tile's copy, so the tile's bytes decide (v5e,
+    4.3 GB of blocks, n from 256 to 16384, f32 and bf16; the table is
+    in PERF.md section 6, PR 26): tiles of 1 MiB and more ran 1.23-2.06 x as
+    fast as two sweeps (64 rows at n=16384 and 128 at n=8192 among
+    them), 512 KiB tiles 1.12-1.39 x, 256 KiB tiles 0.73-0.995 x,
+    8-row tiles 0.2-0.5 x."""
+    return tm * n * itemsize >= 512 << 10
+
+
+def normal_matvec_pays(A: jax.Array) -> bool:
+    """Whether :func:`batched_normal_matvec` on ``A``'s blocks is worth
+    taking unasked: a compiled Mosaic kernel (on the CPU Pallas runs in
+    interpret mode -- a perf trap inside a ``while_loop``) whose row
+    tile is one the chip has shown to beat two sweeps."""
+    if _interpret() or not normal_matvec_supported(A):
+        return False
+    tm, _ = _tile_args(A)
+    return _tile_beats_two_sweeps(tm, A.shape[2], A.dtype.itemsize)
 
 
 def _normal_kernel(a_ref, x_ref, u_ref, q_ref):

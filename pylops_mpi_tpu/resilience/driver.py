@@ -76,7 +76,7 @@ def _run_guarded(solver: str, Op, y, x, niter: int, tol: float,
     elif solver == "cgls":
         xn, it, cost, _, _, code = cgls_guarded(
             Op, y, x, niter=niter, damp=damp, tol=tol,
-            normal=bool(solver_kwargs.get("normal", False)), M=M)
+            normal=solver_kwargs.get("normal"), M=M)
     else:
         if M is not None:
             raise ValueError(

@@ -73,7 +73,8 @@ from ..distributedarray import DistributedArray
 from ..stacked import StackedDistributedArray
 from ..diagnostics import metrics as _metrics
 from ..diagnostics import telemetry, trace as _trace
-from .basic import (_DONATE_X0, _donate_copy, _get_fused, _guard_update,
+from .basic import (_DONATE_X0, _count_cgls_solve, _donate_copy,
+                    _get_fused, _guard_update,
                     _i32, _mkey, _mp_floor, _precond_apply, _rdot,
                     _reject, _resolve_status, _step_scalar, _vdtype,
                     _vkey)
@@ -403,7 +404,8 @@ def _pipe_cg_seed(Op, y, x0, *, niter, M, block):
 def _normal_apply(Op, damp2, xdt, normal):
     """``v → (AᴴA + damp²I) v`` — the operator the pipelined CGLS body
     iterates on. ``normal=True`` uses the one-sweep fused
-    ``Op.normal_matvec`` (same opt-in as classic ``cgls(normal=True)``)."""
+    ``Op.normal_matvec`` (the schedule ``cgls`` resolved: basic.py
+    ``_resolve_normal``)."""
     d2 = _step_scalar(damp2, xdt)
     if normal:
         def applyA(v):
@@ -830,8 +832,7 @@ def run_cgls_fused(Op, y, x0, x0_owned, niter, damp, tol, use_normal,
     else:
         x, iiter, cost, cost1, kold = out
         iiter, code = int(iiter), None
-    _metrics.inc("solver.cgls.solves")
-    _metrics.inc("solver.cgls.iterations", iiter)
+    _count_cgls_solve(iiter, use_normal)
     if guards:
         _rstatus.record("cgls", code, iiter)
     return (x, iiter, np.asarray(cost)[:iiter + 1],

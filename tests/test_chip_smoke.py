@@ -26,7 +26,7 @@ sys.path.insert(0, ROOT)
 
 import chip_smoke  # noqa: E402
 
-SOLVES = ("two_sweep_f32", "normal_f32", "normal_bf16")
+SOLVES = ("two_sweep_f32", "normal_f32", "default_f32", "normal_bf16")
 OPS = ("first_derivative_o3_edge", "first_derivative_o5_edge",
        "second_derivative", "laplacian_3d", "matrixmult_summa", "vstack",
        "fft2d", "fredholm1")
