@@ -117,7 +117,18 @@ def block_gather(blocks, global_shape: Tuple[int, int],
 
 
 def _pad_to(x: jax.Array, rows: int, cols: int) -> jax.Array:
-    return jnp.pad(x, ((0, rows - x.shape[0]), (0, cols - x.shape[1])))
+    """Zero-pad ``x`` to ``(rows, cols)``; ``x`` itself when it already
+    has that shape (``jnp.pad`` by zero widths would copy it)."""
+    dr, dc = rows - x.shape[0], cols - x.shape[1]
+    if dr == 0 and dc == 0:
+        return x
+    return jnp.pad(x, ((0, dr), (0, dc)))
+
+
+def _unpad(x: jax.Array, rows: int, cols: int) -> jax.Array:
+    """Leading ``(rows, cols)`` block of ``x``; ``x`` itself when that
+    is all of it."""
+    return x if x.shape == (rows, cols) else x[:rows, :cols]
 
 
 class _MatMulBase(MPILinearOperator):
@@ -150,7 +161,7 @@ class _MatMulBase(MPILinearOperator):
             from ._precision import default_compute_dtype
             compute_dtype = default_compute_dtype(self.dtype)
         self.compute_dtype = compute_dtype
-        self.A = self._place_A(A)
+        self._place_A(A)
         # adjoint reuses conj(A) tiles on the fly unless saveAt
         # (ref MatrixMult.py:288-292); stored at compute_dtype so the
         # saveAt copy gets the same storage/cast savings. The SUMMA
@@ -176,7 +187,9 @@ class _MatMulBase(MPILinearOperator):
         return out.astype(self.dtype)
 
     def _place_A(self, A):
-        return A
+        """Store the matrix the kernels read, in the layout and dtype
+        they read it in (``self.A``; the SUMMA variant stores tiles)."""
+        self.A = A
 
     def _fold_in(self, x: DistributedArray, nrows: int):
         """Reshape the flat model/data vector into the 2-D GEMM operand.
@@ -216,9 +229,10 @@ class _MPIBlockMatrixMult(_MatMulBase):
         if self.compute_dtype is not None:
             A = A.astype(self.compute_dtype)
         try:
-            return jax.device_put(A, axis_sharding(self.mesh, 2, 0))
+            A = jax.device_put(A, axis_sharding(self.mesh, 2, 0))
         except ValueError:
-            return A  # rows not divisible by P: let XLA choose placement
+            pass  # rows not divisible by P: let XLA choose placement
+        self.A = A
 
     def _matvec(self, x: DistributedArray) -> DistributedArray:
         X, ncol = self._fold_in(x, self.K)
@@ -235,6 +249,15 @@ class _MPIBlockMatrixMult(_MatMulBase):
 class _MPISummaMatrixMult(_MatMulBase):
     """2-D SUMMA variant (ref ``MatrixMult.py:430-765``) as an explicit
     shard_map kernel over an (r, c) mesh.
+
+    The operator holds ONE copy of the matrix: ``Ap``, zero-padded to
+    grid multiples, tiled ``P("r", "c")`` over the grid, the registered
+    pytree child — so the operator is a jit argument of every fused
+    solver and no compiled program embeds the matrix. ``A`` is a
+    property over the tiles. A device array that already has that
+    sharding and needs no padding (and no ``compute_dtype`` cast) is
+    taken as it is, not copied: a matrix too large for one chip is
+    generated in that sharding and handed over.
 
     Two forward schedules, chosen by per-device communication volume at
     construction (``schedule="auto"``):
@@ -337,13 +360,11 @@ class _MPISummaMatrixMult(_MatMulBase):
                     self._ring_slice = _topo.slice_run(self.mesh2, "c")
         super().__init__(A, M, mesh=base, dtype=dtype, saveAt=saveAt,
                          compute_dtype=compute_dtype)
-        pr, pc = self.grid
-        # padded tile sizes (ref pads to grid multiples, MatrixMult.py:589-601)
-        self.Np = pr * int(np.ceil(self.N / pr))
-        self.Kp_r = pr * int(np.ceil(self.K / pr))
-        self.Kp_c = pc * int(np.ceil(self.K / pc))
-        self.Mp = pc * int(np.ceil(self.M / pc))
         from ..diagnostics import trace
+        # what the kernels hold per device, and whether building it
+        # copied the caller's matrix: both ride on the selection event
+        held = dict(tile_bytes=int(self.Ap.nbytes) // int(np.prod(self.grid)),
+                    copied=int(self.Ap is not A))
         if schedule == "auto" and tplan is not None \
                 and tplan.get("schedule") in ("gather", "stat_a"):
             schedule = tplan.get("schedule")
@@ -351,7 +372,7 @@ class _MPISummaMatrixMult(_MatMulBase):
                         schedule=schedule, grid=self.grid,
                         shape=(self.N, self.K, self.M),
                         source=tplan.provenance,
-                        overlap=self.overlap)
+                        overlap=self.overlap, **held)
         elif schedule == "auto":
             # per-device elements received per forward apply — the
             # comm-volume model now lives in diagnostics/costmodel.py
@@ -368,20 +389,8 @@ class _MPISummaMatrixMult(_MatMulBase):
                         shape=(self.N, self.K, self.M),
                         vol_gather=vols["gather"],
                         vol_stat_a=vols["stat_a"],
-                        overlap=self.overlap)
+                        overlap=self.overlap, **held)
         self.schedule = schedule
-        # pad + tile A once, eagerly, and commit it to the 2-D mesh:
-        # padding inside the traced apply would make XLA constant-fold a
-        # full copy of A at compile time (very slow for large A). Stored
-        # at compute_dtype when set — bf16 tiles also halve the
-        # all-gather bytes on the wire, not just HBM reads.
-        # self.compute_dtype, not the ctor arg: the env policy may have
-        # filled it in during super().__init__
-        Ap = _pad_to(jnp.asarray(self.A), self.Np, self.Kp_c)
-        if self.compute_dtype is not None:
-            Ap = Ap.astype(self.compute_dtype)
-        self.Ap = jax.device_put(
-            Ap, NamedSharding(self.mesh2, P("r", "c")))
 
     def _consult_plan(self, A, M, base, dtype, compute_dtype):
         """``tuning.get_plan`` for this construction (None when
@@ -416,7 +425,46 @@ class _MPISummaMatrixMult(_MatMulBase):
             factory=factory)
 
     def _place_A(self, A):
-        return A  # logical A kept for todense/debug; Ap is the hot copy
+        """Pad + tile A once, eagerly, and commit it to the 2-D mesh as
+        ``self.Ap`` — the ONE copy of the matrix this operator holds,
+        the registered pytree child every kernel reads. (Padding
+        inside the traced apply would make XLA constant-fold a full
+        copy of A at compile time.) Stored at ``compute_dtype`` when
+        set — bf16 tiles also halve the all-gather bytes on the wire,
+        not just HBM reads (``self.compute_dtype``, not the ctor arg:
+        the env policy may have filled it in). An array that needs no
+        padding, no cast and already has the tiles' sharding comes
+        through every step below as itself (``device_put`` to the
+        sharding an array has returns that array): same buffers,
+        nothing copied."""
+        pr, pc = self.grid
+        # padded tile sizes (ref pads to grid multiples, MatrixMult.py:589-601)
+        self.Np = pr * int(np.ceil(self.N / pr))
+        self.Kp_r = pr * int(np.ceil(self.K / pr))
+        self.Kp_c = pc * int(np.ceil(self.K / pc))
+        self.Mp = pc * int(np.ceil(self.M / pc))
+        Ap = _pad_to(A, self.Np, self.Kp_c)
+        if self.compute_dtype is not None \
+                and Ap.dtype != np.dtype(self.compute_dtype):
+            Ap = Ap.astype(self.compute_dtype)
+        self.Ap = jax.device_put(
+            Ap, NamedSharding(self.mesh2, P("r", "c")))
+
+    @property
+    def A(self) -> jax.Array:
+        """The logical ``(N, K)`` matrix as a view over the tiles
+        (``todense``-style debugging, the autodiff rules): the tiles
+        themselves when nothing was padded, else their leading block.
+        At the tiles' dtype — ``compute_dtype`` when that is set."""
+        return _unpad(self.Ap, self.N, self.K)
+
+    def _gemm(self, a, b):
+        # the local GEMM under a scope of its own: under
+        # pmt.collective.ring_pass the GEMMs and the hops then separate
+        # by innermost scope in a device trace
+        from ..diagnostics import trace
+        with trace.span("summa.gemm", cat="kernel"):
+            return super()._gemm(a, b)
 
     def _kernel_fwd(self, Ablk, Xblk):
         # Ablk: (Np/pr, Kp_c/pc) tile; Xblk: (Kp_r... ) — gather full
@@ -573,7 +621,7 @@ class _MPISummaMatrixMult(_MatMulBase):
         Y = shard_map(kernel, mesh=self.mesh2,
                       in_specs=(P("r", "c"), P("r", "c")),
                       out_specs=P("r", "c"), check_vma=False)(self.Ap, X)
-        return self._wrap_out(Y[:self.N, :Me], x, self.N, ncol)
+        return self._wrap_out(_unpad(Y, self.N, Me), x, self.N, ncol)
 
     def _rmatvec(self, x: DistributedArray) -> DistributedArray:
         pc = self.grid[1]
@@ -586,7 +634,7 @@ class _MPISummaMatrixMult(_MatMulBase):
         X = shard_map(kernel, mesh=self.mesh2,
                       in_specs=(P("r", "c"), P("r", "c")),
                       out_specs=P("c", None), check_vma=False)(self.Ap, Y)
-        return self._wrap_out(X[:self.K, :Me], x, self.K, ncol)
+        return self._wrap_out(_unpad(X, self.K, Me), x, self.K, ncol)
 
 
 class _MPIAutoMatrixMult(_MatMulBase):
@@ -606,9 +654,10 @@ class _MPIAutoMatrixMult(_MatMulBase):
         if self.compute_dtype is not None:
             A = A.astype(self.compute_dtype)
         try:
-            return jax.device_put(A, NamedSharding(self.mesh2, P("r", "c")))
+            A = jax.device_put(A, NamedSharding(self.mesh2, P("r", "c")))
         except ValueError:
-            return A  # non-divisible tiles: leave placement to XLA
+            pass  # non-divisible tiles: leave placement to XLA
+        self.A = A
 
     def _matvec(self, x: DistributedArray) -> DistributedArray:
         X, ncol = self._fold_in(x, self.K)
@@ -674,12 +723,20 @@ def MPIMatrixMult(A, M: int, saveAt: bool = False, mesh=None,
 # sharded matrix tiles travel into jit as pytree children
 # (multi-process arrays must not be closed over — linearoperator.py).
 # The same registration makes the tiles DIFFERENTIABLE leaves for the
-# autodiff tier (adjoint rules / implicit solver VJPs): gradients flow
-# to ``A`` — and, when ``saveAt=True`` stored a separate ``At``, to
-# ``At`` INDEPENDENTLY, because the rules cannot know the two tiles
-# alias one matrix. A training loop updating weights must either keep
-# ``saveAt=False`` (``At`` is None → a single source of truth) or fold
-# ``gA + gAt.conj().T``-style cotangent pairs itself (docs/autodiff.md).
+# autodiff tier (adjoint rules / implicit solver VJPs). Which leaf
+# carries the matrix and its gradient:
+# - block / auto: ``A`` — and, when ``saveAt=True`` stored a separate
+#   ``At``, ``At`` INDEPENDENTLY, because the rules cannot know the two
+#   tiles alias one matrix. A training loop updating weights must
+#   either keep ``saveAt=False`` (``At`` is None → a single source of
+#   truth) or fold ``gA + gAt.conj().T``-style cotangent pairs itself
+#   (docs/autodiff.md).
+# - summa: ``Ap``, the padded ``(Np, Kp_c)`` tiles the kernels read and
+#   the only copy of the matrix the operator holds (``A`` is a property
+#   over them, ``At`` is never stored). The cotangent arrives in the
+#   tiles' shape with zeros in the pad; ``g.A`` of an operator-shaped
+#   cotangent — the same property — is the gradient in A's shape.
 from ..linearoperator import register_operator_arrays  # noqa: E402
-for _c in (_MPIBlockMatrixMult, _MPISummaMatrixMult, _MPIAutoMatrixMult):
+for _c in (_MPIBlockMatrixMult, _MPIAutoMatrixMult):
     register_operator_arrays(_c, "A", "At")
+register_operator_arrays(_MPISummaMatrixMult, "Ap")
