@@ -131,6 +131,53 @@ def _unpad(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return x if x.shape == (rows, cols) else x[:rows, :cols]
 
 
+def _ring_pays(kernel: str, cols_per_hop: int) -> bool:
+    """Whether SUMMA's ring form of ``kernel`` (``"matvec"``: the
+    stationary-A forward, ``"rmatvec"``: the adjoint) beats its bulk
+    form when one hop's GEMM gets ``cols_per_hop`` columns — the rule
+    behind ``overlap="auto"`` on a TPU. Both ring kernels read the
+    whole resident tile once a hop, ``pc`` times a product; the bulk
+    kernels read it once.
+
+    Measured on the SAME tiles, ring / bulk in ms, best of 3 x 10 jitted
+    calls, TPU v5e, f32 under ``highest`` (PERF.md section 6, PR 28; the
+    rows at 128 and 512 repeated to 0.05 % in a second process). A
+    65,536^2 on 2 x 2 (4.29 GB tiles) unless said:
+
+    ====================  ======================  ======================
+    columns a hop         forward                 adjoint
+    ====================  ======================  ======================
+    8                     11.83 / 6.18   bulk     11.76 / 5.99   bulk
+    32                    12.64 / 6.72   bulk     12.11 / 6.58   bulk
+    32, bf16 tiles        6.51 / 3.79    bulk     6.32 / 3.51    bulk
+    32, 1.07 GB tiles     3.44 / 1.97    bulk     3.19 / 1.88    bulk
+    16, 1 x 4 grid        23.84 / 6.85   bulk     23.55 / 10.10  bulk
+    64                    13.54 / 10.90  bulk     13.23 / 10.90  bulk
+    128                   20.09 / 21.34  (ring)   21.03 / 18.88  bulk
+    256                   43.31 / 39.74  bulk     37.93 / 38.15  ring
+    512                   82.88 / 80.74  bulk     74.98 / 76.17  ring
+    512, bf16 tiles       43.20 / 42.85  bulk     41.14 / 42.62  ring
+    512, 1.07 GB tiles    22.45 / 21.74  bulk     20.26 / 20.75  ring
+    512, 1 x 4 grid       161.08 / 160.64  bulk   142.55 / 144.68  ring
+    1024                  165.08 / 161.90  bulk   151.12 / 152.73  ring
+    2048                  329.98 / 323.64  bulk   301.98 / 305.33  ring
+    ====================  ======================  ======================
+
+    Below 128 columns a hop's GEMM is bound by the tile's bytes, so
+    the ring doubles the product; at 128 it still runs 10 % under the
+    MXU rate of a 256-column one. From 256 the split costs nothing and
+    the adjoint's ring hides its hop of Y (0.6-3.5 % of the product);
+    the forward's ring reduce-scatter stays 0.3-9 % behind the bulk
+    ``psum_scatter`` wherever the MXU binds. The tile's bytes, its
+    itemsize and ``pc`` moved no row across. The forward at 128 is the
+    one row against the rule: the bulk 256-column GEMM is slow there
+    (25.8 TFLOP/s against 27.7 at 512), its neighbours at 64 and 256
+    and the adjoint at 128 go the other way, and the hop it could hide
+    is 16 MB — one shape's tiling, not chased.
+    """
+    return kernel == "rmatvec" and cols_per_hop >= 256
+
+
 class _MatMulBase(MPILinearOperator):
     # subclasses whose adjoint never reads At set this False
     # (see _MPISummaMatrixMult: its kernels use the sharded Ap tiles)
@@ -273,12 +320,13 @@ class _MPISummaMatrixMult(_MatMulBase):
       fewer at the component-bench shape). The adjoint has always
       been stationary-A (gather Y, GEMM, psum).
 
-    ``overlap`` (``PYLOPS_MPI_TPU_OVERLAP``) switches BOTH schedules to
-    their ring-pipelined forms (round 8, arXiv 2112.09017): the bulk
-    collective along ``c`` decomposes into ``pc - 1`` double-buffered
-    ``ppermute`` hops interleaved with ``pc`` per-block GEMMs
-    (:func:`~pylops_mpi_tpu.parallel.collectives.ring_pass`), so each
-    hop's ICI transfer hides behind the resident block's MXU work:
+    ``overlap`` (``PYLOPS_MPI_TPU_OVERLAP``) chooses between the bulk
+    kernels and their ring-pipelined forms (round 8, arXiv 2112.09017):
+    the bulk collective along ``c`` decomposes into ``pc - 1``
+    double-buffered ``ppermute`` hops interleaved with ``pc`` per-block
+    GEMMs (:func:`~pylops_mpi_tpu.parallel.collectives.ring_pass`), so
+    each hop's ICI transfer can hide behind the resident block's MXU
+    work:
 
     - gather/ring: A tiles rotate along ``c``; each step GEMMs the
       resident tile against its k-slice of the gathered X column.
@@ -288,9 +336,24 @@ class _MPISummaMatrixMult(_MatMulBase):
     - adjoint/ring: Y tiles rotate along ``c``; each step's GEMM fills
       the owner's M-column chunk; the ``r`` psum is unchanged.
 
-    ``overlap=off`` (the default off-TPU) keeps the bulk kernels
-    bit-identical; ``on`` reorders the floating-point accumulation
-    (per-block partial sums) and matches within dtype tolerance.
+    A word — ``overlap=True/False/"on"/"off"``, a pinned
+    ``PYLOPS_MPI_TPU_OVERLAP=on|off``, a tuner plan — chooses the ring
+    or the bulk form of all three at every shape. ``auto`` (nothing
+    said) is bulk off a TPU. On a TPU the attribute reads ``"auto"``
+    and the choice is made per traced apply: stat_a/ring and
+    adjoint/ring read the whole resident tile once a HOP (``pc`` times
+    a product where the bulk form reads it once), so each asks
+    :func:`_ring_pays` with the columns one hop's GEMM gets — from the
+    operand's own width, so a block input that widens M to M*K decides
+    by M*K — and a skinny right-hand side takes the bulk form (the
+    measured rows are in that docstring). gather/ring reads each tile
+    once and rings under ``auto`` on a TPU as before (not measured).
+    Each decision leaves a ``summa.ring_select`` trace event
+    (``kernel``, ``cols_per_hop``, ``tile_bytes``, ``ring``,
+    ``source``: ``rule``/``kwarg``/``env``/``plan``). ``off`` keeps
+    the bulk kernels bit-identical; the ring reorders the
+    floating-point accumulation (per-block partial sums) and matches
+    within dtype tolerance.
 
     ``hierarchical`` (``PYLOPS_MPI_TPU_HIERARCHICAL``, round 11): on a
     hybrid mesh the (r, c) grid inherits the base mesh's dcn-major
@@ -331,13 +394,27 @@ class _MPISummaMatrixMult(_MatMulBase):
         if schedule == "auto" or want_overlap or want_hier:
             tplan = self._consult_plan(A, M, base, dtype,
                                        compute_dtype)
+        # who chose between ring and bulk: a word (kwarg, env pin, tuner
+        # plan) holds at every shape; with nothing said the stationary-A
+        # forward and the adjoint ask _ring_pays per traced apply
+        if overlap is None:
+            self._overlap_source = "rule" if want_overlap else "env"
+        else:
+            self._overlap_source = ("rule" if str(overlap).strip().lower()
+                                    == "auto" else "kwarg")
         if want_overlap and tplan is not None \
                 and tplan.get("overlap") in ("on", "off"):
             overlap = tplan.get("overlap")
+            self._overlap_source = "plan"
         if want_hier and tplan is not None \
                 and tplan.get("hierarchical") in ("auto", "on", "off"):
             hierarchical = tplan.get("hierarchical")
+        # True/False: a word, or auto off a TPU (bulk). "auto": left
+        # open on a TPU — the gather forward rings as it always has
+        # there, the other two kernels decide by the rule
         self.overlap = overlap_enabled(overlap)
+        if self.overlap and self._overlap_source == "rule":
+            self.overlap = "auto"
         self.mesh2 = Mesh(base.devices.reshape(self.grid), ("r", "c"))
         # fabric classification of the 2-D grid (round 11): `_hier`
         # turns on the per-fabric cost/byte attribution; `_ring_slice`
@@ -606,18 +683,38 @@ class _MPISummaMatrixMult(_MatMulBase):
         part = self._gemm(jnp.conj(Ablk).T, Yrow)              # (Kp_c/pc, Mp)
         return lax.psum(part, "r")
 
+    def _rings(self, kernel: str, cols_per_hop: int) -> bool:
+        """Ring or bulk for one traced apply of the two kernels that
+        re-read the resident tile every hop (stationary-A forward, the
+        adjoint). A word decides as it always has; ``auto`` on a TPU
+        asks :func:`_ring_pays`. Leaves one ``summa.ring_select`` event
+        per trace (not per execution)."""
+        pr, pc = self.grid
+        ring = bool(self.overlap) and pc > 1
+        if ring and self.overlap == "auto":
+            ring = _ring_pays(kernel, cols_per_hop)
+        from ..diagnostics import trace
+        trace.event("summa.ring_select", cat="schedule", kernel=kernel,
+                    cols_per_hop=cols_per_hop,
+                    tile_bytes=int(self.Ap.nbytes) // (pr * pc),
+                    ring=int(ring), source=self._overlap_source)
+        return ring
+
     def _matvec(self, x: DistributedArray) -> DistributedArray:
         pr, pc = self.grid
         X, ncol = self._fold_in(x, self.K)
         Me = X.shape[1]                       # M, or M*K for block input
         Mp = pc * int(np.ceil(Me / pc))
         X = _pad_to(X, self.Kp_r, Mp)
-        ring = self.overlap and pc > 1
         if self.schedule == "stat_a":
-            kernel = (self._kernel_fwd_stat_a_ring if ring
+            kernel = (self._kernel_fwd_stat_a_ring
+                      if self._rings("matvec", Mp // pc)
                       else self._kernel_fwd_stat_a)
         else:
-            kernel = self._kernel_fwd_ring if ring else self._kernel_fwd
+            # the gather ring rotates A tiles and reads each once: its
+            # auto stays the backend's (not measured, PERF.md)
+            kernel = (self._kernel_fwd_ring if self.overlap and pc > 1
+                      else self._kernel_fwd)
         Y = shard_map(kernel, mesh=self.mesh2,
                       in_specs=(P("r", "c"), P("r", "c")),
                       out_specs=P("r", "c"), check_vma=False)(self.Ap, X)
@@ -629,8 +726,8 @@ class _MPISummaMatrixMult(_MatMulBase):
         Me = Y.shape[1]
         Mp = pc * int(np.ceil(Me / pc))
         Y = _pad_to(Y, self.Np, Mp)
-        kernel = (self._kernel_adj_ring
-                  if self.overlap and pc > 1 else self._kernel_adj)
+        kernel = (self._kernel_adj_ring if self._rings("rmatvec", Mp // pc)
+                  else self._kernel_adj)
         X = shard_map(kernel, mesh=self.mesh2,
                       in_specs=(P("r", "c"), P("r", "c")),
                       out_specs=P("c", None), check_vma=False)(self.Ap, Y)
@@ -688,14 +785,19 @@ def MPIMatrixMult(A, M: int, saveAt: bool = False, mesh=None,
     picks the forward communication schedule: "gather" (all-gather A
     row + X col), "stat_a" (A stays put; gather X, reduce-scatter the
     partials — wins for skinny X), or "auto" (per-device byte count
-    decides). ``overlap`` (summa only; ``True``/``False``/``"auto"``,
-    default the ``PYLOPS_MPI_TPU_OVERLAP`` env seam) runs the selected
-    schedule as a double-buffered ``ppermute`` ring that hides the ICI
-    transfer of each block behind the GEMM on the resident one —
-    ``off`` is bit-identical to the bulk schedules, ``on`` matches
-    within dtype tolerance (the accumulation order changes). ``block``
-    and ``auto`` kinds ignore it (forward is comm-free / the
-    partitioner owns the schedule). ``hierarchical`` (summa only;
+    decides). ``overlap`` (summa only; ``True``/``False``/``"on"``/
+    ``"off"``/``"auto"``, default the ``PYLOPS_MPI_TPU_OVERLAP`` env
+    seam) runs the selected schedule as a double-buffered ``ppermute``
+    ring that hides the ICI transfer of each block behind the GEMM on
+    the resident one — ``off`` is bit-identical to the bulk schedules,
+    ``on`` matches within dtype tolerance (the accumulation order
+    changes). ``auto`` is bulk off a TPU; on a TPU the stationary-A
+    forward and the adjoint decide per apply from the columns one
+    hop's GEMM gets (a skinny right-hand side stays bulk: the ring
+    would re-read the resident tile every hop), the gather forward
+    rings — see ``_MPISummaMatrixMult``. ``block`` and ``auto`` kinds
+    ignore it (forward is comm-free / the partitioner owns the
+    schedule). ``hierarchical`` (summa only;
     ``True``/``False``/``"auto"``, default the
     ``PYLOPS_MPI_TPU_HIERARCHICAL`` env seam) enables the
     topology-aware treatment on hybrid (multi-slice) meshes:

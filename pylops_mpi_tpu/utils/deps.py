@@ -36,7 +36,8 @@ backend — XLA collectives — so the seam carries different switches:
   overlap only on real TPU backends, where it hides ICI transfer
   behind MXU compute — on the CPU simulation the chunked schedules
   only add dispatches. Per-operator ``overlap=`` kwargs override the
-  env.
+  env. (SUMMA reads its ``auto`` more finely: its stationary-A forward
+  and adjoint choose per product — ``ops/matrixmult.py::_ring_pays``.)
 - ``PYLOPS_MPI_TPU_COMM_CHUNKS``: default chunk count (4) for the
   streamed pencil transposes when the overlap is enabled; per-operator
   ``comm_chunks=`` wins. Chunk counts that don't fit the axis fall
@@ -103,7 +104,8 @@ KNOBS = [
     ("PYLOPS_MPI_TPU_OVERLAP", "auto|on|off", "auto",
      "utils/deps.py (ops/matrixmult|fft|stack|derivatives|halo)",
      "pipelined-collectives seam: ring SUMMA, chunked transposes, "
-     "split halo stencils"),
+     "split halo stencils (`auto`: on for a TPU, except that SUMMA's "
+     "stationary-A forward and adjoint decide per product)"),
     ("PYLOPS_MPI_TPU_COMM_CHUNKS", "int>=1", "4",
      "utils/deps.py, ops/fft.py",
      "default chunk count for streamed pencil transposes"),

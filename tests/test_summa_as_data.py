@@ -23,7 +23,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 import pylops_mpi_tpu as pmt
 from pylops_mpi_tpu.autodiff import cgls_solve
 from pylops_mpi_tpu.linearoperator import operator_is_jit_arg
+from pylops_mpi_tpu.ops import matrixmult as mm
 from pylops_mpi_tpu.solvers import basic
+from pylops_mpi_tpu.utils import hlo
 
 NITER = 30
 HI = jax.lax.Precision.HIGHEST
@@ -334,3 +336,198 @@ def test_gradient_with_respect_to_the_matrix(shape):
     pad = np.asarray(g.Ap).copy()
     pad[:N, :K] = 0
     assert not pad.any()
+
+
+# ------------------------------- ring or bulk: `overlap=auto` is a rule
+# every row of the probe's table (PERF.md section 6, PR 28; TPU v5e,
+# A 65,536^2 f32 on 2 x 2 unless said): (what else the row varied,
+# columns a hop, forward ring / bulk ms, adjoint ring / bulk ms)
+PROBE = [
+    ("", 8, (11.831, 6.177), (11.763, 5.987)),
+    ("", 32, (12.644, 6.717), (12.112, 6.582)),
+    ("bf16_tiles", 32, (6.512, 3.793), (6.320, 3.513)),
+    ("tiles_1.07GB", 32, (3.439, 1.972), (3.195, 1.880)),
+    ("grid_1x4", 16, (23.842, 6.847), (23.548, 10.100)),
+    ("", 64, (13.538, 10.904), (13.231, 10.902)),
+    ("", 128, (20.084, 21.342), (21.021, 18.872)),
+    ("", 256, (43.312, 39.735), (37.926, 38.152)),
+    ("", 512, (82.880, 80.743), (74.980, 76.174)),
+    ("bf16_tiles", 512, (43.196, 42.846), (41.145, 42.616)),
+    ("tiles_1.07GB", 512, (22.451, 21.742), (20.264, 20.746)),
+    ("grid_1x4", 512, (161.077, 160.640), (142.545, 144.676)),
+    ("", 1024, (165.075, 161.902), (151.123, 152.731)),
+    ("", 2048, (329.977, 323.638), (301.980, 305.333)),
+]
+# the one row the rule does not follow (its docstring says why)
+NOT_CHASED = {("matvec", 128, "")}
+MEASURED_ROWS = [
+    pytest.param(kernel, cols, ms[0] < ms[1],
+                 id=f"{kernel}-{cols}" + (f"-{what}" if what else ""))
+    for what, cols, fwd, adj in PROBE
+    for kernel, ms in (("matvec", fwd), ("rmatvec", adj))
+    if (kernel, cols, what) not in NOT_CHASED]
+
+
+@pytest.mark.parametrize("kernel,cols,ring_was_faster", MEASURED_ROWS)
+def test_the_rule_follows_every_measured_row(kernel, cols, ring_was_faster):
+    assert mm._ring_pays(kernel, cols) is ring_was_faster
+
+
+def test_the_one_row_the_rule_does_not_chase():
+    """Forward at 128 columns a hop: the ring read 5.9 % faster, its
+    neighbours at 64 and 256 and the adjoint at 128 the other way."""
+    (fwd,) = [f for what, cols, f, _ in PROBE if (cols, what) == (128, "")]
+    assert fwd[0] < fwd[1] and not mm._ring_pays("matvec", 128)
+
+
+@pytest.fixture
+def as_on_a_tpu(monkeypatch):
+    """The backend's answer patched to ``tpu``: ``overlap=auto`` then
+    resolves as on the chip (``deps.overlap_enabled`` asks nothing else)."""
+    monkeypatch.delenv("PYLOPS_MPI_TPU_OVERLAP", raising=False)
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+
+
+def products(N=64, M=8, grid=(2, 2), cols=None, **kw):
+    """A small operator and jitted ``matvec`` / ``rmatvec`` with their
+    inputs: ``{"matvec": (fn, Op, x), "rmatvec": (fn, Op, y)}``."""
+    A, Y = seeded(19, N, N, M, np.float32, cols=cols or 1)
+    mesh = mesh_of(grid)
+    Op = summa(A, M, grid, mesh, **kw)
+    y = pmt.DistributedArray.to_dist(
+        Y.ravel() if cols is None else Y.reshape(N * M, cols), mesh=mesh)
+    return Op, {which: (jax.jit(lambda op, v, w=which: getattr(op, w)(v).array),
+                        Op, y) for which in ("matvec", "rmatvec")}
+
+
+def permutes_and_dots(fn, *args):
+    text = hlo.compiled_hlo(fn, *args)
+    return (len(hlo._op_results(text, "collective-permute")),
+            len(hlo._op_results(text, "dot")))
+
+
+def bulk_counts(which, **kw):
+    """``(permutes, dots)`` of the bulk product on the same shapes: one
+    local GEMM, and whatever hops the partitioner's own re-layout of
+    the 1-D vectors costs (not part of any ring)."""
+    _, fns = products(overlap="off", **kw)
+    n_perm, n_dots = permutes_and_dots(*fns[which])
+    assert n_dots == 1
+    return n_perm, n_dots
+
+
+def assert_rings(fn, op, v, which, **kw):
+    """``pc - 1`` hops beyond the bulk product's, ``pc`` local GEMMs."""
+    pc = op.grid[1]
+    hlo.assert_ring_schedule(fn, op, v, dots=pc, check_chain=False,
+                             steps=bulk_counts(which, **kw)[0] + pc - 1)
+
+
+def test_left_open_on_a_tpu_the_products_lower_as_the_rule_says(as_on_a_tpu):
+    """32 columns a hop, as the benchmark's cell: the rule says bulk, so
+    a default-constructed operator lowers each product to one local GEMM
+    and no hop — the very program ``overlap="off"`` gives."""
+    Op, fns = products(N=256, M=64)
+    assert Op.overlap == "auto" and Op.schedule == "stat_a"
+    assert not any(mm._ring_pays(which, 64 // Op.grid[1]) for which in fns)
+    _, off_fns = products(N=256, M=64, overlap="off")
+    for which, (fn, op, v) in fns.items():
+        text = hlo.compiled_hlo(fn, op, v)
+        assert len(hlo._op_results(text, "dot")) == 1
+        assert hlo.strip_provenance(text) == hlo.strip_provenance(
+            hlo.compiled_hlo(*off_fns[which]))
+
+
+def test_left_open_on_a_tpu_the_solver_loop_is_the_bulk_program(as_on_a_tpu):
+    auto = lowered_cgls(256, M=64)
+    assert auto == lowered_cgls(256, M=64, overlap="off")
+    assert "collective_permute" not in auto
+    assert "collective_permute" in lowered_cgls(256, M=64, overlap="on")
+
+
+def test_left_open_on_a_tpu_a_wide_adjoint_rings(as_on_a_tpu):
+    """256 columns a hop: the rule says ring for the adjoint and bulk
+    for the stationary-A forward, each product for itself."""
+    Op, fns = products(N=2048, M=512)
+    assert Op.overlap == "auto" and Op.schedule == "stat_a"
+    assert permutes_and_dots(*fns["matvec"]) == \
+        bulk_counts("matvec", N=2048, M=512)
+    assert_rings(*fns["rmatvec"], "rmatvec", N=2048, M=512)
+
+
+def test_a_block_input_decides_by_its_own_widened_width(as_on_a_tpu):
+    """Eight columns fold M = 64 into 512: 256 columns a hop where the
+    plain input has 32, so the same operator's adjoint now rings."""
+    _, plain = products(N=256, M=64)
+    _, block = products(N=256, M=64, cols=8)
+    for which in ("matvec", "rmatvec"):
+        assert permutes_and_dots(*plain[which]) == \
+            bulk_counts(which, N=256, M=64)
+    assert permutes_and_dots(*block["matvec"]) == \
+        bulk_counts("matvec", N=256, M=64, cols=8)
+    assert_rings(*block["rmatvec"], "rmatvec", N=256, M=64, cols=8)
+
+
+class SimplePlan(dict):
+    """What ``_consult_plan`` hands back, as far as the constructor
+    reads it: ``get`` and a ``provenance``."""
+    provenance = "tuned"
+
+
+@pytest.mark.parametrize("how", ["kwarg_true", "kwarg_on", "env", "plan"])
+def test_a_word_still_rings_at_the_cells_columns_a_hop(how, monkeypatch,
+                                                       as_on_a_tpu):
+    """``overlap=True`` / ``"on"``, the env pin and a tuner plan's
+    ``on`` choose the ring at 32 columns a hop, whatever the rule says."""
+    kw = {}
+    if how == "kwarg_true":
+        kw["overlap"] = True
+    elif how == "kwarg_on":
+        kw["overlap"] = "on"
+    elif how == "env":
+        monkeypatch.setenv("PYLOPS_MPI_TPU_OVERLAP", "on")
+    else:
+        monkeypatch.setattr(
+            mm._MPISummaMatrixMult, "_consult_plan",
+            lambda self, *a: SimplePlan(schedule="stat_a", overlap="on"))
+    Op, fns = products(N=256, M=64, **kw)
+    assert Op.overlap is True and Op.schedule == "stat_a"
+    assert Op._overlap_source == how.split("_")[0]
+    for which in ("matvec", "rmatvec"):
+        assert_rings(*fns[which], which, N=256, M=64)
+
+
+@pytest.mark.parametrize("word,source,overlap", [
+    (None, "rule", False), ("auto", "rule", False),
+    (False, "kwarg", False), ("off", "kwarg", False)])
+def test_off_a_tpu_nothing_rings_unless_told(word, source, overlap,
+                                             monkeypatch):
+    monkeypatch.delenv("PYLOPS_MPI_TPU_OVERLAP", raising=False)
+    Op, fns = products(N=256, M=64, overlap=word)
+    assert Op.overlap is overlap and Op._overlap_source == source
+    for which in ("matvec", "rmatvec"):
+        assert permutes_and_dots(*fns[which]) == bulk_counts(which, N=256, M=64)
+
+
+@pytest.mark.parametrize("cols,width", [(None, 8), (4, 32)])
+def test_each_traced_apply_leaves_its_selection_event(cols, width,
+                                                      as_on_a_tpu,
+                                                      monkeypatch):
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    from pylops_mpi_tpu.diagnostics import trace
+    Op, fns = products(M=8, cols=cols)
+    trace.clear_events()
+    for which in ("matvec", "rmatvec"):
+        fn, op, v = fns[which]
+        fn.lower(op, v)
+    got = [e["args"] for e in trace.get_events()
+           if e["name"] == "summa.ring_select"]
+    for e in got:
+        e.pop("jax_tracing", None)
+    tile = Op.Ap.nbytes // 4
+    assert got == [dict(kernel=which, cols_per_hop=width // 2,
+                        tile_bytes=tile,
+                        ring=0,
+                        source="rule") for which in ("matvec", "rmatvec")]
+    assert all(e["cat"] == "schedule" for e in trace.get_events()
+               if e["name"] == "summa.ring_select")
