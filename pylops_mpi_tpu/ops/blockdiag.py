@@ -15,7 +15,6 @@ of P small ones.
 
 from __future__ import annotations
 
-import os
 from typing import List, Optional, Sequence
 
 import numpy as np
@@ -64,7 +63,7 @@ class MPIBlockDiag(MPILinearOperator):
         automatically; pass an explicit dtype to override either way.
     normal_path : str, optional
         Which ``normal_matvec`` implementation to use: ``"fused"``
-        (the one-sweep Pallas/XLA-FFI kernel, when supported),
+        (the one-sweep Pallas kernel, when supported),
         ``"two_sweep"`` (plain matvec+rmatvec), or ``None``/``"auto"``
         (default) — fused when available, unless the autotuner
         (``PYLOPS_MPI_TPU_TUNE=on|auto``) has a measured plan saying
@@ -72,8 +71,8 @@ class MPIBlockDiag(MPILinearOperator):
         ``cgls(normal=None)`` follows it through
         :meth:`prefers_fused_normal`: ``"two_sweep"`` (given or tuned)
         keeps the default solve classic; otherwise the solve takes the
-        one-sweep schedule where the fused kernel is Mosaic on a TPU
-        with a row tile the chip has shown faster than two sweeps.
+        one-sweep schedule where the kernel is compiled (a TPU) with a
+        row tile the chip has shown faster than two sweeps.
     """
 
     def __init__(self, ops: Sequence[LocalOperator],
@@ -107,7 +106,7 @@ class MPIBlockDiag(MPILinearOperator):
             from ._precision import default_compute_dtype
             self.compute_dtype = default_compute_dtype(dtype)
         self._batched = self._try_batch()
-        # autotuner seam (round 10): the Pallas/XLA-FFI-vs-two-sweep
+        # autotuner seam (round 10): the Pallas-vs-two-sweep
         # normal-equation path. Only consulted for the default
         # sentinel; PYLOPS_MPI_TPU_TUNE=off leaves _normal_path None
         # (= fused when available — exactly today's behavior).
@@ -237,70 +236,41 @@ class MPIBlockDiag(MPILinearOperator):
             parts.append(jnp.diagonal(jnp.asarray(A)))
         return jnp.concatenate(parts).astype(self.dtype)
 
-    def _ffi_normal_usable(self) -> bool:
-        # CPU backends run the native one-pass XLA-FFI kernel
-        # (native/ffi.py) — Pallas-interpret would be a perf trap
-        # there. Complex blocks (MDD-style per-frequency solves,
-        # ``u = Aᴴ(Ax)`` with adjoint-side conjugation) are default-on
-        # since the planar rewrite: the complex dot runs as two real
-        # dots over the interleaved row, measured 4.9× the XLA
-        # two-sweep on one device and ≥1.0× on the sharded sim mesh
-        # (round 5). PYLOPS_MPI_TPU_FFI_COMPLEX=0 is the kill-switch.
-        import jax as _jax
-        if self._batched is None or _jax.default_backend() != "cpu":
-            return False
-        from ..native import ffi as nffi
-        dt = np.dtype(self._batched.dtype)
-        if not nffi.supports(dt):
-            return False
-        if (np.issubdtype(dt, np.complexfloating)
-                and os.environ.get("PYLOPS_MPI_TPU_FFI_COMPLEX") == "0"):
-            return False
-        return nffi.available()
-
     @property
     def has_fused_normal(self) -> bool:
+        """A one-sweep Pallas kernel exists for these blocks — the same
+        answer on every backend (compiled on a TPU, interpreted
+        elsewhere): batched vector-form blocks on a 1-D mesh, real with
+        a Mosaic-legal row tile, not forced to two sweeps (kwarg or
+        tuned plan). Complex blocks answer no."""
         from .pallas_kernels import normal_matvec_supported
-        if getattr(self, "_normal_path", None) == "two_sweep":
-            return False  # forced (kwarg or tuned plan)
-        if not (self._batched is not None
-                and self._batched_k == 1  # kernels are vector-form
-                and len(self.mesh.axis_names) == 1):  # shard_map is 1-D
-            return False
-        return (normal_matvec_supported(self._batched)
-                or self._ffi_normal_usable())
+        return (self._normal_path != "two_sweep"
+                and self._batched is not None
+                and self._batched_k == 1  # the kernel is vector-form
+                and len(self.mesh.axis_names) == 1  # shard_map is 1-D
+                and normal_matvec_supported(self._batched))
 
     def _normal_kernel_for(self, x: DistributedArray):
-        """The one-sweep kernel ``normal_matvec(x)`` runs, or ``None``
-        when it takes the generic two sweeps."""
-        # the fused kernels are vector-form: block (column-batched)
-        # inputs take the generic two-sweep path, whose widened einsums
-        # carry the column axis natively
-        if not self.has_fused_normal or x.ndim == 2:
+        """*Can*: the one-sweep kernel ``normal_matvec(x)`` runs, or
+        ``None`` when it takes the generic two sweeps."""
+        # the kernel is vector-form and real: block (column-batched)
+        # inputs take the two-sweep path, whose widened einsums carry
+        # the column axis natively, and a complex vector would be
+        # silently truncated
+        if (not self.has_fused_normal or x.ndim == 2
+                or jnp.issubdtype(x.dtype, jnp.complexfloating)):
             return None
-        from .pallas_kernels import normal_matvec_supported
-        if self._ffi_normal_usable() \
-                and np.dtype(x.dtype) == np.dtype(self._batched.dtype):
-            # the native kernel handles real AND complex blocks
-            from ..native.ffi import fused_normal
-            return fused_normal
-        if (normal_matvec_supported(self._batched)
-                and not jnp.issubdtype(x.dtype, jnp.complexfloating)):
-            # complex vectors would be silently truncated by the real
-            # Pallas kernel — only the real path may use it
-            from .pallas_kernels import batched_normal_matvec
-            return batched_normal_matvec
-        return None  # mismatched-dtype x, or complex without the FFI kernel
+        from .pallas_kernels import batched_normal_matvec
+        return batched_normal_matvec
 
     def prefers_fused_normal(self, x) -> bool:
-        """Yes only where ``normal_matvec(x)`` is the Mosaic kernel on a
-        TPU, fed a real vector of the kernel's accumulation dtype, with
-        a row tile the chip has shown faster than two sweeps
-        (``pallas_kernels.normal_matvec_pays``). The CPU's native FFI
-        kernel and Pallas in interpret mode answer no: there the
-        one-sweep path stays an explicit ``cgls(normal=True)``."""
-        from .pallas_kernels import batched_normal_matvec, normal_matvec_pays
-        if self._normal_kernel_for(x) is not batched_normal_matvec:
+        """*Pays*: ``normal_matvec(x)`` is the kernel, compiled (Pallas
+        in interpret mode answers no: off a TPU the one-sweep path
+        stays an explicit ``cgls(normal=True)``), fed a vector of its
+        accumulation dtype, with a row tile the chip has shown faster
+        than two sweeps (``pallas_kernels.normal_matvec_pays``)."""
+        from .pallas_kernels import normal_matvec_pays
+        if self._normal_kernel_for(x) is None:
             return False
         acc = jnp.promote_types(self._batched.dtype, jnp.float32)
         return (np.dtype(x.dtype) == np.dtype(acc)
@@ -308,12 +278,10 @@ class MPIBlockDiag(MPILinearOperator):
 
     def normal_matvec(self, x: DistributedArray):
         """``(u, q) = (OpᴴOp x, Op x)`` with ONE memory sweep of the
-        block matrices when batched: on TPU the Pallas
-        ``_normal_kernel`` feeds both products from each VMEM-resident
-        A tile; on CPU the native XLA-FFI kernel (``native/ffi.py``)
-        does the same against DRAM (measured 1.6x the two-sweep
-        einsum pair at the 4096² flagship block). Falls back to
-        matvec+rmatvec otherwise."""
+        block matrices where :attr:`has_fused_normal` and ``x`` is a
+        real vector: the Pallas ``_normal_kernel`` feeds both products
+        from each VMEM-resident A tile (``pmt_normal``; interpreted off
+        a TPU). Falls back to matvec+rmatvec otherwise."""
         kernel = self._normal_kernel_for(x)
         if kernel is None:
             return super().normal_matvec(x)

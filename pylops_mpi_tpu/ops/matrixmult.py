@@ -131,13 +131,14 @@ def _unpad(x: jax.Array, rows: int, cols: int) -> jax.Array:
     return x if x.shape == (rows, cols) else x[:rows, :cols]
 
 
-def _ring_pays(kernel: str, cols_per_hop: int) -> bool:
-    """Whether SUMMA's ring form of ``kernel`` (``"matvec"``: the
-    stationary-A forward, ``"rmatvec"``: the adjoint) beats its bulk
-    form when one hop's GEMM gets ``cols_per_hop`` columns — the rule
-    behind ``overlap="auto"`` on a TPU. Both ring kernels read the
-    whole resident tile once a hop, ``pc`` times a product; the bulk
-    kernels read it once.
+def _ring_pays(cols_per_hop: int) -> bool:
+    """Whether the ring form of SUMMA's adjoint beats its bulk form
+    when one hop's GEMM gets ``cols_per_hop`` columns — the rule behind
+    ``overlap="auto"`` on a TPU. The ring reads the whole resident tile
+    once a hop, ``pc`` times a product; the bulk kernel reads it once.
+    The stationary-A forward had a ring of the same shape (a ring
+    reduce-scatter in place of ``psum_scatter``); it won no row below
+    and is gone, so the forward's column is why it has none.
 
     Measured on the SAME tiles, ring / bulk in ms, best of 3 x 10 jitted
     calls, TPU v5e, f32 under ``highest`` (PERF.md section 6, PR 28; the
@@ -175,7 +176,7 @@ def _ring_pays(kernel: str, cols_per_hop: int) -> bool:
     and the adjoint at 128 go the other way, and the hop it could hide
     is 16 MB — one shape's tiling, not chased.
     """
-    return kernel == "rmatvec" and cols_per_hop >= 256
+    return cols_per_hop >= 256
 
 
 class _MatMulBase(MPILinearOperator):
@@ -321,39 +322,40 @@ class _MPISummaMatrixMult(_MatMulBase):
       been stationary-A (gather Y, GEMM, psum).
 
     ``overlap`` (``PYLOPS_MPI_TPU_OVERLAP``) chooses between the bulk
-    kernels and their ring-pipelined forms (round 8, arXiv 2112.09017):
-    the bulk collective along ``c`` decomposes into ``pc - 1``
-    double-buffered ``ppermute`` hops interleaved with ``pc`` per-block
-    GEMMs (:func:`~pylops_mpi_tpu.parallel.collectives.ring_pass`), so
-    each hop's ICI transfer can hide behind the resident block's MXU
-    work:
+    kernels and the ring-pipelined forms two of them have (round 8,
+    arXiv 2112.09017): the bulk collective along ``c`` decomposes into
+    ``pc - 1`` double-buffered ``ppermute`` hops interleaved with ``pc``
+    per-block GEMMs
+    (:func:`~pylops_mpi_tpu.parallel.collectives.ring_pass`), so each
+    hop's ICI transfer can hide behind the resident block's MXU work:
 
     - gather/ring: A tiles rotate along ``c``; each step GEMMs the
       resident tile against its k-slice of the gathered X column.
-    - stat_a/ring: A still never moves — the ``psum_scatter`` becomes
-      a ring reduce-scatter whose per-chunk partial GEMM is computed
-      just-in-time at each hop.
     - adjoint/ring: Y tiles rotate along ``c``; each step's GEMM fills
       the owner's M-column chunk; the ``r`` psum is unchanged.
 
+    The stationary-A forward has one kernel, the bulk one
+    (``psum_scatter``): its ring reduce-scatter lost to it in every row
+    the chip measured (:func:`_ring_pays`) and was removed.
+
     A word — ``overlap=True/False/"on"/"off"``, a pinned
-    ``PYLOPS_MPI_TPU_OVERLAP=on|off``, a tuner plan — chooses the ring
-    or the bulk form of all three at every shape. ``auto`` (nothing
-    said) is bulk off a TPU. On a TPU the attribute reads ``"auto"``
-    and the choice is made per traced apply: stat_a/ring and
-    adjoint/ring read the whole resident tile once a HOP (``pc`` times
-    a product where the bulk form reads it once), so each asks
-    :func:`_ring_pays` with the columns one hop's GEMM gets — from the
-    operand's own width, so a block input that widens M to M*K decides
-    by M*K — and a skinny right-hand side takes the bulk form (the
-    measured rows are in that docstring). gather/ring reads each tile
-    once and rings under ``auto`` on a TPU as before (not measured).
-    Each decision leaves a ``summa.ring_select`` trace event
-    (``kernel``, ``cols_per_hop``, ``tile_bytes``, ``ring``,
-    ``source``: ``rule``/``kwarg``/``env``/``plan``). ``off`` keeps
-    the bulk kernels bit-identical; the ring reorders the
-    floating-point accumulation (per-block partial sums) and matches
-    within dtype tolerance.
+    ``PYLOPS_MPI_TPU_OVERLAP=on|off``, a tuner plan — means the ring
+    where a ring exists (the gather forward, the adjoint) or the bulk
+    form, at every shape. ``auto`` (nothing said) is bulk off a TPU.
+    On a TPU the attribute reads ``"auto"`` and the adjoint's choice is
+    made per traced apply: its ring reads the whole resident tile once
+    a HOP (``pc`` times a product where the bulk form reads it once),
+    so it asks :func:`_ring_pays` with the columns one hop's GEMM gets
+    — from the operand's own width, so a block input that widens M to
+    M*K decides by M*K — and a skinny right-hand side takes the bulk
+    form (the measured rows are in that docstring). gather/ring reads
+    each tile once and rings under ``auto`` on a TPU as before (not
+    measured). Each adjoint decision leaves a ``summa.ring_select``
+    trace event (``kernel``, ``cols_per_hop``, ``tile_bytes``,
+    ``ring``, ``source``: ``rule``/``kwarg``/``env``/``plan``); the
+    forward leaves none. ``off`` keeps the bulk kernels bit-identical;
+    the ring reorders the floating-point accumulation (per-block
+    partial sums) and matches within dtype tolerance.
 
     ``hierarchical`` (``PYLOPS_MPI_TPU_HIERARCHICAL``, round 11): on a
     hybrid mesh the (r, c) grid inherits the base mesh's dcn-major
@@ -395,8 +397,8 @@ class _MPISummaMatrixMult(_MatMulBase):
             tplan = self._consult_plan(A, M, base, dtype,
                                        compute_dtype)
         # who chose between ring and bulk: a word (kwarg, env pin, tuner
-        # plan) holds at every shape; with nothing said the stationary-A
-        # forward and the adjoint ask _ring_pays per traced apply
+        # plan) holds at every shape; with nothing said the adjoint
+        # asks _ring_pays per traced apply
         if overlap is None:
             self._overlap_source = "rule" if want_overlap else "env"
         else:
@@ -411,7 +413,7 @@ class _MPISummaMatrixMult(_MatMulBase):
             hierarchical = tplan.get("hierarchical")
         # True/False: a word, or auto off a TPU (bulk). "auto": left
         # open on a TPU — the gather forward rings as it always has
-        # there, the other two kernels decide by the rule
+        # there, the adjoint decides by the rule
         self.overlap = overlap_enabled(overlap)
         if self.overlap and self._overlap_source == "rule":
             self.overlap = "auto"
@@ -597,41 +599,6 @@ class _MPISummaMatrixMult(_MatMulBase):
         return ring_pass(Ablk, "c", pc, body, slice_size=self._ring_slice,
                          fabric=self._fab_c)
 
-    def _kernel_fwd_stat_a_ring(self, Ablk, Xblk):
-        # ring reduce-scatter form of stationary-A: A still never
-        # moves; the bulk psum_scatter becomes pc-1 accumulator hops
-        # along 'c', and the partial GEMM for each output M-chunk is
-        # computed just-in-time at its hop so the chunk transfer hides
-        # behind the next chunk's GEMM. (No hierarchical variant
-        # needed: every hop is a neighbour shift, so on a slice-blocked
-        # 'c' axis only the block-boundary pairs ever cross DCN — the
-        # schedule is already staged by construction.)
-        pc = self.grid[1]
-        Xfull = lax.all_gather(Xblk, "r", axis=0, tiled=True)
-        Xfull = lax.all_gather(Xfull, "c", axis=1, tiled=True)  # (Kp_r, Mp)
-        if self.Kp_c > self.Kp_r:
-            Xfull = jnp.pad(Xfull, ((0, self.Kp_c - self.Kp_r), (0, 0)))
-        kb = self.Kp_c // pc
-        # chunk width from the operand, not self.Mp: block inputs widen
-        # M to M*K and the ring then moves K columns per hop
-        mb = Xfull.shape[1] // pc
-        c = lax.axis_index("c")
-        Xk = lax.dynamic_slice_in_dim(Xfull, c * kb, kb, axis=0)
-
-        def chunk(j):
-            Xkj = lax.dynamic_slice_in_dim(Xk, j * mb, mb, axis=1)
-            return self._gemm(Ablk, Xkj)            # (Np/pr, Mp/pc)
-
-        if pc == 1:
-            return chunk(c * 0)
-        perm = [(r, (r - 1) % pc) for r in range(pc)]
-        buf = chunk((c + 1) % pc)
-        for s in range(pc - 1):
-            rb = lax.ppermute(buf, "c", perm)
-            # the next chunk's GEMM carries no dependence on the hop
-            buf = rb + chunk((c + s + 2) % pc)
-        return buf  # fully reduced chunk c — psum_scatter's layout
-
     def _kernel_adj_ring(self, Ablk, Yblk):
         # ring form of the adjoint: Y tiles rotate along 'c'; each hop
         # GEMMs the resident tile into its owner's M-column chunk
@@ -683,18 +650,18 @@ class _MPISummaMatrixMult(_MatMulBase):
         part = self._gemm(jnp.conj(Ablk).T, Yrow)              # (Kp_c/pc, Mp)
         return lax.psum(part, "r")
 
-    def _rings(self, kernel: str, cols_per_hop: int) -> bool:
-        """Ring or bulk for one traced apply of the two kernels that
-        re-read the resident tile every hop (stationary-A forward, the
-        adjoint). A word decides as it always has; ``auto`` on a TPU
-        asks :func:`_ring_pays`. Leaves one ``summa.ring_select`` event
-        per trace (not per execution)."""
+    def _rings(self, cols_per_hop: int) -> bool:
+        """Ring or bulk for one traced apply of the adjoint, whose ring
+        re-reads the resident tile every hop. A word decides as it
+        always has; ``auto`` on a TPU asks :func:`_ring_pays`. Leaves
+        one ``summa.ring_select`` event per trace (not per
+        execution)."""
         pr, pc = self.grid
         ring = bool(self.overlap) and pc > 1
         if ring and self.overlap == "auto":
-            ring = _ring_pays(kernel, cols_per_hop)
+            ring = _ring_pays(cols_per_hop)
         from ..diagnostics import trace
-        trace.event("summa.ring_select", cat="schedule", kernel=kernel,
+        trace.event("summa.ring_select", cat="schedule", kernel="rmatvec",
                     cols_per_hop=cols_per_hop,
                     tile_bytes=int(self.Ap.nbytes) // (pr * pc),
                     ring=int(ring), source=self._overlap_source)
@@ -707,9 +674,9 @@ class _MPISummaMatrixMult(_MatMulBase):
         Mp = pc * int(np.ceil(Me / pc))
         X = _pad_to(X, self.Kp_r, Mp)
         if self.schedule == "stat_a":
-            kernel = (self._kernel_fwd_stat_a_ring
-                      if self._rings("matvec", Mp // pc)
-                      else self._kernel_fwd_stat_a)
+            # no ring form: _ring_pays' docstring has the rows that
+            # say why
+            kernel = self._kernel_fwd_stat_a
         else:
             # the gather ring rotates A tiles and reads each once: its
             # auto stays the backend's (not measured, PERF.md)
@@ -726,7 +693,7 @@ class _MPISummaMatrixMult(_MatMulBase):
         Me = Y.shape[1]
         Mp = pc * int(np.ceil(Me / pc))
         Y = _pad_to(Y, self.Np, Mp)
-        kernel = (self._kernel_adj_ring if self._rings("rmatvec", Mp // pc)
+        kernel = (self._kernel_adj_ring if self._rings(Mp // pc)
                   else self._kernel_adj)
         X = shard_map(kernel, mesh=self.mesh2,
                       in_specs=(P("r", "c"), P("r", "c")),
@@ -787,15 +754,15 @@ def MPIMatrixMult(A, M: int, saveAt: bool = False, mesh=None,
     partials — wins for skinny X), or "auto" (per-device byte count
     decides). ``overlap`` (summa only; ``True``/``False``/``"on"``/
     ``"off"``/``"auto"``, default the ``PYLOPS_MPI_TPU_OVERLAP`` env
-    seam) runs the selected schedule as a double-buffered ``ppermute``
-    ring that hides the ICI transfer of each block behind the GEMM on
-    the resident one — ``off`` is bit-identical to the bulk schedules,
+    seam) runs the gather forward and the adjoint as a double-buffered
+    ``ppermute`` ring that hides the ICI transfer of each block behind
+    the GEMM on the resident one (the stationary-A forward has no ring
+    and stays bulk) — ``off`` is bit-identical to the bulk schedules,
     ``on`` matches within dtype tolerance (the accumulation order
-    changes). ``auto`` is bulk off a TPU; on a TPU the stationary-A
-    forward and the adjoint decide per apply from the columns one
-    hop's GEMM gets (a skinny right-hand side stays bulk: the ring
-    would re-read the resident tile every hop), the gather forward
-    rings — see ``_MPISummaMatrixMult``. ``block`` and ``auto`` kinds
+    changes). ``auto`` is bulk off a TPU; on a TPU the adjoint decides
+    per apply from the columns one hop's GEMM gets (a skinny right-hand
+    side stays bulk: the ring would re-read the resident tile every
+    hop), the gather forward rings — see ``_MPISummaMatrixMult``. ``block`` and ``auto`` kinds
     ignore it (forward is comm-free / the partitioner owns the
     schedule). ``hierarchical`` (summa only;
     ``True``/``False``/``"auto"``, default the

@@ -251,7 +251,7 @@ def _cost_blockdiag(context: Dict, params: Dict) -> Optional[float]:
         return None
     P = max(1, int(context.get("n_dev") or 1))
     pk = _peaks(context)
-    # the normal-equation apply is HBM-bound: the fused (Pallas/FFI)
+    # the normal-equation apply is HBM-bound: the fused (Pallas)
     # path streams the block stack ONCE per (u, q) pair, the two-sweep
     # einsum pair twice — the whole reason the kernel exists
     sweeps = 1.0 if params.get("normal_path") == "fused" else 2.0
@@ -482,7 +482,7 @@ register_space(TuningSpace(
           Axis("batch", (1, 2, 4, 8, 16, 32, 64), fixed=True)),
     cost=_cost_blockdiag,
     enumerate_fn=_enum_blockdiag,
-    note="fused (Pallas/XLA-FFI one-sweep) vs two-sweep normal "
+    note="fused (Pallas one-sweep) vs two-sweep normal "
          "equations; Pallas tile shape is fixed by the Mosaic 8x128 "
          "rule (ops/pallas_kernels.py), recorded for provenance"))
 

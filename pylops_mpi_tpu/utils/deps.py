@@ -36,8 +36,9 @@ backend — XLA collectives — so the seam carries different switches:
   overlap only on real TPU backends, where it hides ICI transfer
   behind MXU compute — on the CPU simulation the chunked schedules
   only add dispatches. Per-operator ``overlap=`` kwargs override the
-  env. (SUMMA reads its ``auto`` more finely: its stationary-A forward
-  and adjoint choose per product — ``ops/matrixmult.py::_ring_pays``.)
+  env. (SUMMA reads its ``auto`` more finely: its adjoint chooses per
+  product — ``ops/matrixmult.py::_ring_pays`` — and its stationary-A
+  forward has no ring.)
 - ``PYLOPS_MPI_TPU_COMM_CHUNKS``: default chunk count (4) for the
   streamed pencil transposes when the overlap is enabled; per-operator
   ``comm_chunks=`` wins. Chunk counts that don't fit the axis fall
@@ -147,10 +148,6 @@ KNOBS = [
      "pairs)"),
     ("PYLOPS_MPI_TPU_DFT_BASE", "int", "128 on TPU / 16 on CPU",
      "ops/dft.py", "mixed-radix GEMM base of the matmul DFT engine"),
-    ("PYLOPS_MPI_TPU_FFI_COMPLEX", "0|1", "1", "ops/blockdiag.py",
-     "complex blocks may use the native XLA-FFI fused-normal kernel"),
-    ("PYLOPS_MPI_TPU_FFI_THREADS", "int", "cores/devices",
-     "native/ffi.py", "threads per FFI fused-normal kernel call"),
     ("PYLOPS_MPI_TPU_NATIVE", "0|1", "1", "native/__init__.py",
      "build/load the native host-pack helper library"),
     ("PYLOPS_MPI_TPU_NATIVE_THREADS", "int", "min(16, cores)",

@@ -102,30 +102,21 @@ def main() -> None:
     # boundary-slab ppermute halo exchange crosses the process boundary
     flat = pmt.make_mesh()
 
-    # the native one-pass normal kernel (XLA-FFI) across processes:
-    # each process builds/registers the custom call locally, and the
-    # fused loop dispatches it per shard. Needs the FLAT 1-D mesh
-    # (has_fused_normal declines multi-axis meshes), and the
-    # availability decision must be AGREED across processes — a
-    # one-sided build failure branching into divergent programs would
-    # deadlock the mesh-wide collectives instead of failing loudly.
-    from jax.experimental import multihost_utils
-    from pylops_mpi_tpu.native import ffi as _nffi
-    ok_all = multihost_utils.process_allgather(
-        np.array(1.0 if _nffi.available() else 0.0))
-    if float(np.min(ok_all)) > 0:
-        Opf = pmt.MPIBlockDiag([MatrixMult(b, dtype=np.float32)
-                                for b in blocks], mesh=flat)
-        assert Opf.has_fused_normal, \
-            "FFI normal kernel must engage on the flat CPU mesh"
-        dyf = pmt.DistributedArray.to_dist(y, mesh=flat)
-        x0f = pmt.DistributedArray.to_dist(np.zeros_like(xt), mesh=flat)
-        xn, *_ = pmt.cgls(Opf, dyf, x0=x0f, niter=40, tol=0.0,
-                          normal=True)
-        nerr = float(jax.jit(
-            lambda a: jnp.linalg.norm(a - jnp.asarray(xt))
-            / np.linalg.norm(xt))(xn._arr))
-        assert nerr < 1e-3, f"CGLS(normal=True) rel err {nerr}"
+    # the one-sweep normal kernel (Pallas, interpreted on the CPU)
+    # across processes: the fused loop runs it per shard. Needs the
+    # FLAT 1-D mesh (has_fused_normal declines multi-axis meshes).
+    Opf = pmt.MPIBlockDiag([MatrixMult(b, dtype=np.float32)
+                            for b in blocks], mesh=flat)
+    assert Opf.has_fused_normal, \
+        "the Pallas normal kernel must engage on the flat mesh"
+    dyf = pmt.DistributedArray.to_dist(y, mesh=flat)
+    x0f = pmt.DistributedArray.to_dist(np.zeros_like(xt), mesh=flat)
+    xn, *_ = pmt.cgls(Opf, dyf, x0=x0f, niter=40, tol=0.0,
+                      normal=True)
+    nerr = float(jax.jit(
+        lambda a: jnp.linalg.norm(a - jnp.asarray(xt))
+        / np.linalg.norm(xt))(xn._arr))
+    assert nerr < 1e-3, f"CGLS(normal=True) rel err {nerr}"
     nD = 64
     Dop = pmt.MPIFirstDerivative((nD,), kind="centered", order=5,
                                  edge=True, mesh=flat, dtype=np.float32)

@@ -139,7 +139,7 @@ def _small(mesh=None, otherdims=(), n=512, **kw):
 
 def test_on_the_cpu_the_answer_is_no():
     Op = _flagship()
-    assert Op.has_fused_normal            # the native / interpret kernel
+    assert Op.has_fused_normal            # the kernel, interpreted here
     assert Op.prefers_fused_normal(_vec(Op)) is False
     assert pk.normal_matvec_pays(Op._batched) is False
 
@@ -184,6 +184,26 @@ def test_operator_answer_on_a_tpu(monkeypatch, case):
     x = _vec(Op, **vec_kw)
     assert Op.prefers_fused_normal(x) is expected
     assert basic._resolve_normal(Op, x, None) is expected
+
+
+@pytest.mark.parametrize("build,has", [
+    (lambda: _small(), True),
+    (lambda: _small(compute_dtype=jnp.bfloat16), True),
+    (lambda: _complex_blocks(), False)],
+    ids=["f32_blocks", "bf16_storage", "complex_blocks"])
+def test_has_fused_normal_means_one_thing_on_every_backend(monkeypatch,
+                                                           build, has):
+    """``has_fused_normal``: a Pallas kernel exists for these blocks —
+    the CPU's answer is the chip's (only *pays* reads the backend)."""
+    Op = build()
+    assert Op.has_fused_normal is has
+    x = _vec(Op, dtype=Op.dtype)
+    kernel = Op._normal_kernel_for(x)
+    assert (kernel is pk.batched_normal_matvec) is has
+    monkeypatch.setattr(jax, "default_backend", lambda: "tpu")
+    assert Op.has_fused_normal is has
+    assert Op._normal_kernel_for(x) is kernel
+    assert build().has_fused_normal is has     # and built as on a TPU
 
 
 def test_tuned_two_sweep_plan_answers_no(monkeypatch):

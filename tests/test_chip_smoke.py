@@ -90,7 +90,7 @@ def test_rehearsal_flagship_solves_agree_with_reference(rehearsal, name):
     tol = chip_smoke.BF16_TOL if "bf16" in name else chip_smoke.F32_TOL
     assert 0.0 <= row["err"] <= tol
     assert row["err_true"] <= tol
-    assert row["mosaic"] == 0   # interpret / native kernels off the chip
+    assert row["mosaic"] == 0   # the kernels are interpreted off the chip
     assert row["setup_s"] >= 0 and row["run_s"] > 0
 
 

@@ -224,138 +224,3 @@ def test_local_split_native_matches_python():
         nat = native.local_split_native(n, p)
         ref = [s[0] for s in local_split((n,), p, Partition.SCATTER, 0)]
         np.testing.assert_array_equal(nat, ref)
-
-
-# ---------------------------------------------------------- FFI normal
-
-
-def _ffi():
-    from pylops_mpi_tpu.native import ffi as nffi
-    if not nffi.available():
-        pytest.skip("native FFI kernel unavailable (no g++/headers)")
-    return nffi
-
-
-@pytest.mark.parametrize("dtype", [np.float32, np.float64])
-@pytest.mark.parametrize("shape", [(1, 64, 64), (3, 40, 56), (2, 17, 5)])
-def test_ffi_fused_normal_oracle(rng, dtype, shape):
-    """One-pass (AᵀAx, Ax) against the einsum oracle, ragged shapes
-    included (the slab split must handle m not divisible by threads)."""
-    nffi = _ffi()
-    import jax.numpy as jnp
-    nblk, m, n = shape
-    A = jnp.asarray(rng.standard_normal(shape).astype(dtype))
-    X = jnp.asarray(rng.standard_normal((nblk, n)).astype(dtype))
-    U, Q = jax.jit(nffi.fused_normal)(A, X)
-    wq = np.einsum("bmn,bn->bm", np.asarray(A), np.asarray(X))
-    wu = np.einsum("bmn,bm->bn", np.asarray(A), wq)
-    tol = 1e-5 if dtype == np.float32 else 1e-12
-    assert np.linalg.norm(Q - wq) / np.linalg.norm(wq) < tol
-    assert np.linalg.norm(U - wu) / np.linalg.norm(wu) < tol
-
-
-def test_ffi_fused_normal_single_thread_env(rng, monkeypatch):
-    """PYLOPS_MPI_TPU_FFI_THREADS=1 exercises the no-spawn path (the
-    kernel-specific knob, distinct from the pack/IO helpers')."""
-    nffi = _ffi()
-    import jax.numpy as jnp
-    monkeypatch.setenv("PYLOPS_MPI_TPU_FFI_THREADS", "1")
-    A = jnp.asarray(rng.standard_normal((2, 96, 32)).astype(np.float32))
-    X = jnp.asarray(rng.standard_normal((2, 32)).astype(np.float32))
-    U, Q = nffi.fused_normal(A, X)
-    wq = np.einsum("bmn,bn->bm", np.asarray(A), np.asarray(X))
-    wu = np.einsum("bmn,bm->bn", np.asarray(A), wq)
-    assert np.linalg.norm(U - wu) / np.linalg.norm(wu) < 1e-5
-    assert np.linalg.norm(Q - wq) / np.linalg.norm(wq) < 1e-5
-
-
-def test_blockdiag_normal_matvec_uses_ffi_on_cpu(rng, ndev):
-    """On CPU backends the batched BlockDiag normal product must route
-    through the native one-pass kernel and agree with the generic
-    two-sweep pair (the solver-facing contract of cgls(normal=True))."""
-    _ffi()
-    from pylops_mpi_tpu import MPIBlockDiag
-    from pylops_mpi_tpu.ops.local import MatrixMult
-    # P blocks: the batched layout (and thus the kernel) needs
-    # nblocks % P == 0 at ANY test mesh size
-    blocks = [rng.standard_normal((24, 24)).astype(np.float32)
-              for _ in range(ndev)]
-    Op = MPIBlockDiag([MatrixMult(b, dtype=np.float32) for b in blocks])
-    assert Op.has_fused_normal
-    x = DistributedArray.to_dist(
-        rng.standard_normal(Op.shape[1]).astype(np.float32))
-    u, q = Op.normal_matvec(x)
-    q2 = Op.matvec(x)
-    u2 = Op.rmatvec(q2)
-    np.testing.assert_allclose(q.asarray(), q2.asarray(), rtol=2e-5,
-                               atol=2e-5)
-    np.testing.assert_allclose(u.asarray(), u2.asarray(), rtol=2e-4,
-                               atol=2e-4)
-
-
-def test_cgls_normal_matches_two_sweep_cpu(rng, ndev):
-    """cgls(normal=True) through the FFI kernel converges to the same
-    solution as the two-sweep fused loop."""
-    _ffi()
-    from pylops_mpi_tpu import MPIBlockDiag, cgls
-    from pylops_mpi_tpu.ops.local import MatrixMult
-    n = 32
-    P = ndev
-    blocks = []
-    for _ in range(P):
-        b = (rng.standard_normal((n, n)) / np.sqrt(n)).astype(np.float32)
-        np.fill_diagonal(b, b.diagonal() + 4.0)
-        blocks.append(b)
-    Op = MPIBlockDiag([MatrixMult(b, dtype=np.float32) for b in blocks])
-    xt = rng.standard_normal(P * n).astype(np.float32)
-    y = Op.matvec(DistributedArray.to_dist(xt))
-    xa, *_ = cgls(Op, y, niter=50, tol=0.0, normal=True)
-    xb, *_ = cgls(Op, y, niter=50, tol=0.0, normal=False)
-    assert np.linalg.norm(xa.asarray() - xt) / np.linalg.norm(xt) < 1e-4
-    np.testing.assert_allclose(xa.asarray(), xb.asarray(), rtol=1e-3,
-                               atol=1e-4)
-
-
-@pytest.mark.parametrize("dtype", [np.complex64, np.complex128])
-def test_ffi_fused_normal_complex_oracle(rng, dtype):
-    """Complex one-pass (AᴴAx, Ax): the adjoint side conjugates, the
-    forward side does not."""
-    nffi = _ffi()
-    import jax.numpy as jnp
-    A = jnp.asarray((rng.standard_normal((2, 40, 56))
-                     + 1j * rng.standard_normal((2, 40, 56))).astype(dtype))
-    X = jnp.asarray((rng.standard_normal((2, 56))
-                     + 1j * rng.standard_normal((2, 56))).astype(dtype))
-    U, Q = jax.jit(nffi.fused_normal)(A, X)
-    wq = np.einsum("bmn,bn->bm", np.asarray(A), np.asarray(X))
-    wu = np.einsum("bmn,bm->bn", np.asarray(A).conj(), wq)
-    tol = 1e-5 if dtype == np.complex64 else 1e-12
-    assert np.linalg.norm(Q - wq) / np.linalg.norm(wq) < tol
-    assert np.linalg.norm(U - wu) / np.linalg.norm(wu) < tol
-
-
-def test_blockdiag_complex_ffi_default_on(rng, monkeypatch, ndev):
-    """Complex blocks use the FFI kernel by default (planar rewrite,
-    docs/design.md round-5 findings); PYLOPS_MPI_TPU_FFI_COMPLEX=0 is
-    the kill-switch back to the generic pair."""
-    _ffi()
-    from pylops_mpi_tpu import MPIBlockDiag, cgls
-    from pylops_mpi_tpu.ops.local import MatrixMult
-    nb = 16
-    P = ndev
-    blocks = []
-    for _ in range(P):
-        b = (rng.standard_normal((nb, nb))
-             + 1j * rng.standard_normal((nb, nb))) / np.sqrt(nb)
-        b += 4.0 * np.eye(nb)
-        blocks.append(b.astype(np.complex128))
-    Op = MPIBlockDiag([MatrixMult(b) for b in blocks])
-    monkeypatch.delenv("PYLOPS_MPI_TPU_FFI_COMPLEX", raising=False)
-    assert Op._ffi_normal_usable() and Op.has_fused_normal
-    monkeypatch.setenv("PYLOPS_MPI_TPU_FFI_COMPLEX", "0")
-    assert not Op._ffi_normal_usable()          # kill-switch
-    monkeypatch.delenv("PYLOPS_MPI_TPU_FFI_COMPLEX", raising=False)
-    xt = rng.standard_normal(P * nb) + 1j * rng.standard_normal(P * nb)
-    y = Op.matvec(DistributedArray.to_dist(xt))
-    xa, *_ = cgls(Op, y, niter=60, tol=0.0, normal=True)
-    assert np.linalg.norm(xa.asarray() - xt) / np.linalg.norm(xt) < 1e-10

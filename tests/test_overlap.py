@@ -211,33 +211,11 @@ def test_summa_off_bit_identical(rng, monkeypatch):
         assert counts.get("collective-permute", 0) == 0
 
 
-@pytest.mark.parametrize("schedule", ["gather", "stat_a"])
-def test_summa_ring_hlo_pin(rng, schedule):
-    """The ring forward compiles to pc-1 chained collective-permutes
-    interleaved with pc dots (the double-buffered schedule)."""
-    A = rng.standard_normal((24, 16))
-    X = rng.standard_normal((16, 8))
-    Op = MPIMatrixMult(A, 8, kind="summa", dtype=np.float64,
-                       schedule=schedule, overlap="on")
-    pc = Op.grid[1]
-    if pc < 2:
-        pytest.skip("ring needs a >1 column grid")
-    dx = DistributedArray.to_dist(X.ravel())
-    n_perm, n_dots = assert_ring_schedule(jax.jit(Op._matvec), dx,
-                                          steps=pc - 1, dots=pc)
-    assert (n_perm, n_dots >= pc) == (pc - 1, True)
-
-
-def test_summa_adj_ring_hlo_pin(rng):
+def _assert_adj_ring(Op, rng):
     """Adjoint ring pin on the isolated kernel (the full _rmatvec adds
     one output-layout permute that is not part of the ring)."""
     from pylops_mpi_tpu.ops.matrixmult import _pad_to
-    A = rng.standard_normal((24, 16))
-    Op = MPIMatrixMult(A, 8, kind="summa", dtype=np.float64,
-                       schedule="gather", overlap="on")
     pc = Op.grid[1]
-    if pc < 2:
-        pytest.skip("ring needs a >1 column grid")
     Y = _pad_to(jnp.asarray(rng.standard_normal((24, 8))), Op.Np, Op.Mp)
 
     def f(Ap, Yp):
@@ -247,6 +225,43 @@ def test_summa_adj_ring_hlo_pin(rng):
                          check_vma=False)(Ap, Yp)
 
     assert_ring_schedule(jax.jit(f), Op.Ap, Y, steps=pc - 1, dots=pc)
+
+
+@pytest.mark.parametrize("schedule", ["gather", "stat_a"])
+def test_summa_ring_hlo_pin(rng, schedule):
+    """The gather ring forward compiles to pc-1 chained
+    collective-permutes interleaved with pc dots (the double-buffered
+    schedule). The stationary-A forward has no ring: under
+    overlap="on" it keeps the bulk product's collectives, and the
+    operator's adjoint is the one that rings."""
+    A = rng.standard_normal((24, 16))
+    X = rng.standard_normal((16, 8))
+    Op = MPIMatrixMult(A, 8, kind="summa", dtype=np.float64,
+                       schedule=schedule, overlap="on")
+    pc = Op.grid[1]
+    if pc < 2:
+        pytest.skip("ring needs a >1 column grid")
+    dx = DistributedArray.to_dist(X.ravel())
+    if schedule == "stat_a":
+        off = MPIMatrixMult(A, 8, kind="summa", dtype=np.float64,
+                            schedule=schedule, overlap="off")
+        counts = count_collectives(jax.jit(Op._matvec), dx)
+        assert counts == count_collectives(jax.jit(off._matvec), dx)
+        assert counts.get("collective-permute", 0) == 0
+        _assert_adj_ring(Op, rng)
+        return
+    n_perm, n_dots = assert_ring_schedule(jax.jit(Op._matvec), dx,
+                                          steps=pc - 1, dots=pc)
+    assert (n_perm, n_dots >= pc) == (pc - 1, True)
+
+
+def test_summa_adj_ring_hlo_pin(rng):
+    A = rng.standard_normal((24, 16))
+    Op = MPIMatrixMult(A, 8, kind="summa", dtype=np.float64,
+                       schedule="gather", overlap="on")
+    if Op.grid[1] < 2:
+        pytest.skip("ring needs a >1 column grid")
+    _assert_adj_ring(Op, rng)
 
 
 # ----------------------------------------------------------- ring VStack

@@ -358,7 +358,8 @@ PROBE = [
     ("", 1024, (165.075, 161.902), (151.123, 152.731)),
     ("", 2048, (329.977, 323.638), (301.980, 305.333)),
 ]
-# the one row the rule does not follow (its docstring says why)
+# the one row in which the stationary-A forward's ring read faster (the
+# rule's docstring says why it was not chased)
 NOT_CHASED = {("matvec", 128, "")}
 MEASURED_ROWS = [
     pytest.param(kernel, cols, ms[0] < ms[1],
@@ -370,14 +371,23 @@ MEASURED_ROWS = [
 
 @pytest.mark.parametrize("kernel,cols,ring_was_faster", MEASURED_ROWS)
 def test_the_rule_follows_every_measured_row(kernel, cols, ring_was_faster):
-    assert mm._ring_pays(kernel, cols) is ring_was_faster
+    """The adjoint rings where its ring was faster. The stationary-A
+    forward's ring was faster in none of these rows: it has no rule
+    because it has no ring."""
+    if kernel == "rmatvec":
+        assert mm._ring_pays(cols) is ring_was_faster
+    else:
+        assert not ring_was_faster
+        assert not hasattr(mm._MPISummaMatrixMult, "_kernel_fwd_stat_a_ring")
 
 
-def test_the_one_row_the_rule_does_not_chase():
+def test_the_one_row_the_forwards_ring_won():
     """Forward at 128 columns a hop: the ring read 5.9 % faster, its
     neighbours at 64 and 256 and the adjoint at 128 the other way."""
-    (fwd,) = [f for what, cols, f, _ in PROBE if (cols, what) == (128, "")]
-    assert fwd[0] < fwd[1] and not mm._ring_pays("matvec", 128)
+    rows = {cols: (f, a) for what, cols, f, a in PROBE if not what}
+    assert rows[128][0][0] < rows[128][0][1]
+    assert all(rows[c][0][0] > rows[c][0][1] for c in (64, 256))
+    assert rows[128][1][0] > rows[128][1][1] and not mm._ring_pays(128)
 
 
 @pytest.fixture
@@ -429,7 +439,7 @@ def test_left_open_on_a_tpu_the_products_lower_as_the_rule_says(as_on_a_tpu):
     and no hop — the very program ``overlap="off"`` gives."""
     Op, fns = products(N=256, M=64)
     assert Op.overlap == "auto" and Op.schedule == "stat_a"
-    assert not any(mm._ring_pays(which, 64 // Op.grid[1]) for which in fns)
+    assert not mm._ring_pays(64 // Op.grid[1])
     _, off_fns = products(N=256, M=64, overlap="off")
     for which, (fn, op, v) in fns.items():
         text = hlo.compiled_hlo(fn, op, v)
@@ -446,8 +456,8 @@ def test_left_open_on_a_tpu_the_solver_loop_is_the_bulk_program(as_on_a_tpu):
 
 
 def test_left_open_on_a_tpu_a_wide_adjoint_rings(as_on_a_tpu):
-    """256 columns a hop: the rule says ring for the adjoint and bulk
-    for the stationary-A forward, each product for itself."""
+    """256 columns a hop: the rule says ring for the adjoint; the
+    stationary-A forward has the one kernel."""
     Op, fns = products(N=2048, M=512)
     assert Op.overlap == "auto" and Op.schedule == "stat_a"
     assert permutes_and_dots(*fns["matvec"]) == \
@@ -478,7 +488,9 @@ class SimplePlan(dict):
 def test_a_word_still_rings_at_the_cells_columns_a_hop(how, monkeypatch,
                                                        as_on_a_tpu):
     """``overlap=True`` / ``"on"``, the env pin and a tuner plan's
-    ``on`` choose the ring at 32 columns a hop, whatever the rule says."""
+    ``on`` choose the ring at 32 columns a hop, whatever the rule says
+    — where a ring exists: the adjoint. The stationary-A forward keeps
+    the bulk product's counts."""
     kw = {}
     if how == "kwarg_true":
         kw["overlap"] = True
@@ -493,8 +505,36 @@ def test_a_word_still_rings_at_the_cells_columns_a_hop(how, monkeypatch,
     Op, fns = products(N=256, M=64, **kw)
     assert Op.overlap is True and Op.schedule == "stat_a"
     assert Op._overlap_source == how.split("_")[0]
-    for which in ("matvec", "rmatvec"):
-        assert_rings(*fns[which], which, N=256, M=64)
+    assert permutes_and_dots(*fns["matvec"]) == \
+        bulk_counts("matvec", N=256, M=64)
+    assert_rings(*fns["rmatvec"], "rmatvec", N=256, M=64)
+
+
+@pytest.mark.parametrize("grid,dtype", [
+    ((2, 2), np.float32), ((2, 2), np.complex64),
+    ((1, 4), np.float32), ((1, 4), np.complex64)])
+def test_a_word_rings_the_adjoint_only(grid, dtype, as_on_a_tpu):
+    """Under ``overlap="on"`` the stationary-A forward compiles to the
+    very program ``overlap="off"`` gives — it has one kernel — while
+    the adjoint's program gains its ``pc - 1`` hops; both products
+    still meet the dense ones."""
+    N, M = 64, 8
+    A, Y = seeded(23, N, N, M, dtype)
+    mesh = mesh_of(grid)
+    v = pmt.DistributedArray.to_dist(Y.ravel(), mesh=mesh)
+    text = {}
+    for word in ("on", "off"):
+        Op = summa(A, M, grid, mesh, schedule="stat_a", overlap=word)
+        for which in ("matvec", "rmatvec"):
+            fn = jax.jit(lambda op, x, w=which: getattr(op, w)(x).array)
+            text[word, which] = hlo.strip_provenance(
+                hlo.compiled_hlo(fn, Op, v))
+            want = (A if which == "matvec" else A.conj().T) @ Y[..., 0]
+            assert rel(np.asarray(fn(Op, v)).reshape(N, M), want) < 1e-5
+    assert text["on", "matvec"] == text["off", "matvec"]
+    hops = {w: len(hlo._op_results(text[w, "rmatvec"], "collective-permute"))
+            for w in ("on", "off")}
+    assert hops["on"] == hops["off"] + grid[1] - 1
 
 
 @pytest.mark.parametrize("word,source,overlap", [
@@ -525,9 +565,8 @@ def test_each_traced_apply_leaves_its_selection_event(cols, width,
     for e in got:
         e.pop("jax_tracing", None)
     tile = Op.Ap.nbytes // 4
-    assert got == [dict(kernel=which, cols_per_hop=width // 2,
-                        tile_bytes=tile,
-                        ring=0,
-                        source="rule") for which in ("matvec", "rmatvec")]
+    # one event, the adjoint's: the forward has nothing to select
+    assert got == [dict(kernel="rmatvec", cols_per_hop=width // 2,
+                        tile_bytes=tile, ring=0, source="rule")]
     assert all(e["cat"] == "schedule" for e in trace.get_events()
                if e["name"] == "summa.ring_select")
