@@ -230,8 +230,9 @@ def _serving_race_row(niter=20, n_requests=32):
     K=16 block solves against prewarmed executables — vs the same 32
     solved sequentially through the fused single-RHS path, on the
     flagship block-diagonal family. ``tol=0`` pins every solve to
-    exactly ``niter`` iterations AND makes the padded block answers
-    bit-identical to the sequential oracles (the race asserts it).
+    exactly ``niter`` iterations; the row reports the padded block
+    answers' largest difference to the sequential oracles (rounding
+    of the K-column products; ``FamilySpec`` says how close they are).
     Stamps ``solves_per_sec`` (wall basis, submit-to-last-result),
     ``speedup_vs_sequential``, and the daemon's p50/p99
     time-in-queue."""

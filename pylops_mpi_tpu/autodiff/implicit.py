@@ -155,8 +155,10 @@ def _forward_cgls(Op, y, x0, niter, damp, tol, M, block):
     if not _has_tracer(Op, y, x0):
         if block:
             from ..solvers import block as _blk
+            # classic schedule, like the single-RHS forward below: the
+            # key says so, shared with ``block_cgls(normal=False)``
             return _blk._run_block_cgls_fused(Op, y, x0, niter, damp,
-                                              tol, M)
+                                              tol, M, use_normal=False)
         x, iiter, cost, cost1, kold, _ = _b._run_cgls_fused(
             Op, y, x0, False, niter, damp, tol, False, False, M=M)
         return x, iiter, cost, cost1, kold

@@ -43,6 +43,12 @@ def _chunk_ops(ops: Sequence, n_shards: int) -> List[List]:
     return chunks
 
 
+def _ncols(x: DistributedArray) -> int:
+    """Columns of a vector (1) or of the block solvers' ``(rows, K)``
+    vectors (K)."""
+    return x.global_shape[1] if x.ndim == 2 else 1
+
+
 class MPIBlockDiag(MPILinearOperator):
     """Distributed block-diagonal operator
     (ref ``basicoperators/BlockDiag.py:16-144``).
@@ -240,48 +246,52 @@ class MPIBlockDiag(MPILinearOperator):
     def has_fused_normal(self) -> bool:
         """A one-sweep Pallas kernel exists for these blocks — the same
         answer on every backend (compiled on a TPU, interpreted
-        elsewhere): batched vector-form blocks on a 1-D mesh, real with
-        a Mosaic-legal row tile, not forced to two sweeps (kwarg or
-        tuned plan). Complex blocks answer no."""
+        elsewhere): batched plain (``otherdims``-free) blocks on a 1-D
+        mesh, real with a Mosaic-legal row tile, not forced to two
+        sweeps (kwarg or tuned plan). Complex blocks answer no."""
         from .pallas_kernels import normal_matvec_supported
         return (self._normal_path != "two_sweep"
                 and self._batched is not None
-                and self._batched_k == 1  # the kernel is vector-form
+                and self._batched_k == 1  # columns come from x alone
                 and len(self.mesh.axis_names) == 1  # shard_map is 1-D
                 and normal_matvec_supported(self._batched))
 
     def _normal_kernel_for(self, x: DistributedArray):
         """*Can*: the one-sweep kernel ``normal_matvec(x)`` runs, or
-        ``None`` when it takes the generic two sweeps."""
-        # the kernel is vector-form and real: block (column-batched)
-        # inputs take the two-sweep path, whose widened einsums carry
-        # the column axis natively, and a complex vector would be
-        # silently truncated
-        if (not self.has_fused_normal or x.ndim == 2
-                or jnp.issubdtype(x.dtype, jnp.complexfloating)):
+        ``None`` when it takes the generic two sweeps. The kernel
+        carries K columns a block and reads K from the input: a vector
+        is K = 1, the block solvers' ``(rows, K)`` vectors bring their
+        column axis (as many as fit VMEM beside the tile). It is real:
+        a complex vector would be silently truncated."""
+        from .pallas_kernels import (batched_normal_matvec,
+                                     normal_matvec_supported)
+        if (not self.has_fused_normal
+                or jnp.issubdtype(x.dtype, jnp.complexfloating)
+                or not normal_matvec_supported(self._batched, _ncols(x))):
             return None
-        from .pallas_kernels import batched_normal_matvec
         return batched_normal_matvec
 
     def prefers_fused_normal(self, x) -> bool:
         """*Pays*: ``normal_matvec(x)`` is the kernel, compiled (Pallas
         in interpret mode answers no: off a TPU the one-sweep path
-        stays an explicit ``cgls(normal=True)``), fed a vector of its
-        accumulation dtype, with a row tile the chip has shown faster
-        than two sweeps (``pallas_kernels.normal_matvec_pays``)."""
+        stays an explicit ``normal=True``), fed vectors of its
+        accumulation dtype, with a row tile and a column count at which
+        the chip has shown one sweep faster than two
+        (``pallas_kernels.normal_matvec_pays``)."""
         from .pallas_kernels import normal_matvec_pays
         if self._normal_kernel_for(x) is None:
             return False
         acc = jnp.promote_types(self._batched.dtype, jnp.float32)
         return (np.dtype(x.dtype) == np.dtype(acc)
-                and normal_matvec_pays(self._batched))
+                and normal_matvec_pays(self._batched, _ncols(x)))
 
     def normal_matvec(self, x: DistributedArray):
         """``(u, q) = (OpᴴOp x, Op x)`` with ONE memory sweep of the
-        block matrices where :attr:`has_fused_normal` and ``x`` is a
-        real vector: the Pallas ``_normal_kernel`` feeds both products
-        from each VMEM-resident A tile (``pmt_normal``; interpreted off
-        a TPU). Falls back to matvec+rmatvec otherwise."""
+        block matrices where :attr:`has_fused_normal` and ``x`` is
+        real, a vector or ``(rows, K)`` columns: the Pallas kernel
+        feeds both products, all columns, from each VMEM-resident A
+        tile (``pmt_normal``; interpreted off a TPU). Falls back to
+        matvec+rmatvec otherwise."""
         kernel = self._normal_kernel_for(x)
         if kernel is None:
             return super().normal_matvec(x)
@@ -291,23 +301,27 @@ class MPIBlockDiag(MPILinearOperator):
         with trace.op_span(self, "normal_matvec"):
             A = self._batched
             nblk, m, n = A.shape
-            X = x.array.reshape(nblk, n)
+            tail = x.global_shape[1:]     # () for a vector, (K,) else
+            # the kernel wants the long axis on lanes, (nblk, K, n): a
+            # transpose at its edge for the solvers' column-minor
+            # (rows, K) vectors, nothing for a vector
+            X = jnp.swapaxes(x.array.reshape(nblk, n, -1), 1, 2)
             axis = self.mesh.axis_names[0]
             U, Q = shard_map(kernel, mesh=self.mesh,
                              in_specs=(P(axis), P(axis)),
                              out_specs=(P(axis), P(axis)),
                              check_vma=False)(A, X)
-            u = DistributedArray(global_shape=self.shape[1], mesh=self.mesh,
-                                 partition=x.partition, axis=0,
-                                 local_shapes=self.local_shapes_m,
-                                 mask=self.mask, dtype=U.dtype)
-            u[:] = U.reshape(-1)
-            q = DistributedArray(global_shape=self.shape[0], mesh=self.mesh,
-                                 partition=x.partition, axis=0,
-                                 local_shapes=self.local_shapes_n,
-                                 mask=self.mask, dtype=Q.dtype)
-            q[:] = Q.reshape(-1)
-            return u, q
+            out = []
+            for V, rows, locs in ((U, self.shape[1], self.local_shapes_m),
+                                  (Q, self.shape[0], self.local_shapes_n)):
+                v = DistributedArray(
+                    global_shape=(rows,) + tail, mesh=self.mesh,
+                    partition=x.partition, axis=0,
+                    local_shapes=tuple(tuple(s) + tail for s in locs),
+                    mask=self.mask, dtype=V.dtype)
+                v[:] = jnp.swapaxes(V, 1, 2).reshape((rows,) + tail)
+                out.append(v)
+            return tuple(out)
 
 
 class MPIStackedBlockDiag(MPIStackedLinearOperator):

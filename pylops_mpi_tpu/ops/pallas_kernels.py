@@ -14,13 +14,14 @@ Kernels run natively on TPU; on CPU they fall back to ``interpret=True``
 
 from __future__ import annotations
 
-from functools import partial
+from functools import partial, reduce
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
 
 __all__ = ["first_derivative_centered", "second_derivative",
            "stencil_taps", "batched_normal_matvec",
@@ -146,22 +147,27 @@ def stencil_taps(slab: jax.Array, taps, w: int,
 
 # ------------------------------------------------------- fused normal matvec
 # One HBM sweep of A per CGLS iteration instead of two: within each row
-# tile, t = A_tile @ x feeds u += A_tileᵀ t while the tile is still in
-# VMEM, so q = A x and u = AᵀA x cost a single read of A. This is the
-# solver hot-spot of SURVEY §3.2 (the reference reads its matrix once in
-# matvec and once in rmatvec per iteration, ref cls_basic.py:389-397).
+# tile, T = X @ A_tileᵀ feeds U += T @ A_tile while the tile is still in
+# VMEM, so Q = A X and U = AᵀA X cost a single read of A — for all K
+# columns of X at once. This is the solver hot-spot of SURVEY §3.2 (the
+# reference reads its matrix once in matvec and once in rmatvec per
+# iteration, ref cls_basic.py:389-397).
 #
-# Two kernels share the schedule:
+# One kernel body, ``_normal_kernel``, under two names: ``pmt_normal``
+# (f32/f64 blocks) and ``pmt_normal_stream`` (bf16/f16 blocks — the
+# HBM-regime fast path, ISSUE 2: the A tile streams HBM→VMEM at the
+# NARROW dtype, half the bytes of f32). Both dots and the U accumulator
+# run f32 (f64 for f64 blocks, interpreted only), and the X columns are
+# never narrowed below what ``highest`` itself does — bf16 touches
+# storage and the wire, never the solver recurrence
+# (ops/_precision.py module doc).
 #
-# - ``_normal_kernel`` (f32 blocks): tile loaded at its own dtype,
-#   dots accumulate f32.
-# - ``_normal_kernel_stream`` (bf16/f16 blocks — the HBM-regime fast
-#   path, ISSUE 2): the A tile streams HBM→VMEM at the NARROW dtype
-#   (half the bytes of f32 — the only term that matters at 64 MB/block
-#   working sets) and is widened to f32 once in VMEM; both dots and
-#   the u accumulator run f32, and the (f32) x vector is never
-#   narrowed — bf16 touches storage and the wire, never the solver
-#   recurrence (ops/_precision.py module doc).
+# Every operand has its long axis minor: X and U are ``(nblk, K, n)``,
+# Q is ``(nblk, K, m)``, with K the full second-minor dim of each block
+# (Mosaic-legal for any K >= 1; a vector is K = 1). A ``(nblk, m, 1)``
+# Q — the vector form this kernel had through PR 29 — is tiled
+# T(8,128) in HBM, so 2 MB travelled as 268 MB out of the kernel and
+# into each of its readers.
 
 _VMEM_TILE_BYTES = 4 << 20  # A-tile budget (double-buffered by pipeline)
 
@@ -193,9 +199,8 @@ def _pick_tile(m: int, n: int, itemsize: int, min_sublane: int = 8):
 
 def _tile_args(A: jax.Array):
     """(row-tile, streaming?) for ``A``'s blocks. Narrow (sub-4-byte)
-    blocks take the streaming kernel: the VMEM budget is charged for
-    the f32 widened copy (worst term), the sublane rule for the narrow
-    loaded block."""
+    blocks stream: the VMEM budget is charged for the f32 widened copy
+    (worst term), the sublane rule for the narrow loaded block."""
     m, n = A.shape[1], A.shape[2]
     stream = A.dtype.itemsize < 4
     tm = _pick_tile(m, n, max(A.dtype.itemsize, 4),
@@ -203,14 +208,20 @@ def _tile_args(A: jax.Array):
     return tm, stream
 
 
-def normal_matvec_supported(A: jax.Array) -> bool:
+def normal_matvec_supported(A: jax.Array, K: int = 1) -> bool:
     """Pallas path requires real floating blocks (complex dots fall back
     to the generic two-sweep path) for which a Mosaic-legal row tile
-    fits the VMEM budget — otherwise the generic path must be used."""
+    fits the VMEM budget, and a block of ``K`` columns no larger than
+    that budget (``K * n`` elements of the accumulation dtype: the
+    kernel keeps X, U and their bf16 parts resident beside the tile;
+    compiled for a described v5e, 256 columns of n=4096 fit its 48 MiB
+    and 384 do not, 64 / 128 at n=16384, 1024 / 2048 at n=1024) —
+    otherwise the generic path must be used."""
     if not (pallas_available() and A.ndim == 3
             and not jnp.iscomplexobj(A)):
         return False
-    return _tile_args(A)[0] is not None
+    cols_bytes = K * A.shape[2] * max(A.dtype.itemsize, 4)
+    return _tile_args(A)[0] is not None and cols_bytes <= _VMEM_TILE_BYTES
 
 
 def _tile_beats_two_sweeps(tm: int, n: int, itemsize: int) -> bool:
@@ -226,87 +237,169 @@ def _tile_beats_two_sweeps(tm: int, n: int, itemsize: int) -> bool:
     return tm * n * itemsize >= 512 << 10
 
 
-def normal_matvec_pays(A: jax.Array) -> bool:
-    """Whether :func:`batched_normal_matvec` on ``A``'s blocks is worth
-    taking unasked: a compiled Mosaic kernel (on the CPU Pallas runs in
-    interpret mode -- a perf trap inside a ``while_loop``) whose row
-    tile is one the chip has shown to beat two sweeps."""
-    if _interpret() or not normal_matvec_supported(A):
+def _cols_beat_two_sweeps(K: int) -> bool:
+    """Whether the chip has shown the one-sweep kernel carrying ``K``
+    columns a block faster than the XLA pair. Up to 16 columns the
+    tile's copy from HBM binds both and one sweep is half of two; from
+    32 the MXU's passes show, and at 128 the pair is MXU-bound too.
+    v5e, the flagship's 128 blocks of 4096^2 f32 (8.59 GB, 4 MiB
+    tiles), ms a product (best of 3 x 10), one sweep / pair through
+    ``MPIBlockDiag.normal_matvec`` / ``rmatvec(matvec)`` with the
+    solvers' ``(rows, K)`` vectors (my chip run, PR 31; PERF.md
+    section 6):
+
+    ======= ======= ======= =====
+    K       one     pair    ratio
+    ======= ======= ======= =====
+    1       11.45   23.03   2.01
+    2       11.47   23.52   2.05
+    3       11.47   23.55   2.05
+    4       11.46   23.57   2.06
+    8       11.53   23.49   2.04
+    16      11.71   24.49   2.09
+    32      12.60   24.19   1.92
+    48      15.60   24.23   1.55
+    64      19.14   25.88   1.35
+    128     37.56   37.99   1.01
+    ======= ======= ======= =====
+
+    65-127 columns are not measured and stay with the pair, as the tie
+    at 128 does: the one-sweep recurrence carries rounding of its own
+    and has to pay for it."""
+    return K <= 64
+
+
+def normal_matvec_pays(A: jax.Array, K: int = 1) -> bool:
+    """Whether :func:`batched_normal_matvec` on ``A``'s blocks with
+    ``K`` columns a block is worth taking unasked: a compiled Mosaic
+    kernel (on the CPU Pallas runs in interpret mode -- a perf trap
+    inside a ``while_loop``) whose row tile
+    (:func:`_tile_beats_two_sweeps`) and column count
+    (:func:`_cols_beat_two_sweeps`) are ones at which the chip has
+    shown one sweep to beat two."""
+    if _interpret() or not normal_matvec_supported(A, K):
         return False
     tm, _ = _tile_args(A)
-    return _tile_beats_two_sweeps(tm, A.shape[2], A.dtype.itemsize)
+    return (_tile_beats_two_sweeps(tm, A.shape[2], A.dtype.itemsize)
+            and _cols_beat_two_sweeps(int(K)))
+
+
+def _bf16_parts(v, parts: int):
+    """``v`` (f32) as ``parts`` bf16 terms, largest first, each the
+    rounding of what the ones before left over (the remainders are
+    exact in f32): three terms carry f32's 24 bits."""
+    out = []
+    for _ in range(parts):
+        p = v.astype(jnp.bfloat16)
+        out.append(p)
+        v = v - p.astype(jnp.float32)
+    return out
+
+
+def _dot_highest(lhs, a_parts, dims):
+    """``lhs (K, ·)`` f32 against A given as its bf16 parts: the
+    products XLA's ``highest`` makes of two three-term expansions (part
+    ``i`` of one with part ``j`` of the other for ``i + j <= 2``; the
+    three smallest are below f32's last bit), each one MXU pass with
+    f32 accumulation, added smallest first. The K columns' own parts
+    sit stacked on sublanes, so a part of A passes through the MXU
+    once for all of them — where Mosaic's own f32 matmul passes every
+    part of the tile once a product, and no longer hides under the
+    tile's copy from HBM (PERF.md section 6, PR 31). A stored in bf16
+    is its own single part."""
+    K = lhs.shape[0]
+    l3 = jnp.concatenate(_bf16_parts(lhs, 3), axis=0)       # (3K, ·)
+    terms = []
+    for j, a in enumerate(a_parts):
+        p = jax.lax.dot_general(l3[:(3 - j) * K], a, dims,
+                                preferred_element_type=jnp.float32,
+                                precision=jax.lax.Precision.DEFAULT)
+        terms += [(i + j, p[i * K:(i + 1) * K]) for i in range(3 - j)]
+    terms.sort(key=lambda t: -t[0])
+    return reduce(jnp.add, (t for _, t in terms))
 
 
 def _normal_kernel(a_ref, x_ref, u_ref, q_ref):
-    i = pl.program_id(1)
-    acc = jnp.promote_types(a_ref.dtype, jnp.float32)  # f32 acc for bf16/f32
-    a = a_ref[0].astype(acc)                        # (TM, n)
-    x = x_ref[0].astype(acc)                        # (1, n)
-    t = jax.lax.dot_general(a, x, (((1,), (1,)), ((), ())),
-                            preferred_element_type=acc)  # (TM, 1)
-    q_ref[...] = t[None].astype(q_ref.dtype)        # block (1, TM, 1)
-    u = jax.lax.dot_general(t, a, (((0,), (0,)), ((), ())),
-                            preferred_element_type=acc)  # (1, n)
+    """One row tile of one block: ``a_ref (1, tm, n)`` at A's storage
+    dtype (a narrow tile's HBM→VMEM copy moved the narrow bytes),
+    ``x_ref``/``u_ref (1, K, n)``, ``q_ref`` K rows of ``tm`` lanes —
+    columns on sublanes, the long axes on lanes. ``u_ref`` stays
+    resident over the row-tile axis."""
+    nt, nn = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+    acc = jnp.promote_types(a_ref.dtype, jnp.float32)
+    x = x_ref[0].astype(acc)                                # (K, n)
+    if acc == jnp.float32:
+        a = a_ref[0]                                        # (tm, n)
+        parts = ([a] if a.dtype == jnp.bfloat16 else
+                 _bf16_parts(a.astype(jnp.float32), 3))
+        t = _dot_highest(x, parts, nt)                      # (K, tm)
+        u = _dot_highest(t, parts, nn)                      # (K, n)
+    else:           # wider than f32: interpreted only, plain dots
+        a = a_ref[0].astype(acc)
+        t = jax.lax.dot_general(x, a, nt, preferred_element_type=acc)
+        u = jax.lax.dot_general(t, a, nn, preferred_element_type=acc)
+    q_ref[...] = t.reshape(q_ref.shape).astype(q_ref.dtype)
 
-    @pl.when(i == 0)
+    @pl.when(pl.program_id(1) == 0)
     def _():
         u_ref[...] = jnp.zeros_like(u_ref)
 
-    u_ref[...] += u[None].astype(u_ref.dtype)
+    u_ref[0] += u.astype(u_ref.dtype)
 
 
-def _normal_kernel_stream(a_ref, x_ref, u_ref, q_ref):
-    """bf16-tile-streaming variant: ``a_ref`` is the NARROW block (its
-    HBM→VMEM copy moved the narrow bytes — the streaming win); the one
-    widen to f32 happens here in VMEM, and everything downstream
-    (both dots, the running u accumulator, the q/u outputs) is f32.
-    The x vector arrives f32 and stays f32 — no per-iteration rounding
-    of solver state."""
-    i = pl.program_id(1)
-    a = a_ref[0].astype(jnp.float32)                # one VMEM widen/tile
-    x = x_ref[0].astype(jnp.float32)                # (1, n), f32 already
-    t = jax.lax.dot_general(a, x, (((1,), (1,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-    q_ref[...] = t[None].astype(q_ref.dtype)
-    u = jax.lax.dot_general(t, a, (((0,), (0,)), ((), ())),
-                            preferred_element_type=jnp.float32)
-
-    @pl.when(i == 0)
-    def _():
-        u_ref[...] = jnp.zeros_like(u_ref)
-
-    u_ref[...] += u[None].astype(u_ref.dtype)
+# Scoped VMEM the kernel may use: the double-buffered 4 MiB tile, its
+# bf16 parts and the products' temporaries pass Mosaic's default of
+# 16 MiB on the flagship's tile (the chip's VMEM is 128 MiB on
+# v5e/v6e).
+_VMEM_LIMIT_BYTES = 48 << 20
 
 
 def batched_normal_matvec(A: jax.Array, X: jax.Array):
-    """``(u, q) = (AᵀA x, A x)`` per block, reading each ``A`` block once.
+    """``(U, Q) = (AᵀA X, A X)`` per block, reading each ``A`` block once
+    for all of ``X``'s columns.
 
-    A: ``(nblk, m, n)`` real (f32, or bf16/f16 storage — the narrow
-    case streams through ``_normal_kernel_stream``); X: ``(nblk, n)``,
-    kept at ITS dtype (f32 for the mixed-precision solver stack).
-    Returns ``u (nblk, n)``, ``q (nblk, m)`` at X's dtype. Call per
-    shard (inside shard_map); on CPU runs in interpret mode. The x/u/q
-    operands are staged as trivially-blocked 3-D views — a 2-D
-    ``(1, n)`` block over an ``(nblk, n)`` array has a sublane dim of 1
-    that is neither 8-divisible nor equal to ``nblk``, which Mosaic
-    rejects.
+    A: ``(nblk, m, n)`` real (f32/f64, or bf16/f16 storage — the narrow
+    case is named ``pmt_normal_stream``); X: ``(nblk, K, n)``, K >= 1
+    columns a block laid ROW-wise (long axis minor), kept at ITS dtype
+    (f32 for the mixed-precision solver stack). Returns
+    ``U (nblk, K, n)``, ``Q (nblk, K, m)`` at X's dtype. Call per shard
+    (inside shard_map); on CPU runs in interpret mode. K is the full
+    second-minor dim of the x/u/q blocks, which Mosaic accepts at any
+    size. Q's minor block dim is the row tile: where that is neither a
+    multiple of 128 lanes nor the whole of ``m`` (tiles of 8-64 rows,
+    which never pay unasked), Q leaves the kernel as
+    ``(nblk, m // tm, K, tm)`` — whole trailing dims, legal at any
+    width — and is put in order outside.
     """
     nblk, m, n = A.shape
+    K = X.shape[1]
     tm, stream = _tile_args(A)
-    if tm is None:
-        raise ValueError(f"no Mosaic-legal row tile for blocks of {m}x{n}; "
-                         "gate on normal_matvec_supported()")
+    if not normal_matvec_supported(A, K):
+        raise ValueError(f"no Mosaic-legal row tile for blocks of {m}x{n} "
+                         f"with {K} columns; gate on "
+                         "normal_matvec_supported()")
     out_dtype = X.dtype
+    lane_dense = tm % 128 == 0 or tm == m
+    if lane_dense:
+        q_spec = pl.BlockSpec((1, K, tm), lambda b, i: (b, 0, i))
+        q_shape = (nblk, K, m)
+    else:
+        q_spec = pl.BlockSpec((1, 1, K, tm), lambda b, i: (b, i, 0, 0))
+        q_shape = (nblk, m // tm, K, tm)
     u, q = pl.pallas_call(
-        _normal_kernel_stream if stream else _normal_kernel,
+        _normal_kernel,
         grid=(nblk, m // tm),
         in_specs=[pl.BlockSpec((1, tm, n), lambda b, i: (b, i, 0)),
-                  pl.BlockSpec((1, 1, n), lambda b, i: (b, 0, 0))],
-        out_specs=[pl.BlockSpec((1, 1, n), lambda b, i: (b, 0, 0)),
-                   pl.BlockSpec((1, tm, 1), lambda b, i: (b, i, 0))],
-        out_shape=[jax.ShapeDtypeStruct((nblk, 1, n), out_dtype),
-                   jax.ShapeDtypeStruct((nblk, m, 1), out_dtype)],
+                  pl.BlockSpec((1, K, n), lambda b, i: (b, 0, 0))],
+        out_specs=[pl.BlockSpec((1, K, n), lambda b, i: (b, 0, 0)),
+                   q_spec],
+        out_shape=[jax.ShapeDtypeStruct((nblk, K, n), out_dtype),
+                   jax.ShapeDtypeStruct(q_shape, out_dtype)],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
         name="pmt_normal_stream" if stream else "pmt_normal",
-    )(A, X[:, None, :])
-    return u[:, 0, :], q[:, :, 0]
+    )(A, X)
+    if not lane_dense:
+        q = q.transpose(0, 2, 1, 3).reshape(nblk, K, m)
+    return u, q

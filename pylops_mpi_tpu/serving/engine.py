@@ -97,9 +97,22 @@ def bucket_for(count: int, buckets: Optional[Sequence[int]] = None) -> int:
 class FamilySpec:
     """One servable operator family: the operator INSTANCE (reused for
     every solve so the fused cache hits), the engine and its fixed
-    solve parameters. ``tol=0.0`` is the bit-for-bit setting: it pins
-    every column to the full ``niter`` schedule, so a packed solve
-    equals its single-RHS oracle exactly."""
+    solve parameters. ``tol=0.0`` pins every column to the full
+    ``niter`` schedule, so a packed solve runs the iterations its
+    single-RHS oracle runs, and zero padding is exact. How close the
+    answers then are depends on the program the bucket compiles. A
+    ``K=1`` bucket IS the single-RHS program (same cache entry, same
+    sweep schedule): bit for bit on every backend. A wider bucket on
+    the classic two-sweep schedule — every backend's ``cg``; ``cgls``
+    on the CPU and wherever ``solvers/basic._resolve_normal`` answers
+    no — differs only where the backend's K-column product rounds
+    unlike its one-column product (the CPU's slow race in
+    ``tests/test_serving.py`` reads 1.5e-6 absolute). A wider ``cgls``
+    bucket on the one-sweep schedule (a batched real ``MPIBlockDiag``
+    on a TPU, at the widths the chip has shown to pay) carries the
+    gradient recurrence instead: packed and single-RHS answers agree
+    within the schedule's drift band (1.1 x the classic error to the
+    solution, ``tests/test_cgls_normal_default.py``)."""
     name: str
     operator: object
     solver: str = "cgls"          # "cg" | "cgls"

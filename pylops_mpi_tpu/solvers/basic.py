@@ -967,22 +967,24 @@ def cg_guarded(Op, y: Vector, x0: Optional[Vector] = None,
 def _resolve_normal(Op, x0: Vector, normal: Optional[bool],
                     use_fused: bool = True) -> bool:
     """The one rule for CGLS's sweep schedule (``cgls``,
-    ``cgls_guarded``, ``resilient_solve``): a caller's ``True``/``False``
-    wins; ``None`` asks the operator whether its ``normal_matvec`` would
-    run a compiled one-sweep kernel that pays for the model vector at
-    hand (``MPILinearOperator.prefers_fused_normal``) — never off the
-    fused path, which has no one-sweep body."""
+    ``cgls_guarded``, ``resilient_solve``, ``block_cgls``): a caller's
+    ``True``/``False`` wins; ``None`` asks the operator whether its
+    ``normal_matvec`` would run a compiled one-sweep kernel that pays
+    for the model vector (or ``(rows, K)`` block of columns) at hand
+    (``MPILinearOperator.prefers_fused_normal``) — never off the fused
+    path, which has no one-sweep body."""
     if normal is not None:
         return bool(normal)
     ask = getattr(Op, "prefers_fused_normal", None)
     return bool(use_fused and ask is not None and ask(x0))
 
 
-def _count_cgls_solve(iiter: int, use_normal: bool) -> None:
-    _metrics.inc("solver.cgls.solves")
-    _metrics.inc("solver.cgls.iterations", iiter)
+def _count_cgls_solve(iiter: int, use_normal: bool,
+                      solver: str = "cgls") -> None:
+    _metrics.inc(f"solver.{solver}.solves")
+    _metrics.inc(f"solver.{solver}.iterations", iiter)
     if use_normal:
-        _metrics.inc("solver.cgls.one_sweep")
+        _metrics.inc(f"solver.{solver}.one_sweep")
 
 
 def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
@@ -1049,8 +1051,8 @@ def cgls(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
     (``Op.prefers_fused_normal(x0)``): one sweep only where
     ``normal_matvec`` would run a compiled one-sweep kernel that beats
     two sweeps for this vector — today a batched ``MPIBlockDiag`` of
-    real blocks on a 1-D mesh, on a TPU (Mosaic), a 1-D real model of
-    the blocks' accumulation dtype, a row tile the chip has shown fast;
+    real blocks on a 1-D mesh, on a TPU (Mosaic), a real model of the
+    blocks' accumulation dtype, a row tile the chip has shown fast;
     everything else, and every operator on the CPU, compiles the
     classic program. The recurrence carries rounding of its own: in
     f32 its error to the true model stayed within 1.04 × the classic

@@ -49,9 +49,10 @@ from jax import lax
 from ..distributedarray import DistributedArray
 from ..diagnostics import metrics as _metrics
 from ..diagnostics import telemetry, trace as _trace
-from .basic import (_DONATE_X0, _donate_copy, _get_fused, _i32, _mkey,
-                    _mp_floor, _precond_apply, _precond_signature,
-                    _reject, _step_scalar, _vdtype, _vkey)
+from .basic import (_DONATE_X0, _count_cgls_solve, _donate_copy, _get_fused,
+                    _i32, _mkey, _mp_floor, _precond_apply,
+                    _precond_signature, _reject, _resolve_normal,
+                    _step_scalar, _vdtype, _vkey)
 
 __all__ = ["block_cg", "block_cgls", "block_cg_segmented",
            "batched_solve", "BatchedResult", "batched_cache_info"]
@@ -236,13 +237,67 @@ def _block_cg_fused(Op, y, x0, tol, *, niter: int, M=None,
 
 
 def _make_block_cgls_body(Op, xdt, damp2, floors, tol, *, M=None,
-                          guards=False, carry_status=False, stall_n=0):
-    """Block-CGLS (classic two-sweep) loop body over ``(x, s, c, q,
-    kold, iiter, cost, cost1[, status][, bestk, stall])`` — per-column
-    scalars throughout; see :func:`_make_block_cg_body`. ``M``
-    approximates ``(OpᴴOp + damp²I)⁻¹`` and is applied to the normal
-    residual, all K columns at once."""
+                          normal=False, guards=False, carry_status=False,
+                          stall_n=0):
+    """Block-CGLS loop body — per-column scalars throughout; see
+    :func:`_make_block_cg_body`. Classic two-sweep over ``(x, s, c, q,
+    kold, iiter, cost, cost1[, status][, bestk, stall])``; ``normal``
+    is the one-sweep schedule of ``basic._make_cgls_body`` over ``(x,
+    s, r, c, ...)``: ``(u, q) = Op.normal_matvec(c)`` for all K columns
+    and the gradient recurrence ``r ← r − a·(u + damp²·c)`` per column.
+    ``M`` approximates ``(OpᴴOp + damp²I)⁻¹`` and is applied to the
+    normal residual, all K columns at once, in both schedules."""
     from ..resilience import status as _rstatus
+
+    def body_normal(state):
+        if guards:
+            x, s, r, c, kold, iiter, cost, cost1, status, bestk, stall \
+                = state
+        elif carry_status:
+            x, s, r, c, kold, iiter, cost, cost1, status = state
+        else:
+            x, s, r, c, kold, iiter, cost, cost1 = state
+        done = kold <= jnp.maximum(floors, tol)
+        if guards or carry_status:
+            done = done | (status != _rstatus.RUNNING)
+        u, q = Op.normal_matvec(c)
+        a = jnp.abs(kold / (_bdot(q, q) + damp2 * _bdot(c, c)))
+        a = jnp.where(done, jnp.zeros_like(a), a)
+        xn = x + c * _step_scalar(a, xdt)
+        sn_ = s - q * _step_scalar(a, xdt)
+        rn = r - (u + c * damp2) * _step_scalar(a, xdt)
+        zn = _precond_apply(M, rn, xdt)
+        k = _bdot(rn, zn)
+        k = jnp.where(done, kold, k)
+        b = jnp.where(done, jnp.zeros_like(k), k / kold)
+        cn = zn + c * _step_scalar(b, xdt)
+        if guards:
+            bad = (~jnp.isfinite(a)) | (~jnp.isfinite(k)) \
+                | (~jnp.isfinite(b))
+            x = _reject(bad, x, xn)
+            s = _reject(bad, s, sn_)
+            r = _reject(bad, r, rn)
+            c = _reject(bad, c, cn)
+            k = jnp.where(bad, kold, k)
+            status, bestk, stall = _bguard_update(status, bestk, stall,
+                                                  bad, k, done, stall_n)
+        else:
+            x, s, r, c = xn, sn_, rn, cn
+        iiter = iiter + 1
+        sn = jnp.sqrt(_bdot(s, s))
+        cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+        r2 = jnp.sqrt(sn ** 2 + damp2 * _bdot(x, x))
+        cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        telemetry.iteration("block_cgls", iiter, resid=sn, k=k, alpha=a)
+        if guards:
+            return (x, s, r, c, k, iiter, cost, cost1, status, bestk,
+                    stall)
+        if carry_status:
+            return (x, s, r, c, k, iiter, cost, cost1, status)
+        return (x, s, r, c, k, iiter, cost, cost1)
+
+    if normal:
+        return body_normal
 
     def body(state):
         if guards:
@@ -295,7 +350,8 @@ def _make_block_cgls_body(Op, xdt, damp2, floors, tol, *, M=None,
 
 
 def _block_cgls_fused(Op, y, x0, damp, tol, *, niter: int, M=None,
-                      guards: bool = False, stall_n: int = 0):
+                      normal: bool = False, guards: bool = False,
+                      stall_n: int = 0):
     from ..resilience import status as _rstatus
     damp2 = damp ** 2
     xdt = _vdtype(x0)
@@ -304,7 +360,12 @@ def _block_cgls_fused(Op, y, x0, damp, tol, *, niter: int, M=None,
     rq = Op.rmatvec(s) - x * damp  # the reference's un-squared setup
     z = _precond_apply(M, rq, xdt)  # damp quirk (solvers/basic module
     c = z                           # doc); M seeds the first direction
-    q = Op.matvec(c)
+    if normal:
+        # the recurrence tracks the true gradient Opᴴs − damp²x: seeded
+        # from the damp²-form, as basic._cgls_setup does
+        head = (x, s, rq + x * (damp - damp2), c)
+    else:
+        head = (x, s, c, Op.matvec(c))
     kold = _bdot(rq, z)
     floors = _mp_floor(kold)
     sn0 = jnp.sqrt(_bdot(s, s))
@@ -314,11 +375,12 @@ def _block_cgls_fused(Op, y, x0, damp, tol, *, niter: int, M=None,
         jnp.zeros_like(cost0),
         jnp.sqrt(sn0 ** 2 + damp2 * _bdot(x, x)), 0, 0)
     body = _make_block_cgls_body(Op, xdt, damp2, floors, tol, M=M,
-                                 guards=guards, stall_n=stall_n)
+                                 normal=normal, guards=guards,
+                                 stall_n=stall_n)
     if guards:
         K = kold.shape[0]
-        state = (x, s, c, q, kold, jnp.asarray(0), cost0, cost1_0,
-                 _status0(K), kold, jnp.zeros((K,), jnp.int32))
+        state = head + (kold, jnp.asarray(0), cost0, cost1_0,
+                        _status0(K), kold, jnp.zeros((K,), jnp.int32))
 
         def cond(st):
             return ((st[5] < niter)
@@ -334,7 +396,7 @@ def _block_cgls_fused(Op, y, x0, damp, tol, *, niter: int, M=None,
     def cond(st):
         return (st[5] < niter) & (jnp.max(st[4]) > tol)
 
-    state = (x, s, c, q, kold, jnp.asarray(0), cost0, cost1_0)
+    state = head + (kold, jnp.asarray(0), cost0, cost1_0)
     out = lax.while_loop(cond, body, state)
     return out[0], out[5], out[6], out[7], out[4]
 
@@ -427,7 +489,8 @@ def block_cg(Op, y: DistributedArray,
 
 
 def _run_block_cgls_fused(Op, y, x0, niter, damp, tol, M=None,
-                          x0_owned: bool = False):
+                          x0_owned: bool = False,
+                          use_normal: bool = False):
     """Compile-cache-and-run the unguarded fused block-CGLS loop;
     raw ``(x, iiter, cost, cost1, kold)`` with ``(iiter+1, K)`` sliced
     histories — the :func:`~pylops_mpi_tpu.solvers.basic._run_cgls_fused`
@@ -435,18 +498,20 @@ def _run_block_cgls_fused(Op, y, x0, niter, damp, tol, M=None,
     (identical ``_get_fused`` key) so the autodiff tier's concrete
     forward (autodiff/implicit.py) reuses the SAME cached executables
     and AOT bank entries as plain solves instead of growing a parallel
-    executable set."""
-    fn = _get_fused(Op, (id(Op), "block_cgls", niter, _vkey(y),
-                         _vkey(x0)) + _mkey(M),
+    executable set. ``use_normal`` (the sweep schedule, resolved by the
+    caller) is part of the key, and so of the AOT bank's: a flip never
+    meets a stale executable."""
+    fn = _get_fused(Op, (id(Op), "block_cgls", use_normal, niter,
+                         _vkey(y), _vkey(x0)) + _mkey(M),
                     lambda op: partial(_block_cgls_fused, op,
-                                       niter=niter, M=M),
+                                       niter=niter, M=M,
+                                       normal=use_normal),
                     donate_argnums=_DONATE_X0, keepalive=M,
                     aot_eligible=(M is None))
     x, iiter, cost, cost1, kold = fn(
         y, x0 if x0_owned else _donate_copy(x0), damp, tol)
     iiter = int(iiter)
-    _metrics.inc("solver.block_cgls.solves")
-    _metrics.inc("solver.block_cgls.iterations", iiter)
+    _count_cgls_solve(iiter, use_normal, "block_cgls")
     return (x, iiter, np.asarray(cost)[:iiter + 1],
             np.asarray(cost1)[:iiter + 1], np.asarray(kold))
 
@@ -454,12 +519,28 @@ def _run_block_cgls_fused(Op, y, x0, niter, damp, tol, M=None,
 def block_cgls(Op, y: DistributedArray,
                x0: Optional[DistributedArray] = None, niter: int = 10,
                damp: float = 0.0, tol: float = 1e-4,
-               guards: Optional[bool] = None, M=None):
-    """Fused block CGLS (classic two-sweep schedule); see
-    :func:`block_cg`. Returns ``(x, istop, iiter, kold, r2norm,
-    cost)`` — the :func:`~pylops_mpi_tpu.solvers.basic.cgls` shape with
-    per-column ``istop``/``kold``/``r2norm`` vectors and a
-    ``(iiter+1, K)`` cost history.
+               guards: Optional[bool] = None, M=None,
+               normal: Optional[bool] = None):
+    """Fused block CGLS; see :func:`block_cg`. Returns ``(x, istop,
+    iiter, kold, r2norm, cost)`` — the
+    :func:`~pylops_mpi_tpu.solvers.basic.cgls` shape with per-column
+    ``istop``/``kold``/``r2norm`` vectors and a ``(iiter+1, K)`` cost
+    history.
+
+    ``normal`` picks the sweep schedule as in
+    :func:`~pylops_mpi_tpu.solvers.basic.cgls`, through the same
+    resolver (``basic._resolve_normal``): ``True`` is the one-sweep
+    iteration (``(u, q) = Op.normal_matvec(c)`` for all K columns, the
+    gradient recurrence per column), ``False`` the classic matvec +
+    rmatvec pair, ``None`` (default) asks
+    ``Op.prefers_fused_normal(x0)`` — one sweep only where a compiled
+    one-sweep kernel beats two sweeps at this column count (a batched
+    real ``MPIBlockDiag`` on a TPU, at the widths the chip has shown:
+    ``pallas_kernels.normal_matvec_pays``); everything else, and every
+    operator on the CPU, compiles the classic program. ``K=1`` hands
+    the same answer to the single-RHS program; the
+    communication-avoiding engine (``PYLOPS_MPI_TPU_CA``) takes it
+    too. The traced autodiff forward keeps the classic schedule.
 
     ``PYLOPS_MPI_TPU_AUTODIFF=on`` reroutes traced inputs to the
     implicit-diff rule — see :func:`block_cg`."""
@@ -476,17 +557,18 @@ def block_cgls(Op, y: DistributedArray,
         x0 = _zero_block_model(Op, y)
     from ..resilience.status import guards_enabled
     use_guards = guards_enabled(guards)
+    use_normal = _resolve_normal(Op, x0, normal)
     with _trace.span("solver.block_cgls", cat="solver",
                      op=type(Op).__name__, shape=Op.shape, batch=K,
                      dtype=_vdtype(x0), niter=niter, damp=damp, tol=tol,
-                     guards=use_guards,
+                     normal=use_normal, guards=use_guards,
                      telemetry=telemetry.telemetry_enabled()):
         if K == 1:
             from ..resilience import status as _rstatus
             from .basic import _run_cgls_fused
             x1, iiter, cost, cost1, kold, code = _run_cgls_fused(
                 Op, _squeeze_col(y), _squeeze_col(x0), True, niter,
-                damp, tol, False, use_guards, M=M)
+                damp, tol, use_normal, use_guards, M=M)
             if use_guards:
                 _rstatus.record_columns("block_cgls", [code], iiter)
             kold = np.atleast_1d(np.asarray(kold))
@@ -499,28 +581,31 @@ def block_cgls(Op, y: DistributedArray,
         if _ca_mode != "off":
             return _ca.run_block_cgls(Op, y, x0, x0_owned, niter, damp,
                                       tol, use_guards, M=M,
-                                      mode=_ca_mode)
+                                      mode=_ca_mode,
+                                      use_normal=use_normal)
         if use_guards:
             from ..resilience import status as _rstatus
             stall_n = _rstatus.stall_window()
             fn = _get_fused(
-                Op, (id(Op), "block_cgls", niter, _vkey(y), _vkey(x0),
-                     _rstatus.guards_signature(True)) + _mkey(M),
+                Op, (id(Op), "block_cgls", use_normal, niter, _vkey(y),
+                     _vkey(x0), _rstatus.guards_signature(True))
+                + _mkey(M),
                 lambda op: partial(_block_cgls_fused, op, niter=niter,
-                                   M=M, guards=True, stall_n=stall_n),
+                                   M=M, normal=use_normal, guards=True,
+                                   stall_n=stall_n),
                 donate_argnums=_DONATE_X0, keepalive=M,
                 aot_eligible=(M is None))
             x, iiter, cost, cost1, kold, status = fn(
                 y, x0 if x0_owned else _donate_copy(x0), damp, tol)
             iiter = int(iiter)
-            _metrics.inc("solver.block_cgls.solves")
-            _metrics.inc("solver.block_cgls.iterations", iiter)
+            _count_cgls_solve(iiter, use_normal, "block_cgls")
             _rstatus.record_columns(
                 "block_cgls", [int(cd) for cd in np.asarray(status)],
                 iiter)
         else:
             x, iiter, cost, cost1, kold = _run_block_cgls_fused(
-                Op, y, x0, niter, damp, tol, M=M, x0_owned=x0_owned)
+                Op, y, x0, niter, damp, tol, M=M, x0_owned=x0_owned,
+                use_normal=use_normal)
             return (x, np.where(kold < tol, 1, 2), iiter, kold,
                     cost1[-1], cost)
         kold = np.asarray(kold)

@@ -859,19 +859,20 @@ def run_block_cg(Op, y, x0, x0_owned, niter, tol, guards, M=None,
 
 
 def run_block_cgls(Op, y, x0, x0_owned, niter, damp, tol, guards,
-                   M=None, mode="pipelined"):
+                   M=None, mode="pipelined", use_normal=False):
     """Pipelined block CGLS (K > 1): public contract of
     ``block.block_cgls``'s fused section — ``(x, istop, iiter, kold,
     r2norm, cost)`` with the CA cost-lane caveat (normal-residual
-    norms)."""
+    norms). ``use_normal`` is the schedule ``block_cgls`` resolved, as
+    in :func:`run_cgls_fused`."""
     from ..resilience import status as _rstatus
     spec, stall_n, extra = _guard_ctx(Op, guards)
-    fn = _get_fused(Op, (id(Op), "ca-block_cgls", niter, _vkey(y),
-                         _vkey(x0)) + extra + ca_key("pipelined")
-                    + _mkey(M),
+    fn = _get_fused(Op, (id(Op), "ca-block_cgls", use_normal, niter,
+                         _vkey(y), _vkey(x0)) + extra
+                    + ca_key("pipelined") + _mkey(M),
                     lambda op: partial(_pipe_cgls_fused, op, niter=niter,
-                                       normal=False, guards=guards, M=M,
-                                       stall_n=stall_n, fault=spec,
+                                       normal=use_normal, guards=guards,
+                                       M=M, stall_n=stall_n, fault=spec,
                                        block=True),
                     donate_argnums=_DONATE_X0, keepalive=M,
                     aot_eligible=(M is None and spec is None))
@@ -884,8 +885,7 @@ def run_block_cgls(Op, y, x0, x0_owned, niter, damp, tol, guards,
     else:
         x, iiter, cost, cost1, kold = out
         iiter = int(iiter)
-    _metrics.inc("solver.block_cgls.solves")
-    _metrics.inc("solver.block_cgls.iterations", iiter)
+    _count_cgls_solve(iiter, use_normal, "block_cgls")
     kold = np.asarray(kold)
     istop = np.where(kold < tol, 1, 2)
     return (x, istop, iiter, kold, np.asarray(cost1)[iiter],

@@ -244,6 +244,87 @@ def test_blockdiag_fused_normal_parity(rng):
                                atol=2e-4)
 
 
+def _block_vector(Op, rng, K, dtype, rows=None):
+    rows = Op.shape[1] if rows is None else rows
+    x = DistributedArray(global_shape=(rows, K), dtype=dtype)
+    v = rng.standard_normal((rows, K))
+    if np.issubdtype(np.dtype(dtype), np.complexfloating):
+        v = v + 1j * rng.standard_normal((rows, K))
+    x[:] = v.astype(dtype)
+    return x
+
+
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+@pytest.mark.parametrize("K", [1, 3, 16])
+def test_blockdiag_normal_matvec_block_input(rng, K, dtype):
+    """``normal_matvec`` on the block solvers' ``(rows, K)`` vectors is
+    the one-sweep kernel (K read from the input) and equals ``matvec``
+    then ``rmatvec`` column by column, in their shapes and layout."""
+    from pylops_mpi_tpu.ops import pallas_kernels as pk
+    mats = [rng.standard_normal((24, 16)).astype(dtype) for _ in range(P)]
+    Op = MPIBlockDiag([MatrixMult(m, dtype=dtype) for m in mats])
+    x = _block_vector(Op, rng, K, dtype)
+    assert Op.has_fused_normal
+    assert Op._normal_kernel_for(x) is pk.batched_normal_matvec
+    u, q = Op.normal_matvec(x)
+    q2 = Op.matvec(x)
+    u2 = Op.rmatvec(q2)
+    for got, want in ((u, u2), (q, q2)):
+        assert got.global_shape == want.global_shape
+        assert got.local_shapes == want.local_shapes
+        assert got.dtype == want.dtype and got.partition == want.partition
+    tol = 2e-5 if dtype == np.float32 else 1e-12
+    scale = np.abs(u2.asarray()).max()
+    np.testing.assert_allclose(q.asarray(), q2.asarray(), rtol=tol,
+                               atol=tol * scale)
+    np.testing.assert_allclose(u.asarray(), u2.asarray(), rtol=10 * tol,
+                               atol=10 * tol * scale)
+    # column j of the block product is the vector product of column j
+    j = K - 1
+    uj, qj = Op.normal_matvec(DistributedArray.to_dist(x.asarray()[:, j]))
+    np.testing.assert_allclose(u.asarray()[:, j], uj.asarray(),
+                               rtol=10 * tol, atol=10 * tol * scale)
+    np.testing.assert_allclose(q.asarray()[:, j], qj.asarray(), rtol=tol,
+                               atol=tol * scale)
+
+
+def _complex_blocks(rng):
+    return MPIBlockDiag([MatrixMult(
+        (rng.standard_normal((8, 8)) + 1j * rng.standard_normal((8, 8))
+         ).astype(np.complex64)) for _ in range(P)]), np.complex64
+
+
+def _hetero_blocks(rng):
+    return MPIBlockDiag([MatrixMult(
+        rng.standard_normal((8 + (i % 2), 8)).astype(np.float32),
+        dtype=np.float32) for i in range(P)]), np.float32
+
+
+def _multi_rhs_blocks(rng):
+    return MPIBlockDiag([MatrixMult(
+        rng.standard_normal((8, 8)).astype(np.float32), otherdims=(2,),
+        dtype=np.float32) for _ in range(P)]), np.float32
+
+
+@pytest.mark.parametrize("build", [_complex_blocks, _hetero_blocks,
+                                   _multi_rhs_blocks],
+                         ids=["complex", "heterogeneous", "batched_k2"])
+def test_blockdiag_normal_matvec_block_input_falls_back(rng, build):
+    """Complex blocks, heterogeneous blocks and blocks with columns of
+    their own (``_batched_k > 1``) have no one-sweep kernel, for block
+    inputs as for vectors: ``normal_matvec`` takes the generic pair."""
+    Op, dtype = build(rng)
+    x = _block_vector(Op, rng, 3, dtype)
+    assert not Op.has_fused_normal
+    assert Op._normal_kernel_for(x) is None
+    assert Op.prefers_fused_normal(x) is False
+    u, q = Op.normal_matvec(x)
+    q2 = Op.matvec(x)
+    u2 = Op.rmatvec(q2)
+    np.testing.assert_array_equal(q.asarray(), q2.asarray())
+    np.testing.assert_array_equal(u.asarray(), u2.asarray())
+
+
 def test_blockdiag_compute_dtype_bf16(rng):
     """bf16 block storage: reduced-precision matvec stays within bf16
     error of the f32 result (the TPU HBM-halving mode)."""

@@ -3,6 +3,8 @@ StackedDistributedArray as pytrees through jit, masked solves inside a
 single compiled program, and collective-schedule assertions on the
 lowered solver loop."""
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -237,3 +239,91 @@ def test_fused_ista_collective_schedule_is_scalar_only(rng, momentum):
     # exceed scalar size
     assert 1 <= ar["count"] <= 6, rep
     assert ar["max_bytes"] <= 16, rep
+
+
+# ------------------------------------------- block CGLS, one-sweep schedule
+def _block_problem(rng, K=4, m=256, n=128):
+    P = len(jax.devices())
+    Op = MPIBlockDiag([MatrixMult(
+        (rng.standard_normal((m, n)) / np.sqrt(n)).astype(np.float32)
+        + 4 * np.eye(m, n, dtype=np.float32), dtype=np.float32)
+        for _ in range(P)])
+    y = DistributedArray(global_shape=(P * m, K), dtype=np.float32)
+    y[:] = rng.standard_normal((P * m, K)).astype(np.float32)
+    x0 = DistributedArray(global_shape=(P * n, K), dtype=np.float32)
+    return Op, y, x0
+
+
+@pytest.mark.parametrize("normal", [True, False],
+                         ids=["one_sweep", "classic"])
+def test_block_cgls_program_for_a_tpu_holds_one_kernel_in_its_loop(
+        rng, monkeypatch, normal):
+    """The ``block_cgls`` program lowered for a TPU (from here, no chip:
+    ``lowering_platforms``; Pallas is asked to compile, not to
+    interpret). One-sweep: ONE Mosaic call, ``pmt_normal``, inside the
+    ``while`` body and no ``bmn,bnk`` contraction there — the two
+    ``dot_general``s left are the pre-loop ``Op x0`` and ``Opᴴ s``.
+    Classic: no kernel, and the loop holds the block matvec and
+    rmatvec."""
+    from pylops_mpi_tpu.ops import pallas_kernels as pk
+    from pylops_mpi_tpu.solvers.block import _block_cgls_fused
+    Op, y, x0 = _block_problem(rng)
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    fn = jax.jit(lambda op, yy, xx: _block_cgls_fused(
+        op, yy, xx, 0.0, 0.0, niter=5, normal=normal)[0].array)
+    text = fn.trace(Op, y, x0).lower(lowering_platforms=("tpu",)).as_text()
+    assert text.count("stablehlo.while") == 1
+    loop = text.index("stablehlo.while")
+    dots = [m.start() for m in re.finditer(r"stablehlo\.dot_general", text)]
+    calls = [m.start() for m in re.finditer(r"@tpu_custom_call", text)]
+    if normal:
+        assert len(calls) == 1 and calls[0] > loop
+        assert re.findall(r"kernel_name\W+(\w+)", text) == ["pmt_normal"]
+        assert len(dots) == 2 and all(d < loop for d in dots)
+    else:
+        assert not calls and "pmt_normal" not in text
+        assert len(dots) == 5
+        assert sum(d > loop for d in dots) == 2
+
+
+def test_use_normal_is_part_of_the_fused_and_aot_keys(rng, monkeypatch):
+    """A flip of the schedule never meets a stale executable: the
+    ``_get_fused`` key and the AOT bank's key (the same tuple behind
+    the operator's structural signature) carry ``use_normal``, so with
+    the AOT tier armed each schedule compiles once — also for a fresh
+    operator instance of the same signature — and answers as the
+    unarmed program of its own schedule does."""
+    from pylops_mpi_tpu import aot
+    from pylops_mpi_tpu.solvers import basic
+    Op, y, _ = _block_problem(rng, K=2, m=32, n=24)
+    mats = [np.asarray(b) for b in Op._batched]
+    fresh = lambda: MPIBlockDiag([MatrixMult(m, dtype=np.float32)
+                                  for m in mats])
+    solve = lambda op, normal: np.asarray(pmt.block_cgls(
+        op, y, niter=4, tol=0.0, normal=normal)[0].asarray())
+    pmt.clear_fused_cache()
+    plain = {normal: solve(Op, normal) for normal in (False, True)}
+    keys = [k for k in basic._FUSED_CACHE if k[0] == id(Op)]
+    assert sorted(k[2] for k in keys) == [False, True]
+    assert keys[0][:2] == keys[1][:2] == (id(Op), "block_cgls")
+    assert keys[0][3:] == keys[1][3:]
+
+    prev = jax.config.jax_compilation_cache_dir
+    jax.config.update("jax_compilation_cache_dir", None)
+    monkeypatch.setenv("PYLOPS_MPI_TPU_AOT", "on")
+    monkeypatch.delenv("PYLOPS_MPI_TPU_AOT_CACHE", raising=False)
+    try:
+        pmt.clear_fused_cache()
+        aot.clear_memory()
+        aot.reset_compile_count()
+        seen = []
+        for normal in (False, True, False, True):
+            np.testing.assert_array_equal(solve(fresh(), normal),
+                                          plain[normal])
+            seen.append(aot.compile_count())
+        assert seen == [1, 2, 2, 2]
+    finally:
+        jax.config.update("jax_compilation_cache_dir", prev)
+        aot.clear_memory()
+        aot.reset_compile_count()
+        pmt.clear_fused_cache()

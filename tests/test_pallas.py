@@ -33,10 +33,15 @@ def test_second_derivative_kernel(rng):
 
 # ---------------------------------------------------- fused normal matvec
 def _normal_oracle(A, X):
-    """``(AᴴA x, A x)`` per block by NumPy einsum, in A's own dtype."""
-    A, X = np.asarray(A), np.asarray(X)
-    q = np.einsum("bmn,bn->bm", A, X)
-    return np.einsum("bmn,bm->bn", A.conj(), q), q
+    """``(AᴴA X, A X)`` per block by NumPy einsum at double width;
+    ``X (nblk, K, n)``: K columns a block, row-wise — or ``(nblk, n)``,
+    a vector a block, answered in that shape."""
+    wide = np.result_type(np.asarray(A).dtype, np.float64)
+    A, X = np.asarray(A, dtype=wide), np.asarray(X, dtype=wide)
+    Xk = X if X.ndim == 3 else X[:, None, :]
+    q = np.einsum("bmn,bkn->bkm", A, Xk)
+    u = np.einsum("bmn,bkm->bkn", A.conj(), q)
+    return (u, q) if X.ndim == 3 else (u[:, 0], q[:, 0])
 
 
 def _rel(got, want):
@@ -47,18 +52,51 @@ def _rel(got, want):
 @pytest.mark.parametrize("shape", [(2, 24, 16), (1, 64, 64), (3, 40, 56),
                                    (2, 17, 5)])
 def test_batched_normal_matvec_oracle(rng, dtype, shape):
-    """One sweep against the einsum oracle: one 64-row tile a block,
-    several 8-row tiles (24, 40), and a ragged block whose only legal
-    tile is the whole block (17 x 5)."""
+    """One sweep against the einsum oracle at K = 1 (a vector a block):
+    one 64-row tile a block, several 8-row tiles (24, 40), and a ragged
+    block whose only legal tile is the whole block (17 x 5)."""
     nblk, m, n = shape
     A = jnp.asarray(rng.standard_normal(shape).astype(dtype))
-    X = jnp.asarray(rng.standard_normal((nblk, n)).astype(dtype))
+    X = jnp.asarray(rng.standard_normal((nblk, 1, n)).astype(dtype))
     assert pk.normal_matvec_supported(A)
     u, q = pk.batched_normal_matvec(A, X)
+    assert u.shape == (nblk, 1, n) and q.shape == (nblk, 1, m)
     assert u.dtype == q.dtype == dtype
     wu, wq = _normal_oracle(A, X)
     tol = 1e-5 if dtype == np.float32 else 1e-12
     assert _rel(q, wq) < tol and _rel(u, wu) < tol
+
+
+@pytest.mark.parametrize("K", [1, 2, 3, 8, 16])
+@pytest.mark.parametrize("shape,tile", [((2, 384, 40), 128),
+                                        ((2, 48, 40), 16)],
+                         ids=["lane_dense_q", "sub_128_row_tile"])
+@pytest.mark.parametrize("storage,name", [
+    (np.float32, "pmt_normal"), (jnp.bfloat16, "pmt_normal_stream")],
+    ids=["f32", "bf16_stream"])
+def test_batched_normal_matvec_columns_oracle(rng, storage, name, shape,
+                                              tile, K):
+    """K columns a block from one read of A: ``X (nblk, K, n)`` in,
+    ``U (nblk, K, n)`` and ``Q (nblk, K, m)`` out, equal to the einsum
+    pair column by column — f32 blocks, and bf16 storage under the
+    streaming name (the f32 columns are never narrowed). Three row
+    tiles a block in both shapes: 128 rows, where the kernel writes Q's
+    ``(1, K, tm)`` blocks in place, and 16, where Q leaves as
+    ``(nblk, m/tm, K, tm)`` and is put in order outside."""
+    nblk, m, n = shape
+    A = jnp.asarray(rng.standard_normal(shape).astype(np.float32)
+                    ).astype(storage)
+    X = jnp.asarray(rng.standard_normal((nblk, K, n)).astype(np.float32))
+    assert pk._tile_args(A) == (tile, storage != np.float32)
+    u, q = pk.batched_normal_matvec(A, X)
+    assert u.shape == (nblk, K, n) and q.shape == (nblk, K, m)
+    assert u.dtype == q.dtype == np.float32
+    wu, wq = _normal_oracle(A.astype(jnp.float32), X)
+    assert _rel(q, wq) < 1e-5 and _rel(u, wu) < 1e-5
+    import jax
+    text = jax.jit(pk.batched_normal_matvec).lower(A, X).as_text(
+        debug_info=True)
+    assert set(re.findall(r"pmt_normal\w*", text)) == {name}
 
 
 @pytest.mark.parametrize("dtype", [np.float32, np.float64])
@@ -85,6 +123,45 @@ def test_normal_matvec_without_a_legal_tile_takes_two_sweeps(
     tol = 1e-5 if dtype == np.float32 else 1e-12
     assert _rel(q.asarray().reshape(P, 17), wq) < tol
     assert _rel(u.asarray().reshape(P, 5), wu) < tol
+
+
+@pytest.mark.parametrize("n,K,fits", [(4096, 256, True), (4096, 257, False),
+                                      (16384, 64, True), (16384, 65, False),
+                                      (1024, 1024, True), (1024, 1025, False)])
+def test_normal_matvec_supported_bounds_the_column_block(n, K, fits):
+    """*Can* has a width: the K columns stay in VMEM beside the tile,
+    so a column block larger than the tile budget has no kernel (the
+    bound is where the compile for a described v5e starts to fail)."""
+    import jax
+    A = jax.ShapeDtypeStruct((2, 1024, n), jnp.float32)
+    assert pk.normal_matvec_supported(A) and pk.normal_matvec_supported(A, 1)
+    assert pk.normal_matvec_supported(A, K) is fits
+
+
+def test_normal_matvec_wider_than_vmem_takes_two_sweeps(rng, monkeypatch):
+    """A block input wider than the kernel can hold falls back to
+    matvec + rmatvec, like any input without a kernel; narrower inputs
+    of the same operator keep the kernel."""
+    from pylops_mpi_tpu import MPIBlockDiag, DistributedArray
+    from pylops_mpi_tpu.ops.local import MatrixMult
+    import jax
+    P = len(jax.devices())
+    blocks = rng.standard_normal((P, 24, 16)).astype(np.float32)
+    Op = MPIBlockDiag([MatrixMult(b, dtype=np.float32) for b in blocks])
+    monkeypatch.setattr(pk, "_VMEM_TILE_BYTES", 2048)   # 32 columns of 16
+    xs = {}
+    for K in (32, 33):
+        xs[K] = DistributedArray(global_shape=(P * 16, K), dtype=np.float32)
+        xs[K][:] = rng.standard_normal((P * 16, K)).astype(np.float32)
+    assert Op.has_fused_normal
+    assert Op._normal_kernel_for(xs[32]) is pk.batched_normal_matvec
+    assert Op._normal_kernel_for(xs[33]) is None
+    with pytest.raises(ValueError, match="33 columns"):
+        pk.batched_normal_matvec(Op._batched, jnp.zeros((P, 33, 16)))
+    u, q = Op.normal_matvec(xs[33])
+    q2 = Op.matvec(xs[33])
+    np.testing.assert_array_equal(q.asarray(), q2.asarray())
+    np.testing.assert_array_equal(u.asarray(), Op.rmatvec(q2).asarray())
 
 
 @pytest.mark.parametrize("storage,name", [(None, "pmt_normal"),

@@ -385,8 +385,9 @@ def test_pallas_streaming_normal_matvec_bf16(rng):
     Ab = A.astype(jnp.bfloat16)
     X = jnp.asarray(rng.standard_normal((4, 32)).astype(np.float32))
     assert pk.normal_matvec_supported(Ab)
-    u, q = pk.batched_normal_matvec(Ab, X)
+    u, q = pk.batched_normal_matvec(Ab, X[:, None, :])    # K = 1
     assert u.dtype == jnp.float32 and q.dtype == jnp.float32
+    u, q = u[:, 0], q[:, 0]
     qs = np.einsum("bmn,bn->bm", np.asarray(A), np.asarray(X))
     us = np.einsum("bmn,bm->bn", np.asarray(A), qs)
     np.testing.assert_allclose(np.asarray(q), qs, rtol=1e-5, atol=1e-5)
