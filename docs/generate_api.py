@@ -263,7 +263,7 @@ PAGES = {
     "models": [
         ("Model workflows", "pylops_mpi_tpu.models",
          ["PoststackLinearModelling", "MPIPoststackLinearModelling",
-          "poststack_inversion", "MPILSM", "KirchhoffDemigration",
+          "poststack_regularized", "poststack_inversion", "MPILSM", "KirchhoffDemigration",
           "TravelTimeSpray", "kernel_to_frequency", "ricker"]),
         ("Multi-dimensional deconvolution", "pylops_mpi_tpu.models.mdd",
          ["mdd"]),

@@ -11,6 +11,7 @@ so composed distributed operators trace into a single XLA program.
 
 from __future__ import annotations
 
+import functools
 from typing import Callable, Optional, Sequence, Tuple, Union
 
 import numpy as np
@@ -18,6 +19,7 @@ import jax
 import jax.numpy as jnp
 
 from . import dft
+from ..diagnostics import trace as _trace
 
 __all__ = [
     "LocalOperator", "MatrixMult", "Identity", "Diagonal", "Zero",
@@ -25,6 +27,19 @@ __all__ = [
     "Roll", "Pad", "Flip", "FunctionOperator", "VStack", "HStack",
     "BlockDiag", "FFT", "Conv1D", "NonStationaryConvolve1D",
 ]
+
+
+def _scoped(apply):
+    """Run a local apply under the span ``local.<Class>`` (the rule of
+    ``diagnostics/trace.py``: a named scope ``pmt.local.<Class>`` under
+    a jit trace), so a device trace splits the distributed operator
+    that composes local ones — ``pmt.MPIBlockDiag.matvec`` into
+    convolution and derivative."""
+    @functools.wraps(apply)
+    def wrapped(self, x):
+        with _trace.span("local." + type(self).__name__):
+            return apply(self, x)
+    return wrapped
 
 
 class LocalOperator:
@@ -146,7 +161,9 @@ class _Scaled(LocalOperator):
         return self.alpha * self.A._matvec(x)
 
     def _rmatvec(self, x):
-        return np.conj(self.alpha) * self.A._rmatvec(x)
+        # the scalar's own conjugate keeps its type: np.conj of a Python
+        # float is a float64, which would widen an f32 adjoint under x64
+        return self.alpha.conjugate() * self.A._rmatvec(x)
 
 
 class _Product(LocalOperator):
@@ -343,7 +360,28 @@ def _deriv_setup(dims, axis, sampling):
     return dims, axis, sampling
 
 
-class FirstDerivative(LocalOperator):
+class _AxisStencil(LocalOperator):
+    """What the derivative stencils share: slices, zero padding and
+    concatenation along ``self.axis`` WHERE IT LIES. A ``moveaxis`` of
+    the minor (lane) axis to the front and back was, in the program
+    compiled for a v5e, two transposed copies of the array an apply
+    (PERF.md section 6, PR 32)."""
+
+    def _sl(self, v, lo, hi=None):
+        idx = [slice(None)] * v.ndim
+        idx[self.axis] = slice(lo, hi)
+        return v[tuple(idx)]
+
+    def _pad0(self, v, before, after):
+        padw = [(0, 0)] * v.ndim
+        padw[self.axis] = (before, after)
+        return jnp.pad(v, padw)
+
+    def _cat(self, *rows):
+        return jnp.concatenate(rows, axis=self.axis)
+
+
+class FirstDerivative(_AxisStencil):
     """Local first derivative, matching pylops' stencils so the
     distributed variant (ref ``basicoperators/FirstDerivative.py``) has a
     bit-exact local building block. ``kind``: forward | backward |
@@ -364,73 +402,66 @@ class FirstDerivative(LocalOperator):
             raise NotImplementedError("'order' must be 3 or 5")
         super().__init__(self.dims_nd, self.dims_nd, dtype=dtype)
 
-    def _move(self, x):
-        return jnp.moveaxis(x.reshape(self.dims_nd), self.axis, 0)
-
-    def _back(self, y):
-        return jnp.moveaxis(y, 0, self.axis).ravel()
-
-    @staticmethod
-    def _pad0(v, before, after):
-        padw = [(before, after)] + [(0, 0)] * (v.ndim - 1)
-        return jnp.pad(v, padw)
-
+    @_scoped
     def _matvec(self, x):
-        v = self._move(x)
+        v = x.reshape(self.dims_nd)
         s = self.sampling
-        p = self._pad0
+        n = v.shape[self.axis]
+        p, g = self._pad0, self._sl
         if self.kind == "forward":
-            y = p((v[1:] - v[:-1]) / s, 0, 1)
+            y = p((g(v, 1) - g(v, 0, -1)) / s, 0, 1)
         elif self.kind == "backward":
-            y = p((v[1:] - v[:-1]) / s, 1, 0)
+            y = p((g(v, 1) - g(v, 0, -1)) / s, 1, 0)
         elif self.order == 3:
-            y = p((v[2:] - v[:-2]) / (2 * s), 1, 1)
+            y = p((g(v, 2) - g(v, 0, -2)) / (2 * s), 1, 1)
             if self.edge:
-                y = y + p(((v[1] - v[0]) / s)[None], 0, v.shape[0] - 1)
-                y = y + p(((v[-1] - v[-2]) / s)[None], v.shape[0] - 1, 0)
+                y = y + p((g(v, 1, 2) - g(v, 0, 1)) / s, 0, n - 1)
+                y = y + p((g(v, -1) - g(v, -2, -1)) / s, n - 1, 0)
         else:  # centered, 5-point: (x[i-2] - 8x[i-1] + 8x[i+1] - x[i+2])/12Δ
-            y = p((v[:-4] - 8 * v[1:-3] + 8 * v[3:-1] - v[4:]) / (12 * s), 2, 2)
+            y = p((g(v, 0, -4) - 8 * g(v, 1, -3) + 8 * g(v, 3, -1)
+                   - g(v, 4)) / (12 * s), 2, 2)
             if self.edge:
-                n = v.shape[0]
-                y = y + p(((v[1] - v[0]) / s)[None], 0, n - 1)
-                y = y + p(((v[2] - v[0]) / (2 * s))[None], 1, n - 2)
-                y = y + p(((v[-1] - v[-3]) / (2 * s))[None], n - 2, 1)
-                y = y + p(((v[-1] - v[-2]) / s)[None], n - 1, 0)
-        return self._back(y)
+                y = y + p((g(v, 1, 2) - g(v, 0, 1)) / s, 0, n - 1)
+                y = y + p((g(v, 2, 3) - g(v, 0, 1)) / (2 * s), 1, n - 2)
+                y = y + p((g(v, -1) - g(v, -3, -2)) / (2 * s), n - 2, 1)
+                y = y + p((g(v, -1) - g(v, -2, -1)) / s, n - 1, 0)
+        return y.ravel()
 
+    @_scoped
     def _rmatvec(self, x):
-        v = self._move(x)
+        v = x.reshape(self.dims_nd)
         s = self.sampling
-        n = v.shape[0]
-        p = self._pad0
+        n = v.shape[self.axis]
+        p, g, cat = self._pad0, self._sl, self._cat
         if self.kind == "forward":
-            c = v[:-1] / s
+            c = g(v, 0, -1) / s
             y = p(c, 1, 0) - p(c, 0, 1)
         elif self.kind == "backward":
-            c = v[1:] / s
+            c = g(v, 1) / s
             y = p(c, 1, 0) - p(c, 0, 1)
         elif self.order == 3:
-            c = v[1:-1] / (2 * s)
+            c = g(v, 1, -1) / (2 * s)
             y = p(c, 2, 0) - p(c, 0, 2)
             if self.edge:
-                e0 = jnp.stack([-v[0] / s, v[0] / s])
-                y = y + p(e0, 0, n - 2)
-                e1 = jnp.stack([-v[-1] / s, v[-1] / s])
-                y = y + p(e1, n - 2, 0)
+                v0, v1 = g(v, 0, 1), g(v, -1)
+                y = y + p(cat(-v0 / s, v0 / s), 0, n - 2)
+                y = y + p(cat(-v1 / s, v1 / s), n - 2, 0)
         else:
-            c = v[2:-2] / (12 * s)
+            c = g(v, 2, -2) / (12 * s)
             y = p(c, 0, 4) - 8 * p(c, 1, 3) + 8 * p(c, 3, 1) - p(c, 4, 0)
             if self.edge:
-                y = y + p(jnp.stack([-v[0] / s, v[0] / s]), 0, n - 2)
-                y = y + p(jnp.stack([-v[1] / (2 * s), jnp.zeros_like(v[1]),
-                                     v[1] / (2 * s)]), 0, n - 3)
-                y = y + p(jnp.stack([-v[-2] / (2 * s), jnp.zeros_like(v[1]),
-                                     v[-2] / (2 * s)]), n - 3, 0)
-                y = y + p(jnp.stack([-v[-1] / s, v[-1] / s]), n - 2, 0)
-        return self._back(y)
+                v0, v1, vm2, vm1 = g(v, 0, 1), g(v, 1, 2), g(v, -2, -1), \
+                    g(v, -1)
+                y = y + p(cat(-v0 / s, v0 / s), 0, n - 2)
+                y = y + p(cat(-v1 / (2 * s), jnp.zeros_like(v1),
+                              v1 / (2 * s)), 0, n - 3)
+                y = y + p(cat(-vm2 / (2 * s), jnp.zeros_like(vm2),
+                              vm2 / (2 * s)), n - 3, 0)
+                y = y + p(cat(-vm1 / s, vm1 / s), n - 2, 0)
+        return y.ravel()
 
 
-class SecondDerivative(LocalOperator):
+class SecondDerivative(_AxisStencil):
     """3-point second derivative, all three pylops stencil kinds
     (ref ``basicoperators/SecondDerivative.py:78-108`` registers
     forward/centered/backward; ``edge`` affects centered only, as in
@@ -451,40 +482,38 @@ class SecondDerivative(LocalOperator):
         self.kind, self.edge = kind, edge
         super().__init__(self.dims_nd, self.dims_nd, dtype=dtype)
 
-    @staticmethod
-    def _pad0(v, before, after):
-        padw = [(before, after)] + [(0, 0)] * (v.ndim - 1)
-        return jnp.pad(v, padw)
-
     # row offset of the stencil core within the output, per kind
     _CORE_OFFSET = {"forward": (0, 2), "centered": (1, 1), "backward": (2, 0)}
 
     def _matvec(self, x):
-        v = jnp.moveaxis(x.reshape(self.dims_nd), self.axis, 0)
+        v = x.reshape(self.dims_nd)
         s2 = self.sampling ** 2
-        p = self._pad0
+        p, g = self._pad0, self._sl
+        n = v.shape[self.axis]
         before, after = self._CORE_OFFSET[self.kind]
-        y = p((v[:-2] - 2 * v[1:-1] + v[2:]) / s2, before, after)
+        y = p((g(v, 0, -2) - 2 * g(v, 1, -1) + g(v, 2)) / s2, before, after)
         if self.kind == "centered" and self.edge:
-            n = v.shape[0]
-            y = y + p(((v[0] - 2 * v[1] + v[2]) / s2)[None], 0, n - 1)
-            y = y + p(((v[-3] - 2 * v[-2] + v[-1]) / s2)[None], n - 1, 0)
-        return jnp.moveaxis(y, 0, self.axis).ravel()
+            y = y + p((g(v, 0, 1) - 2 * g(v, 1, 2) + g(v, 2, 3)) / s2,
+                      0, n - 1)
+            y = y + p((g(v, -3, -2) - 2 * g(v, -2, -1) + g(v, -1)) / s2,
+                      n - 1, 0)
+        return y.ravel()
 
     def _rmatvec(self, x):
-        v = jnp.moveaxis(x.reshape(self.dims_nd), self.axis, 0)
+        v = x.reshape(self.dims_nd)
         s2 = self.sampling ** 2
-        p = self._pad0
-        n = v.shape[0]
+        p, g, cat = self._pad0, self._sl, self._cat
+        n = v.shape[self.axis]
         before, after = self._CORE_OFFSET[self.kind]
         # adjoint spreads each output row back over its 3 input columns:
         # c holds the rows carrying the core, shifted to columns 0/1/2
-        c = v[before:n - after] / s2
+        c = g(v, before, n - after) / s2
         y = p(c, 0, 2) - 2 * p(c, 1, 1) + p(c, 2, 0)
         if self.kind == "centered" and self.edge:
-            y = y + p(jnp.stack([v[0], -2 * v[0], v[0]]) / s2, 0, n - 3)
-            y = y + p(jnp.stack([v[-1], -2 * v[-1], v[-1]]) / s2, n - 3, 0)
-        return jnp.moveaxis(y, 0, self.axis).ravel()
+            v0, v1 = g(v, 0, 1), g(v, -1)
+            y = y + p(cat(v0, -2 * v0, v0) / s2, 0, n - 3)
+            y = y + p(cat(v1, -2 * v1, v1) / s2, n - 3, 0)
+        return y.ravel()
 
 
 class Laplacian(LocalOperator):
@@ -670,7 +699,27 @@ class FFT(LocalOperator):
 
 class Conv1D(LocalOperator):
     """Stationary 1-D convolution along ``axis`` (zero-phase placement via
-    ``offset``), the local building block for deconvolution models."""
+    ``offset``), the local building block for deconvolution models:
+    ``y[i] = sum_j h[j] x[i + offset - j]``, zero outside the array.
+
+    Applied as a block-Toeplitz product that streams the array once, by
+    ONE form on every backend: the Pallas kernel ``pmt_conv1d``
+    (``pallas_kernels.conv1d_toeplitz``; compiled on a TPU, interpreted
+    elsewhere, as ``pmt_normal`` is). The axis is cut into tiles of
+    ``L = 128 * ceil((nh - 1) / 128)`` samples (``conv1d_tile``), so an
+    output tile reads its own input tile and the one on either side,
+    each through one ``L x L`` Toeplitz block of the filter. f32
+    products are ``highest``-equivalent (six bf16 products by hand);
+    wider dtypes, interpreted only, take plain dots.
+
+    Live in an apply: the input and the output, **2 arrays**; an axis
+    that is no multiple of ``L`` is zero-padded up to one and the
+    result cut back, **4 arrays**. Complex data or a complex filter
+    pass as their real and imaginary parts stacked on rows (one kernel
+    call a part of the filter). ``conv1d.path_select`` (``taps``,
+    ``n``, ``form``, ``pad``) says what was traced under
+    ``PYLOPS_MPI_TPU_TRACE``. The gather form this replaces held ``nh``
+    copies of the array."""
 
     def __init__(self, dims, h, axis: int = 0, offset: int = 0, dtype=None):
         dims = tuple(np.atleast_1d(dims))
@@ -680,24 +729,55 @@ class Conv1D(LocalOperator):
         self.offset = offset
         super().__init__(dims, dims, dtype=dtype or self.h.dtype)
 
+    @staticmethod
+    def _blocks(h, offset, L):
+        """``[T_-1; T_0; T_+1]`` stacked on rows, ``(3L, L)``:
+        ``T_d[a, b] = h[b + offset - a - d * L]`` (zero outside the
+        filter) is the block through which sample ``a`` of input tile
+        ``k + d`` reaches sample ``b`` of output tile ``k``."""
+        nh = h.shape[0]
+        a = jnp.arange(3 * L)[:, None]          # row a of block d: d*L + a
+        b = jnp.arange(L)[None, :]
+        j = b + offset - a + L                  # = b + offset - a' - d*L
+        return jnp.where((j >= 0) & (j < nh), h[jnp.clip(j, 0, nh - 1)], 0)
+
     def _conv(self, x, h, offset):
+        from . import pallas_kernels as pk
         n = self.dims_nd[self.axis]
         v = jnp.moveaxis(x.reshape(self.dims_nd), self.axis, -1)
-        shp = v.shape
         v2 = v.reshape(-1, n)
+        rows = v2.shape[0]
         nh = h.shape[0]
-        # full correlation via padded FFT would also work; direct conv keeps
-        # dtypes exact for small filters
-        pad = (nh - 1 - offset, offset)
-        vp = jnp.pad(v2, ((0, 0), pad))
-        idx = jnp.arange(n)[:, None] + jnp.arange(nh)[None, :]
-        patches = vp[:, idx]                    # (batch, n, nh)
-        y = patches @ jnp.flip(h)
-        return jnp.moveaxis(y.reshape(shp), -1, self.axis).ravel()
+        L = pk.conv1d_tile(nh)
+        pad = -n % L
+        _trace.event("conv1d.path_select", cat="schedule", taps=nh, n=n,
+                     form="pmt_conv1d", pad=pad)
+        cplx = jnp.iscomplexobj(v2) or jnp.iscomplexobj(h)
+        if cplx:            # real and imaginary parts stacked on rows
+            v2 = jnp.concatenate([v2.real, v2.imag])
+        if pad:
+            v2 = jnp.pad(v2, ((0, 0), (0, pad)))
+        real = v2.dtype
 
+        def through(hpart):
+            T = self._blocks(hpart.astype(real), offset, L)
+            return pk.conv1d_toeplitz(v2, T)[:, :n]
+
+        if not cplx:
+            y = through(h)
+        else:
+            yr = through(h.real)
+            y = yr[:rows] + 1j * yr[rows:]
+            if jnp.iscomplexobj(h):
+                yi = through(h.imag)
+                y = y - yi[rows:] + 1j * yi[:rows]
+        return jnp.moveaxis(y.reshape(v.shape), -1, self.axis).ravel()
+
+    @_scoped
     def _matvec(self, x):
         return self._conv(x, self.h, self.offset)
 
+    @_scoped
     def _rmatvec(self, x):
         # correlation = convolution with reversed conj filter, mirrored offset
         h = jnp.flip(jnp.conj(self.h))

@@ -26,6 +26,7 @@ from jax.experimental.pallas import tpu as pltpu
 __all__ = ["first_derivative_centered", "second_derivative",
            "stencil_taps", "batched_normal_matvec",
            "normal_matvec_supported", "normal_matvec_pays",
+           "conv1d_toeplitz", "conv1d_tile",
            "pallas_available"]
 
 
@@ -403,3 +404,93 @@ def batched_normal_matvec(A: jax.Array, X: jax.Array):
     if not lane_dense:
         q = q.transpose(0, 2, 1, 3).reshape(nblk, K, m)
     return u, q
+
+
+# ------------------------------------------------------- stationary conv1d
+# ``ops/local.py::Conv1D`` as one pass over the array: a block of whole
+# rows (the convolved axis on lanes) comes into VMEM once, every output
+# tile of L lanes is the product of the (at most three) input tiles
+# around it with the filter's Toeplitz blocks on the MXU, and the block
+# of outputs leaves once. ``highest`` by hand, as ``_dot_highest``: the
+# six products of two three-term bf16 expansions, f32 accumulation,
+# smallest first.
+
+_CONV_BLOCK_BYTES = 2 << 20   # one input block; in and out double-buffered
+
+
+def conv1d_tile(nh: int) -> int:
+    """The tile ``L`` of an ``nh``-tap filter: whole 128-lane groups
+    that hold ``nh - 1`` samples, so an output tile reads no further
+    than the tile on either side. The blocks and their bf16 parts take
+    ``30 L^2`` bytes of VMEM: compiled for a v5e the kernel holds
+    ``L = 768`` (769 taps); a longer filter is Mosaic's to refuse."""
+    return 128 * max(1, -(-(nh - 1) // 128))
+
+
+def _conv_rows(n: int, itemsize: int) -> int:
+    """Rows a block: as many as ``_CONV_BLOCK_BYTES`` hold, whole
+    sublane groups, at least one."""
+    return max(8, (_CONV_BLOCK_BYTES // (itemsize * n)) // 8 * 8)
+
+
+def _conv1d_kernel(t_ref, x_ref, o_ref, *, L: int):
+    """``x_ref``/``o_ref (R, n)``, ``t_ref (3L, L)``: the blocks
+    ``[T_-1; T_0; T_+1]`` stacked on rows, so the input tiles
+    ``k-1 .. k+1`` side by side meet the rows ``0 .. 3L`` (an edge tile
+    meets the rows of the tiles that exist). The blocks' bf16 parts are
+    made HERE, a grid step (0.2 MB beside the block's 2): made outside,
+    by XLA, the round trip f32 -> bf16 -> f32 of a fused expansion is
+    computed in excess precision on the chip and the second and third
+    parts come out zero — a one-pass bf16 convolution, 1.3e-3 from the
+    plain one where this is 1e-6 (PERF.md section 6, PR 32). Wider
+    than f32 (interpreted only): plain dots."""
+    nt = x_ref.shape[1] // L
+    f32 = x_ref.dtype == jnp.float32
+    xp = _bf16_parts(x_ref[...], 3) if f32 else [x_ref[...]]
+    tp = _bf16_parts(t_ref[...], 3) if f32 else [t_ref[...]]
+    for k in range(nt):                  # static: unrolled at trace time
+        lo, hi = max(k - 1, 0), min(k + 2, nt)
+        rows = slice((lo - k + 1) * L, (hi - k + 1) * L)
+        if not f32:
+            o_ref[:, k * L:(k + 1) * L] = jnp.dot(
+                xp[0][:, lo * L:hi * L], tp[0][rows, :],
+                preferred_element_type=x_ref.dtype,
+                precision=jax.lax.Precision.HIGHEST)
+            continue
+        terms = []
+        for j in range(3):
+            t = tp[j][rows, :]
+            terms += [(i + j, jnp.dot(xp[i][:, lo * L:hi * L], t,
+                                      preferred_element_type=jnp.float32,
+                                      precision=jax.lax.Precision.DEFAULT))
+                      for i in range(3 - j)]
+        terms.sort(key=lambda p: -p[0])
+        o_ref[:, k * L:(k + 1) * L] = reduce(jnp.add,
+                                             (p for _, p in terms))
+
+
+def conv1d_toeplitz(v: jax.Array, T: jax.Array) -> jax.Array:
+    """``y = v @ Toeplitz`` along the minor axis of ``v (rows, n)``,
+    real, ``n`` whole tiles: the banded Toeplitz matrix given as its
+    blocks ``T (3L, L)`` = ``[T_-1; T_0; T_+1]`` (``Conv1D._blocks``):
+    output tile ``k`` is input tiles ``k-1, k, k+1`` through them, zero
+    beyond the ends. The rows need not divide into blocks: rows carry
+    no dependency, Mosaic masks the ragged last block."""
+    rows, n = v.shape
+    L = T.shape[1]
+    if n % L or jnp.iscomplexobj(v):
+        raise ValueError(f"conv1d_toeplitz: real rows of whole {L}-sample "
+                         f"tiles, not {v.dtype}[{rows}, {n}]")
+    R = _conv_rows(n, v.dtype.itemsize)
+    return pl.pallas_call(
+        partial(_conv1d_kernel, L=L),
+        grid=((rows + R - 1) // R,),
+        in_specs=[pl.BlockSpec((3 * L, L), lambda i: (0, 0)),
+                  pl.BlockSpec((R, n), lambda i: (i, 0))],
+        out_specs=pl.BlockSpec((R, n), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((rows, n), v.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+        name="pmt_conv1d",
+    )(T.astype(v.dtype), v)

@@ -1,0 +1,400 @@
+"""3-D post-stack inversion (PR 32): the streaming ``Conv1D``, the 3-D
+modelling operator and Laplacian against a plain NumPy reference, the
+regularised stacked solve against textbook CGLS, the scopes the device
+trace splits it by, and the sweep schedule each operator resolves to.
+Small on the CPU; the one compile for a described v5e (the kernel at
+the benchmark's width) lives in a fixture, per the on-chip-measurement
+guide."""
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+import pylops_mpi_tpu as pmt
+from pylops_mpi_tpu import DistributedArray, StackedDistributedArray
+from pylops_mpi_tpu.models import (MPIPoststackLinearModelling,
+                                   poststack_inversion,
+                                   poststack_regularized, ricker)
+from pylops_mpi_tpu.ops import pallas_kernels as pk
+from pylops_mpi_tpu.ops.local import Conv1D, MatrixMult
+from pylops_mpi_tpu.solvers import basic
+from pylops_mpi_tpu.utils import hlo
+
+
+# ------------------------------------------------- plain NumPy reference
+def np_conv(x, h, offset, axis=-1):
+    n = x.shape[axis]
+    return np.apply_along_axis(
+        lambda t: np.convolve(t, h)[offset:offset + n], axis, x)
+
+
+def np_modelling(m, wav):
+    d = np.empty_like(m)
+    d[..., 1:-1] = 0.5 * (m[..., 2:] - m[..., :-2])
+    d[..., 0] = m[..., 1] - m[..., 0]
+    d[..., -1] = m[..., -1] - m[..., -2]
+    return 0.5 * np_conv(d, wav, len(wav) // 2)
+
+
+def np_laplacian(m):
+    out = np.zeros_like(m)
+    for ax in range(m.ndim):
+        a, b, c = ([slice(None)] * m.ndim for _ in range(3))
+        a[ax], b[ax], c[ax] = slice(0, -2), slice(1, -1), slice(2, None)
+        out[tuple(b)] += m[tuple(a)] - 2 * m[tuple(b)] + m[tuple(c)]
+    return out
+
+
+def np_cgls(A, y, x0, niter):
+    """Textbook CGLS from ``x0`` on a dense matrix."""
+    x = x0.copy()
+    s = y - A @ x
+    r = A.T @ s
+    c, q, k = r.copy(), A @ r, r @ r
+    for _ in range(niter):
+        a = k / (q @ q)
+        x, s = x + a * c, s - a * q
+        r = A.T @ s
+        k, kold = r @ r, k
+        c = r + (k / kold) * c
+        q = A @ c
+    return x
+
+
+WAV = ricker(np.arange(41) * 0.004, 15)[0]          # 81 taps
+
+
+# ----------------------------------------------------------- the Conv1D
+CONV_CASES = {
+    "odd81-centre-f32-300": ((5, 300), 1, 81, 40, np.float32),
+    "odd41-centre-f32-1024": ((3, 1024), 1, 41, 20, np.float32),
+    "even10-axis0-f32": ((200, 6), 0, 10, 3, np.float32),
+    "odd9-3d-f64": ((3, 4, 64), 2, 9, 4, np.float64),
+    "offset0-f32-512": ((7, 512), 1, 81, 0, np.float32),
+    "offset-last-two-tiles": ((7, 512), 1, 200, 199, np.float32),
+    "nt0-130-not-128s": ((4, 130), -1, 41, 20, np.float32),
+    "middle-axis-f32": ((3, 260, 5), 1, 31, 15, np.float32),
+    "one-tap": ((6, 256), 1, 1, 0, np.float32),
+    "filter-longer-than-axis": ((4, 50), 1, 81, 40, np.float64),
+    "two-taps-f64-1d": ((384,), 0, 2, 1, np.float64),
+    "complex64-data-and-filter": ((4, 130), 1, 41, 20, np.complex64),
+    "complex128-300-taps": ((3, 700), 1, 300, 20, np.complex128),
+    "rows-not-a-block": ((1031, 128), 1, 5, 2, np.float32),
+}
+
+
+def _seeded(rng, shape, dt):
+    v = rng.standard_normal(shape)
+    if np.issubdtype(dt, np.complexfloating):
+        v = v + 1j * rng.standard_normal(shape)
+    return v.astype(dt)
+
+
+@pytest.mark.parametrize("case", sorted(CONV_CASES))
+def test_conv1d_matches_numpy_and_its_adjoint(rng, case):
+    dims, axis, nh, off, dt = CONV_CASES[case]
+    h, x, u = (_seeded(rng, s, dt) for s in ((nh,), dims, dims))
+    op = Conv1D(dims, h, axis=axis, offset=off, dtype=dt)
+    y = np.asarray(op.matvec(jnp.asarray(x.ravel()))).reshape(dims)
+    xa = np.asarray(op.rmatvec(jnp.asarray(u.ravel()))).reshape(dims)
+    assert y.dtype == dt and xa.dtype == dt
+    wide = np.result_type(dt, np.float64)
+    want = np_conv(x.astype(wide), h.astype(wide), off, axis % len(dims))
+    tol = 1e-6 if np.finfo(dt).bits == 32 else 1e-13
+    assert np.linalg.norm(y - want) <= tol * np.linalg.norm(want)
+    # the dot test: <u, A x> = <A^H u, x>
+    lhs, rhs = np.vdot(u, y), np.vdot(xa, x)
+    assert abs(lhs - rhs) <= 20 * tol * (np.linalg.norm(u)
+                                         * np.linalg.norm(y))
+
+
+@pytest.mark.parametrize("rows,n,nh,off", [(20, 512, 81, 40),
+                                           (9, 256, 81, 0),
+                                           (16, 1024, 200, 100)])
+def test_pmt_conv1d_interpreted_matches_numpy(rng, rows, n, nh, off):
+    h = jnp.asarray(rng.standard_normal(nh).astype(np.float32))
+    x = rng.standard_normal((rows, n)).astype(np.float32)
+    T = Conv1D._blocks(h, off, pk.conv1d_tile(nh))
+    y = np.asarray(pk.conv1d_toeplitz(jnp.asarray(x), T))
+    want = np_conv(x.astype(np.float64), np.asarray(h, np.float64), off)
+    assert np.linalg.norm(y - want) <= 3e-7 * np.linalg.norm(want)
+
+
+@pytest.mark.parametrize("nh,L", [(1, 128), (2, 128), (41, 128), (81, 128),
+                                  (129, 128), (130, 256), (257, 256),
+                                  (600, 640)])
+def test_conv1d_tile_holds_the_filter(nh, L):
+    """One form, one shape rule: whole 128-lane groups that hold
+    ``nh - 1`` samples, so three blocks cover the band."""
+    assert pk.conv1d_tile(nh) == L
+    h = jnp.arange(1.0, nh + 1.0)
+    for off in (0, nh // 2, nh - 1):
+        T = np.asarray(Conv1D._blocks(h, off, L))
+        assert T.shape == (3 * L, L)
+        # every tap reaches every output sample once
+        assert np.allclose(T.sum(axis=0), float(h.sum()))
+
+
+def test_conv1d_toeplitz_wants_whole_tiles():
+    T = Conv1D._blocks(jnp.ones(5), 2, 128)
+    with pytest.raises(ValueError, match="whole 128-sample tiles"):
+        pk.conv1d_toeplitz(jnp.zeros((8, 200), jnp.float32), T)
+    with pytest.raises(ValueError, match="real rows"):
+        pk.conv1d_toeplitz(jnp.zeros((8, 256), jnp.complex64), T)
+
+
+@pytest.mark.parametrize("n,pad", [(300, 84), (1024, 0)])
+def test_conv1d_path_select_event(monkeypatch, n, pad):
+    from pylops_mpi_tpu.diagnostics import trace
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    trace.clear_events()
+    op = Conv1D((4, n), jnp.asarray(WAV, jnp.float32), axis=1, offset=40,
+                dtype=np.float32)
+    op.matvec(jnp.ones(4 * n, jnp.float32))
+    ev = [e for e in trace.get_events() if e["name"] == "conv1d.path_select"]
+    trace.clear_events()
+    assert ev and ev[0]["args"]["taps"] == 81 and ev[0]["args"]["n"] == n
+    assert ev[0]["args"]["form"] == "pmt_conv1d"
+    assert ev[0]["args"]["pad"] == pad
+
+
+# -------------------------------- the kernel, compiled for a described v5e
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.sharding import SingleDeviceSharding
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:                                  # noqa: BLE001
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    return SingleDeviceSharding(topo.devices[0])
+
+
+V5E_CASES = {
+    # rows, n, taps, temporaries allowed in volumes
+    "survey-41-taps": (192 * 1024, 1024, 41, 2.05),
+    "survey-81-taps": (192 * 1024, 1024, 81, 2.05),
+    "ragged-axis-1000": (192 * 1024, 1000, 41, 4.2),
+    "769-taps-the-largest-tile": (4096, 1536, 769, 2.05),
+}
+
+
+@pytest.mark.parametrize("case", sorted(V5E_CASES))
+def test_pmt_conv1d_compiles_for_v5e(one_chip, monkeypatch, case):
+    """At the benchmark's shape (192 x 1,024 traces of 1,024 samples),
+    on a ragged axis and at the largest tile: Mosaic accepts the
+    kernel, and no array of more than the stated multiple of one volume
+    is live in the apply or the adjoint — the input and the output; two
+    more where the axis is padded to whole tiles."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    rows, n, nh, allowed = V5E_CASES[case]
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        # as on the chip, where x64 is off (Mosaic has no i64 indices)
+        with jax.enable_x64(False):
+            h = jnp.asarray(ricker(np.arange(nh // 2 + 1) * 0.004, 15)[0],
+                            jnp.float32)
+            op = Conv1D((rows, n), h, axis=1, offset=nh // 2,
+                        dtype=np.float32)
+            x = jax.ShapeDtypeStruct((rows * n,), jnp.float32,
+                                     sharding=one_chip)
+            compiled = [jax.jit(f).lower(x).compile()
+                        for f in (op._matvec, op._rmatvec)]
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    vol = 4 * rows * n
+    for c in compiled:
+        assert "pmt_conv1d" in c.as_text()
+        # the flat vector and the (rows, n) tiles are different layouts
+        # on the chip: a relayout each way is all that is left beside
+        # the call (and the padded copies, where the axis is ragged)
+        ma = c.memory_analysis()
+        assert ma.temp_size_in_bytes <= allowed * vol, (case, ma)
+
+
+# ------------------------------------------- the 3-D operators, 1/2/4 devices
+def _cube(rng, ny=8, nx=6, nt0=160, dt=np.float32):
+    m = np.cumsum(rng.standard_normal((ny, nx, nt0)) * 0.05, axis=-1)
+    return (8.0 + m).astype(dt)
+
+
+@pytest.mark.parametrize("ndev", [1, 2, 4])
+def test_poststack_3d_modelling_and_laplacian(rng, ndev):
+    mesh = pmt.make_mesh(ndev)
+    m = _cube(rng)
+    ny, nx, nt0 = m.shape
+    StackOp, Op, Lap = poststack_regularized(
+        WAV.astype(np.float32), nt0, (ny, nx), 100.0, mesh=mesh,
+        dtype=np.float32)
+    assert StackOp.dtype == Op.dtype == Lap.dtype == np.float32
+    assert Lap.axes == (0, 1, 2) and Lap.dims_nd == (ny, nx, nt0)
+    dm = DistributedArray.to_dist(m.ravel(), mesh=mesh,
+                                  local_shapes=Op.local_shapes_m)
+    y = StackOp.matvec(dm)
+    m64 = m.astype(np.float64)
+    want_d, want_r = np_modelling(m64, WAV), 10.0 * np_laplacian(m64)
+    got_d = y.distarrays[0].asarray().reshape(m.shape)
+    got_r = y.distarrays[1].asarray().reshape(m.shape)
+    assert got_d.dtype == got_r.dtype == np.float32
+    assert np.linalg.norm(got_d - want_d) <= 1e-6 * np.linalg.norm(want_d)
+    assert np.linalg.norm(got_r - want_r) <= 1e-6 * np.linalg.norm(want_r)
+    # the adjoint of the whole stack, by the dot test
+    u = StackedDistributedArray([
+        DistributedArray.to_dist(rng.standard_normal(m.size).astype(
+            np.float32), mesh=mesh, local_shapes=Op.local_shapes_n),
+        DistributedArray.to_dist(rng.standard_normal(m.size).astype(
+            np.float32), mesh=mesh)])
+    xa = StackOp.rmatvec(u).asarray().astype(np.float64)
+    lhs = sum(np.vdot(a.asarray().astype(np.float64),
+                      b.asarray().astype(np.float64))
+              for a, b in zip(u.distarrays, y.distarrays))
+    size = np.sqrt(sum(np.linalg.norm(a.asarray()) ** 2
+                       for a in u.distarrays)
+                   * sum(np.linalg.norm(b.asarray()) ** 2
+                         for b in y.distarrays))
+    assert abs(lhs - np.vdot(xa, m64.ravel())) <= 1e-5 * size
+
+
+def test_the_2d_call_keeps_working(rng):
+    nx, nt0 = 12, 64
+    Op = MPIPoststackLinearModelling(WAV[30:51], nt0, nx)
+    assert Op.shape == (nx * nt0, nx * nt0) and Op.dtype == np.float64
+    m = rng.standard_normal((nx, nt0))
+    dm = DistributedArray.to_dist(m.ravel(), local_shapes=Op.local_shapes_m)
+    got = Op.matvec(dm).asarray().reshape(nx, nt0)
+    np.testing.assert_allclose(got, np_modelling(m, WAV[30:51]),
+                               rtol=1e-10, atol=1e-12)
+
+
+# ------------------------------------------ the whole regularised solve
+def _dense_system(shape, wav, scale):
+    n = int(np.prod(shape))
+    eye = np.eye(n).reshape((n,) + shape)
+    A1 = np.stack([np_modelling(e, wav).ravel() for e in eye], axis=1)
+    A2 = np.stack([scale * np_laplacian(e).ravel() for e in eye], axis=1)
+    return np.concatenate([A1, A2])
+
+
+@pytest.mark.parametrize("ndev,dt,tol", [(1, np.float64, 1e-9),
+                                         (4, np.float64, 1e-9),
+                                         (2, np.float32, 2e-4)])
+def test_regularised_solve_matches_textbook_cgls(rng, ndev, dt, tol):
+    mesh = pmt.make_mesh(ndev)
+    shape = (4, 3, 24)
+    wav = WAV[34:47].astype(dt)                           # 13 taps
+    m = _cube(rng, *shape, dt=dt)
+    x0 = np_conv(m.astype(np.float64), np.ones(5) / 5, 2).astype(dt)
+    d = np_modelling(m.astype(np.float64), wav.astype(np.float64))
+    got, Op = poststack_inversion(d.astype(dt), wav, niter=12, epsR=2.0,
+                                  damp=0.0, mesh=mesh, dtype=dt, x0=x0)
+    assert got.shape == shape and got.dtype == dt
+    A = _dense_system(shape, wav.astype(np.float64), 2.0)
+    y = np.concatenate([d.ravel(), np.zeros(m.size)])
+    want = np_cgls(A, y, x0.astype(np.float64).ravel(), 12)
+    assert np.linalg.norm(got.ravel() - want) <= tol * np.linalg.norm(want)
+    # and it moved: the answer is not the background
+    assert np.linalg.norm(want - x0.ravel()) > 1e-3 * np.linalg.norm(want)
+
+
+# --------------------------------------------------- scopes and schedules
+def _small_system(dt=np.float32):
+    mesh = pmt.make_mesh(1)
+    ny, nx, nt0 = 4, 4, 256
+    StackOp, Op, Lap = poststack_regularized(
+        WAV.astype(dt), nt0, (ny, nx), 100.0, mesh=mesh, dtype=dt)
+
+    def vec():
+        return DistributedArray(global_shape=ny * nx * nt0, mesh=mesh,
+                                dtype=dt)
+    return StackOp, Op, StackedDistributedArray([vec(), vec()]), vec()
+
+
+@pytest.fixture(scope="module")
+def fused_hlo():
+    StackOp, _, y, x0 = _small_system()
+    return hlo.compiled_hlo(
+        lambda op, y, x0: basic._cgls_fused(op, y, x0, 0.0, 0.0, niter=3),
+        StackOp, y, x0)
+
+
+@pytest.mark.parametrize("scope", [
+    "pmt.local.Conv1D", "pmt.local.FirstDerivative",
+    "pmt.MPILaplacian.matvec", "pmt.MPILaplacian.rmatvec",
+    "pmt.MPIBlockDiag.matvec", "pmt.MPIBlockDiag.rmatvec",
+    "pmt.MPIStackedVStack.matvec", "pmt.MPIStackedVStack.rmatvec"])
+def test_scopes_in_the_fused_solver(fused_hlo, scope):
+    """The names the device trace splits the stacked solve by survive
+    on the ops inside the fused ``while_loop``."""
+    names = [ln for ln in fused_hlo.split("\n")
+             if "op_name=" in ln and "/while/body/" in ln and scope in ln]
+    assert names, scope
+    if scope.startswith("pmt.local."):    # inside the operator's scope
+        assert all("pmt.MPIBlockDiag." in ln for ln in names)
+
+
+@pytest.mark.parametrize("which", ["flagship", "modelling", "stack"])
+def test_sweep_schedule_each_operator_resolves_to(rng, monkeypatch, which):
+    """As on a TPU (the kernels compiled): the flagship's batched
+    blocks still take one sweep, the post-stack operators two."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    if which == "flagship":
+        mesh = pmt.make_mesh(1)
+        blocks = [MatrixMult(rng.standard_normal((512, 512)).astype(
+            np.float32)) for _ in range(2)]
+        Op = pmt.MPIBlockDiag(blocks, mesh=mesh)
+        x0 = DistributedArray(global_shape=1024, mesh=mesh,
+                              dtype=np.float32)
+        assert basic._resolve_normal(Op, x0, None) is True
+        return
+    StackOp, Op, _, x0 = _small_system()
+    op = Op if which == "modelling" else StackOp
+    assert basic._resolve_normal(op, x0, None) is False
+
+
+def test_stacked_cgls_leaves_a_callers_x0_valid(rng):
+    """The cell hands ``pmt.cgls`` the same ``x0`` solve after solve:
+    the caller's vector stays what it was, and a second solve from it
+    gives the first one's answer, bit for bit."""
+    StackOp, Op, y, x0 = _small_system()
+    y.distarrays[0][:] = jnp.asarray(
+        rng.standard_normal(x0.global_shape[0]).astype(np.float32))
+    x0[:] = jnp.asarray(8 + 0.1 * rng.standard_normal(
+        x0.global_shape[0]).astype(np.float32))
+    before = x0.asarray().copy()
+    xa = pmt.cgls(StackOp, y, x0=x0, niter=5, tol=0.0)[0].asarray()
+    np.testing.assert_array_equal(x0.asarray(), before)
+    xb = pmt.cgls(StackOp, y, x0=x0, niter=5, tol=0.0)[0].asarray()
+    np.testing.assert_array_equal(xa, xb)
+    assert np.linalg.norm(xa - before) > 0
+
+
+def test_fused_cgls_leaves_a_callers_x0_alone_without_an_eager_copy(
+        rng, monkeypatch):
+    """A caller's ``x0`` goes into the fused CGLS program undonated (the
+    program copies it at entry): no eager device copy at the head of
+    the solver's span, the caller's vector stays valid, and the entry
+    is keyed apart from the donated one a fresh ``x0`` takes."""
+    def refuse(v):
+        raise AssertionError("fused CGLS made an eager copy of x0")
+    monkeypatch.setattr(basic, "_donate_copy", refuse)
+    mesh = pmt.make_mesh(1)
+    Op = pmt.MPIBlockDiag([MatrixMult(
+        (rng.standard_normal((12, 12)) + 6 * np.eye(12)).astype(np.float32))],
+        mesh=mesh)
+    y = DistributedArray.to_dist(rng.standard_normal(12).astype(np.float32),
+                                 mesh=mesh)
+    x0 = DistributedArray.to_dist(rng.standard_normal(12).astype(np.float32),
+                                  mesh=mesh)
+    before = x0.asarray().copy()
+    xa = pmt.cgls(Op, y, x0=x0, niter=12, tol=0.0)[0].asarray()
+    np.testing.assert_array_equal(x0.asarray(), before)
+    xb = pmt.cgls(Op, y, niter=12, tol=0.0)[0].asarray()
+    np.testing.assert_allclose(xa, xb, atol=5e-4)   # both at the f32 floor
+    donated = sorted(bool(k[k.index("cgls") + 5]) for k in basic._FUSED_CACHE
+                     if k[0] == id(Op))
+    assert donated == [False, True]
