@@ -159,7 +159,7 @@ def _forward_cgls(Op, y, x0, niter, damp, tol, M, block):
             # key says so, shared with ``block_cgls(normal=False)``
             return _blk._run_block_cgls_fused(Op, y, x0, niter, damp,
                                               tol, M, use_normal=False)
-        x, iiter, cost, cost1, kold, _ = _b._run_cgls_fused(
+        x, iiter, cost, cost1, kold, _, _ = _b._run_cgls_fused(
             Op, y, x0, False, niter, damp, tol, False, False, M=M)
         return x, iiter, cost, cost1, kold
     from ..solvers import ca as _ca

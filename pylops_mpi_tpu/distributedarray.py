@@ -132,9 +132,33 @@ class DistributedArray:
             if len(mask) != self._n_shards:
                 raise ValueError(f"mask must have {self._n_shards} entries")
         self._mask = mask
-        dtype = jnp.zeros(0, dtype=dtype).dtype if dtype is not None else jnp.zeros(0).dtype
-        self._arr = lax.with_sharding_constraint(
-            jnp.zeros(self._phys_shape(), dtype=dtype), self._sharding())
+        self._dtype = jnp.zeros(0, dtype=dtype).dtype if dtype is not None else jnp.zeros(0).dtype
+
+    # ------------------------------------------------------------- storage
+    # ``_buf`` None with a ``_dtype``: zeros nobody has read yet (``_arr``);
+    # ``_wrap`` and ``tree_unflatten`` set ``_buf`` and need no ``_dtype``
+    _buf = _dtype = None
+
+    @property
+    def _arr(self):
+        """The physical array. A fresh ``DistributedArray`` is zeros that
+        are made at their first read: ``x = DistributedArray(...)``
+        followed by ``x[:] = a`` (the reference's idiom, and every
+        operator's) never allocates and fills a buffer it is about to
+        drop — at 805 MB a vector that is a device pass and, while the
+        host runs ahead of the device, a vector of memory each."""
+        if self._buf is None and self._dtype is not None:
+            # one dispatch: the zeros are made where they are to lie
+            zeros = jnp.zeros(self._phys_shape(), dtype=self._dtype,
+                              device=self._sharding())
+            if _is_tracer(zeros):       # never keep a trace's value
+                return zeros
+            self._buf = zeros
+        return self._buf
+
+    @_arr.setter
+    def _arr(self, value):
+        self._buf = value
 
     # -------------------------------------------------------------- layout
     @property
@@ -165,13 +189,14 @@ class DistributedArray:
             return axis_sharding(self._mesh, len(self._global_shape), self._axis)
         return replicated_sharding(self._mesh)
 
-    def _place(self, arr: jax.Array) -> jax.Array:
+    def _place(self, arr: jax.Array, may_alias: bool = False) -> jax.Array:
         """Pin physical placement (constraint under trace, device_put when
-        concrete)."""
+        concrete). ``may_alias``: a device array that already lies as
+        the sharding says is taken as it is, not copied."""
         sh = self._sharding()
         if _is_tracer(arr):
             return lax.with_sharding_constraint(arr, sh)
-        return jax.device_put(arr, sh)
+        return jax.device_put(arr, sh, may_alias=may_alias or None)
 
     def _from_global(self, garr: jax.Array) -> jax.Array:
         """Logical global → physical (pad each shard to ``s_phys``): one
@@ -263,7 +288,7 @@ class DistributedArray:
 
     @property
     def dtype(self):
-        return self._arr.dtype
+        return self._dtype if self._buf is None else self._buf.dtype
 
     @property
     def ndim(self) -> int:
@@ -365,7 +390,9 @@ class DistributedArray:
         if key == slice(None, None, None):
             v = jnp.broadcast_to(jnp.asarray(value, dtype=self.dtype),
                                  self._global_shape)
-            self._arr = self._place(self._from_global(v))
+            # arrays are immutable: a device array of the right shape,
+            # dtype and placement becomes the storage, uncopied
+            self._arr = self._place(self._from_global(v), may_alias=True)
         else:
             g = self._global().at[key].set(value)
             self._arr = self._place(self._from_global(g))

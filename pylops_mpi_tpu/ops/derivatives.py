@@ -18,6 +18,16 @@ round-trip is a reshape of the logical array.
 Distribution is along axis 0 of the N-D layout, as in the reference;
 derivatives along non-distributed axes (used by Laplacian/Gradient)
 reuse the same local stencils, which XLA partitions trivially (no comm).
+
+One operator does not leave its stencil to XLA: the centered,
+``edge=False`` :class:`MPILaplacian` of a 2-D or 3-D array in a
+balanced axis-0 split is ONE Pallas pass over each shard's cube,
+forward and adjoint (kernel ``pmt_laplacian``, inside a ``shard_map``
+with the two ghost planes as operands; 2 volumes live in an apply). Nine
+shifted slices and three pads over three axes, two of them at lane and
+sublane offsets 1 and 2, are not one pass over the cube for XLA: five
+to seven volume passes' worth on a TPU v5e (PERF.md section 6, PR 33).
+Every other kind, edge and layout keeps the sum of slices.
 """
 
 from __future__ import annotations
@@ -30,6 +40,7 @@ import jax.numpy as jnp
 from ..distributedarray import DistributedArray, Partition, local_split
 from ..stacked import StackedDistributedArray
 from ..linearoperator import MPILinearOperator
+from ..diagnostics import trace as _trace
 from .local import (FirstDerivative as _LocalFirst,
                     SecondDerivative as _LocalSecond)
 from .stack import MPIStackedVStack
@@ -439,8 +450,36 @@ class MPILaplacian(_StencilOperator):
     """Laplacian: weighted sum of second derivatives along ``axes``
     (ref ``basicoperators/Laplacian.py:15-126``, which routes the
     distributed axis through MPISecondDerivative and local axes through
-    MPIBlockDiag — here one fused stencil covers both, XLA inserting the
-    halo exchange only for axis 0)."""
+    MPIBlockDiag).
+
+    One operator, two forms, chosen from what the operator and its
+    input show (:meth:`_kernel_refusal`; no keyword, no environment
+    variable):
+
+    * ``pmt_laplacian`` — forward and adjoint are each ONE Pallas pass
+      over the shard's cube where it lies
+      (``pallas_kernels.laplacian_stencil``: compiled on a TPU,
+      interpreted elsewhere), inside a ``shard_map`` over the 1-D mesh
+      with the two axis-0 ghost planes from
+      ``collectives.ring_halo_ghosts`` as operands (zeros at the ends
+      of the global array; one device sends nothing). Taken for a real
+      floating dtype and real weights, ``kind="centered"``,
+      ``edge=False``, 2-D or 3-D ``dims`` (2-D is a cube with a middle
+      axis of one), every stencilled axis at least 3 long, a 1-D mesh,
+      an input in the balanced, unpadded axis-0 split with a plane or
+      more a shard and, where the kernel is compiled, f32 planes of
+      whole ``(8, 128)`` tiles (``n1 % 8 == 0``, ``n2 % 128 == 0``) of
+      at most 4 MiB. Live in an apply: the input and the output,
+      **2 volumes**, and two ghost planes.
+    * ``slices`` — everything else (``forward`` / ``backward`` kinds,
+      ``edge=True``, complex, ragged shares, a hybrid mesh, a 1-D or
+      4-D array): the sum of ``ops/local.py::SecondDerivative`` applies
+      on the logical global array, XLA inserting the halo exchange for
+      axis 0.
+
+    ``laplacian.path_select`` (``form``, ``dims``, ``axes``,
+    ``adjoint``, ``shards`` and, for ``slices``, a one-word ``why``)
+    says what a traced apply took under ``PYLOPS_MPI_TPU_TRACE``."""
 
     def __init__(self, dims, axes=(-2, -1), weights=(1, 1), sampling=(1, 1),
                  kind: str = "centered", edge: bool = False, mesh=None,
@@ -455,9 +494,49 @@ class MPILaplacian(_StencilOperator):
                                   kind=kind, edge=edge, dtype=dtype)
                      for ax, s in zip(axes, sampling)]
 
+    def _cube(self, rows: int) -> Tuple[int, int, int]:
+        """A shard of ``rows`` planes as the kernel's cube: 2-D ``dims``
+        get a middle axis of one."""
+        return (rows,) + (1,) * (3 - len(self.dims_nd)) + self.dims_nd[1:]
+
+    def _kernel_refusal(self, x: DistributedArray) -> Optional[str]:
+        """``None`` where ``pmt_laplacian`` takes the apply, else the
+        one word ``laplacian.path_select`` gives as ``why``."""
+        from .pallas_kernels import laplacian_legal
+        dims, P_ = self.dims_nd, int(self.mesh.devices.size)
+        if self.kind != "centered":
+            return "kind"
+        if self.edge:
+            return "edge"
+        if not (jnp.issubdtype(x.dtype, jnp.floating)
+                and all(np.isreal(w) for w in self.weights)):
+            return "dtype"
+        if not isinstance(self._axes, str):
+            return "mesh"
+        if len(dims) not in (2, 3) or any(dims[ax] < 3 for ax in self.axes):
+            return "short"
+        inner = int(np.prod(dims[1:]))
+        if (dims[0] % P_ or x.partition != Partition.SCATTER or x.axis != 0
+                or x.ndim != 1
+                or x._axis_sizes != (dims[0] // P_ * inner,) * P_):
+            return "ragged"
+        if not laplacian_legal(self._cube(dims[0] // P_), x.dtype):
+            return "align"
+        return None
+
     def _apply(self, x: DistributedArray, forward: bool) -> DistributedArray:
         if x.partition in (Partition.BROADCAST, Partition.UNSAFE_BROADCAST):
             x = x.to_partition(Partition.SCATTER)
+        why = self._kernel_refusal(x)
+        _trace.event("laplacian.path_select", cat="schedule",
+                     form="slices" if why else "pmt_laplacian",
+                     dims=self.dims_nd, axes=self.axes,
+                     adjoint=int(not forward),
+                     shards=int(self.mesh.devices.size),
+                     **({"why": why} if why else {}))
+        if why is None:
+            return DistributedArray._wrap(
+                self._apply_kernel(x._arr, forward), x)
         g = x.array.ravel()
         if forward:
             arr = sum(w * op._matvec(g) for w, op in zip(self.weights, self._ops))
@@ -470,6 +549,38 @@ class MPILaplacian(_StencilOperator):
                              dtype=arr.dtype)
         y[:] = arr
         return y
+
+    def _apply_kernel(self, arr, forward: bool):
+        """``pmt_laplacian`` on every shard of the flat physical array:
+        a ``shard_map``, so GSPMD is never handed a custom call it
+        cannot partition."""
+        from jax import lax, shard_map
+        from jax.sharding import PartitionSpec as PSpec
+        from ..parallel.collectives import ring_halo_ghosts
+        from .pallas_kernels import laplacian_stencil
+        dims, P_ = self.dims_nd, int(self.mesh.devices.size)
+        rows = dims[0] // P_
+        cube = self._cube(rows)
+        coef = [0.0, 0.0, 0.0]
+        for ax, w, s in zip(self.axes, self.weights, self.sampling):
+            # 2-D dims: axis 1 lies on the cube's lanes, axis 2
+            cube_ax = ax if ax == 0 else ax + 3 - len(dims)
+            coef[cube_ax] += float(np.real(w)) / float(s) ** 2
+        axis_name, slice_map = self._axes, self._slice_map
+
+        def kernel(xb):
+            b = xb.reshape(cube)
+            if coef[0]:
+                gf, gb = ring_halo_ghosts(b, axis_name, P_, 1, 1, rows,
+                                          slice_map=slice_map)
+            else:
+                gf = gb = jnp.zeros((1,) + cube[1:], b.dtype)
+            base = lax.axis_index(axis_name) * rows
+            return laplacian_stencil(b, gf, gb, base, dims[0], coef,
+                                     adjoint=not forward).reshape(-1)
+
+        return shard_map(kernel, mesh=self.mesh, in_specs=PSpec(axis_name),
+                         out_specs=PSpec(axis_name), check_vma=False)(arr)
 
 
 class MPIGradient(MPILinearOperator):

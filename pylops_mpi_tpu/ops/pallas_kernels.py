@@ -1,15 +1,30 @@
-"""Pallas TPU kernels for the stencil hot loops.
+"""The program's Pallas kernels, by the name each carries in a device
+trace. All are compiled by Mosaic on a TPU and interpreted
+(``interpret=True``) on any other backend, which is how the tests run
+them.
 
-The reference's hot stencil path is ghost-cell exchange + NumPy slicing
-per rank (SURVEY §3.3). Here the default path is already a fused XLA
-stencil; this module adds hand-written Pallas kernels for the
-first/second-derivative inner loops so the shift+subtract+scale chain is
-a single VMEM pass instead of several HLO slices — useful when the
-operator is applied standalone (XLA fuses it into neighbours anyway when
-composed).
-
-Kernels run natively on TPU; on CPU they fall back to ``interpret=True``
-(tests) or the plain jnp formulation.
+- ``pmt_taps`` (:func:`stencil_taps`; :func:`first_derivative_centered`
+  and :func:`second_derivative` are conveniences over it) — an
+  arbitrary static tap stencil along axis 0 of a haloed slab as one
+  VMEM pass: the slab is loaded once and every tap is a shifted slice
+  of the loaded block (a slab no lane-wide strip of which fits VMEM
+  takes the same taps as ``jnp`` slices). The axis-0 core of the
+  explicit ghost-cell path of ``ops/derivatives.py::_StencilOperator``
+  on a TPU.
+- ``pmt_normal`` / ``pmt_normal_stream``
+  (:func:`batched_normal_matvec`) — ``(AᴴA X, A X)`` for K columns a
+  block from ONE read of A: the one-sweep CGLS schedule of
+  ``ops/blockdiag.py``.
+- ``pmt_conv1d`` (:func:`conv1d_toeplitz`) — a stationary 1-D
+  convolution along the minor axis as banded Toeplitz tiles on the MXU:
+  ``ops/local.py::Conv1D``'s one form. Live in an apply: the input and
+  the output, 2 volumes.
+- ``pmt_laplacian`` (:func:`laplacian_stencil`) — the centered,
+  ``edge=False`` Laplacian of a 2-D or 3-D array, forward or adjoint,
+  as ONE pass over the cube where it lies: the form
+  ``ops/derivatives.py::MPILaplacian`` takes where its rule allows.
+  Live in an apply: the input and the output, 2 volumes, and two ghost
+  planes.
 """
 
 from __future__ import annotations
@@ -27,6 +42,7 @@ __all__ = ["first_derivative_centered", "second_derivative",
            "stencil_taps", "batched_normal_matvec",
            "normal_matvec_supported", "normal_matvec_pays",
            "conv1d_toeplitz", "conv1d_tile",
+           "laplacian_stencil", "laplacian_legal",
            "pallas_available"]
 
 
@@ -494,3 +510,193 @@ def conv1d_toeplitz(v: jax.Array, T: jax.Array) -> jax.Array:
         interpret=_interpret(),
         name="pmt_conv1d",
     )(T.astype(v.dtype), v)
+
+
+# ------------------------------------------------------ centered Laplacian
+# ``ops/derivatives.py::MPILaplacian`` (``kind="centered"``,
+# ``edge=False``) as one pass over a shard's cube ``(rows, n1, n2)``
+# where it lies: axis 0 major, axis 1 on sublanes, axis 2 on lanes.
+# The grid walks the planes once, in order. A plane arrives from HBM
+# ONCE and is kept in a two-plane ring in VMEM for the two steps that
+# need it again (as the centre, then as the plane above), so step ``i``
+# holds planes ``i-2`` (ring), ``i-1`` (ring) and ``i`` (the block just
+# fetched) and writes output plane ``i-1``: one volume read, one
+# written. The two ghost planes either side of the shard are operands
+# (zeros at the ends of the global array). In-plane neighbours are
+# shifts inside VMEM: along sublanes the ring keeps ``_LAP_HALO`` zero
+# rows above and below each plane, so every strip's window is an
+# aligned load that already holds "zero beyond the ends"; along lanes
+# a roll, whose wrap-around only ever lands on an index the mask
+# zeroes.
+#
+# Measured alone on a TPU v5e at 192 x 1,024 x 1,024 float32 (1.61 GB
+# moved; 1.97 ms at the HBM peak), forward / adjoint, ms an apply
+# (``benchmarks/laplacian_probe.py``; PERF.md section 6, PR 33): this
+# ring form 2.59 / 2.61 with strips of 32 rows (8-row strips 3.01 /
+# 3.29: the loop's 128 short bodies a plane bind; 16 to 128 rows 2.58 -
+# 2.61: the plane's DMA binds); the same arithmetic with the cube
+# passed three times under index maps i-1, i, i+1 (three reads) 4.71 /
+# 4.71 at every strip height; the pad-and-slice form XLA makes of
+# ``ops/local.py::Laplacian`` 18.63 / 18.69.
+
+_LAP_HALO = 8                 # zero rows above and below a ring plane
+_LAP_STRIPS = (32, 16, 8)     # rows a strip: the tallest that divides n1
+_LAP_PLANE_BYTES = 4 << 20    # largest plane the compiled kernel takes
+
+
+def laplacian_legal(shape, dtype) -> bool:
+    """Whether :func:`laplacian_stencil` takes a shard ``(rows, n1,
+    n2)`` of ``dtype``: always where it is interpreted; compiled,
+    Mosaic wants f32 planes of whole ``(8, 128)`` tiles (``n1 % 8 ==
+    0``, ``n2 % 128 == 0``) and the kernel holds ten planes in VMEM (the
+    ring's two, the block in and out and the two ghosts double-
+    buffered), so a plane of at most 4 MiB."""
+    if _interpret():
+        return True
+    _, n1, n2 = shape
+    return (np.dtype(dtype) == np.float32 and n1 % 8 == 0 and n2 % 128 == 0
+            and 4 * n1 * n2 <= _LAP_PLANE_BYTES)
+
+
+def _laplacian_plane(o_ref, window, above, below, g, *, n0: int, n1: int,
+                     coef, adjoint: bool, R: int, keep=None):
+    """One output plane, global index ``g``, strip by strip of ``R``
+    rows. ``window(r)``: rows ``[r - H, r + R + H)`` of the centre
+    plane, zeros beyond its ends (``H = _LAP_HALO``); ``above(r)`` /
+    ``below(r)``: rows ``[r, r + R)`` of the planes either side;
+    ``keep(r, rows)``, if given, is handed each strip of the plane
+    below as it is read. Forward ``sum_a c_a Z_a S_a x`` masks each
+    axis' OUTPUT on that axis' two boundary planes, the adjoint
+    ``sum_a c_a S_a Z_a x`` its INPUT: one body, ``adjoint`` says which
+    side. The axis-0 mask is by GLOBAL index, a scalar a plane."""
+    c0, c1, c2 = coef
+    H, n2, dt = _LAP_HALO, o_ref.shape[2], o_ref.dtype
+
+    def interior(k):
+        return ((k > 0) & (k < n0 - 1)).astype(dt)
+    if adjoint:
+        au, ac, ad = (c0 * interior(g - 1), c0 * interior(g),
+                      c0 * interior(g + 1))
+    else:
+        au = ac = ad = c0 * interior(g)
+    lane = jax.lax.broadcasted_iota(jnp.int32, (R, n2), 1)
+    lanes = (lane > 0) & (lane < n2 - 1)
+
+    def rows_inside(r, nrows):
+        j = r + jax.lax.broadcasted_iota(jnp.int32, (nrows, n2), 0)
+        return (j > 0) & (j < n1 - 1)
+
+    def strip(s, carry):
+        r = pl.multiple_of(s * R, R)
+        w = window(r)
+        cc = w[H:H + R]
+        y = jnp.zeros((R, n2), dt)
+        if c1:
+            v = jnp.where(rows_inside(r - H, R + 2 * H), w, 0) if adjoint \
+                else w
+            t = v[H - 1:H - 1 + R] + v[H + 1:H + 1 + R] - 2 * v[H:H + R]
+            if not adjoint:
+                t = jnp.where(rows_inside(r, R), t, 0)
+            y = y + c1 * t
+        if c2:
+            v = jnp.where(lanes, cc, 0) if adjoint else cc
+            t = pltpu.roll(v, 1, 1) + pltpu.roll(v, n2 - 1, 1) - 2 * v
+            if not adjoint:
+                t = jnp.where(lanes, t, 0)
+            y = y + c2 * t
+        dn = below(r)
+        if c0:
+            y = y + (au * above(r) + ad * dn - 2 * ac * cc)
+        if keep is not None:
+            keep(r, dn)
+        o_ref[0, pl.ds(r, R), :] = y
+        return carry
+    jax.lax.fori_loop(0, n1 // R, strip, None)
+
+
+def _laplacian_kernel(base_ref, x_ref, gf_ref, gb_ref, o_ref, ring, *,
+                      n0: int, coef, adjoint: bool, R: int):
+    """Step ``i`` of ``rows + 1``: ``x_ref`` is plane ``min(i, rows -
+    1)``, ``o_ref`` plane ``max(i - 1, 0)`` (step 0 only fills the
+    ring; its block is written back after step 1, when the index
+    moves). Plane ``p`` lives in ``ring[p % 2]``, the front ghost as
+    plane ``-1``. ``base_ref[0]`` is the global index of the shard's
+    first plane, ``n0`` the global plane count."""
+    i = pl.program_id(0)
+    rows = pl.num_programs(0) - 1
+    n1, n2 = x_ref.shape[1], x_ref.shape[2]
+    H = _LAP_HALO
+
+    @pl.when(i == 0)
+    def _():
+        zeros = jnp.zeros((H, n2), x_ref.dtype)
+        for slot in (0, 1):
+            ring[slot, 0:H, :] = zeros
+            ring[slot, H + n1:H + n1 + H, :] = zeros
+
+        def fill(s, carry):
+            r = pl.multiple_of(s * R, R)
+            ring[1, pl.ds(r + H, R), :] = gf_ref[0, pl.ds(r, R), :]
+            ring[0, pl.ds(r + H, R), :] = x_ref[0, pl.ds(r, R), :]
+            return carry
+        jax.lax.fori_loop(0, n1 // R, fill, None)
+
+    def emit(dn_ref):
+        centre, above = (i - 1) % 2, i % 2
+
+        def keep(r, dn):                     # plane i, for step i + 1
+            ring[above, pl.ds(r + H, R), :] = dn
+        _laplacian_plane(
+            o_ref, lambda r: ring[centre, pl.ds(r, R + 2 * H), :],
+            lambda r: ring[above, pl.ds(r + H, R), :],
+            lambda r: dn_ref[0, pl.ds(r, R), :], base_ref[0] + i - 1,
+            n0=n0, n1=n1, coef=coef, adjoint=adjoint, R=R, keep=keep)
+
+    @pl.when((i > 0) & (i < rows))
+    def _():
+        emit(x_ref)
+
+    @pl.when(i == rows)
+    def _():
+        emit(gb_ref)
+
+
+def laplacian_stencil(x: jax.Array, ghost_front: jax.Array,
+                      ghost_back: jax.Array, base, n0: int, coef,
+                      adjoint: bool) -> jax.Array:
+    """Centered, ``edge=False`` Laplacian of one shard ``x (rows, n1,
+    n2)`` of a cube of ``n0`` planes split along axis 0, forward or
+    adjoint, as ONE pass: kernel ``pmt_laplacian`` (compiled on a TPU,
+    interpreted elsewhere). ``ghost_front`` / ``ghost_back (1, n1,
+    n2)``: the neighbours' planes either side, zeros at the ends of the
+    global array; ``base``: the global index of ``x``'s first plane (an
+    int32 scalar, traced or not); ``coef``: three static floats
+    ``weights[a] / sampling[a]**2``, 0.0 for an axis left out. Gate on
+    :func:`laplacian_legal`. Live in an apply: the input and the
+    output, **2 volumes**, and the two ghost planes."""
+    rows, n1, n2 = x.shape
+    coef = tuple(float(c) for c in coef)
+    R = next((r for r in _LAP_STRIPS if n1 % r == 0), n1)
+    plane = (1, n1, n2)
+
+    def ghost():
+        return pl.BlockSpec(plane, lambda i, b: (0, 0, 0))
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(rows + 1,),
+        in_specs=[pl.BlockSpec(
+            plane, lambda i, b: (jnp.minimum(i, rows - 1), 0, 0)),
+            ghost(), ghost()],
+        out_specs=pl.BlockSpec(
+            plane, lambda i, b: (jnp.maximum(i - 1, 0), 0, 0)),
+        scratch_shapes=[pltpu.VMEM((2, n1 + 2 * _LAP_HALO, n2), x.dtype)])
+    return pl.pallas_call(
+        partial(_laplacian_kernel, n0=int(n0), coef=coef,
+                adjoint=bool(adjoint), R=R),
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct(x.shape, x.dtype),
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES,
+            dimension_semantics=("arbitrary",)),
+        interpret=_interpret(),
+        name="pmt_laplacian",
+    )(jnp.asarray(base, jnp.int32).reshape(1), x, ghost_front, ghost_back)

@@ -993,15 +993,32 @@ def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
     """Compile-cache-and-run the fused CGLS loop; see
     :func:`_run_cg_fused` for the guard/status contract (including the
     ``M=None`` cache-key neutrality). Returns
-    ``(x, iiter, cost, cost1, kold, status_code_or_None)``. Non-``off``
+    ``(x, iiter, cost, cost1, kold, status_code_or_None, kmax)``,
+    ``kmax`` being ``max(kold)`` on the host. Non-``off``
     ``PYLOPS_MPI_TPU_CA`` modes dispatch to solvers/ca.py (whose CGLS
-    cost lanes carry normal-residual norms — docs/ca.md)."""
+    cost lanes carry normal-residual norms — docs/ca.md).
+
+    A solve turns to the host as seldom as it can. The small results
+    (``iiter``, the two cost histories, ``kold``,
+    the status word) come to the host in ONE round (``jax.device_get``
+    starts every copy before it waits for the first), and ``damp`` and
+    ``tol`` are device scalars made once a value
+    (:func:`_scalar_operand`). Before, a solve was two scalar
+    transfers, the program, ``int(iiter)``, two ``np.asarray`` and
+    ``float(jnp.max(kold))`` (a second program): every one a hand-over
+    between threads whose cost is the host's to decide. On a one-chip
+    machine, whose cores are shared, whole processes ran each such
+    step 2-3 x slower than others (``flagship_n4096.solve_k1``: 371.0
+    and 377.6 ms a solve on the same code, the device's 363.3 ms the
+    same in both; with this and the one-dispatch zeros of a fresh
+    ``DistributedArray``, 368.0 and 371.6; PERF.md section 6, PR 33)."""
     from . import ca as _ca
     _ca_mode = _ca.resolve_mode(Op, "cgls")
     if _ca_mode != "off":
-        return _ca.run_cgls_fused(Op, y, x0, x0_owned, niter, damp,
-                                  tol, use_normal, guards, M=M,
-                                  mode=_ca_mode)
+        out = _ca.run_cgls_fused(Op, y, x0, x0_owned, niter, damp,
+                                 tol, use_normal, guards, M=M,
+                                 mode=_ca_mode)
+        return out + (float(jnp.max(out[4])),)
     builder = _cgls_fused_normal if use_normal else _cgls_fused
     # A caller's ``x0`` is NOT donated here: the program copies it into
     # the carry at entry — the same bytes as the eager ``_donate_copy``
@@ -1016,35 +1033,65 @@ def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
     # key (``_get_fused``), so the two entries never mix. To go back to
     # ``_donate_copy`` with the check's offset (PERF.md section 7).
     donate = _DONATE_X0 if x0_owned else ()
+    key = (id(Op), "cgls", use_normal, niter, _vkey(y), _vkey(x0))
+    args = (y, x0, _scalar_operand(damp, y), _scalar_operand(tol, y))
     if guards:
         from ..resilience import faults as _faults, status as _rstatus
         spec = _faults.consume()
         stall_n = _rstatus.stall_window()
-        fn = _get_fused(Op, (id(Op), "cgls", use_normal, niter,
-                             _vkey(y), _vkey(x0),
-                             _rstatus.guards_signature(True),
-                             _faults.fault_signature(spec)) + _mkey(M),
+        fn = _get_fused(Op, key + (_rstatus.guards_signature(True),
+                                   _faults.fault_signature(spec))
+                        + _mkey(M),
                         lambda op: partial(builder, op, niter=niter,
                                            guards=True, M=M,
                                            stall_n=stall_n, fault=spec),
                         donate_argnums=donate, keepalive=M,
                         aot_eligible=(M is None and spec is None))
-        x, iiter, cost, cost1, kold, status = fn(y, x0, damp, tol)
+        x, iiter, cost, cost1, kold, status = fn(*args)
+        iiter, cost, cost1, kmax, status = jax.device_get(
+            (iiter, cost, cost1, kold, status))
         iiter, code = int(iiter), int(status)
         _rstatus.record("cgls", code, iiter)
         _count_cgls_solve(iiter, use_normal)
-        return (x, iiter, np.asarray(cost)[:iiter + 1],
-                np.asarray(cost1)[:iiter + 1], kold, code)
-    fn = _get_fused(Op, (id(Op), "cgls", use_normal, niter,
-                         _vkey(y), _vkey(x0)) + _mkey(M),
+        return (x, iiter, cost[:iiter + 1], cost1[:iiter + 1], kold, code,
+                float(np.max(kmax)))
+    fn = _get_fused(Op, key + _mkey(M),
                     lambda op: partial(builder, op, niter=niter, M=M),
                     donate_argnums=donate, keepalive=M,
                     aot_eligible=(M is None))
-    x, iiter, cost, cost1, kold = fn(y, x0, damp, tol)
+    x, iiter, cost, cost1, kold = fn(*args)
+    iiter, cost, cost1, kmax = jax.device_get((iiter, cost, cost1, kold))
     iiter = int(iiter)
     _count_cgls_solve(iiter, use_normal)
-    return (x, iiter, np.asarray(cost)[:iiter + 1],
-            np.asarray(cost1)[:iiter + 1], kold, None)
+    return (x, iiter, cost[:iiter + 1], cost1[:iiter + 1], kold, None,
+            float(np.max(kmax)))
+
+
+_SCALAR_OPERANDS: "OrderedDict" = OrderedDict()
+
+
+def _scalar_operand(v, like: Optional[Vector] = None):
+    """A Python ``float``/``int`` operand of a fused solve as a device
+    scalar made once a value (weakly typed, as ``jax.jit`` would make
+    it: the same program): a solve then starts no host-to-device
+    transfer of its own for ``damp`` and ``tol``. Anything else — an
+    array, a NumPy scalar, a tracer — passes as it is; so does every
+    operand of a solve whose vectors (``like``) lie on more than one
+    device or process, where a scalar made here would lie on one and
+    be copied to the others every call."""
+    if type(v) not in (float, int) or jax.process_count() > 1:
+        return v
+    if like is not None:
+        first = like.distarrays[0] if isinstance(
+            like, StackedDistributedArray) else like
+        if int(first.mesh.devices.size) != 1:
+            return v
+    k = (type(v), v, jax.config.jax_enable_x64)
+    if k not in _SCALAR_OPERANDS:
+        _SCALAR_OPERANDS[k] = jnp.asarray(v)
+        if len(_SCALAR_OPERANDS) > 64:
+            _SCALAR_OPERANDS.popitem(last=False)
+    return _SCALAR_OPERANDS[k]
 
 
 def cgls(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
@@ -1123,10 +1170,10 @@ def cgls(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cgls"):
         if use_fused:
-            x, iiter, cost, cost1, kold, _ = _run_cgls_fused(
+            x, iiter, cost, cost1, kold, _, kmax = _run_cgls_fused(
                 Op, y, x0, x0_owned, niter, damp, tol, use_normal,
                 use_guards, M=M)
-            istop = 1 if float(jnp.max(kold)) < tol else 2
+            istop = 1 if kmax < tol else 2
             return x, istop, iiter, kold, cost1[-1], cost
         solver = CGLS(Op)
         solver._callback_wrap(callback)
@@ -1152,7 +1199,7 @@ def cgls_guarded(Op, y: Vector, x0: Optional[Vector] = None,
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cgls"):
         return _run_cgls_fused(Op, y, x0, x0_owned, niter, damp, tol,
-                               use_normal, True, M=M)
+                               use_normal, True, M=M)[:6]
 
 
 def _vkey(v: Vector):

@@ -566,7 +566,7 @@ def block_cgls(Op, y: DistributedArray,
         if K == 1:
             from ..resilience import status as _rstatus
             from .basic import _run_cgls_fused
-            x1, iiter, cost, cost1, kold, code = _run_cgls_fused(
+            x1, iiter, cost, cost1, kold, code, _ = _run_cgls_fused(
                 Op, _squeeze_col(y), _squeeze_col(x0), True, niter,
                 damp, tol, use_normal, use_guards, M=M)
             if use_guards:

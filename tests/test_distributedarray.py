@@ -521,3 +521,92 @@ def test_masked_redistribute_keeps_mask(rng):
     dy = dx.redistribute(1)
     assert dy.mask == tuple(mask)
     np.testing.assert_allclose(dy.asarray(), x, rtol=1e-14)
+
+
+# ----------------------------------------------- zeros made at their first read
+@pytest.mark.parametrize("global_shape,partition", [
+    ((24,), Partition.SCATTER), ((21,), Partition.SCATTER),
+    ((6, 8), Partition.SCATTER), ((10,), Partition.BROADCAST)])
+@pytest.mark.parametrize("dtype", [np.float32, np.complex64])
+def test_fresh_array_allocates_at_first_read(global_shape, partition, dtype):
+    """A fresh ``DistributedArray`` knows its shape and dtype but holds
+    no buffer until something reads it; then it is zeros, placed as
+    the partition says, and stays that array."""
+    arr = DistributedArray(global_shape=global_shape, partition=partition,
+                           dtype=dtype)
+    assert arr._buf is None and arr.dtype == np.dtype(dtype)
+    assert arr.global_shape == global_shape
+    np.testing.assert_array_equal(arr.asarray(), np.zeros(global_shape, dtype))
+    first = arr._buf
+    assert first is not None and arr._arr is first
+    assert first.shape == arr._phys_shape()
+    assert first.sharding.is_equivalent_to(arr._sharding(), first.ndim)
+
+
+@pytest.mark.parametrize("global_shape,partition", [
+    ((24,), Partition.SCATTER), ((21,), Partition.SCATTER),
+    ((6, 8), Partition.SCATTER), ((10,), Partition.BROADCAST)])
+def test_fresh_zeros_are_one_dispatch(monkeypatch, global_shape, partition):
+    """The first read makes the zeros where they are to lie, in one
+    call: no zeros on the default device and a placement after it."""
+    import jax.numpy as jnp
+    from jax import lax
+    arr = DistributedArray(global_shape=global_shape, partition=partition,
+                           dtype=np.float32)
+    made, placed = [], []
+    real = jnp.zeros
+    monkeypatch.setattr(jnp, "zeros", lambda *k, **kw: (
+        made.append(kw.get("device")), real(*k, **kw))[1])
+    monkeypatch.setattr(lax, "with_sharding_constraint", lambda *k, **kw: (
+        placed.append(k), k[0])[1])
+    monkeypatch.setattr(jax, "device_put", lambda *k, **kw: (
+        placed.append(k), k[0])[1])
+    first = arr._arr
+    assert made == [arr._sharding()] and not placed
+    assert first.sharding.is_equivalent_to(arr._sharding(), first.ndim)
+
+
+@pytest.mark.parametrize("n", [24, 21])
+def test_constructor_then_setitem_never_makes_the_zeros(monkeypatch, n):
+    """``x = DistributedArray(...); x[:] = a`` — the idiom of the
+    reference and of every operator — reads no zeros: none are made."""
+    import jax.numpy as jnp
+    arr = DistributedArray(global_shape=n, dtype=np.float32)
+    a = jnp.arange(n, dtype=jnp.float32) + 1
+    made = []
+    real = jnp.zeros
+    monkeypatch.setattr(jnp, "zeros", lambda *k, **kw: (
+        made.append(k), real(*k, **kw))[1])
+    arr[:] = a
+    assert not [k for k in made if k and np.prod(k[0]) >= n]
+    np.testing.assert_array_equal(arr.asarray(), np.asarray(a))
+
+
+def test_setitem_takes_a_placed_device_array_uncopied():
+    """On one device the array handed to ``x[:] = a`` IS the storage
+    (arrays are immutable, and the solvers donate only vectors they
+    made themselves); ``a`` stays valid."""
+    import jax.numpy as jnp
+    mesh = plt_.make_mesh(1)
+    arr = DistributedArray(global_shape=64, mesh=mesh, dtype=np.float32)
+    a = jnp.arange(64, dtype=jnp.float32)
+    arr[:] = a
+    assert arr._arr.unsafe_buffer_pointer() == a.unsafe_buffer_pointer()
+    arr += 1                                  # a new array, not a write
+    np.testing.assert_array_equal(np.asarray(a), np.arange(64))
+    np.testing.assert_array_equal(arr.asarray(), np.arange(64) + 1)
+
+
+@pytest.mark.parametrize("how", ["closure", "argument"])
+def test_fresh_array_under_jit_leaks_no_tracer(how):
+    """Zeros first read inside a trace are that trace's value and are
+    not kept: the array can be read again outside it."""
+    arr = DistributedArray(global_shape=16, dtype=np.float32)
+    one = DistributedArray.to_dist(np.ones(16, np.float32))
+    if how == "closure":
+        got = jax.jit(lambda v: v + arr)(one)
+        assert arr._buf is None
+    else:
+        got = jax.jit(lambda v, z: v + z)(one, arr)
+    np.testing.assert_array_equal(got.asarray(), np.ones(16))
+    np.testing.assert_array_equal(arr.asarray(), np.zeros(16))

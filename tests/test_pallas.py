@@ -426,3 +426,183 @@ def test_stencil_col_tile_budgeting():
     assert _stencil_col_tile(nrows, 1024, 4) == 128
     assert _stencil_col_tile(nrows, 1000, 4) == 128  # non-divisor OK
     assert _stencil_col_tile(10 * _STENCIL_TILE_BYTES, 1024, 4) == 0
+
+
+# ------------------------------------------- pmt_laplacian (MPILaplacian)
+def _laplacian_pair(dims, axes, dtype, shards, **kw):
+    """``MPILaplacian`` on ``shards`` devices, jitted (an eager apply
+    re-traces the interpreted kernel every call), and the slice form it
+    must equal — the weighted sum of ``ops/local.py::SecondDerivative``
+    — with unequal weights and samplings."""
+    import jax
+    import pylops_mpi_tpu as pmt
+    from pylops_mpi_tpu.ops.local import SecondDerivative
+    w = tuple(1.0 + 0.5 * i for i in range(len(axes)))
+    s = tuple(1.0 + 0.25 * i for i in range(len(axes)))
+    mesh = pmt.make_mesh(shards)
+    Lop = pmt.MPILaplacian(dims, axes=axes, weights=w, sampling=s, mesh=mesh,
+                           dtype=dtype, **kw)
+    ops = [SecondDerivative(dims, axis=ax, sampling=si, dtype=dtype, **kw)
+           for ax, si in zip(axes, s)]
+
+    def slices(x, adjoint=False):
+        x = jnp.asarray(x)
+        return np.asarray(sum(
+            wi * (op._rmatvec(x) if adjoint else op._matvec(x))
+            for wi, op in zip(w, ops)))
+    return (Lop, jax.jit(lambda v: Lop.matvec(v)),
+            jax.jit(lambda v: Lop.rmatvec(v)), slices, mesh)
+
+
+def _path_events(fn):
+    """What ``laplacian.path_select`` recorded while ``fn`` ran."""
+    from pylops_mpi_tpu.diagnostics import trace
+    trace.clear_events()
+    out = fn()
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "laplacian.path_select"]
+    trace.clear_events()
+    return out, ev
+
+
+LAP_CASES = [((8, 6, 10), (0,)), ((8, 6, 10), (1, 2)),
+             ((8, 6, 10), (0, 1, 2)), ((16, 12), (0,)), ((16, 12), (1,)),
+             ((16, 12), (0, 1))]
+
+
+@pytest.mark.parametrize("shards", [1, 2, 8])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64],
+                         ids=["f32", "f64"])
+@pytest.mark.parametrize("dims,axes", LAP_CASES)
+def test_pmt_laplacian_equals_the_slice_form(rng, monkeypatch, ndev, dims,
+                                             axes, dtype, shards):
+    """Forward and adjoint through the kernel (interpreted here) equal
+    the sum of ``SecondDerivative`` slices to rounding: 2-D and 3-D,
+    any subset of axes, unequal weights and samplings, one plane a
+    shard included (8 planes over 8 devices); and the rule says, once a
+    traced apply, that it took the kernel."""
+    from pylops_mpi_tpu import DistributedArray
+    if shards > ndev:
+        pytest.skip(f"needs {shards} devices")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    Lop, mv, rmv, slices, mesh = _laplacian_pair(dims, axes, dtype, shards)
+    x = rng.standard_normal(int(np.prod(dims))).astype(dtype)
+    xd = DistributedArray.to_dist(x, mesh=mesh)
+    (y, ya), ev = _path_events(
+        lambda: (mv(xd).asarray(), rmv(xd).asarray()))
+    assert [(e["form"], e["adjoint"]) for e in ev] == [
+        ("pmt_laplacian", 0), ("pmt_laplacian", 1)]
+    assert all(e["shards"] == shards and "why" not in e
+               and tuple(e["dims"]) == dims and tuple(e["axes"]) == axes
+               for e in ev)
+    tol = 2e-6 if dtype == np.float32 else 1e-13
+    want, wanta = slices(x), slices(x, adjoint=True)
+    assert y.dtype == ya.dtype == dtype
+    np.testing.assert_allclose(y, want, rtol=0, atol=tol * np.abs(want).max())
+    np.testing.assert_allclose(ya, wanta, rtol=0,
+                               atol=tol * np.abs(wanta).max())
+
+
+@pytest.mark.parametrize("shards", [1, 2, 8])
+@pytest.mark.parametrize("dims,axes", [((8, 6, 10), (0, 1, 2)),
+                                       ((16, 12), (0, 1)),
+                                       ((8, 6, 10), (1, 2))])
+def test_pmt_laplacian_dot_test(rng, ndev, dims, axes, shards):
+    """``<L x, y> = <x, L^H y>`` through the kernel's two sides."""
+    from pylops_mpi_tpu import DistributedArray
+    if shards > ndev:
+        pytest.skip(f"needs {shards} devices")
+    Lop, mv, rmv, _, mesh = _laplacian_pair(dims, axes, np.float64, shards)
+    n = int(np.prod(dims))
+    u, v = rng.standard_normal(n), rng.standard_normal(n)
+    ud = DistributedArray.to_dist(u, mesh=mesh)
+    vd = DistributedArray.to_dist(v, mesh=mesh)
+    assert Lop._kernel_refusal(ud) is None
+    lhs, rhs = np.vdot(mv(ud).asarray(), v), np.vdot(u, rmv(vd).asarray())
+    assert abs(lhs - rhs) <= 1e-12 * max(abs(lhs), abs(rhs))
+
+
+@pytest.mark.parametrize("shards", [1, 2])
+@pytest.mark.parametrize("dims,axes", [((4, 3, 5), (0, 1, 2)),
+                                       ((6, 4), (0, 1))])
+def test_pmt_laplacian_dense_on_a_tiny_cube(ndev, dims, axes, shards):
+    """Column by column against the stencil's matrix written out in
+    NumPy (Kronecker products of the 1-D second difference with its two
+    boundary rows zero): every boundary plane of every axis, where a
+    wrong mask hides; the adjoint is its transpose."""
+    from pylops_mpi_tpu import DistributedArray
+    if shards > ndev:
+        pytest.skip(f"needs {shards} devices")
+    Lop, mv, rmv, _, mesh = _laplacian_pair(dims, axes, np.float64, shards)
+
+    def second(n, s):
+        D = np.zeros((n, n))
+        for i in range(1, n - 1):
+            D[i, i - 1:i + 2] = np.array([1.0, -2.0, 1.0]) / s ** 2
+        return D
+    want = 0
+    for ax, w, s in zip(Lop.axes, Lop.weights, Lop.sampling):
+        term = np.ones((1, 1))
+        for a, n in enumerate(dims):
+            term = np.kron(term, second(n, s) if a == ax else np.eye(n))
+        want = want + w * term
+    eye = np.eye(int(np.prod(dims)))
+    got = np.stack([mv(DistributedArray.to_dist(e, mesh=mesh)).asarray()
+                    for e in eye], axis=1)
+    gota = np.stack([rmv(DistributedArray.to_dist(e, mesh=mesh)).asarray()
+                     for e in eye], axis=1)
+    np.testing.assert_allclose(got, want, rtol=0, atol=1e-13)
+    np.testing.assert_allclose(gota, want.T, rtol=0, atol=1e-13)
+
+
+@pytest.mark.parametrize("why,dims,kw,dtype", [
+    ("kind", (16, 12), dict(kind="forward"), np.float64),
+    ("kind", (16, 12), dict(kind="backward"), np.float64),
+    ("edge", (16, 12), dict(edge=True), np.float64),
+    ("dtype", (16, 12), {}, np.complex128),
+    ("ragged", (13, 12), {}, np.float64),
+    ("short", (16, 2), {}, np.float64),
+    ("short", (16,), {}, np.float64)])
+def test_laplacian_fallbacks_keep_the_slice_form(rng, monkeypatch, ndev, why,
+                                                 dims, kw, dtype):
+    """What the kernel does not take gives the answers it gave, and
+    ``laplacian.path_select`` says ``slices`` with the one-word
+    reason."""
+    from pylops_mpi_tpu import DistributedArray
+    if why == "ragged" and ndev < 2:
+        pytest.skip("one device has no ragged split")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    axes = (0, 1) if len(dims) > 1 else (0,)
+    Lop, mv, rmv, slices, mesh = _laplacian_pair(dims, axes, dtype,
+                                                 min(2, ndev), **kw)
+    n = int(np.prod(dims))
+    x = rng.standard_normal(n).astype(dtype)
+    if dtype == np.complex128:
+        x = x + 1j * rng.standard_normal(n)
+    xd = DistributedArray.to_dist(x, mesh=mesh)
+    (y, ya), ev = _path_events(
+        lambda: (mv(xd).asarray(), rmv(xd).asarray()))
+    assert [(e["form"], e["why"], e["adjoint"]) for e in ev] == [
+        ("slices", why, 0), ("slices", why, 1)]
+    np.testing.assert_allclose(y, slices(x), rtol=1e-12, atol=1e-12)
+    np.testing.assert_allclose(ya, slices(x, adjoint=True), rtol=1e-12,
+                               atol=1e-12)
+
+
+@pytest.mark.parametrize("why,dims", [("align", (8, 12, 128)),
+                                      ("align", (8, 16, 100)),
+                                      (None, (8, 16, 128))])
+def test_laplacian_rule_where_the_kernel_is_compiled(monkeypatch, why, dims):
+    """As on a TPU: Mosaic takes f32 planes of whole (8, 128) tiles of
+    at most 4 MiB; anything else keeps the slices (``align``)."""
+    from pylops_mpi_tpu import DistributedArray
+    import pylops_mpi_tpu as pmt
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    mesh = pmt.make_mesh(1)
+    Lop = pmt.MPILaplacian(dims, axes=(0, 1, 2), weights=(1, 1, 1),
+                           sampling=(1, 1, 1), mesh=mesh, dtype=np.float32)
+    for dtype, said in ((np.float32, why), (np.float64, "align")):
+        x = DistributedArray(global_shape=int(np.prod(dims)), mesh=mesh,
+                             dtype=dtype)
+        assert Lop._kernel_refusal(x) == said
+    assert not pk.laplacian_legal((2, 2048, 1024), np.float32)   # 8 MiB

@@ -6,6 +6,8 @@ Small on the CPU; the one compile for a described v5e (the kernel at
 the benchmark's width) lives in a fixture, per the on-chip-measurement
 guide."""
 
+import re
+
 import numpy as np
 import pytest
 import jax
@@ -159,17 +161,42 @@ def test_conv1d_path_select_event(monkeypatch, n, pad):
     assert ev[0]["args"]["pad"] == pad
 
 
+def test_laplacian_path_select_event_once_a_traced_apply(monkeypatch):
+    """The stacked system's regulariser says which form it took: one
+    ``laplacian.path_select`` a traced apply, ``pmt_laplacian``."""
+    from pylops_mpi_tpu.diagnostics import trace
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    _, _, _, x0 = _small_system()
+    Lap = poststack_regularized(WAV.astype(np.float32), 256, (4, 4), 100.0,
+                                mesh=pmt.make_mesh(1), dtype=np.float32)[2]
+    trace.clear_events()
+    f = jax.jit(lambda v: Lap.rmatvec(Lap.matvec(v)))
+    f(x0), f(x0)                      # the second call traces nothing
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "laplacian.path_select"]
+    trace.clear_events()
+    assert [(e["form"], e["adjoint"]) for e in ev] == [
+        ("pmt_laplacian", 0), ("pmt_laplacian", 1)]
+    assert all(tuple(e["dims"]) == (4, 4, 256) and e["shards"] == 1
+               and tuple(e["axes"]) == (0, 1, 2) for e in ev)
+
+
 # -------------------------------- the kernel, compiled for a described v5e
 @pytest.fixture(scope="module")
-def one_chip():
+def v5e_devices():
     from jax.experimental import topologies
-    from jax.sharding import SingleDeviceSharding
     try:
         topo = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:                                  # noqa: BLE001
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    return SingleDeviceSharding(topo.devices[0])
+    return topo.devices
+
+
+@pytest.fixture(scope="module")
+def one_chip(v5e_devices):
+    from jax.sharding import SingleDeviceSharding
+    return SingleDeviceSharding(v5e_devices[0])
 
 
 V5E_CASES = {
@@ -215,6 +242,52 @@ def test_pmt_conv1d_compiles_for_v5e(one_chip, monkeypatch, case):
         # the call (and the padded copies, where the axis is ragged)
         ma = c.memory_analysis()
         assert ma.temp_size_in_bytes <= allowed * vol, (case, ma)
+
+
+@pytest.mark.parametrize("adjoint", [False, True],
+                         ids=["forward", "adjoint"])
+@pytest.mark.parametrize("chips", [1, 4])
+def test_pmt_laplacian_compiles_for_v5e(v5e_devices, monkeypatch, chips,
+                                        adjoint):
+    """At the benchmark's shape a chip (192 planes of 1,024 x 1,024
+    float32), on one chip and on the four of the survey: Mosaic accepts
+    the kernel inside ``MPILaplacian``'s apply, the rule takes it, every
+    device's program holds ONE call, and beside it only the relayouts
+    between the flat vector and the cube are left — no volume-sized
+    pad, no gather; across chips the ghost planes travel as
+    collective-permutes."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    dims = (192 * chips, 1024, 1024)
+    V = int(np.prod(dims))
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            mesh = Mesh(np.array(v5e_devices[:chips]), ("sp",))
+            Lap = pmt.MPILaplacian(dims, axes=(0, 1, 2), weights=(1, 1, 1),
+                                   sampling=(1, 1, 1), mesh=mesh,
+                                   dtype=np.float32)
+            # a described device holds no array: the vector is abstract
+            x = DistributedArray.tree_unflatten(
+                (mesh, pmt.Partition.SCATTER, 0, (V,), pmt.local_split(
+                    (V,), chips, pmt.Partition.SCATTER, 0), None),
+                [jax.ShapeDtypeStruct((V,), jnp.float32,
+                                      sharding=NamedSharding(mesh, P("sp")))])
+            assert Lap._kernel_refusal(x) is None
+            f = Lap.rmatvec if adjoint else Lap.matvec
+            c = jax.jit(lambda v: f(v)).lower(x).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+    text = c.as_text()
+    assert len(re.findall(r"custom-call\(", text)) == 1
+    assert "pmt_laplacian" in text and "all-gather" not in text
+    if chips > 1:
+        assert "collective-permute-start" in text
+    assert not re.findall(r"f32\[192,1024,\d+\]\S* pad\(", text)
+    assert c.memory_analysis().temp_size_in_bytes <= 2.05 * 4 * V / chips
 
 
 # ------------------------------------------- the 3-D operators, 1/2/4 devices
@@ -335,6 +408,14 @@ def test_scopes_in_the_fused_solver(fused_hlo, scope):
     assert names, scope
     if scope.startswith("pmt.local."):    # inside the operator's scope
         assert all("pmt.MPIBlockDiag." in ln for ln in names)
+    if scope.startswith("pmt.MPILaplacian."):
+        # ONE call of the kernel under the scope (interpreted here: the
+        # grid's loop is its one ``while``), and no pad-and-slice left
+        grid_loops = re.findall(
+            r' while\([^\n]*op_name="[^"]*/while/body/[^"]*%s/pmt_laplacian/'
+            r'while"' % re.escape(scope), fused_hlo)
+        assert len(grid_loops) == 1, scope
+        assert not [ln for ln in names if re.search(r" pad\(", ln)]
 
 
 @pytest.mark.parametrize("which", ["flagship", "modelling", "stack"])

@@ -489,3 +489,97 @@ def test_cg_masked_groups_tail_stable(rng):
     c = np.asarray(cost)  # (niter+1, ngroups): no blow-up tail anywhere
     assert np.isfinite(c).all()
     assert (c[-1] < 10 * c.min(axis=0) + 1e-10).all()
+
+
+# ------------------------------------- one round between host and device a solve
+def _round_problem(rng, dtype=np.float32):
+    mats = []
+    for _ in range(8):
+        a = rng.standard_normal((6, 6))
+        mats.append((a @ a.T + 6 * np.eye(6)).astype(dtype))
+    Op = MPIBlockDiag([MatrixMult(m, dtype=dtype) for m in mats])
+    y = DistributedArray.to_dist(rng.standard_normal(48).astype(dtype))
+    return Op, y
+
+
+@pytest.mark.parametrize("guards", [False, True])
+@pytest.mark.parametrize("normal", [False, True])
+def test_cgls_reads_its_small_results_in_one_round(rng, monkeypatch, guards,
+                                                   normal):
+    """A fused ``cgls`` brings ``iiter``, both cost histories, ``kold``
+    (and the status word) to the host with ONE ``jax.device_get`` and
+    dispatches no second program for ``max(kold)``; what it returns is
+    what the reads one by one returned."""
+    import jax.numpy as jnp
+    from pylops_mpi_tpu.solvers import basic as B
+    Op, y = _round_problem(rng)
+    kw = dict(niter=6, damp=0.1, tol=0.0, guards=guards, normal=normal)
+    x, istop, iiter, kold, r2, cost = cgls(Op, y, **kw)      # warm
+    gets, maxes = [], []
+    real_get, real_max = jax.device_get, jnp.max
+    monkeypatch.setattr(jax, "device_get",
+                        lambda t: (gets.append(t), real_get(t))[1])
+    monkeypatch.setattr(jnp, "max",
+                        lambda *a, **k: (maxes.append(a), real_max(*a, **k))[1])
+    x2, istop2, iiter2, kold2, r22, cost2 = cgls(Op, y, **kw)
+    assert len(gets) == 1 and len(gets[0]) == (5 if guards else 4)
+    assert not maxes
+    assert (istop2, iiter2) == (istop, iiter) == (2, 6)
+    assert isinstance(cost2, np.ndarray) and cost2.shape == (7,)
+    np.testing.assert_array_equal(cost2, cost)
+    assert r22 == r2 and float(kold2) == float(kold)
+    np.testing.assert_array_equal(x2.asarray(), x.asarray())
+    from pylops_mpi_tpu.solvers.basic import cgls_guarded
+    six = cgls_guarded(Op, y, niter=6, damp=0.1, tol=0.0, normal=normal)
+    assert len(six) == 6 and six[1] == 6       # its public shape stands
+
+
+@pytest.mark.parametrize("value", [0.0, 0.25, 1e-4, 0, 3])
+def test_scalar_operand_is_made_once_a_value(value):
+    """``damp`` and ``tol`` go to a fused solve as device scalars made
+    once a value, typed as ``jax.jit`` types the Python number (weakly,
+    float or int): the same program, no transfer a solve."""
+    from pylops_mpi_tpu.solvers import basic as B
+    a = B._scalar_operand(value)
+    assert a is B._scalar_operand(value)
+    assert isinstance(a, jax.Array) and a.shape == () and a.weak_type
+    kind = np.floating if isinstance(value, float) else np.integer
+    assert np.issubdtype(a.dtype, kind) and a == value
+    assert B._scalar_operand(1.0) is not B._scalar_operand(1)
+
+
+@pytest.mark.parametrize("other", [np.float32(0.5), np.asarray(0.5), "array"])
+def test_scalar_operand_passes_everything_else(other):
+    import jax.numpy as jnp
+    from pylops_mpi_tpu.solvers import basic as B
+    if isinstance(other, str):
+        other = jnp.float32(0.5)
+    assert B._scalar_operand(other) is other
+
+
+@pytest.mark.parametrize("ndev", [1, 8])
+def test_scalar_operands_stay_bounded_and_compile_nothing_new(rng, ndev):
+    """Many values do not grow the table without bound, and a value
+    seen again reaches no compiler: the program is keyed by the
+    operand's type, not its value. Vectors on several devices take
+    the Python numbers as they are (a scalar made on one device would
+    be copied to the others every call)."""
+    import pylops_mpi_tpu as pmt
+    from pylops_mpi_tpu.solvers import basic as B
+    for i in range(200):
+        B._scalar_operand(1.0 + i)
+    assert len(B._SCALAR_OPERANDS) <= 64
+    B.clear_fused_cache()
+    mesh = pmt.make_mesh(ndev)
+    mats = [(2 + i) * np.eye(6, dtype=np.float32) for i in range(8)]
+    Op = MPIBlockDiag([MatrixMult(m, dtype=np.float32) for m in mats],
+                      mesh=mesh)
+    y = DistributedArray.to_dist(
+        rng.standard_normal(48).astype(np.float32), mesh=mesh)
+    made = B._scalar_operand(0.3, y)
+    assert isinstance(made, jax.Array) if ndev == 1 else made == 0.3
+    for damp in (0.1, 0.2, 0.1):
+        cgls(Op, y, niter=3, damp=damp, tol=0.0)
+    assert len(B._FUSED_CACHE) == 1
+    (fn, _, _), = B._FUSED_CACHE.values()
+    assert fn.__kwdefaults__["_jfn"]._cache_size() == 1
