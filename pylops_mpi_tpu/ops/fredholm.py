@@ -34,6 +34,17 @@ from ..parallel.mesh import axis_sharding
 
 __all__ = ["MPIFredholm1"]
 
+# forward / adjoint contraction of a real kernel, and of the plane pair
+# (batched over the pair): the adjoint contracts the OTHER axis of the
+# stored kernel, so nothing of its size is transposed, conjugated or kept
+_SPECS = {False: ("kxy,kyz->kxz", "pkxy,pkyz->pkxz"),
+          True: ("kxy,kxz->kyz", "pkxy,pkxz->pkyz")}
+
+
+def _real_dtype(dtype) -> np.dtype:
+    """The real counterpart of a (complex) dtype."""
+    return np.real(np.ones(1, dtype=np.dtype(dtype))).dtype
+
 
 class MPIFredholm1(MPILinearOperator):
     """Distributed Fredholm1 (ref ``Fredholm1.py:14-169``).
@@ -45,55 +56,88 @@ class MPIFredholm1(MPILinearOperator):
     reference (identical results, ref ``Fredholm1.py:120-131``); here
     the batched einsum on the MXU is always the right schedule.
 
+    **The kernel is held ONCE, and a complex kernel as its planes.**
+    A complex ``G`` is stored as a real plane pair ``(2, nsl, nx, ny)``
+    (``[0]`` real, ``[1]`` imaginary, the slice axis still sharded) on
+    every backend, and may be GIVEN as that pair — a real 4-D array
+    with a leading axis of two — which, when it is a device array of
+    the stored dtype lying as the mesh wants it, is kept as itself (no
+    copy). Why planes: a TPU holds no complex array natively; XLA
+    splits a complex64 program argument into two float32 arrays at
+    every program's entry (``X64SplitLow``/``High``), so a complex
+    kernel of 8.59 GB is 8.59 GB of temporaries beside itself in every
+    program that touches it (compiled for a v5e: 16.44 of 15.75 GB, it
+    does not fit), and the complex ``einsum`` XLA then builds
+    (three real products, Gauss) reads each plane twice an apply. On
+    the planes an apply is one real ``einsum`` batched over the pair,
+    the vector's parts side by side as columns — ``Gr·[vr|vi]`` and
+    ``Gi·[vi|vr]`` — so each plane, and so the kernel's 8 bytes an
+    element, is read ONCE an apply (PERF.md section 6, PR 34). A
+    complex ``G`` handed in as such is split once at construction (a
+    host array on the host; a device array by one transient copy of
+    its size — give the planes where that does not fit).
+
+    ``saveGt`` is, like ``usematmul``, accepted for signature parity
+    and has no effect: the reference stores
+    ``conj(G.transpose(0, 2, 1))`` beside ``G`` to spare its per-slice
+    matmul a strided read; here the adjoint contracts the OTHER axis of
+    the stored planes (``kxy,kxz->kyz``; ``Gᴴ = Grᵀ − i·Giᵀ``), so no
+    second array of the kernel's size exists at construction or inside
+    an apply.
+
     ``compute_dtype`` (e.g. ``jnp.complex64`` for a c128 operator,
     ``jnp.bfloat16`` for a real one) narrows the STORAGE of the
     kernel — by far the memory hog at ``nsl·nx·ny`` — while vectors
     and accumulation stay in the operator dtype (the
     ``MPIBlockDiag(compute_dtype=...)`` HBM-bandwidth lever; the
-    reference's engine has no narrow-storage path).
+    reference's engine has no narrow-storage path); for a plane pair a
+    complex ``compute_dtype`` means its real counterpart.
 
     ``planar=True``: the complex-free execution mode for TPU runtimes
-    with no complex lowering (round-5 hardware finding, ops/dft.py).
-    The complex kernel ``G`` is stored as a STACKED REAL plane pair
-    ``(2, nsl, nx, ny)`` (``[0]`` real, ``[1]`` imag, slice axis still
-    sharded), model/data vectors carry the matching ``(2, nsl, ·, nz)``
-    plane layout, the operator dtype is the real plane dtype, and each
-    complex batched GEMM runs as 4 real einsums — no complex dtype ever
-    reaches the device. This is the Fredholm core of the planar MDC
-    chain (``ops/mdc.py``); only BROADCAST vectors are supported (the
-    zero-collective slice-aligned SCATTER layout is a flat-vector
-    contract that the leading plane axis breaks).
+    with no complex lowering (round-5 hardware finding, ops/dft.py):
+    the VECTORS too are plane pairs — model/data carry the
+    ``(2, nsl, ·, nz)`` layout and the operator dtype is the real plane
+    dtype, so no complex dtype ever reaches the device. This is the
+    Fredholm core of the planar MDC chain (``ops/mdc.py``); only
+    BROADCAST vectors are supported (the zero-collective slice-aligned
+    SCATTER layout is a flat-vector contract that the leading plane
+    axis breaks).
     """
 
     def __init__(self, G, nz: int = 1, saveGt: bool = False,
                  usematmul: bool = True, mesh=None, dtype="float64",
                  compute_dtype=None, planar: bool = False):
-        G = jnp.asarray(G)
+        if not isinstance(G, jax.Array):
+            G = np.asarray(G)          # a host kernel is split on the host
+        xp = jnp if isinstance(G, jax.Array) else np
         self.planar = bool(planar)
-        if self.planar:
-            # planes store the REAL representation: a complex
-            # compute_dtype narrows to its real counterpart
-            if compute_dtype is not None and \
-                    np.issubdtype(np.dtype(compute_dtype),
-                                  np.complexfloating):
-                compute_dtype = np.real(
-                    np.ones(1, dtype=compute_dtype)).dtype
-            if np.issubdtype(np.dtype(dtype), np.complexfloating):
-                dtype = np.real(np.ones(1, dtype=np.dtype(dtype))).dtype
+        cplx = bool(np.issubdtype(G.dtype, np.complexfloating))
+        if G.ndim == 4 and (cplx or G.shape[0] != 2):
+            raise ValueError("a 4-D G is the complex kernel's real plane "
+                             f"pair (2, nsl, nx, ny); got {G.dtype} "
+                             f"{tuple(G.shape)}")
+        # the kernel is a plane pair: given as one, complex, or planar
+        self._planes = G.ndim == 4 or cplx or self.planar
+        if self.planar and np.issubdtype(np.dtype(dtype),
+                                         np.complexfloating):
+            dtype = _real_dtype(dtype)
+        elif self._planes and not self.planar:
+            dtype = np.result_type(dtype, np.complex64)   # complex vectors
         if compute_dtype is None:
             # env-policy default: bf16 storage for f32 kernels under
             # the bf16 policy, c64 for c128 under the c64 policy
             from ._precision import default_compute_dtype
             compute_dtype = default_compute_dtype(dtype)
+        if self._planes and compute_dtype is not None and \
+                np.issubdtype(np.dtype(compute_dtype), np.complexfloating):
+            # planes store the REAL representation
+            compute_dtype = _real_dtype(compute_dtype)
         self.compute_dtype = compute_dtype
         self.nz = int(nz)
-        if self.planar:
-            pdt = np.real(np.ones(1, dtype=G.dtype)).dtype
-            G = jnp.stack([jnp.real(G).astype(pdt),
-                           jnp.imag(G).astype(pdt)])
-            self.nsl, self.nx, self.ny = G.shape[1:]
-        else:
-            self.nsl, self.nx, self.ny = G.shape
+        if self._planes and G.ndim == 3:
+            pdt = _real_dtype(G.dtype)
+            G = xp.stack([xp.real(G).astype(pdt), xp.imag(G).astype(pdt)])
+        self.nsl, self.nx, self.ny = G.shape[-3:]
         if compute_dtype is not None:
             G = G.astype(compute_dtype)
         from ..parallel.mesh import default_mesh
@@ -111,18 +155,12 @@ class MPIFredholm1(MPILinearOperator):
                                 int(np.prod(self.dims))),
                          dtype=np.dtype(dtype))
         try:
+            # a device array that already lies so becomes the storage
             self.G = jax.device_put(
-                G, axis_sharding(self.mesh, G.ndim, len(plead)))
+                G, axis_sharding(self.mesh, G.ndim, G.ndim - 3),
+                may_alias=True)
         except ValueError:
-            self.G = G
-        if not saveGt:
-            self.GT = None
-        elif self.planar:
-            # conj-transpose planes: (Grᵀ, -Giᵀ) per slice
-            self.GT = jnp.stack([G[0].transpose(0, 2, 1),
-                                 -G[1].transpose(0, 2, 1)])
-        else:
-            self.GT = jnp.conj(G.transpose(0, 2, 1))
+            self.G = jnp.asarray(G)
         self._ndev = int(self.mesh.devices.size)
 
     @property
@@ -183,52 +221,56 @@ class MPIFredholm1(MPILinearOperator):
         narrow, accumulation in the operator dtype (the shared
         narrow-storage rule, :mod:`ops._precision`)."""
         from ._precision import einsum_narrow
+        out = _real_dtype(self.dtype) if self._planes \
+            else np.result_type(v.dtype, self.dtype)
         if self.compute_dtype is None:
-            v = v.astype(self.dtype)
-        return einsum_narrow(spec, K, v, self.compute_dtype, self.dtype)
+            v = v.astype(out)
+        return einsum_narrow(spec, K, v, self.compute_dtype, out)
+
+    def _contract_planes(self, vr, vi, adjoint: bool):
+        """The complex product on the stored plane pair, each plane
+        read ONCE, as one real ``einsum`` batched over the pair: the
+        vector's parts side by side as columns, ``A = Gr·[vr|vi]`` and
+        ``B = Gi·[vi|vr]``; forward ``(A₀ − B₀, A₁ + B₁)``, adjoint —
+        ``Gᴴ = Grᵀ − i·Giᵀ``, contracting the other axis —
+        ``(A₀ + B₀, A₁ − B₁)``. The stored array enters whole, never
+        sliced: an apply outside a jit makes no copy of a plane either.
+        Four real products where XLA's own complex ``dot`` makes three
+        (Gauss) and reads each plane twice: the sweep binds here, not
+        the flops."""
+        nz = vr.shape[-1]
+        V = jnp.stack([jnp.concatenate([vr, vi], -1),
+                       jnp.concatenate([vi, vr], -1)])
+        A, Bm = self._contract(_SPECS[adjoint][1], self.G, V)
+        if adjoint:
+            return A[..., :nz] + Bm[..., :nz], A[..., nz:] - Bm[..., nz:]
+        return A[..., :nz] - Bm[..., :nz], A[..., nz:] + Bm[..., nz:]
+
+    def _apply(self, x: DistributedArray, adjoint: bool) -> DistributedArray:
+        inner, outer = (self.nx, self.ny) if adjoint else (self.ny, self.nx)
+        dims = self.dimsd if adjoint else self.dims
+        self._check_partition(x, inner)
+        ncol = int(x.global_shape[1]) if x.ndim == 2 else None
+        v = x.array.reshape(dims if ncol is None
+                            else dims[:-1] + (self.nz * ncol,))
+        if self.planar:
+            out = jnp.stack(self._contract_planes(v[0], v[1], adjoint))
+        elif self._planes:
+            out = jax.lax.complex(*self._contract_planes(
+                jnp.real(v), jnp.imag(v), adjoint))
+        else:
+            out = self._contract(_SPECS[adjoint][0], self.G, v)
+        return self._wrap(out, x, self.shape[1 if adjoint else 0], outer,
+                          ncol)
 
     def _matvec(self, x: DistributedArray) -> DistributedArray:
-        self._check_partition(x, self.ny)
-        ncol = int(x.global_shape[1]) if x.ndim == 2 else None
-        m = x.array.reshape(self.dims if ncol is None
-                            else self.dims[:-1] + (self.nz * ncol,))
-        if self.planar:
-            # complex batched GEMM on plane pairs, 4 real einsums (the
-            # Karatsuba 3-einsum form needs a kernel-sized Gr+Gi temp —
-            # an extra full sweep of the memory hog — so the plain
-            # 4-sweep lowering wins here, unlike the host-folded
-            # constants of ops/dft.py)
-            c = lambda K, v: self._contract("kxy,kyz->kxz", K, v)
-            dr = c(self.G[0], m[0]) - c(self.G[1], m[1])
-            di = c(self.G[0], m[1]) + c(self.G[1], m[0])
-            d = jnp.stack([dr, di])
-        else:
-            d = self._contract("kxy,kyz->kxz", self.G, m)
-        return self._wrap(d, x, self.shape[0], self.nx, ncol)
+        return self._apply(x, adjoint=False)
 
     def _rmatvec(self, x: DistributedArray) -> DistributedArray:
-        self._check_partition(x, self.nx)
-        ncol = int(x.global_shape[1]) if x.ndim == 2 else None
-        d = x.array.reshape(self.dimsd if ncol is None
-                            else self.dimsd[:-1] + (self.nz * ncol,))
-        if self.planar:
-            if self.GT is not None:
-                Hr, Hi = self.GT[0], self.GT[1]
-            else:  # Gᴴ planes: (Grᵀ, -Giᵀ) per slice
-                Hr = self.G[0].transpose(0, 2, 1)
-                Hi = -self.G[1].transpose(0, 2, 1)
-            c = lambda K, v: self._contract("kyx,kxz->kyz", K, v)
-            mr = c(Hr, d[0]) - c(Hi, d[1])
-            mi = c(Hr, d[1]) + c(Hi, d[0])
-            m = jnp.stack([mr, mi])
-        else:
-            GT = self.GT if self.GT is not None \
-                else jnp.conj(self.G).transpose(0, 2, 1)
-            m = self._contract("kyx,kxz->kyz", GT, d)
-        return self._wrap(m, x, self.shape[1], self.ny, ncol)
+        return self._apply(x, adjoint=True)
 
 
 # the frequency-sharded kernel travels into jit as a pytree child
 # (multi-process arrays must not be closed over — linearoperator.py)
 from ..linearoperator import register_operator_arrays  # noqa: E402
-register_operator_arrays(MPIFredholm1, "G", "GT")
+register_operator_arrays(MPIFredholm1, "G")

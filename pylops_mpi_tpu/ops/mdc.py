@@ -5,10 +5,18 @@ Rebuild of ``pylops_mpi/waveeqprocessing/MDC.py:12-180``: the lazy chain
 applied to the replicated model/data (wrapped local operators,
 ref ``MDC.py:55-58``), I/I1 slice to the first ``nfmax`` frequencies,
 and the frequency-sharded :class:`MPIFredholm1` is the distributed core.
-Kernel prescaling ``dr·dt·√nt`` (ref ``MDC.py:37-43``).
+The reference prescales the kernel by ``dr·dt·√nt`` (ref
+``MDC.py:37-43``) — a second array of the kernel's size; here the
+factor rides on the SPECTRUM the Fredholm product returns (the chain
+is linear: ``(αG) m = α (G m)``), so the kernel is held once, as
+:class:`MPIFredholm1` stores it: a complex kernel as its real
+(re, im) plane pair, which may be handed over as that pair — a real
+``(2, nfmax, ns, nr)`` device array is kept as itself.
 
-Engines: the ``complex`` chain carries complex frequency-domain
-vectors between the stages (the reference layout). The ``planar``
+Engines: the ``complex`` chain — the default wherever the runtime
+lowers complex dtypes, a v5e included (PERF.md section 6, PR 34) —
+carries complex frequency-domain vectors between the stages (the
+reference layout). The ``planar``
 chain — auto-selected when the resolved local-FFT mode is ``planar``,
 i.e. on TPU runtimes with no complex lowering at all (round-5 hardware
 finding, ``ops/dft.py``) — keeps every intermediate as a STACKED REAL
@@ -29,6 +37,7 @@ from typing import Optional
 import numpy as np
 import jax.numpy as jnp
 
+from ..diagnostics import trace as _trace
 from ..linearoperator import MPILinearOperator, aslinearoperator
 from . import dft
 from .fredholm import MPIFredholm1
@@ -63,43 +72,77 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
     """Distributed MDC operator (ref ``MDC.py:82-180``). ``G`` is the
     full frequency-domain kernel ``(nfmax, ns, nr)`` (one controller —
     the reference passes each rank its frequency chunk).
+
+    **The kernel is held once**: ``G`` is neither scaled (the factor
+    ``dr·dt·√nt`` multiplies the Fredholm product's spectrum;
+    ``prescaled=True`` leaves it out) nor conjugate-transposed
+    (``saveGt`` is accepted for the reference's sake and has no
+    effect: :class:`MPIFredholm1`'s adjoint contracts the other axis
+    of the stored kernel). ``G`` is upstream's complex
+    ``(nfmax, ns, nr)`` — split into planes once, at construction — or
+    the plane pair itself, real ``(2, nfmax, ns, nr)``, which a device
+    array of the right dtype stays: the form for a kernel that fills
+    the chip (8.59 GB at ocean-bottom scale: a complex64 argument
+    costs its own size again in every program on a TPU; PERF.md
+    section 6, PR 34).
+
     ``compute_dtype`` (e.g. ``jnp.complex64``) narrows the stored
     kernel — the operator's memory hog — via
     ``MPIFredholm1(compute_dtype=...)``; FFTs and vectors keep the
-    operator dtype. ``engine``: ``"complex"`` | ``"planar"`` | None
-    (auto — planar exactly when ``dft.resolved_mode() == "planar"``,
-    the no-complex-lowering TPU case); both engines expose identical
-    external shapes/dtypes (real model in, real data out)."""
-    G = jnp.asarray(G)
+    operator dtype. ``engine``: ``"complex"`` | ``"planar"`` | None.
+    None is a rule in what the operator sees: ``complex`` (complex
+    spectra between the stages, ``jnp.fft``; inside a program a complex
+    array IS a pair of real ones on a TPU, at no cost) wherever the
+    runtime lowers complex dtypes — every CPU, and the v5e (at
+    ocean-bottom scale the whole apply takes 54.5 ms forward and 53.5
+    adjoint against ``planar``'s 53.3 and 52.3: XLA lowers the
+    1,023-sample ``jnp.fft`` as a dense DFT product there, the planar
+    chain's GEMM DFT by another name; PERF.md section 6, PR 34) — and
+    ``planar`` exactly when
+    ``dft.resolved_mode() == "planar"`` (a runtime with no complex
+    lowering, where nothing else runs). ``mdc.engine_select``
+    (``engine``, ``nfmax``, ``ns``, ``nr``, ``nv``, ``kernel_bytes``,
+    a one-word ``why``: ``kwarg``, ``fft_mode`` or ``complex_lowers``)
+    says what was built under ``PYLOPS_MPI_TPU_TRACE``. Both engines
+    expose identical external shapes/dtypes (real model in, real data
+    out)."""
+    if not hasattr(G, "ndim"):
+        G = np.asarray(G)
     if twosided and nt % 2 == 0:
         raise ValueError("nt must be odd number")
-    if engine is None:
-        engine = "planar" if dft.resolved_mode() == "planar" \
-            else "complex"
-    if engine not in ("complex", "planar"):
+    if engine not in ("complex", "planar", None):
         raise ValueError(f"engine must be 'complex', 'planar' or None, "
                          f"got {engine!r}")
-    dtype = G.dtype
+    why = "kwarg"
+    if engine is None:
+        planar = dft.resolved_mode() == "planar"
+        engine = "planar" if planar else "complex"
+        why = "fft_mode" if planar else "complex_lowers"
+    # a real 4-D G is the complex kernel's plane pair (MPIFredholm1)
+    dtype = np.result_type(G.dtype, np.complex64)
     rdtype = np.real(np.ones(1, dtype=dtype)).dtype
-    nfmax, ns, nr = G.shape
+    nfmax, ns, nr = G.shape[-3:]
     nfft = int(np.ceil((nt + 1) / 2))
     nfmax_req = nfmax if nfreq is None else nfreq
     if nfmax_req > nfft:
         nfmax_req = nfft
         logging.warning("nfmax set equal to ceil[(nt+1)/2]=%d" % nfft)
     if nfmax_req != nfmax:
-        G = G[:nfmax_req]
+        G = G[..., :nfmax_req, :, :]
         nfmax = nfmax_req
 
-    scale = 1.0 if prescaled else dr * dt * np.sqrt(nt)
+    _trace.event("mdc.engine_select", cat="schedule", engine=engine,
+                 nfmax=nfmax, ns=ns, nr=nr, nv=nv,
+                 kernel_bytes=nfmax * ns * nr * dtype.itemsize, why=why)
 
     if engine == "planar":
         # conj folds into the stored kernel: Fredholm1.conj() == the
         # operator with kernel conj(G) (the _ConjLinearOperator wrapper
         # conjugates vectors, which is an identity on real planes and
         # would silently do nothing here)
-        Gk = jnp.conj(G) if conj else G
-        Frop = MPIFredholm1(scale * Gk, nv, saveGt=saveGt, mesh=mesh,
+        Gk = G if not conj else jnp.conj(G) if G.ndim == 3 \
+            else jnp.stack([G[0], -G[1]])
+        Frop = MPIFredholm1(Gk, nv, saveGt=saveGt, mesh=mesh,
                             dtype=rdtype, compute_dtype=compute_dtype,
                             planar=True)
         Fop = aslinearoperator(_LocalFFT(
@@ -112,7 +155,7 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
         I1op = aslinearoperator(_plane_freq_slice(nfft, nfmax, ns * nv,
                                                   F1op.dtype))
     else:
-        Frop = MPIFredholm1(scale * G, nv, saveGt=saveGt, mesh=mesh,
+        Frop = MPIFredholm1(G, nv, saveGt=saveGt, mesh=mesh,
                             dtype=dtype, compute_dtype=compute_dtype)
         if conj:
             Frop = Frop.conj()
@@ -128,6 +171,9 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
         I1op = aslinearoperator(_LocalIdentity(nfmax * ns * nv,
                                                nfft * ns * nv,
                                                dtype=dtype))
+    if not prescaled:
+        # on the spectrum, not on the kernel: no second kernel is made
+        Frop = Frop * rdtype.type(dr * dt * np.sqrt(nt))
     MDCop = F1op.H * I1op.H * Frop * Iop * Fop
     MDCop.dtype = rdtype
     return MDCop

@@ -1,0 +1,332 @@
+"""Multi-dimensional deconvolution as a deployment (PR 34): the system
+(``pmt.MPIMDC`` with defaults -> ``pmt.cgls``) against the benchmark
+builder's plain reference — forward, adjoint and the 30-iteration
+answer on 1, 2 and 8 virtual devices; the frequency shares adding up to
+the uncut operator; the kernel held once, as its planes; the scopes and
+the event the device trace and the log read. Small, seeded,
+float32/complex64, on the CPU."""
+
+import json
+import os
+import sys
+
+import numpy as np
+import pytest
+import jax
+import jax.numpy as jnp
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+if ROOT not in sys.path:
+    sys.path.insert(0, ROOT)
+
+import pylops_mpi_tpu as pmt
+from pylops_mpi_tpu import DistributedArray, Partition
+from pylops_mpi_tpu.diagnostics import trace
+from pylops_mpi_tpu.models import mdd
+from pylops_mpi_tpu.ops import local
+from pylops_mpi_tpu.solvers import basic
+from pylops_mpi_tpu.utils import hlo
+from chipbench.builders import mdd as B
+
+SIZES = {"nfmax": 64, "nfmax_deployment": 256, "ns": 64, "nr": 64,
+         "nt": 1023, "nv": 4, "dt": 0.004, "dr": 12.5, "f0": 20.0,
+         "sigma": 0.25, "tau_max": 0.2, "events": 6, "noise": 0.05}
+NITER = 30
+# a kernel whose PLANE outweighs every vector, for the two tests that
+# look for arrays of the kernel's size
+WIDE = dict(SIZES, nfmax=8, nt=33, nv=2)
+
+
+def _rel(a, b):
+    a, b = np.asarray(a), np.asarray(b)
+    wide = np.complex128 if np.iscomplexobj(b) else np.float64
+    a, b = a.astype(wide), b.astype(wide)
+    return np.linalg.norm(a - b) / np.linalg.norm(b)
+
+
+def _planes(sizes=SIZES, seed=0):
+    return B.make_kernel(sizes)(jax.random.key(seed))
+
+
+def _mdc(P, mesh=None, sizes=SIZES, **kw):
+    """Upstream's arguments and nothing else, as the cell's builder."""
+    return pmt.MPIMDC(P, nt=sizes["nt"], nv=sizes["nv"], dt=sizes["dt"],
+                      dr=sizes["dr"], twosided=True, mesh=mesh, **kw)
+
+
+def _vec(a, mesh):
+    out = DistributedArray(global_shape=a.size, mesh=mesh,
+                           partition=Partition.BROADCAST, dtype=np.float32)
+    out[:] = jnp.asarray(a, jnp.float32).ravel()
+    return out
+
+
+@pytest.fixture(scope="module")
+def case():
+    P = _planes()
+    x = B.make_response(SIZES)(jax.random.key(1))
+    mv, rmv = B.plain_system(SIZES)
+    with jax.default_matmul_precision("highest"):
+        d = mv(P, x)
+        u = jax.random.normal(jax.random.key(2), d.shape, jnp.float32)
+        xref, drop = B.plain_solve(SIZES, NITER)(P, d)
+        return dict(P=P, x=x, d=d, u=u, Ad=rmv(P, u), xref=xref,
+                    drop=float(drop))
+
+
+# ------------------------------- the system against the plain reference
+@pytest.mark.parametrize("ndev", [1, 2, 8])
+@pytest.mark.parametrize("what", ["forward", "adjoint", "answer"])
+def test_system_matches_the_plain_reference(case, ndev, what):
+    mesh = pmt.make_mesh(ndev)
+    Op = _mdc(case["P"], mesh)
+    assert Op.dtype == np.float32
+    if what == "forward":
+        got = Op.matvec(_vec(case["x"], mesh)).asarray()
+        assert got.dtype == np.float32
+        assert _rel(got, np.ravel(case["d"])) < 2e-6
+    elif what == "adjoint":
+        got = Op.rmatvec(_vec(case["u"], mesh)).asarray()
+        assert _rel(got, np.ravel(case["Ad"])) < 2e-6
+        # the dot test, in the real inner product
+        lhs = float(np.vdot(np.ravel(case["u"]), np.ravel(case["d"])))
+        rhs = float(np.vdot(got, np.ravel(case["x"])))
+        assert abs(lhs - rhs) <= 1e-5 * np.linalg.norm(case["u"]) \
+            * np.linalg.norm(case["d"])
+    else:
+        x0 = DistributedArray(global_shape=Op.shape[1], mesh=mesh,
+                              partition=Partition.BROADCAST,
+                              dtype=np.float32)
+        x = pmt.cgls(Op, _vec(case["d"], mesh), x0=x0, niter=NITER,
+                     tol=0.0)[0]
+        assert x.partition == Partition.BROADCAST
+        assert _rel(x.asarray(), np.ravel(case["xref"])) < 1e-4
+        assert case["drop"] < 0.5
+
+
+def test_the_reference_keeps_pylops_real_fft_convention():
+    """Orthonormal, the twinned bins scaled by sqrt(2): the half
+    spectrum is an isometry of the real signal, and the adjoint passes
+    the dot test."""
+    v = jax.random.normal(jax.random.key(3), (65, 5, 3), jnp.float32)  # odd
+    for nt in (65, 64):
+        x = v[:nt]
+        y = B.rfft_t(x, nt // 2 + 1, shift=True)
+        assert float(jnp.linalg.norm(y)) == pytest.approx(
+            float(jnp.linalg.norm(x)), rel=1e-5 if nt % 2 else 1e-2)
+        u = jax.lax.complex(*(jax.random.normal(jax.random.key(k), y.shape,
+                                                jnp.float32) for k in (4, 5)))
+        lhs = float(jnp.real(jnp.vdot(u, y)))
+        rhs = float(jnp.vdot(B.rfft_t_adj(u, nt, shift=True), x))
+        assert abs(lhs - rhs) <= 1e-5 * float(jnp.linalg.norm(u)
+                                              * jnp.linalg.norm(y))
+    # and it is the program's: local.FFT on the same array
+    op = local.FFT((65, 5, 3), axis=0, real=True, ifftshift_before=True,
+                   dtype=np.float32)
+    assert _rel(op.matvec(v.ravel()),
+                B.rfft_t(v, 33, shift=True).ravel()) < 1e-6
+
+
+# ------------------------------------------------------- the share test
+def test_the_four_shares_add_up_to_the_uncut_forward(case):
+    """The cell's share is tied to the deployment: the forward data of
+    the four frequency shares (each a zero-padded kernel) add up to the
+    uncut reference's forward, and the first share given as its own
+    64-of-256-style cut is that share."""
+    whole = dict(SIZES, nfmax=SIZES["nfmax_deployment"])
+    Pw = _planes(whole, seed=5)
+    x = _vec(case["x"], None)
+    nf = SIZES["nfmax"]
+    total = 0.0
+    for k in range(whole["nfmax"] // nf):
+        keep = (jnp.arange(whole["nfmax"]) // nf == k)[None, :, None, None]
+        part = _mdc(jnp.where(keep, Pw, 0.0), sizes=whole).matvec(x)
+        if k == 0:
+            first = _mdc(Pw[:, :nf]).matvec(x).asarray()
+            assert _rel(first, part.asarray()) < 1e-6
+        total = total + part.asarray().astype(np.float64)
+    with jax.default_matmul_precision("highest"):
+        want = B.plain_system(whole)[0](Pw, case["x"])
+    assert _rel(total, np.ravel(want)) < 2e-6
+
+
+# ------------------------------------------------ the kernel, held once
+def test_a_device_kernel_given_as_planes_is_kept_as_itself():
+    P = jax.block_until_ready(_planes(WIDE, seed=6))
+    size = P.nbytes
+
+    def buffers():
+        """Device buffers of a plane's size or more (two ``jax.Array``
+        objects may share one)."""
+        return {a.unsafe_buffer_pointer() for a in jax.live_arrays()
+                if a.nbytes >= size // 2
+                and len(a.sharding.device_set) == 1}
+
+    before = buffers()
+    Op = _mdc(P, pmt.make_mesh(1), WIDE)
+    held = [leaf for leaf in jax.tree_util.tree_leaves(Op)
+            if getattr(leaf, "shape", None) == P.shape]
+    assert len(held) == 1
+    assert held[0].unsafe_buffer_pointer() == P.unsafe_buffer_pointer()
+    # no second array of the kernel's size (or of one plane's) was made
+    assert not buffers() - before
+    # and it travels into the fused solver as an argument, not a constant
+    from pylops_mpi_tpu.linearoperator import operator_is_jit_arg
+    assert operator_is_jit_arg(Op)
+
+
+@pytest.mark.parametrize("form", ["complex-device", "complex-host",
+                                  "planes-host"])
+def test_a_complex_kernel_is_the_same_operator(case, form):
+    """Upstream's form, ``G (nfmax, ns, nr)`` complex, is split into
+    planes at construction; a host kernel on the host."""
+    P = case["P"]
+    G = {"complex-device": lambda: jax.lax.complex(P[0], P[1]),
+         "complex-host": lambda: np.asarray(P[0]) + 1j * np.asarray(P[1]),
+         "planes-host": lambda: np.asarray(P)}[form]()
+    Op = _mdc(G)
+    assert Op.dtype == np.float32
+    stored = [leaf for leaf in jax.tree_util.tree_leaves(Op)
+              if getattr(leaf, "ndim", 0) == 4]
+    assert len(stored) == 1 and stored[0].dtype == jnp.float32
+    assert _rel(Op.matvec(_vec(case["x"], None)).asarray(),
+                np.ravel(case["d"])) < 2e-6
+
+
+@pytest.mark.parametrize("bad", [np.ones((3, 4, 5, 5), np.float32),
+                                 np.ones((2, 4, 5, 5), np.complex64)])
+def test_a_4d_kernel_must_be_a_real_plane_pair(bad):
+    with pytest.raises(ValueError, match="plane"):
+        pmt.MPIFredholm1(bad, nz=1, dtype=np.complex64)
+
+
+@pytest.mark.parametrize("saveGt", [True, False])
+def test_saveGt_changes_nothing(case, saveGt):
+    """Kept in the signature for upstream's sake: the adjoint contracts
+    the other axis of the stored planes either way, and nothing of the
+    kernel's size is stored beside it."""
+    Op = _mdc(case["P"], saveGt=saveGt)
+    got = Op.rmatvec(_vec(case["u"], None)).asarray()
+    assert _rel(got, np.ravel(case["Ad"])) < 2e-6
+    assert sum(getattr(leaf, "ndim", 0) >= 3
+               for leaf in jax.tree_util.tree_leaves(Op)) == 1
+
+
+# ------------------------------------------------- scopes and the event
+@pytest.fixture(scope="module")
+def fused_hlo():
+    mesh = pmt.make_mesh(1)
+    Op = _mdc(_planes(WIDE), mesh, WIDE)
+    return hlo.compiled_hlo(
+        lambda op, y, x0: basic._cgls_fused(op, y, x0, 0.0, 0.0, niter=3),
+        Op, _vec(np.ones(Op.shape[0]), mesh), _vec(np.zeros(Op.shape[1]),
+                                                   mesh))
+
+
+@pytest.mark.parametrize("scope", [
+    "pmt.local.FFT", "pmt.MPIFredholm1.matvec", "pmt.MPIFredholm1.rmatvec",
+    "pmt._ProductLinearOperator.matvec",
+    "pmt._ProductLinearOperator.rmatvec"])
+def test_scopes_in_the_fused_solver(fused_hlo, scope):
+    """The names the device trace splits the solve by survive on the
+    ops inside the fused ``while_loop``; the local FFT sits inside the
+    product chain's scope."""
+    names = [ln for ln in fused_hlo.split("\n")
+             if "op_name=" in ln and "/while/body/" in ln and scope in ln]
+    assert names, scope
+    if scope == "pmt.local.FFT":
+        assert all("pmt._ProductLinearOperator." in ln for ln in names)
+
+
+def test_the_fused_solver_holds_no_second_kernel(fused_hlo):
+    """No instruction of the compiled solve outside a fusion makes an
+    array of the kernel's size (the kernel is a parameter). The CPU
+    backend copies the plane a product reads; compiled for a v5e
+    nothing of even a plane's size is left
+    (``test_poststack_3d.py::test_mdc_solver_compiles_for_v5e``)."""
+    plane = 2 * WIDE["nfmax"] * WIDE["ns"] * WIDE["nr"]
+    import re
+    fused = False
+    for ln in fused_hlo.split("\n"):
+        if ln and not ln.startswith(" "):
+            fused = "fused_computation" in ln or "fusion" in ln.split("(")[0]
+        m = re.match(r"\s*(?:ROOT )?%?[\w.\-]+ = f32\[([\d,]+)\]\S* (\w[\w\-]*)\(",
+                     ln)
+        if not m or fused or m.group(2) in ("parameter", "get-tuple-element",
+                                            "bitcast"):
+            continue
+        n = int(np.prod([int(d) for d in m.group(1).split(",")]))
+        assert n < plane, ln[:200]
+
+
+@pytest.mark.parametrize("engine,why", [(None, "complex_lowers"),
+                                        ("planar", "kwarg"),
+                                        ("complex", "kwarg")])
+def test_engine_select_event(case, monkeypatch, engine, why):
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    trace.clear_events()
+    _mdc(case["P"], **({} if engine is None else {"engine": engine}))
+    ev = [e for e in trace.get_events() if e["name"] == "mdc.engine_select"]
+    assert len(ev) == 1
+    a = ev[0]["args"]
+    assert a["engine"] == (engine or "complex") and a["why"] == why
+    assert (a["nfmax"], a["ns"], a["nr"], a["nv"]) == (64, 64, 64, 4)
+    assert a["kernel_bytes"] == 8 * 64 * 64 * 64
+    monkeypatch.setenv("PYLOPS_MPI_TPU_FFT_MODE", "planar")
+    from pylops_mpi_tpu.ops import dft
+    dft._mode_cache = None
+    trace.clear_events()
+    _mdc(case["P"])
+    a = [e for e in trace.get_events()
+         if e["name"] == "mdc.engine_select"][0]["args"]
+    assert engine is not None or (a["engine"], a["why"]) == ("planar",
+                                                             "fft_mode")
+
+
+# ---------------------------------------------------------- models.mdd
+@pytest.mark.parametrize("where", ["device", "host"])
+def test_models_mdd_runs_the_cells_solve(case, where):
+    """Device or host arrays, the operator's real dtype, ``niter`` and
+    ``tol`` passed through: the lines the cell's loop mirrors."""
+    P, d = case["P"], case["d"]
+    if where == "host":
+        P, d = np.asarray(P), np.asarray(d)
+    x, Op = mdd(P, d, nt=SIZES["nt"], nv=SIZES["nv"], dt=SIZES["dt"],
+                dr=SIZES["dr"], twosided=True, niter=NITER, tol=0.0)
+    assert Op.dtype == np.float32 and x.dtype == np.float32
+    assert x.shape == (SIZES["nt"], SIZES["nr"], SIZES["nv"])
+    assert _rel(x, case["xref"]) < 1e-4
+
+
+# ------------------------------------------------ the folded local FFT
+@pytest.mark.parametrize("axis", [0, 1, 2])
+@pytest.mark.parametrize("real", [True, False])
+def test_local_fft_folds_the_other_axes(rng, axis, real):
+    """The apply works on ``(pre, n, post)`` with the 1s left out: the
+    flat vector, forward and adjoint, is numpy's."""
+    dims = (9, 6, 4)
+    op = local.FFT(dims, axis=axis, real=real, dtype=np.float64)
+    x = rng.standard_normal(dims)
+    if real:
+        want = np.fft.rfft(x, axis=axis, norm="ortho")
+        k = [slice(None)] * 3
+        k[axis] = slice(1, (dims[axis] - 1) // 2 + 1)
+        want[tuple(k)] *= np.sqrt(2)
+    else:
+        want = np.fft.fft(x, axis=axis, norm="ortho")
+    got = np.asarray(op.matvec(jnp.asarray(x.ravel())))
+    assert _rel(np.abs(got), np.abs(want.ravel())) < 1e-12
+    assert np.allclose(got, want.ravel(), atol=1e-12)
+    u = rng.standard_normal(want.shape) + 1j * rng.standard_normal(want.shape)
+    back = np.asarray(op.rmatvec(jnp.asarray(u.ravel())))
+    lhs = np.real(np.vdot(u.ravel(), got))
+    rhs = np.real(np.vdot(back, x.ravel()))
+    assert abs(lhs - rhs) <= 1e-10 * np.linalg.norm(u) * np.linalg.norm(got)
+
+
+def test_the_configuration_is_what_these_tests_run():
+    with open(os.path.join(ROOT, "chipbench", "configs",
+                           "mdd_obc.json")) as f:
+        cfg = json.load(f)
+    small = dict(cfg["sizes"], **cfg["rehearse"])
+    assert small == SIZES

@@ -290,6 +290,72 @@ def test_pmt_laplacian_compiles_for_v5e(v5e_devices, monkeypatch, chips,
     assert c.memory_analysis().temp_size_in_bytes <= 2.05 * 4 * V / chips
 
 
+def _mdc_solver_for_v5e(v5e_devices, kernel_as: str):
+    """Compile ``mdd_obc.cgls_nv16``'s fused solver for one described
+    chip at the cell's full size (PR 34; kept in THIS file because it
+    holds the one topology fixture of the suite): the operator built
+    inside the traced function from an abstract kernel — ``planes``:
+    float32 ``(2, 64, 4096, 4096)``, what the cell hands over;
+    ``complex``: complex64 ``(64, 4096, 4096)``."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    nf, n, nt, nv = 64, 4096, 1023, 16
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            mesh = Mesh(np.array(v5e_devices[:1]), ("sp",))
+            G = jax.ShapeDtypeStruct(
+                (2, nf, n, n), jnp.float32,
+                sharding=NamedSharding(mesh, P(None, "sp"))) \
+                if kernel_as == "planes" else jax.ShapeDtypeStruct(
+                    (nf, n, n), jnp.complex64,
+                    sharding=NamedSharding(mesh, P("sp")))
+            V = nt * n * nv
+            vec = lambda: DistributedArray.tree_unflatten(
+                (mesh, pmt.Partition.BROADCAST, 0, (V,), pmt.local_split(
+                    (V,), 1, pmt.Partition.BROADCAST, 0), None),
+                [jax.ShapeDtypeStruct((V,), jnp.float32,
+                                      sharding=NamedSharding(mesh, P()))])
+            fn = jax.jit(lambda g, y, x0: basic._cgls_fused(
+                pmt.MPIMDC(g, nt=nt, nv=nv, dt=0.004, dr=12.5,
+                           twosided=True, mesh=mesh),
+                y, x0, jnp.float32(0), jnp.float32(0), niter=30))
+            return fn.lower(G, vec(), vec()).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+def test_mdc_solver_compiles_for_v5e(v5e_devices):
+    """The kernel held ONCE beside the solve: arguments are the
+    8.59 GB of planes and two vectors, temporaries a few vectors — no
+    array of even a plane's size is made — and the 16-wide minor axis
+    of ``(nt, nr, nv)`` is not padded to 128 lanes (3.2 GB for one FFT
+    pair before ``local.FFT`` folded its axes)."""
+    c = _mdc_solver_for_v5e(v5e_devices, "planes")
+    kernel, vec = 8 * 64 * 4096 * 4096, 4 * 1023 * 4096 * 16
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes <= kernel + 2.01 * vec
+    assert ma.temp_size_in_bytes <= 8 * vec < kernel // 4, ma
+    text = c.as_text()
+    assert "X64Split" not in text              # no complex at the entry
+    for scope in ("pmt.local.FFT", "pmt.MPIFredholm1.matvec",
+                  "pmt.MPIFredholm1.rmatvec"):
+        assert re.search(r'op_name="[^"]*/while/body/[^"]*%s'
+                         % re.escape(scope), text), scope
+
+
+def test_a_complex64_kernel_does_not_fit_a_v5e(v5e_devices):
+    """What forced the planes: XLA splits a complex64 program argument
+    into two float32 arrays at the program's entry, 8.59 GB of
+    temporaries beside the 8.59 GB kernel (PERF.md section 6, PR 34).
+    The operator therefore splits a complex kernel ONCE, at
+    construction; traced, as here, that split is in the program."""
+    with pytest.raises(Exception, match="RESOURCE_EXHAUSTED|memory"):
+        _mdc_solver_for_v5e(v5e_devices, "complex")
+
+
 # ------------------------------------------- the 3-D operators, 1/2/4 devices
 def _cube(rng, ny=8, nx=6, nt0=160, dt=np.float32):
     m = np.cumsum(rng.standard_normal((ny, nx, nt0)) * 0.05, axis=-1)
