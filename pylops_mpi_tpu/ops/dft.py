@@ -153,9 +153,14 @@ def use_matmul_fft() -> bool:
 # --------------------------------------------------------------- helpers
 
 @lru_cache(maxsize=128)
-def _dft_mat_np(n: int, sign: float, dtype: str) -> np.ndarray:
+def _dft_mat_np(n: int, sign: float, dtype: str,
+                cols: int | None = None) -> np.ndarray:
+    """The DFT matrix of length ``n``, or its first ``cols`` columns."""
     k = np.arange(n)
-    return np.exp(sign * 2j * np.pi * np.outer(k, k) / n).astype(dtype)
+    # the phase reduced as an integer, (j k) mod n, before the 2 pi / n:
+    # every angle lies in one turn, exact to float64's rounding
+    return np.exp(sign * 2j * np.pi * (np.outer(k, k[:cols]) % n)
+                  / n).astype(dtype)
 
 
 @lru_cache(maxsize=128)
@@ -337,8 +342,9 @@ def plane_dtype(dtype) -> str:
 
 
 @lru_cache(maxsize=128)
-def _dft_mat_planar_np(n: int, sign: float, dtype: str):
-    F = _dft_mat_np(n, sign, "complex128")
+def _dft_mat_planar_np(n: int, sign: float, dtype: str,
+                       cols: int | None = None):
+    F = _dft_mat_np(n, sign, "complex128", cols)
     Fr = np.ascontiguousarray(F.real, dtype)
     Fi = np.ascontiguousarray(F.imag, dtype)
     return Fr, Fi, (Fr + Fi).astype(dtype)

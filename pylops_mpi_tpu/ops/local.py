@@ -602,6 +602,80 @@ class BlockDiag(LocalOperator):
 
 
 # -------------------------------------------------------------- transforms
+def truncated_dft_pays(nfft: int, nfkeep: int) -> bool:
+    """Whether a real transform of length ``nfft`` of which only the
+    first ``nfkeep`` bins are kept is made as ONE real matrix product
+    against ``(nfft, 2 nfkeep)`` cosines and sines (yes) or as the
+    whole ``jnp.fft`` with the cut beside it (no). A rule in what the
+    operator sees, the same on every backend, so the CPU tests run the
+    form the chip runs.
+
+    Measured on a TPU v5e (``chip_probe/fft_forms_probe.py``, PR 35;
+    ms an apply of one 268 MB f32 vector under ``highest``, forward /
+    adjoint, product against ``jnp.fft``-with-the-cut):
+
+    ======  ======  =======  =============  =============
+    nfft    nfkeep  traces   product        fft and cut
+    ======  ======  =======  =============  =============
+    1,023   64      65,536   2.18 / 2.19    15.77 / 26.28
+    1,023   128     65,536   2.97 / 2.80    17.61 / 26.47
+    1,023   256     65,536   4.94 / 5.17    18.66 / 27.81
+    1,023   511     65,536   10.00 / 10.15  20.96 / 29.86
+    1,000   256     65,536   4.85 / 5.14    15.40 / 26.44
+    1,022   256     65,536   4.88 / 5.13    15.57 / 26.18
+    4,095   512     16,384   7.02 / 6.22    51.21 / 82.11
+    4,095   2,047   16,384   25.35 / 23.58  54.77 / 85.66
+    1,024   64      65,536   2.19 / 2.15    12.33 / 16.07
+    1,024   256     65,536   4.86 / 5.19    13.56 / 17.62
+    1,024   512     65,536   9.09 / 9.24    15.34 / 19.63
+    4,096   64      16,384   2.21 / 2.03    12.09 / 16.44
+    4,096   512     16,384   6.94 / 6.17    12.22 / 16.66
+    4,096   2,048   16,384   24.37 / 22.91  15.33 / 20.17
+    ======  ======  =======  =============  =============
+
+    - a length that is no power of two: XLA's own transform there IS a
+      dense DFT product, with every column (its time grows with the
+      length: 16–30 ms at 1,000–1,023 samples, 50–86 at 4,095), so the
+      product with fewer columns pays for every band cut, up to the
+      whole half spectrum less one bin;
+    - a power of two: XLA has a real FFT there, 12 ms forward and
+      16–20 adjoint whatever the length, while the product's time
+      grows with its columns: it pays up to the last band measured to
+      win, **512 bins**; 2,048 lost.
+
+    With nothing cut (``nfkeep`` the whole half spectrum) the
+    transform is ``jnp.fft``, as it was."""
+    if nfkeep >= nfft // 2 + 1:
+        return False
+    return bool(nfft & (nfft - 1)) or nfkeep <= 512
+
+
+@functools.lru_cache(maxsize=32)
+def _truncated_dft_matrix(nt: int, nfft: int, nfkeep: int, shift: bool,
+                          dtype: str) -> np.ndarray:
+    """``W = [C | S]``, ``(nt, 2 nfkeep)``: the first ``nfkeep`` bins
+    of pylops' isometric real FFT of length ``nfft`` as a real matrix —
+    ``C[t, k] = s_k cos(2 pi k t'/nfft) / sqrt(nfft)``, ``S`` the same
+    with ``-sin``; ``s_k = sqrt(2)`` on the strictly positive
+    non-Nyquist bins, 1 on the others; ``t'`` the place of sample ``t``
+    after ``ifftshift`` where ``shift`` is set (a permutation of rows)
+    and a sample the transform truncates away a zero row. ``x @ W``
+    is the kept spectrum's parts side by side, ``W @ [re; im]`` the
+    adjoint: zero-pad, halve the doubled bins, ``irfft``, ``fftshift``
+    (bin 0's imaginary part meets a zero column). Made in float64 from
+    ``dft``'s planes (phases reduced as integers), then rounded."""
+    Fr, Fi, _ = dft._dft_mat_planar_np(nfft, -1.0, "float64", nfkeep)
+    k = np.arange(nfkeep)
+    s = np.where((k >= 1) & (k < (nfft + 1) // 2), np.sqrt(2.0), 1.0) \
+        / np.sqrt(nfft)
+    W = np.zeros((nt, 2 * nfkeep))
+    rows = min(nt, nfft)
+    W[:rows] = np.concatenate([Fr[:rows] * s, Fi[:rows] * s], axis=1)
+    if shift:
+        W = np.fft.fftshift(W, axes=0)
+    return W.astype(dtype)
+
+
 class FFT(LocalOperator):
     """1-D (real-input) FFT along an axis of an N-D layout, with the
     norm/scaling conventions pylops uses: ``norm="ortho"`` plus, for
@@ -613,14 +687,33 @@ class FFT(LocalOperator):
     ``planes=True`` (requires ``real=True``): the half-spectrum leaves
     as a STACKED REAL plane pair — data layout ``(2,) + dimsd`` with
     ``[0]`` the real and ``[1]`` the imaginary plane, operator dtype
-    the real plane dtype — computed via ``dft.rfft_planes`` /
-    ``irfft_planes`` so no complex dtype ever reaches the device. This
-    is the local transform of the planar MDC chain (``ops/mdc.py``) on
-    TPU runtimes without complex lowering."""
+    the real plane dtype — so no complex dtype ever reaches the device.
+    This is the local transform of the planar MDC chain
+    (``ops/mdc.py``) on TPU runtimes without complex lowering.
+
+    ``nfkeep`` (requires ``real=True``; ``MPIMDC`` sets it from its
+    ``nfmax``, a user has no reason to): only the first ``nfkeep`` bins
+    of the half spectrum are the operator's output — the transform
+    followed by the frequency cut, as one operator whose adjoint
+    zero-pads. Where :func:`truncated_dft_pays` says so an apply is
+    then ONE real matrix product at the package's matmul precision
+    against ``W = [C | S]`` of ``(nt, 2 nfkeep)`` cosines and sines
+    (``_truncated_dft_matrix``: the ``ifftshift``, the ortho norm and
+    the √2 are in the matrix), the adjoint the product with the same
+    matrix transposed, and both engines share it: the complex one gets
+    ``lax.complex`` of the product's two halves, the planar one the
+    halves as they are. Elsewhere (nothing cut, or the rule's other
+    side) the apply is ``jnp.fft`` (``dft``) with the cut and the pad
+    beside it. ``fft.path_select`` (``form`` = ``truncated_dft`` or
+    ``fft``, ``nt``, ``nfft``, ``nfkeep``, ``traces``, ``adjoint`` and,
+    for ``fft``, a one-word ``why``: ``complex``, ``uncut``, or
+    ``wide`` — a band of a power-of-two length beyond the rule)
+    says what a traced apply took under ``PYLOPS_MPI_TPU_TRACE``."""
 
     def __init__(self, dims, axis: int = 0, nfft: Optional[int] = None,
                  real: bool = True, ifftshift_before: bool = False,
-                 dtype=None, planes: bool = False):
+                 dtype=None, planes: bool = False,
+                 nfkeep: Optional[int] = None):
         dims = tuple(np.atleast_1d(dims))
         self.dims_nd = dims
         self.axis = axis % len(dims)
@@ -632,8 +725,13 @@ class FFT(LocalOperator):
                              "plane-pair half-spectrum layout)")
         self.ifftshift_before = bool(ifftshift_before)
         nf = self.nfft // 2 + 1 if real else self.nfft
+        self.nfkeep = nf if nfkeep is None else int(nfkeep)
+        if not 1 <= self.nfkeep <= nf or (self.nfkeep < nf and not real):
+            raise ValueError(f"nfkeep must keep 1 to {nf} leading bins of "
+                             f"a real transform's half spectrum, got "
+                             f"{nfkeep!r} (real={real})")
         dimsd = list(dims)
-        dimsd[self.axis] = nf
+        dimsd[self.axis] = self.nfkeep
         self.dimsd_nd = tuple(dimsd)
         # bins 1..nf-1 except the Nyquist bin of an even nfft
         self._double_hi = nf - 1 if self.nfft % 2 == 0 else nf
@@ -647,21 +745,42 @@ class FFT(LocalOperator):
         post = int(np.prod(dims[self.axis + 1:], dtype=np.int64))
         fold = lambda n: tuple(d for d, keep in (
             (pre, pre > 1), (n, True), (post, post > 1)) if keep)
-        self._cdims, self._cdimsd = fold(dims[self.axis]), fold(nf)
+        self._cdims, self._cdimsd = fold(dims[self.axis]), fold(self.nfkeep)
         self._caxis = int(pre > 1)
+        self._traces = pre * post
+        small = np.dtype(dtype or "float32").itemsize == 4
+        rdt = np.float32 if small else np.float64
+        # why an apply is jnp.fft, or None where it is the one product
+        if not real:
+            self._why = "complex"
+        elif self.nfkeep == nf:
+            self._why = "uncut"
+        elif not truncated_dft_pays(self.nfft, self.nfkeep):
+            self._why = "wide"
+        else:
+            self._why = None
+        self._W = None if self._why else _truncated_dft_matrix(
+            dims[self.axis], self.nfft, self.nfkeep, self.ifftshift_before,
+            np.dtype(rdt).name)
         if self.planes:
-            pdt = np.float32 \
-                if np.dtype(dtype or "float32").itemsize == 4 \
-                else np.float64
-            super().__init__(dims, (2,) + self.dimsd_nd, dtype=pdt)
+            super().__init__(dims, (2,) + self.dimsd_nd, dtype=rdt)
             return
-        cplx = np.complex64 if np.dtype(dtype or "float32").itemsize == 4 else np.complex128
-        super().__init__(dims, self.dimsd_nd, dtype=cplx)
+        super().__init__(dims, self.dimsd_nd,
+                         dtype=np.complex64 if small else np.complex128)
+
+    def _select(self, adjoint: bool):
+        _trace.event("fft.path_select", cat="schedule",
+                     form="fft" if self._why else "truncated_dft",
+                     nt=self._cdims[self._caxis], nfft=self.nfft,
+                     nfkeep=self.nfkeep, traces=self._traces,
+                     adjoint=int(adjoint),
+                     **({"why": self._why} if self._why else {}))
+        return self._why is None
 
     def _scale_pos(self, y, factor):
         # mask-multiply, not .at[].multiply: scatter ops miscompile under
         # the SPMD partitioner on sharded operands
-        nf = self._cdimsd[self._caxis]
+        nf = y.shape[self._caxis]
         ar = jnp.arange(nf)
         # a Python float: weakly typed, y keeps its dtype under x64
         fac = jnp.where((ar >= 1) & (ar < self._double_hi), float(factor),
@@ -670,20 +789,46 @@ class FFT(LocalOperator):
         shape[self._caxis] = nf
         return y * fac.reshape(shape)
 
+    def _cut(self, y, n):
+        """The first ``n`` entries along the transform's axis."""
+        return jax.lax.slice_in_dim(y, 0, n, axis=self._caxis)
+
+    def _pad_bins(self, v):
+        """Zeros for the bins that were cut away (the cut's adjoint)."""
+        pad = [(0, 0)] * v.ndim
+        pad[self._caxis] = (0, self.nfft // 2 + 1 - self.nfkeep)
+        return jnp.pad(v, pad)
+
+    def _product(self, v, over: int):
+        """``W`` contracted over its axis ``over`` (0: the samples,
+        forward; 1: the parts' bins, adjoint) with ``v``'s transform
+        axis, the result's new axis put where that one was."""
+        ax = self._caxis
+        y = jnp.tensordot(jnp.asarray(self._W), v, axes=((over,), (ax,)))
+        return jnp.moveaxis(y, 0, ax)
+
     @_scoped
     def _matvec(self, x):
         v = x.reshape(self._cdims)
         ax = self._caxis
+        if self._select(adjoint=False):
+            # (..., 2 nfkeep, ...): the real parts, then the imaginary
+            y = self._product(jnp.real(v), 0)
+            y = jnp.moveaxis(y.reshape(
+                y.shape[:ax] + (2, self.nfkeep) + y.shape[ax + 1:]), ax, 0)
+            if self.planes:
+                return y.astype(self.dtype).ravel()
+            return jax.lax.complex(y[0], y[1]).ravel()
         if self.ifftshift_before:
             v = jnp.fft.ifftshift(v, axes=ax)
         if self.planes:
             yr, yi = dft.rfft_planes(v, n=self.nfft, axis=ax, norm="ortho")
-            yr = self._scale_pos(yr, np.sqrt(2.0))
-            yi = self._scale_pos(yi, np.sqrt(2.0))
+            yr = self._scale_pos(self._cut(yr, self.nfkeep), np.sqrt(2.0))
+            yi = self._scale_pos(self._cut(yi, self.nfkeep), np.sqrt(2.0))
             return jnp.stack([yr, yi]).astype(self.dtype).ravel()
         if self.real:
             y = dft.rfft(v.real, n=self.nfft, axis=ax, norm="ortho")
-            y = self._scale_pos(y, np.sqrt(2.0))
+            y = self._scale_pos(self._cut(y, self.nfkeep), np.sqrt(2.0))
         else:
             y = dft.fft(v, n=self.nfft, axis=ax, norm="ortho")
         return y.ravel()
@@ -691,23 +836,32 @@ class FFT(LocalOperator):
     @_scoped
     def _rmatvec(self, x):
         ax = self._caxis
+        if self._select(adjoint=True):
+            if self.planes:
+                v = x.reshape((2,) + self._cdimsd)
+            else:
+                v = x.reshape(self._cdimsd)
+                v = jnp.stack([v.real, v.imag])
+            v = jnp.moveaxis(v, 0, ax)
+            v = v.reshape(v.shape[:ax] + (2 * self.nfkeep,)
+                          + v.shape[ax + 2:])
+            y = self._product(v, 1)
+            return y.astype(self.dtype).ravel() if self.planes else y.ravel()
         if self.planes:
             v = x.reshape((2,) + self._cdimsd)
-            vr = self._scale_pos(v[0], 1.0 / np.sqrt(2.0))
-            vi = self._scale_pos(v[1], 1.0 / np.sqrt(2.0))
+            vr = self._pad_bins(self._scale_pos(v[0], 1.0 / np.sqrt(2.0)))
+            vi = self._pad_bins(self._scale_pos(v[1], 1.0 / np.sqrt(2.0)))
             y = dft.irfft_planes(vr, vi, n=self.nfft, axis=ax, norm="ortho")
         else:
             v = x.reshape(self._cdimsd)
             if self.real:
                 # adjoint of (√2-scaled) rfft: halve the doubled bins and
                 # let irfft's Hermitian extension supply the other half
-                v = self._scale_pos(v, 1.0 / np.sqrt(2.0))
+                v = self._pad_bins(self._scale_pos(v, 1.0 / np.sqrt(2.0)))
                 y = dft.irfft(v, n=self.nfft, axis=ax, norm="ortho")
             else:
                 y = dft.ifft(v, n=self.nfft, axis=ax, norm="ortho")
-        idx = [slice(None)] * len(self._cdims)
-        idx[ax] = slice(0, self._cdims[ax])
-        y = y[tuple(idx)]
+        y = self._cut(y, self._cdims[ax])
         if self.ifftshift_before:
             y = jnp.fft.fftshift(y, axes=ax)
         return y.astype(self.dtype).ravel() if self.planes else y.ravel()

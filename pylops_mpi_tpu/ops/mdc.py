@@ -1,10 +1,17 @@
 """Multi-dimensional convolution (MDC).
 
-Rebuild of ``pylops_mpi/waveeqprocessing/MDC.py:12-180``: the lazy chain
-``F1ᴴ · I1ᴴ · Fredholm1 · I · F`` where F/F1 are real FFTs along time
-applied to the replicated model/data (wrapped local operators,
-ref ``MDC.py:55-58``), I/I1 slice to the first ``nfmax`` frequencies,
-and the frequency-sharded :class:`MPIFredholm1` is the distributed core.
+Rebuild of ``pylops_mpi/waveeqprocessing/MDC.py:12-180``. The
+reference's lazy chain is ``F1ᴴ · I1ᴴ · Fredholm1 · I · F`` — real FFTs
+along time of the replicated model/data (wrapped local operators, ref
+``MDC.py:55-58``), ``I``/``I1`` cutting to the first ``nfmax``
+frequencies, the frequency-sharded :class:`MPIFredholm1` the
+distributed core. Here it is THREE operators, ``F1ᴴ · Fredholm1 · F``:
+the local transform makes only the bins the product keeps
+(``local.FFT(nfkeep=nfmax)``: with fewer kept than the half spectrum
+has, one real matrix product against ``(nt, 2 nfmax)`` cosines and
+sines where ``local.truncated_dft_pays`` says so, forward and
+adjoint; PERF.md section 6, PR 35), so no full half spectrum is made
+to be thrown away and no cut or zero pad stands beside the transform.
 The reference prescales the kernel by ``dr·dt·√nt`` (ref
 ``MDC.py:37-43``) — a second array of the kernel's size; here the
 factor rides on the SPECTRUM the Fredholm product returns (the chain
@@ -13,20 +20,17 @@ is linear: ``(αG) m = α (G m)``), so the kernel is held once, as
 (re, im) plane pair, which may be handed over as that pair — a real
 ``(2, nfmax, ns, nr)`` device array is kept as itself.
 
-Engines: the ``complex`` chain — the default wherever the runtime
-lowers complex dtypes, a v5e included (PERF.md section 6, PR 34) —
-carries complex frequency-domain vectors between the stages (the
-reference layout). The ``planar``
-chain — auto-selected when the resolved local-FFT mode is ``planar``,
-i.e. on TPU runtimes with no complex lowering at all (round-5 hardware
-finding, ``ops/dft.py``) — keeps every intermediate as a STACKED REAL
-plane pair: ``local.FFT(planes=True)`` produces ``(2, nfft, ·, nv)``
-half-spectrum planes via ``dft.rfft_planes``, the frequency slice is a
-plane-aware pad/crop, and ``MPIFredholm1(planar=True)`` contracts the
-kernel as stored (re, im) planes — so the compiled end-to-end MDC
-program contains no complex dtype anywhere (model and data are real
-time-domain vectors on both ends in either engine; shapes and numerics
-match the complex chain to plane precision).
+Engines — they differ only in how the spectrum is carried between the
+three operators: ``complex`` (the default wherever the runtime lowers
+complex dtypes, a v5e included; PERF.md section 6, PR 34) as complex
+``(nfmax, ·, nv)`` vectors, the reference layout; ``planar``
+(auto-selected when the resolved local-FFT mode is ``planar``, i.e. on
+TPU runtimes with no complex lowering at all, ``ops/dft.py``) as a
+STACKED REAL plane pair ``(2, nfmax, ·, nv)`` that
+``MPIFredholm1(planar=True)`` contracts against the kernel's stored
+planes — so the compiled end-to-end MDC program contains no complex
+dtype anywhere. Model and data are real time-domain vectors on both
+ends in either engine; shapes and numerics match to plane precision.
 """
 
 from __future__ import annotations
@@ -41,27 +45,9 @@ from ..diagnostics import trace as _trace
 from ..linearoperator import MPILinearOperator, aslinearoperator
 from . import dft
 from .fredholm import MPIFredholm1
-from .local import (FFT as _LocalFFT, FunctionOperator as _LocalFunction,
-                    Identity as _LocalIdentity)
+from .local import FFT as _LocalFFT
 
 __all__ = ["MPIMDC"]
-
-
-def _plane_freq_slice(nfft: int, nfmax: int, inner: int, dtype):
-    """Plane-aware frequency-slice operator: ``(2, nfft, inner)`` real
-    planes -> first ``nfmax`` frequencies of each plane (adjoint
-    zero-pads back) — the planar analog of the flat-prefix
-    ``local.Identity`` slice the complex chain uses."""
-
-    def f(v):
-        return v.reshape(2, nfft, inner)[:, :nfmax].ravel()
-
-    def fH(v):
-        return jnp.pad(v.reshape(2, nfmax, inner),
-                       ((0, 0), (0, nfft - nfmax), (0, 0))).ravel()
-
-    return _LocalFunction(f, fH, N=2 * nfmax * inner,
-                          M=2 * nfft * inner, dtype=dtype)
 
 
 def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
@@ -86,19 +72,26 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
     costs its own size again in every program on a TPU; PERF.md
     section 6, PR 34).
 
+    **The chain is three operators**, ``F1ᴴ · Fredholm1 · F``: ``F``
+    and ``F1`` are ``local.FFT(nfkeep=nfmax)``, which make the
+    ``nfmax`` bins the product keeps and no others — with
+    ``nfmax < nfft`` one real matrix product against ``(nt, 2 nfmax)``
+    cosines and sines, forward and adjoint, where
+    ``local.truncated_dft_pays`` says so; with nothing cut, ``jnp.fft``
+    as before (``fft.path_select`` says which).
+
     ``compute_dtype`` (e.g. ``jnp.complex64``) narrows the stored
     kernel — the operator's memory hog — via
-    ``MPIFredholm1(compute_dtype=...)``; FFTs and vectors keep the
-    operator dtype. ``engine``: ``"complex"`` | ``"planar"`` | None.
-    None is a rule in what the operator sees: ``complex`` (complex
-    spectra between the stages, ``jnp.fft``; inside a program a complex
-    array IS a pair of real ones on a TPU, at no cost) wherever the
-    runtime lowers complex dtypes — every CPU, and the v5e (at
-    ocean-bottom scale the whole apply takes 54.5 ms forward and 53.5
-    adjoint against ``planar``'s 53.3 and 52.3: XLA lowers the
-    1,023-sample ``jnp.fft`` as a dense DFT product there, the planar
-    chain's GEMM DFT by another name; PERF.md section 6, PR 34) — and
-    ``planar`` exactly when
+    ``MPIFredholm1(compute_dtype=...)``; transforms and vectors keep
+    the operator dtype. ``engine``: ``"complex"`` | ``"planar"`` |
+    None — the two differ only in how the spectrum is carried between
+    the three operators (complex ``(nfmax, ·, nv)`` vectors, or their
+    real (re, im) planes stacked); one product makes it for both. None
+    is a rule in what the operator sees: ``complex`` (inside a program
+    a complex array IS a pair of real ones on a TPU, at no cost)
+    wherever the runtime lowers complex dtypes — every CPU, and the
+    v5e, where the two lay 2 % apart at ocean-bottom scale (PERF.md
+    section 6, PR 34) — and ``planar`` exactly when
     ``dft.resolved_mode() == "planar"`` (a runtime with no complex
     lowering, where nothing else runs). ``mdc.engine_select``
     (``engine``, ``nfmax``, ``ns``, ``nr``, ``nv``, ``kernel_bytes``,
@@ -115,9 +108,8 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
                          f"got {engine!r}")
     why = "kwarg"
     if engine is None:
-        planar = dft.resolved_mode() == "planar"
-        engine = "planar" if planar else "complex"
-        why = "fft_mode" if planar else "complex_lowers"
+        engine = "planar" if dft.resolved_mode() == "planar" else "complex"
+        why = "fft_mode" if engine == "planar" else "complex_lowers"
     # a real 4-D G is the complex kernel's plane pair (MPIFredholm1)
     dtype = np.result_type(G.dtype, np.complex64)
     rdtype = np.real(np.ones(1, dtype=dtype)).dtype
@@ -135,7 +127,8 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
                  nfmax=nfmax, ns=ns, nr=nr, nv=nv,
                  kernel_bytes=nfmax * ns * nr * dtype.itemsize, why=why)
 
-    if engine == "planar":
+    planar = engine == "planar"
+    if planar:
         # conj folds into the stored kernel: Fredholm1.conj() == the
         # operator with kernel conj(G) (the _ConjLinearOperator wrapper
         # conjugates vectors, which is an identity on real planes and
@@ -145,35 +138,21 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
         Frop = MPIFredholm1(Gk, nv, saveGt=saveGt, mesh=mesh,
                             dtype=rdtype, compute_dtype=compute_dtype,
                             planar=True)
-        Fop = aslinearoperator(_LocalFFT(
-            (nt, nr, nv), axis=0, real=True, ifftshift_before=twosided,
-            dtype=rdtype, planes=True))
-        F1op = aslinearoperator(_LocalFFT(
-            (nt, ns, nv), axis=0, real=True, dtype=rdtype, planes=True))
-        Iop = aslinearoperator(_plane_freq_slice(nfft, nfmax, nr * nv,
-                                                 Fop.dtype))
-        I1op = aslinearoperator(_plane_freq_slice(nfft, nfmax, ns * nv,
-                                                  F1op.dtype))
     else:
         Frop = MPIFredholm1(G, nv, saveGt=saveGt, mesh=mesh,
                             dtype=dtype, compute_dtype=compute_dtype)
         if conj:
             Frop = Frop.conj()
-        Fop = aslinearoperator(_LocalFFT((nt, nr, nv), axis=0, real=True,
-                                         ifftshift_before=twosided,
-                                         dtype=rdtype))
-        F1op = aslinearoperator(_LocalFFT((nt, ns, nv), axis=0, real=True,
-                                          ifftshift_before=False,
-                                          dtype=rdtype))
-        Iop = aslinearoperator(_LocalIdentity(nfmax * nr * nv,
-                                              nfft * nr * nv,
-                                              dtype=dtype))
-        I1op = aslinearoperator(_LocalIdentity(nfmax * ns * nv,
-                                               nfft * ns * nv,
-                                               dtype=dtype))
+    # the transforms make the nfmax bins the product keeps, no more
+    Fop = aslinearoperator(_LocalFFT(
+        (nt, nr, nv), axis=0, real=True, ifftshift_before=twosided,
+        dtype=rdtype, planes=planar, nfkeep=nfmax))
+    F1op = aslinearoperator(_LocalFFT(
+        (nt, ns, nv), axis=0, real=True, dtype=rdtype, planes=planar,
+        nfkeep=nfmax))
     if not prescaled:
         # on the spectrum, not on the kernel: no second kernel is made
         Frop = Frop * rdtype.type(dr * dt * np.sqrt(nt))
-    MDCop = F1op.H * I1op.H * Frop * Iop * Fop
+    MDCop = F1op.H * Frop * Fop
     MDCop.dtype = rdtype
     return MDCop

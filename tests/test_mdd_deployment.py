@@ -324,6 +324,261 @@ def test_local_fft_folds_the_other_axes(rng, axis, real):
     assert abs(lhs - rhs) <= 1e-10 * np.linalg.norm(u) * np.linalg.norm(got)
 
 
+# ------------------------- the transform that makes only the bins kept
+def _np_cut_transform(x, axis, shift, nfkeep):
+    """NumPy's: ``ifftshift``, ``rfft`` (ortho, the twinned bins times
+    sqrt(2)), then the cut to the first ``nfkeep`` bins."""
+    nt = x.shape[axis]
+    xs = np.fft.ifftshift(x, axes=axis) if shift else x
+    y = np.fft.rfft(xs, axis=axis, norm="ortho")
+    k = [slice(None)] * x.ndim
+    k[axis] = slice(1, (nt - 1) // 2 + 1)
+    y[tuple(k)] *= np.sqrt(2)
+    k[axis] = slice(0, nfkeep)
+    return y[tuple(k)]
+
+
+def _np_cut_adjoint(u, nt, axis, shift):
+    """NumPy's: zero-pad the bins cut away, halve the doubled bins,
+    ``irfft``, ``fftshift``."""
+    pad = [(0, 0)] * u.ndim
+    pad[axis] = (0, nt // 2 + 1 - u.shape[axis])
+    up = np.pad(u, pad)
+    k = [slice(None)] * u.ndim
+    k[axis] = slice(1, (nt - 1) // 2 + 1)
+    up[tuple(k)] /= np.sqrt(2)
+    y = np.fft.irfft(up, n=nt, axis=axis, norm="ortho")
+    return np.fft.fftshift(y, axes=axis) if shift else y
+
+
+def _planes_of(u):
+    return np.concatenate([u.real.ravel(), u.imag.ravel()])
+
+
+@pytest.mark.parametrize("axis", [0, 1, 2])
+@pytest.mark.parametrize("keep", ["one", "middle", "nyquist"])
+@pytest.mark.parametrize("shift", [False, True])
+@pytest.mark.parametrize("nt", [33, 32])
+def test_the_cut_transform_is_numpys_rfft_then_cut(rng, nt, shift, keep,
+                                                   axis):
+    """``local.FFT(nfkeep=K)`` is ``rfft``-then-cut and its adjoint
+    pad-then-``irfft``: float64 to 1e-12, float32 to 2e-6, complex and
+    ``planes=True``, and the dot test. ``nyquist`` keeps every bin (the
+    uncut transform, through ``jnp.fft``); the others are the one
+    product."""
+    nf = nt // 2 + 1
+    K = {"one": 1, "middle": nf // 2, "nyquist": nf}[keep]
+    dims = [5, 4, 3]
+    dims[axis] = nt
+    x = rng.standard_normal(dims)
+    want = _np_cut_transform(x, axis, shift, K)
+    u = rng.standard_normal(want.shape) + 1j * rng.standard_normal(want.shape)
+    back = _np_cut_adjoint(u, nt, axis, shift)
+    for dtype, tol in ((np.float64, 1e-12), (np.float32, 2e-6)):
+        for planes in (False, True):
+            op = local.FFT(dims, axis=axis, real=True, ifftshift_before=shift,
+                           dtype=dtype, planes=planes, nfkeep=K)
+            assert (op._why is None) == (K < nf)
+            assert op.shape == ((2 if planes else 1) * want.size, x.size)
+            cdt = np.complex128 if dtype == np.float64 else np.complex64
+            assert op.dtype == (dtype if planes else cdt)
+            got = np.asarray(op.matvec(jnp.asarray(x.ravel(), dtype)))
+            assert got.dtype == op.dtype
+            if planes:
+                got = got[:want.size] + 1j * got[want.size:]
+            assert _rel(got, want.ravel()) < tol
+            uin = _planes_of(u).astype(dtype) if planes \
+                else u.ravel().astype(cdt)
+            z = np.asarray(op.rmatvec(jnp.asarray(uin)))
+            assert z.dtype == dtype
+            assert _rel(z, back.ravel()) < tol
+            lhs = np.real(np.vdot(u.ravel(), got))
+            rhs = np.vdot(z, x.ravel())
+            assert abs(lhs - rhs) <= 10 * tol * np.linalg.norm(u) \
+                * np.linalg.norm(got)
+
+
+@pytest.mark.parametrize("nt", [33, 32])
+@pytest.mark.parametrize("shift", [False, True])
+def test_the_matrix_reaches_the_nyquist_bin(rng, nt, shift):
+    """``W = [C | S]`` with every bin kept IS the isometric half
+    spectrum: bin 0 and an even length's Nyquist bin are not doubled
+    and their sine columns are zero, so ``W Wᵀ = I``."""
+    nf = nt // 2 + 1
+    W = local._truncated_dft_matrix(nt, nt, nf, shift, "float64")
+    assert W.shape == (nt, 2 * nf) and W.dtype == np.float64
+    x = rng.standard_normal((nt, 7))
+    y = x.T @ W
+    want = _np_cut_transform(x, 0, shift, nf)
+    assert np.allclose(y[:, :nf].T, want.real, atol=1e-13)
+    assert np.allclose(y[:, nf:].T, want.imag, atol=1e-13)
+    assert not W[:, nf].any()
+    assert nt % 2 or np.abs(W[:, -1]).max() < 1e-15
+    assert np.allclose(W @ W.T, np.eye(nt), atol=1e-13)
+    assert local._truncated_dft_matrix(nt, nt, 5, shift,
+                                       "float32").dtype == np.float32
+
+
+@pytest.mark.parametrize("nfft", [40, 24])
+def test_the_cut_transform_pads_or_truncates_to_nfft(rng, nfft):
+    """A sample the transform truncates away meets a zero row; a longer
+    ``nfft`` is more columns of the same rows."""
+    nt, K = 33, 6
+    x = rng.standard_normal((nt, 5))
+    op = local.FFT((nt, 5), axis=0, nfft=nfft, real=True, dtype=np.float64,
+                   nfkeep=K)
+    xp = np.zeros((nfft, 5))
+    xp[:min(nt, nfft)] = x[:nfft]
+    want = _np_cut_transform(xp, 0, False, K)
+    assert _rel(op.matvec(jnp.asarray(x.ravel())), want.ravel()) < 1e-12
+    assert not op._W[nfft:].any()
+
+
+@pytest.mark.parametrize("planes", [False, True])
+@pytest.mark.parametrize("shift", [False, True])
+def test_the_rules_other_side_is_the_fft_with_the_cut_beside_it(
+        rng, shift, planes):
+    """A band of a power-of-two length wider than the rule takes (XLA
+    has a real FFT there): the same operator as ``jnp.fft``, the cut
+    after it and the pad before its adjoint."""
+    nt, K, dims = 2048, 600, (2048, 3)
+    op = local.FFT(dims, axis=0, real=True, ifftshift_before=shift,
+                   dtype=np.float64, planes=planes, nfkeep=K)
+    assert op._why == "wide" and op._W is None
+    x = rng.standard_normal(dims)
+    want = _np_cut_transform(x, 0, shift, K)
+    got = np.asarray(op.matvec(jnp.asarray(x.ravel())))
+    if planes:
+        got = got[:want.size] + 1j * got[want.size:]
+    assert _rel(got, want.ravel()) < 1e-12
+    u = want[::-1] * (1 + 0.5j)
+    z = op.rmatvec(jnp.asarray(_planes_of(u) if planes else u.ravel()))
+    assert _rel(z, _np_cut_adjoint(u, nt, 0, shift).ravel()) < 1e-12
+
+
+@pytest.mark.parametrize("kw", [dict(nfkeep=0), dict(nfkeep=18),
+                                dict(nfkeep=4, real=False)])
+def test_nfkeep_cuts_a_real_half_spectrum_only(kw):
+    with pytest.raises(ValueError, match="nfkeep"):
+        local.FFT((33, 4), axis=0, **kw)
+
+
+@pytest.mark.parametrize("nfft,nfkeep,pays", [
+    (1023, 64, True), (1023, 256, True), (1023, 511, True),
+    (1000, 256, True), (4095, 2047, True),          # no power of two
+    (1024, 64, True), (1024, 512, True), (4096, 512, True),
+    (4096, 513, False), (4096, 2048, False),        # a power of two
+    (1023, 512, False), (1024, 513, False)])        # nothing cut
+def test_the_rule_by_shape(nfft, nfkeep, pays):
+    """Both sides of ``truncated_dft_pays``, at the shapes whose rows
+    its docstring holds (measured on the chip, PR 35)."""
+    assert local.truncated_dft_pays(nfft, nfkeep) is pays
+
+
+@pytest.mark.parametrize("adjoint", [0, 1])
+@pytest.mark.parametrize("form,why,kw", [
+    ("truncated_dft", None, dict(nfkeep=6)),
+    ("fft", "uncut", dict()),
+    ("fft", "wide", dict(nfkeep=16)),
+    ("fft", "complex", dict(real=False))])
+def test_fft_path_select_event(monkeypatch, form, why, kw, adjoint):
+    """One event a traced apply, beside ``conv1d.path_select``."""
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    if why == "wide":                # a rule that stops at 8 bins
+        monkeypatch.setattr(local, "truncated_dft_pays",
+                            lambda nfft, nfkeep: nfkeep <= 8)
+    op = local.FFT((33, 4, 3), axis=0, ifftshift_before=kw.get("real", True),
+                   dtype=np.float32, **kw)
+    v = jnp.ones(op.shape[0] if adjoint else op.shape[1], op.dtype)
+    trace.clear_events()
+    jax.jit(op.rmatvec if adjoint else op.matvec)(v)
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "fft.path_select"]
+    assert len(ev) == 1
+    a = ev[0]
+    assert (a["form"], a.get("why")) == (form, why)
+    assert (a["nt"], a["nfft"], a["traces"], a["adjoint"]) == (33, 33, 12,
+                                                               adjoint)
+    assert a["nfkeep"] == {"truncated_dft": 6, "uncut": 17, "wide": 16,
+                           "complex": 33}[why or form]
+
+
+# ------------------------------- MPIMDC against the OLD chain, written out
+def _old_mdc(P, mesh, engine, sizes=SIZES):
+    """``MPIMDC`` as PR 34 built it: ``F1ᴴ · I1ᴴ · Fredholm1 · I · F``,
+    the whole half spectrum made by ``local.FFT`` and cut by a slice
+    operator beside it (``local.Identity`` on the complex engine's
+    flat prefix, a plane-aware crop on the planar one's)."""
+    from pylops_mpi_tpu.linearoperator import aslinearoperator
+    nt, nv = sizes["nt"], sizes["nv"]
+    nfmax, ns, nr = P.shape[-3:]
+    nfft = (nt + 1) // 2
+    planar = engine == "planar"
+
+    def cut(inner):
+        if not planar:
+            return local.Identity(nfmax * inner, nfft * inner,
+                                  dtype=np.complex64)
+        return local.FunctionOperator(
+            lambda v: v.reshape(2, nfft, inner)[:, :nfmax].ravel(),
+            lambda v: jnp.pad(v.reshape(2, nfmax, inner),
+                              ((0, 0), (0, nfft - nfmax), (0, 0))).ravel(),
+            N=2 * nfmax * inner, M=2 * nfft * inner, dtype=np.float32)
+
+    fft = lambda n, shift: aslinearoperator(local.FFT(
+        (nt, n, nv), axis=0, real=True, ifftshift_before=shift,
+        dtype=np.float32, planes=planar))
+    Frop = pmt.MPIFredholm1(
+        P, nv, mesh=mesh, dtype=np.float32 if planar else np.complex64,
+        planar=planar) * np.float32(sizes["dr"] * sizes["dt"] * np.sqrt(nt))
+    Op = (fft(ns, False).H * aslinearoperator(cut(ns * nv)).H * Frop
+          * aslinearoperator(cut(nr * nv)) * fft(nr, True))
+    Op.dtype = np.dtype(np.float32)
+    return Op
+
+
+@pytest.mark.parametrize("ndev", [1, 2, 8])
+@pytest.mark.parametrize("engine", ["complex", "planar"])
+def test_mdc_is_the_old_chain(case, ndev, engine):
+    """Forward, adjoint and the 30-iteration answer of the three-operator
+    chain against the five-operator one it replaces."""
+    mesh = pmt.make_mesh(ndev)
+    new, old = _mdc(case["P"], mesh, engine=engine), \
+        _old_mdc(case["P"], mesh, engine)
+    assert new.shape == old.shape and new.dtype == old.dtype == np.float32
+    x, u, d = (_vec(case[k], mesh) for k in ("x", "u", "d"))
+    assert _rel(new.matvec(x).asarray(), old.matvec(x).asarray()) < 2e-6
+    assert _rel(new.rmatvec(u).asarray(), old.rmatvec(u).asarray()) < 2e-6
+    x0 = lambda Op: DistributedArray(
+        global_shape=Op.shape[1], mesh=mesh, partition=Partition.BROADCAST,
+        dtype=np.float32)
+    got, want = (pmt.cgls(Op, d, x0=x0(Op), niter=NITER, tol=0.0)[0]
+                 for Op in (new, old))
+    assert _rel(got.asarray(), want.asarray()) < 2e-5
+
+
+def test_mdc_with_nothing_cut_is_the_uncut_transform(monkeypatch):
+    """``nfmax == nfft``: the operator is what it was, ``jnp.fft``."""
+    sizes = dict(SIZES, nt=65, nfmax=33, ns=8, nr=8)
+    P = _planes(sizes, seed=8)
+    Op, old = _mdc(P, None, sizes), _old_mdc(P, None, "complex", sizes)
+    x = _vec(np.random.default_rng(0).standard_normal(Op.shape[1]), None)
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    trace.clear_events()
+    got = Op.matvec(x).asarray()
+    forms = [(e["args"]["form"], e["args"]["why"], e["args"]["adjoint"])
+             for e in trace.get_events() if e["name"] == "fft.path_select"]
+    assert forms == [("fft", "uncut", 0), ("fft", "uncut", 1)]
+    assert _rel(got, old.matvec(x).asarray()) < 1e-6
+
+
+def test_mdc_signature_is_upstreams_and_the_engine():
+    import inspect
+    assert list(inspect.signature(pmt.MPIMDC).parameters) == [
+        "G", "nt", "nv", "nfreq", "dt", "dr", "twosided", "saveGt", "conj",
+        "prescaled", "mesh", "compute_dtype", "engine"]
+
+
 def test_the_configuration_is_what_these_tests_run():
     with open(os.path.join(ROOT, "chipbench", "configs",
                            "mdd_obc.json")) as f:

@@ -327,13 +327,18 @@ def _mdc_solver_for_v5e(v5e_devices, kernel_as: str):
         cc.reset_cache()
 
 
-def test_mdc_solver_compiles_for_v5e(v5e_devices):
+@pytest.fixture(scope="module")
+def mdc_v5e(v5e_devices):
+    return _mdc_solver_for_v5e(v5e_devices, "planes")
+
+
+def test_mdc_solver_compiles_for_v5e(mdc_v5e):
     """The kernel held ONCE beside the solve: arguments are the
     8.59 GB of planes and two vectors, temporaries a few vectors — no
     array of even a plane's size is made — and the 16-wide minor axis
     of ``(nt, nr, nv)`` is not padded to 128 lanes (3.2 GB for one FFT
     pair before ``local.FFT`` folded its axes)."""
-    c = _mdc_solver_for_v5e(v5e_devices, "planes")
+    c = mdc_v5e
     kernel, vec = 8 * 64 * 4096 * 4096, 4 * 1023 * 4096 * 16
     ma = c.memory_analysis()
     assert ma.argument_size_in_bytes <= kernel + 2.01 * vec
@@ -344,6 +349,32 @@ def test_mdc_solver_compiles_for_v5e(v5e_devices):
                   "pmt.MPIFredholm1.rmatvec"):
         assert re.search(r'op_name="[^"]*/while/body/[^"]*%s'
                          % re.escape(scope), text), scope
+
+
+@pytest.mark.parametrize("what", ["no_half_spectrum", "constants",
+                                  "temporaries", "no_fft"])
+def test_mdc_solver_v5e_makes_only_the_bins_it_keeps(mdc_v5e, what):
+    """PR 35, at the cell's full size: the transform is a product
+    against ``(1023, 128)`` cosines and sines — nowhere in the solve an
+    array of the half spectrum's ``512 x 65,536`` elements, no dense
+    1,023-point DFT, and fewer temporaries than the four full
+    transforms took (6.01 vectors, PR 34)."""
+    text = mdc_v5e.as_text()
+    if what == "no_half_spectrum":
+        spectrum = 512 * 4096 * 16
+        for dims in set(re.findall(r"\b(?:f32|c64)\[([\d,]+)\]", text)):
+            n = int(np.prod([int(d) for d in dims.split(",")]))
+            assert n not in (spectrum, 2 * spectrum), dims
+    elif what == "constants":
+        consts = re.findall(r"f32\[(\d+),(\d+)\]\S* constant\(", text)
+        assert consts.count(("1023", "128")) == 2, consts   # Fop's, F1op's
+        assert not [c for c in consts if c[0] == "1023" and c != (
+            "1023", "128")], consts
+    elif what == "temporaries":
+        vec = 4 * 1023 * 4096 * 16
+        assert mdc_v5e.memory_analysis().temp_size_in_bytes <= 4.1 * vec
+    else:
+        assert not re.findall(r" fft\(", text)
 
 
 def test_a_complex64_kernel_does_not_fit_a_v5e(v5e_devices):
