@@ -22,6 +22,15 @@ Where the cache lives is decided here and nowhere else, by one rule:
 The directory is part of the cache key's lookup path, so it is always a
 fixed path: never a temp name, a pid or a time.
 
+The key holds the program's metadata too
+(``jax_compilation_cache_include_metadata_in_key``): the names
+``diagnostics/trace.py`` puts into ``op_name`` are what a profile of
+this program is read by, and JAX's default key leaves them out, so a
+cache filled before a scope existed served the executable without it
+(``mdd_obc.cgls_nv16``'s solver came back from the chip machine's
+cache without the ``pmt.solver.*`` scopes: PERF.md section 6, PR 36).
+The price is one compile a program when its source moves.
+
 Multi-host contract: rank 0 writes, other ranks read — every rank
 lowers the same SPMD program, so one writer suffices and NFS cache
 dirs see no cross-rank write races. Non-zero ranks get the read-only
@@ -73,6 +82,9 @@ def maybe_enable_compile_cache(default: Optional[str] = None
         import jax
         if not os.environ.get("JAX_COMPILATION_CACHE_DIR"):
             jax.config.update("jax_compilation_cache_dir", path)
+        # a cached executable has to carry the names it is traced by
+        jax.config.update(
+            "jax_compilation_cache_include_metadata_in_key", True)
         if rank_writes():
             # bank every compile, however fast: CPU-sim programs
             # compile in ms and the defaults would skip them all

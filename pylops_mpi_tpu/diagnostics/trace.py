@@ -25,8 +25,9 @@ Two sinks, one rule (:func:`span`, which :func:`op_span` ends in):
    session is running and a flag test in C++ when none is. Named scopes
    are provenance (``metadata={op_name=…}``), never instructions:
    compare programs through ``utils/hlo.py::strip_provenance``. JAX's
-   compilation-cache key ignores metadata, so a cache filled before the
-   scopes existed serves unnamed programs.
+   compilation-cache key ignores metadata by default, and a cache
+   filled before a scope existed then serves the program without it:
+   ``aot/compile_cache.py`` puts the metadata into the key.
 2. **The ring buffer, gated** by ``PYLOPS_MPI_TPU_TRACE``:
 
 - ``off`` (default): no event is recorded and no flush handler is

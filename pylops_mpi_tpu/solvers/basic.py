@@ -543,39 +543,46 @@ def _make_cgls_body(Op, xdt, damp2, floors, *, M=None, normal=False,
             x, s, c, q, kold, iiter, cost, cost1, status = state
         else:
             x, s, c, q, kold, iiter, cost, cost1 = state
-        done = kold <= floors
-        a = _abs(kold / (_rdot(q, q) + damp2 * _rdot(c, c)))
-        a = jnp.where(done, jnp.zeros_like(a), a)
-        if stall_at is not None:
-            a = _faults.inject_stall(a, iiter, stall_at)
-        xn = x + c * _step_scalar(a, xdt)
-        sn_ = s - q * _step_scalar(a, xdt)
-        r = Op.rmatvec(sn_) - xn * damp2
+        with _trace.span("solver.step"):
+            done = kold <= floors
+            a = _abs(kold / (_rdot(q, q) + damp2 * _rdot(c, c)))
+            a = jnp.where(done, jnp.zeros_like(a), a)
+            if stall_at is not None:
+                a = _faults.inject_stall(a, iiter, stall_at)
+            xn = x + c * _step_scalar(a, xdt)
+            sn_ = s - q * _step_scalar(a, xdt)
+        r = Op.rmatvec(sn_)
+        with _trace.span("solver.direction"):
+            r = r - xn * damp2
         z = _precond_apply(M, r, xdt)
-        k = _rdot(r, z)
-        k = jnp.where(done, kold, k)
-        b = jnp.where(done, jnp.zeros_like(k), k / kold)
-        cn = z + c * _step_scalar(b, xdt)
+        with _trace.span("solver.direction"):
+            k = _rdot(r, z)
+            k = jnp.where(done, kold, k)
+            b = jnp.where(done, jnp.zeros_like(k), k / kold)
+            cn = z + c * _step_scalar(b, xdt)
         qn = Op.matvec(cn)
-        if nan_at is not None:
-            qn = _faults.inject_nan(qn, iiter, nan_at)
-        if guards:
-            bad = (jnp.any(~jnp.isfinite(a)) | jnp.any(~jnp.isfinite(k))
-                   | jnp.any(~jnp.isfinite(b)))
-            x = _reject(bad, x, xn)
-            s = _reject(bad, s, sn_)
-            c = _reject(bad, c, cn)
-            q = _reject(bad, q, qn)
-            k = jnp.where(bad, kold, k)
-            status, bestk, stall = _guard_update(status, bestk, stall,
-                                                 bad, k, done, stall_n)
-        else:
-            x, s, c, q = xn, sn_, cn, qn
-        iiter = iiter + 1
-        sn = jnp.asarray(s.norm())
-        cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
-        r2 = jnp.sqrt(sn ** 2 + damp2 * _rdot(x, x))
-        cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        with _trace.span("solver.direction"):
+            if nan_at is not None:
+                qn = _faults.inject_nan(qn, iiter, nan_at)
+            if guards:
+                bad = (jnp.any(~jnp.isfinite(a))
+                       | jnp.any(~jnp.isfinite(k))
+                       | jnp.any(~jnp.isfinite(b)))
+                x = _reject(bad, x, xn)
+                s = _reject(bad, s, sn_)
+                c = _reject(bad, c, cn)
+                q = _reject(bad, q, qn)
+                k = jnp.where(bad, kold, k)
+                status, bestk, stall = _guard_update(
+                    status, bestk, stall, bad, k, done, stall_n)
+            else:
+                x, s, c, q = xn, sn_, cn, qn
+        with _trace.span("solver.cost"):
+            iiter = iiter + 1
+            sn = jnp.asarray(s.norm())
+            cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+            r2 = jnp.sqrt(sn ** 2 + damp2 * _rdot(x, x))
+            cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
         # no-op unless telemetry is enabled (see _make_cg_body note)
         telemetry.iteration("cgls", iiter, resid=sn, k=k, alpha=a)
         if guards:
@@ -595,38 +602,42 @@ def _make_cgls_body(Op, xdt, damp2, floors, *, M=None, normal=False,
             x, s, r, c, kold, iiter, cost, cost1 = state
         done = kold <= floors
         u, q = Op.normal_matvec(c)
-        if nan_at is not None:
-            u = _faults.inject_nan(u, iiter, nan_at)
-            q = _faults.inject_nan(q, iiter, nan_at)
-        a = _abs(kold / (_rdot(q, q) + damp2 * _rdot(c, c)))
-        a = jnp.where(done, jnp.zeros_like(a), a)
-        if stall_at is not None:
-            a = _faults.inject_stall(a, iiter, stall_at)
-        xn = x + c * _step_scalar(a, xdt)
-        sn_ = s - q * _step_scalar(a, xdt)
-        rn = r - (u + c * damp2) * _step_scalar(a, xdt)
+        with _trace.span("solver.step"):
+            if nan_at is not None:
+                u = _faults.inject_nan(u, iiter, nan_at)
+                q = _faults.inject_nan(q, iiter, nan_at)
+            a = _abs(kold / (_rdot(q, q) + damp2 * _rdot(c, c)))
+            a = jnp.where(done, jnp.zeros_like(a), a)
+            if stall_at is not None:
+                a = _faults.inject_stall(a, iiter, stall_at)
+            xn = x + c * _step_scalar(a, xdt)
+            sn_ = s - q * _step_scalar(a, xdt)
+            rn = r - (u + c * damp2) * _step_scalar(a, xdt)
         zn = _precond_apply(M, rn, xdt)
-        k = _rdot(rn, zn)
-        k = jnp.where(done, kold, k)
-        b = jnp.where(done, jnp.zeros_like(k), k / kold)
-        cn = zn + c * _step_scalar(b, xdt)
-        if guards:
-            bad = (jnp.any(~jnp.isfinite(a)) | jnp.any(~jnp.isfinite(k))
-                   | jnp.any(~jnp.isfinite(b)))
-            x = _reject(bad, x, xn)
-            s = _reject(bad, s, sn_)
-            r = _reject(bad, r, rn)
-            c = _reject(bad, c, cn)
-            k = jnp.where(bad, kold, k)
-            status, bestk, stall = _guard_update(status, bestk, stall,
-                                                 bad, k, done, stall_n)
-        else:
-            x, s, r, c = xn, sn_, rn, cn
-        iiter = iiter + 1
-        sn = jnp.asarray(s.norm())
-        cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
-        r2 = jnp.sqrt(sn ** 2 + damp2 * _rdot(x, x))
-        cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        with _trace.span("solver.direction"):
+            k = _rdot(rn, zn)
+            k = jnp.where(done, kold, k)
+            b = jnp.where(done, jnp.zeros_like(k), k / kold)
+            cn = zn + c * _step_scalar(b, xdt)
+            if guards:
+                bad = (jnp.any(~jnp.isfinite(a))
+                       | jnp.any(~jnp.isfinite(k))
+                       | jnp.any(~jnp.isfinite(b)))
+                x = _reject(bad, x, xn)
+                s = _reject(bad, s, sn_)
+                r = _reject(bad, r, rn)
+                c = _reject(bad, c, cn)
+                k = jnp.where(bad, kold, k)
+                status, bestk, stall = _guard_update(
+                    status, bestk, stall, bad, k, done, stall_n)
+            else:
+                x, s, r, c = xn, sn_, rn, cn
+        with _trace.span("solver.cost"):
+            iiter = iiter + 1
+            sn = jnp.asarray(s.norm())
+            cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+            r2 = jnp.sqrt(sn ** 2 + damp2 * _rdot(x, x))
+            cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
         # no-op unless telemetry is enabled (see _make_cg_body note)
         telemetry.iteration("cgls", iiter, resid=sn, k=k, alpha=a)
         if guards:
@@ -646,24 +657,31 @@ def _cgls_setup(Op, y: Vector, x0: Vector, damp, damp2, *, niter: int,
     single-shot fused loops here and the segmented driver
     (solvers/segmented.py), which must seed the exact same carry."""
     x = x0  # donated: carry aliases the caller's buffer (see _DONATE_X0)
-    s = y - Op.matvec(x)
-    rq = Op.rmatvec(s) - x * damp  # ref's un-squared setup damp (see
-    z = _precond_apply(M, rq, _vdtype(x0))  # module doc) seeds only
-    c = z                          # the first direction, as in the
-    if not normal:                 # classic path
+    s = Op.matvec(x)
+    with _trace.span("solver.setup"):
+        s = y - s
+    rq = Op.rmatvec(s)
+    with _trace.span("solver.setup"):
+        # ref's un-squared setup damp (see module doc) seeds only the
+        # first direction, as in the classic path
+        rq = rq - x * damp
+    z = _precond_apply(M, rq, _vdtype(x0))
+    c = z
+    if not normal:
         q = Op.matvec(c)
-    kold = _rdot(rq, z)
-    floors = _mp_floor(kold)
-    if normal:
-        # the recurrence tracks the true gradient r = Opᴴs − damp²x, so
-        # it must start from the damp²-form, not the quirked one
-        r = rq + x * (damp - damp2)
-    sn0 = jnp.asarray(s.norm())
-    cost0 = jnp.zeros((niter + 1,) + jnp.shape(sn0), dtype=sn0.dtype)
-    cost0 = lax.dynamic_update_index_in_dim(cost0, sn0, 0, 0)
-    cost1_0 = lax.dynamic_update_index_in_dim(
-        jnp.zeros_like(cost0),
-        jnp.sqrt(sn0 ** 2 + damp2 * _rdot(x, x)), 0, 0)
+    with _trace.span("solver.setup"):
+        kold = _rdot(rq, z)
+        floors = _mp_floor(kold)
+        if normal:
+            # the recurrence tracks the true gradient r = Opᴴs − damp²x,
+            # so it must start from the damp²-form, not the quirked one
+            r = rq + x * (damp - damp2)
+        sn0 = jnp.asarray(s.norm())
+        cost0 = jnp.zeros((niter + 1,) + jnp.shape(sn0), dtype=sn0.dtype)
+        cost0 = lax.dynamic_update_index_in_dim(cost0, sn0, 0, 0)
+        cost1_0 = lax.dynamic_update_index_in_dim(
+            jnp.zeros_like(cost0),
+            jnp.sqrt(sn0 ** 2 + damp2 * _rdot(x, x)), 0, 0)
     if normal:
         return (x, s, r, c, kold), floors, cost0, cost1_0
     return (x, s, c, q, kold), floors, cost0, cost1_0
@@ -1019,52 +1037,59 @@ def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
                                  tol, use_normal, guards, M=M,
                                  mode=_ca_mode)
         return out + (float(jnp.max(out[4])),)
-    builder = _cgls_fused_normal if use_normal else _cgls_fused
-    # A caller's ``x0`` is NOT donated here: the program copies it into
-    # the carry at entry — the same bytes as the eager ``_donate_copy``
-    # the other solvers make, without a device op of the vector's size
-    # dispatched at the head of the solver's span. On the chip's
-    # profiler the device's clock runs ~1.2 ms ahead of the host's, so
-    # that eager copy of an 805 MB ``x0`` began 0.54-0.99 ms BEFORE the
-    # span that dispatched it in four traces, against the 1 ms the
-    # benchmark's clock check allows (PERF.md section 6, PR 32): the
-    # yardstick is not this PR's to mend, and a violation silences
-    # five per-layer metrics of a cell. ``donate`` is part of the cache
-    # key (``_get_fused``), so the two entries never mix. To go back to
-    # ``_donate_copy`` with the check's offset (PERF.md section 7).
-    donate = _DONATE_X0 if x0_owned else ()
-    key = (id(Op), "cgls", use_normal, niter, _vkey(y), _vkey(x0))
-    args = (y, x0, _scalar_operand(damp, y), _scalar_operand(tol, y))
-    if guards:
-        from ..resilience import faults as _faults, status as _rstatus
-        spec = _faults.consume()
-        stall_n = _rstatus.stall_window()
-        fn = _get_fused(Op, key + (_rstatus.guards_signature(True),
-                                   _faults.fault_signature(spec))
-                        + _mkey(M),
-                        lambda op: partial(builder, op, niter=niter,
-                                           guards=True, M=M,
-                                           stall_n=stall_n, fault=spec),
-                        donate_argnums=donate, keepalive=M,
-                        aot_eligible=(M is None and spec is None))
-        x, iiter, cost, cost1, kold, status = fn(*args)
-        iiter, cost, cost1, kmax, status = jax.device_get(
-            (iiter, cost, cost1, kold, status))
-        iiter, code = int(iiter), int(status)
-        _rstatus.record("cgls", code, iiter)
+    # The wrapper's two host phases tile it, inside the caller's
+    # ``pmt.solver.<name>`` span: ``launch`` until the fused program's
+    # asynchronous call has returned, ``collect`` until the small
+    # results are on the host. Two annotations a solve, no wait of
+    # their own.
+    with _trace.span("solver.launch", cat="solver", solver="cgls"):
+        builder = _cgls_fused_normal if use_normal else _cgls_fused
+        # A caller's ``x0`` is NOT donated here: the program copies it
+        # into the carry at entry — the same bytes as the eager
+        # ``_donate_copy`` the other solvers make, without a device op
+        # of the vector's size dispatched at the head of the solver's
+        # span. On the chip's profiler the device's clock runs ~1.2 ms
+        # ahead of the host's, so that eager copy of an 805 MB ``x0``
+        # began 0.54-0.99 ms BEFORE the span that dispatched it in four
+        # traces, against the 1 ms the benchmark's clock check allows
+        # (PERF.md section 6, PR 32): the yardstick is not this PR's to
+        # mend, and a violation silences five per-layer metrics of a
+        # cell. ``donate`` is part of the cache key (``_get_fused``), so
+        # the two entries never mix. To go back to ``_donate_copy`` with
+        # the check's offset (PERF.md section 7).
+        donate = _DONATE_X0 if x0_owned else ()
+        key = (id(Op), "cgls", use_normal, niter, _vkey(y), _vkey(x0))
+        args = (y, x0, _scalar_operand(damp, y), _scalar_operand(tol, y))
+        if guards:
+            from ..resilience import faults as _faults, status as _rstatus
+            spec = _faults.consume()
+            stall_n = _rstatus.stall_window()
+            fn = _get_fused(Op, key + (_rstatus.guards_signature(True),
+                                       _faults.fault_signature(spec))
+                            + _mkey(M),
+                            lambda op: partial(builder, op, niter=niter,
+                                               guards=True, M=M,
+                                               stall_n=stall_n,
+                                               fault=spec),
+                            donate_argnums=donate, keepalive=M,
+                            aot_eligible=(M is None and spec is None))
+        else:
+            fn = _get_fused(Op, key + _mkey(M),
+                            lambda op: partial(builder, op, niter=niter,
+                                               M=M),
+                            donate_argnums=donate, keepalive=M,
+                            aot_eligible=(M is None))
+        x, iiter, cost, cost1, kold, *status = fn(*args)
+    with _trace.span("solver.collect", cat="solver", solver="cgls"):
+        iiter, cost, cost1, kmax, *status = jax.device_get(
+            (iiter, cost, cost1, kold, *status))
+        iiter, code = int(iiter), None
+        if guards:
+            code = int(status[0])
+            _rstatus.record("cgls", code, iiter)
         _count_cgls_solve(iiter, use_normal)
         return (x, iiter, cost[:iiter + 1], cost1[:iiter + 1], kold, code,
                 float(np.max(kmax)))
-    fn = _get_fused(Op, key + _mkey(M),
-                    lambda op: partial(builder, op, niter=niter, M=M),
-                    donate_argnums=donate, keepalive=M,
-                    aot_eligible=(M is None))
-    x, iiter, cost, cost1, kold = fn(*args)
-    iiter, cost, cost1, kmax = jax.device_get((iiter, cost, cost1, kold))
-    iiter = int(iiter)
-    _count_cgls_solve(iiter, use_normal)
-    return (x, iiter, cost[:iiter + 1], cost1[:iiter + 1], kold, None,
-            float(np.max(kmax)))
 
 
 _SCALAR_OPERANDS: "OrderedDict" = OrderedDict()

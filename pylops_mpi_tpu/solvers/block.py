@@ -261,33 +261,36 @@ def _make_block_cgls_body(Op, xdt, damp2, floors, tol, *, M=None,
         if guards or carry_status:
             done = done | (status != _rstatus.RUNNING)
         u, q = Op.normal_matvec(c)
-        a = jnp.abs(kold / (_bdot(q, q) + damp2 * _bdot(c, c)))
-        a = jnp.where(done, jnp.zeros_like(a), a)
-        xn = x + c * _step_scalar(a, xdt)
-        sn_ = s - q * _step_scalar(a, xdt)
-        rn = r - (u + c * damp2) * _step_scalar(a, xdt)
+        with _trace.span("solver.step"):
+            a = jnp.abs(kold / (_bdot(q, q) + damp2 * _bdot(c, c)))
+            a = jnp.where(done, jnp.zeros_like(a), a)
+            xn = x + c * _step_scalar(a, xdt)
+            sn_ = s - q * _step_scalar(a, xdt)
+            rn = r - (u + c * damp2) * _step_scalar(a, xdt)
         zn = _precond_apply(M, rn, xdt)
-        k = _bdot(rn, zn)
-        k = jnp.where(done, kold, k)
-        b = jnp.where(done, jnp.zeros_like(k), k / kold)
-        cn = zn + c * _step_scalar(b, xdt)
-        if guards:
-            bad = (~jnp.isfinite(a)) | (~jnp.isfinite(k)) \
-                | (~jnp.isfinite(b))
-            x = _reject(bad, x, xn)
-            s = _reject(bad, s, sn_)
-            r = _reject(bad, r, rn)
-            c = _reject(bad, c, cn)
-            k = jnp.where(bad, kold, k)
-            status, bestk, stall = _bguard_update(status, bestk, stall,
-                                                  bad, k, done, stall_n)
-        else:
-            x, s, r, c = xn, sn_, rn, cn
-        iiter = iiter + 1
-        sn = jnp.sqrt(_bdot(s, s))
-        cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
-        r2 = jnp.sqrt(sn ** 2 + damp2 * _bdot(x, x))
-        cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        with _trace.span("solver.direction"):
+            k = _bdot(rn, zn)
+            k = jnp.where(done, kold, k)
+            b = jnp.where(done, jnp.zeros_like(k), k / kold)
+            cn = zn + c * _step_scalar(b, xdt)
+            if guards:
+                bad = (~jnp.isfinite(a)) | (~jnp.isfinite(k)) \
+                    | (~jnp.isfinite(b))
+                x = _reject(bad, x, xn)
+                s = _reject(bad, s, sn_)
+                r = _reject(bad, r, rn)
+                c = _reject(bad, c, cn)
+                k = jnp.where(bad, kold, k)
+                status, bestk, stall = _bguard_update(
+                    status, bestk, stall, bad, k, done, stall_n)
+            else:
+                x, s, r, c = xn, sn_, rn, cn
+        with _trace.span("solver.cost"):
+            iiter = iiter + 1
+            sn = jnp.sqrt(_bdot(s, s))
+            cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+            r2 = jnp.sqrt(sn ** 2 + damp2 * _bdot(x, x))
+            cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
         telemetry.iteration("block_cgls", iiter, resid=sn, k=k, alpha=a)
         if guards:
             return (x, s, r, c, k, iiter, cost, cost1, status, bestk,
@@ -310,34 +313,40 @@ def _make_block_cgls_body(Op, xdt, damp2, floors, tol, *, M=None,
         done = kold <= jnp.maximum(floors, tol)
         if guards or carry_status:
             done = done | (status != _rstatus.RUNNING)
-        a = jnp.abs(kold / (_bdot(q, q) + damp2 * _bdot(c, c)))
-        a = jnp.where(done, jnp.zeros_like(a), a)
-        xn = x + c * _step_scalar(a, xdt)
-        sn_ = s - q * _step_scalar(a, xdt)
-        r = Op.rmatvec(sn_) - xn * damp2
+        with _trace.span("solver.step"):
+            a = jnp.abs(kold / (_bdot(q, q) + damp2 * _bdot(c, c)))
+            a = jnp.where(done, jnp.zeros_like(a), a)
+            xn = x + c * _step_scalar(a, xdt)
+            sn_ = s - q * _step_scalar(a, xdt)
+        r = Op.rmatvec(sn_)
+        with _trace.span("solver.direction"):
+            r = r - xn * damp2
         z = _precond_apply(M, r, xdt)
-        k = _bdot(r, z)
-        k = jnp.where(done, kold, k)
-        b = jnp.where(done, jnp.zeros_like(k), k / kold)
-        cn = z + c * _step_scalar(b, xdt)
+        with _trace.span("solver.direction"):
+            k = _bdot(r, z)
+            k = jnp.where(done, kold, k)
+            b = jnp.where(done, jnp.zeros_like(k), k / kold)
+            cn = z + c * _step_scalar(b, xdt)
         qn = Op.matvec(cn)
-        if guards:
-            bad = (~jnp.isfinite(a)) | (~jnp.isfinite(k)) \
-                | (~jnp.isfinite(b))
-            x = _reject(bad, x, xn)
-            s = _reject(bad, s, sn_)
-            c = _reject(bad, c, cn)
-            q = _reject(bad, q, qn)
-            k = jnp.where(bad, kold, k)
-            status, bestk, stall = _bguard_update(status, bestk, stall,
-                                                  bad, k, done, stall_n)
-        else:
-            x, s, c, q = xn, sn_, cn, qn
-        iiter = iiter + 1
-        sn = jnp.sqrt(_bdot(s, s))
-        cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
-        r2 = jnp.sqrt(sn ** 2 + damp2 * _bdot(x, x))
-        cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        with _trace.span("solver.direction"):
+            if guards:
+                bad = (~jnp.isfinite(a)) | (~jnp.isfinite(k)) \
+                    | (~jnp.isfinite(b))
+                x = _reject(bad, x, xn)
+                s = _reject(bad, s, sn_)
+                c = _reject(bad, c, cn)
+                q = _reject(bad, q, qn)
+                k = jnp.where(bad, kold, k)
+                status, bestk, stall = _bguard_update(
+                    status, bestk, stall, bad, k, done, stall_n)
+            else:
+                x, s, c, q = xn, sn_, cn, qn
+        with _trace.span("solver.cost"):
+            iiter = iiter + 1
+            sn = jnp.sqrt(_bdot(s, s))
+            cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+            r2 = jnp.sqrt(sn ** 2 + damp2 * _bdot(x, x))
+            cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
         telemetry.iteration("block_cgls", iiter, resid=sn, k=k, alpha=a)
         if guards:
             return (x, s, c, q, k, iiter, cost, cost1, status, bestk,
@@ -356,24 +365,32 @@ def _block_cgls_fused(Op, y, x0, damp, tol, *, niter: int, M=None,
     damp2 = damp ** 2
     xdt = _vdtype(x0)
     x = x0  # donated (see _DONATE_X0)
-    s = y - Op.matvec(x)
-    rq = Op.rmatvec(s) - x * damp  # the reference's un-squared setup
-    z = _precond_apply(M, rq, xdt)  # damp quirk (solvers/basic module
-    c = z                           # doc); M seeds the first direction
+    s = Op.matvec(x)
+    with _trace.span("solver.setup"):
+        s = y - s
+    rq = Op.rmatvec(s)
+    with _trace.span("solver.setup"):
+        # the reference's un-squared setup damp quirk (solvers/basic
+        # module doc); M seeds the first direction
+        rq = rq - x * damp
+    z = _precond_apply(M, rq, xdt)
+    c = z
     if normal:
-        # the recurrence tracks the true gradient Opᴴs − damp²x: seeded
-        # from the damp²-form, as basic._cgls_setup does
-        head = (x, s, rq + x * (damp - damp2), c)
+        with _trace.span("solver.setup"):
+            # the recurrence tracks the true gradient Opᴴs − damp²x:
+            # seeded from the damp²-form, as basic._cgls_setup does
+            head = (x, s, rq + x * (damp - damp2), c)
     else:
         head = (x, s, c, Op.matvec(c))
-    kold = _bdot(rq, z)
-    floors = _mp_floor(kold)
-    sn0 = jnp.sqrt(_bdot(s, s))
-    cost0 = jnp.zeros((niter + 1,) + jnp.shape(sn0), dtype=sn0.dtype)
-    cost0 = lax.dynamic_update_index_in_dim(cost0, sn0, 0, 0)
-    cost1_0 = lax.dynamic_update_index_in_dim(
-        jnp.zeros_like(cost0),
-        jnp.sqrt(sn0 ** 2 + damp2 * _bdot(x, x)), 0, 0)
+    with _trace.span("solver.setup"):
+        kold = _bdot(rq, z)
+        floors = _mp_floor(kold)
+        sn0 = jnp.sqrt(_bdot(s, s))
+        cost0 = jnp.zeros((niter + 1,) + jnp.shape(sn0), dtype=sn0.dtype)
+        cost0 = lax.dynamic_update_index_in_dim(cost0, sn0, 0, 0)
+        cost1_0 = lax.dynamic_update_index_in_dim(
+            jnp.zeros_like(cost0),
+            jnp.sqrt(sn0 ** 2 + damp2 * _bdot(x, x)), 0, 0)
     body = _make_block_cgls_body(Op, xdt, damp2, floors, tol, M=M,
                                  normal=normal, guards=guards,
                                  stall_n=stall_n)
@@ -501,19 +518,21 @@ def _run_block_cgls_fused(Op, y, x0, niter, damp, tol, M=None,
     executable set. ``use_normal`` (the sweep schedule, resolved by the
     caller) is part of the key, and so of the AOT bank's: a flip never
     meets a stale executable."""
-    fn = _get_fused(Op, (id(Op), "block_cgls", use_normal, niter,
-                         _vkey(y), _vkey(x0)) + _mkey(M),
-                    lambda op: partial(_block_cgls_fused, op,
-                                       niter=niter, M=M,
-                                       normal=use_normal),
-                    donate_argnums=_DONATE_X0, keepalive=M,
-                    aot_eligible=(M is None))
-    x, iiter, cost, cost1, kold = fn(
-        y, x0 if x0_owned else _donate_copy(x0), damp, tol)
-    iiter = int(iiter)
-    _count_cgls_solve(iiter, use_normal, "block_cgls")
-    return (x, iiter, np.asarray(cost)[:iiter + 1],
-            np.asarray(cost1)[:iiter + 1], np.asarray(kold))
+    with _trace.span("solver.launch", cat="solver", solver="block_cgls"):
+        fn = _get_fused(Op, (id(Op), "block_cgls", use_normal, niter,
+                             _vkey(y), _vkey(x0)) + _mkey(M),
+                        lambda op: partial(_block_cgls_fused, op,
+                                           niter=niter, M=M,
+                                           normal=use_normal),
+                        donate_argnums=_DONATE_X0, keepalive=M,
+                        aot_eligible=(M is None))
+        x, iiter, cost, cost1, kold = fn(
+            y, x0 if x0_owned else _donate_copy(x0), damp, tol)
+    with _trace.span("solver.collect", cat="solver", solver="block_cgls"):
+        iiter = int(iiter)
+        _count_cgls_solve(iiter, use_normal, "block_cgls")
+        return (x, iiter, np.asarray(cost)[:iiter + 1],
+                np.asarray(cost1)[:iiter + 1], np.asarray(kold))
 
 
 def block_cgls(Op, y: DistributedArray,
@@ -585,34 +604,38 @@ def block_cgls(Op, y: DistributedArray,
                                       use_normal=use_normal)
         if use_guards:
             from ..resilience import status as _rstatus
-            stall_n = _rstatus.stall_window()
-            fn = _get_fused(
-                Op, (id(Op), "block_cgls", use_normal, niter, _vkey(y),
-                     _vkey(x0), _rstatus.guards_signature(True))
-                + _mkey(M),
-                lambda op: partial(_block_cgls_fused, op, niter=niter,
-                                   M=M, normal=use_normal, guards=True,
-                                   stall_n=stall_n),
-                donate_argnums=_DONATE_X0, keepalive=M,
-                aot_eligible=(M is None))
-            x, iiter, cost, cost1, kold, status = fn(
-                y, x0 if x0_owned else _donate_copy(x0), damp, tol)
-            iiter = int(iiter)
-            _count_cgls_solve(iiter, use_normal, "block_cgls")
-            _rstatus.record_columns(
-                "block_cgls", [int(cd) for cd in np.asarray(status)],
-                iiter)
-        else:
-            x, iiter, cost, cost1, kold = _run_block_cgls_fused(
-                Op, y, x0, niter, damp, tol, M=M, x0_owned=x0_owned,
-                use_normal=use_normal)
-            return (x, np.where(kold < tol, 1, 2), iiter, kold,
-                    cost1[-1], cost)
-        kold = np.asarray(kold)
-        istop = np.where(kold < tol, 1, 2)
-        return (x, istop, iiter, kold,
-                np.asarray(cost1)[iiter],
-                np.asarray(cost)[:iiter + 1])
+            with _trace.span("solver.launch", cat="solver",
+                             solver="block_cgls"):
+                stall_n = _rstatus.stall_window()
+                fn = _get_fused(
+                    Op, (id(Op), "block_cgls", use_normal, niter,
+                         _vkey(y), _vkey(x0),
+                         _rstatus.guards_signature(True)) + _mkey(M),
+                    lambda op: partial(_block_cgls_fused, op,
+                                       niter=niter, M=M,
+                                       normal=use_normal, guards=True,
+                                       stall_n=stall_n),
+                    donate_argnums=_DONATE_X0, keepalive=M,
+                    aot_eligible=(M is None))
+                x, iiter, cost, cost1, kold, status = fn(
+                    y, x0 if x0_owned else _donate_copy(x0), damp, tol)
+            with _trace.span("solver.collect", cat="solver",
+                             solver="block_cgls"):
+                iiter = int(iiter)
+                _count_cgls_solve(iiter, use_normal, "block_cgls")
+                _rstatus.record_columns(
+                    "block_cgls", [int(cd) for cd in np.asarray(status)],
+                    iiter)
+                kold = np.asarray(kold)
+                istop = np.where(kold < tol, 1, 2)
+                return (x, istop, iiter, kold,
+                        np.asarray(cost1)[iiter],
+                        np.asarray(cost)[:iiter + 1])
+        x, iiter, cost, cost1, kold = _run_block_cgls_fused(
+            Op, y, x0, niter, damp, tol, M=M, x0_owned=x0_owned,
+            use_normal=use_normal)
+        return (x, np.where(kold < tol, 1, 2), iiter, kold,
+                cost1[-1], cost)
 
 
 # ------------------------------------------------------ segmented blocks

@@ -482,7 +482,8 @@ def cache_config(monkeypatch):
     saved = (jax.config.jax_compilation_cache_dir,
              jax.config.jax_persistent_cache_min_compile_time_secs,
              jax.config.jax_persistent_cache_min_entry_size_bytes,
-             cc._enabled_dir)
+             cc._enabled_dir,
+             jax.config.jax_compilation_cache_include_metadata_in_key)
     monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
     monkeypatch.delenv("PYLOPS_MPI_TPU_COMPILE_CACHE", raising=False)
     cc._enabled_dir = None
@@ -493,6 +494,8 @@ def cache_config(monkeypatch):
     jax.config.update("jax_persistent_cache_min_entry_size_bytes",
                       saved[2])
     cc._enabled_dir = saved[3]
+    jax.config.update("jax_compilation_cache_include_metadata_in_key",
+                      saved[4])
 
 
 def test_compile_cache_unset_is_noop(cache_config):
@@ -513,6 +516,8 @@ def test_compile_cache_entry_default(cache_config, tmp_path):
     assert jax.config.jax_compilation_cache_dir == d
     assert jax.config.jax_persistent_cache_min_compile_time_secs == 0.0
     assert jax.config.jax_persistent_cache_min_entry_size_bytes == 0
+    # the names a profile is read by are part of the key
+    assert jax.config.jax_compilation_cache_include_metadata_in_key
     assert cache_config.maybe_enable_compile_cache(d) == d  # idempotent
 
 
