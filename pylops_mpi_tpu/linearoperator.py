@@ -58,6 +58,12 @@ class MPILinearOperator:
         if Op is not None:
             self.shape = Op.shape if shape is None else shape
             self.dtype = Op.dtype if dtype is None else dtype
+            if shape is None:
+                # the wrapped local operator's N-D shapes, as every
+                # lazy wrapper forwards its operand's (metadata: the
+                # applies work on the flat vector)
+                self.dims = getattr(Op, "dims", None)
+                self.dimsd = getattr(Op, "dimsd", None)
         else:
             self.shape = shape
             self.dtype = np.dtype(dtype) if dtype is not None else None

@@ -43,6 +43,18 @@ def _chunk_ops(ops: Sequence, n_shards: int) -> List[List]:
     return chunks
 
 
+def _stacked_dims(dims: Sequence[tuple]) -> Optional[tuple]:
+    """N-D shape of blocks laid one after another along their leading
+    axis: ``(sum n_i,) + trailing`` where every block is N-D with the
+    same trailing axes (post-stack modelling's ``(ny_i, nx, nt0)``
+    blocks are the ``(ny, nx, nt0)`` cube), else ``None`` (flat)."""
+    trailing = {tuple(d[1:]) for d in dims}
+    if len(trailing) != 1 or any(len(d) < 2 for d in dims):
+        return None
+    return (int(sum(d[0] for d in dims)),) + tuple(
+        int(n) for n in trailing.pop())
+
+
 def _ncols(x: DistributedArray) -> int:
     """Columns of a vector (1) or of the block solvers' ``(rows, K)``
     vectors (K)."""
@@ -79,6 +91,13 @@ class MPIBlockDiag(MPILinearOperator):
         keeps the default solve classic; otherwise the solve takes the
         one-sweep schedule where the kernel is compiled (a TPU) with a
         row tile the chip has shown faster than two sweeps.
+
+    ``dims`` / ``dimsd`` are metadata read from the blocks, no apply
+    uses them: N-D blocks with equal trailing axes declare the cube
+    they stack into (``(sum ny_i, nx, nt0)``), anything else — every
+    ``MatrixMult`` — the flat ``(shape[1],)`` / ``(shape[0],)``. The
+    fused solvers hold their carries in that shape
+    (``solvers/basic.py::_carry_shape``).
     """
 
     def __init__(self, ops: Sequence[LocalOperator],
@@ -107,6 +126,8 @@ class MPIBlockDiag(MPILinearOperator):
             (int(sum(op.shape[1] for op in c)),) for c in self.chunks)
         shape = (int(nops.sum()), int(mops.sum()))
         dtype = dtype or np.result_type(*[op.dtype for op in self.ops])
+        self.dims = _stacked_dims([op.dims for op in self.ops])
+        self.dimsd = _stacked_dims([op.dimsd for op in self.ops])
         super().__init__(shape=shape, dtype=dtype)
         if self.compute_dtype is None:  # env-policy default (f32 only)
             from ._precision import default_compute_dtype

@@ -332,12 +332,21 @@ class MPIVStack(MPILinearOperator):
 class MPIStackedVStack(MPIStackedLinearOperator):
     """Vertical stack of distributed operators: one shared model, stacked
     data (ref ``VStack.py:153-203``). Output is a StackedDistributedArray
-    with one component per operator."""
+    with one component per operator.
+
+    ``dims`` / ``dimsd`` are metadata read from the children, no apply
+    uses them: ``dims`` is the children's where they all declare the
+    same (else the flat ``(shape[1],)``), ``dimsd`` one tuple a child,
+    as the stacked data has one component a child. The fused solvers
+    hold their carries so (``solvers/basic.py::_carry_shape``)."""
 
     def __init__(self, ops: Sequence[MPILinearOperator]):
         self.ops = list(ops)
         if len({op.shape[1] for op in self.ops}) != 1:
             raise ValueError("column size mismatch in MPIStackedVStack")
+        dims = {tuple(op.dims) for op in self.ops}
+        self.dims = dims.pop() if len(dims) == 1 else None
+        self.dimsd = tuple(tuple(op.dimsd) for op in self.ops)
         shape = (int(sum(op.shape[0] for op in self.ops)), self.ops[0].shape[1])
         dtype = np.result_type(*[op.dtype for op in self.ops])
         super().__init__(shape=shape, dtype=dtype)

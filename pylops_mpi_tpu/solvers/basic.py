@@ -33,7 +33,7 @@ import jax
 import jax.numpy as jnp
 from jax import lax
 
-from ..distributedarray import DistributedArray
+from ..distributedarray import DistributedArray, Partition
 from ..stacked import StackedDistributedArray
 from ..diagnostics import metrics as _metrics
 from ..diagnostics import telemetry, trace as _trace
@@ -337,7 +337,12 @@ class CGLS(_BaseSolver):
 # the caller's buffer instead of a program-entry copy, which is why
 # the builders bind the carry as ``x = x0`` (a traced ``x0.copy()``
 # would be exactly the copy-of-donated-state the HLO pin forbids —
-# tests/test_precision.py::test_fused_cgls_donation).
+# tests/test_precision.py::test_fused_cgls_donation). Where the loop
+# holds ``x`` N-D (``_while_carried``) the donation still holds — the
+# program's ``input_output_alias`` pairs ``x0`` with the flat ``x`` it
+# returns, compiled for a v5e as on the CPU — but the carry is then the
+# entry's relayout of that buffer, not the buffer in place: the exit's
+# flatten writes the answer back into it.
 #
 # In-loop guards (ISSUE 6): every builder takes a static ``guards``
 # flag. ``guards=False`` (the default, and the only mode when
@@ -411,6 +416,159 @@ def _fault_sites(guards: bool, fault):
     if fault.get("kind") == "stall":
         return None, fault["iteration"]
     return None, None
+
+
+# Shaped carries. The solvers' vectors are flat; an operator that works
+# on an N-D cube reshapes at its edges, and on a TPU a flat
+# ``f32[V]{T(1024)}`` against a tiled ``(ny, nx, nt){T(8,128)}`` cube is
+# a relayout COPY of the whole vector each time (seven an iteration,
+# 17 of 70 ms, in the stacked post-stack system; PERF.md section 6,
+# PR 37). So the fused loops hold each vector of their carry in the
+# shape its operator declares (``dims`` model side, ``dimsd`` data
+# side): one reshape at the loop's entry, one at its exit, and the
+# UNCHANGED flat body between ``flatten`` and ``shape`` — XLA cancels
+# every ``reshape(-1)`` / ``reshape(dims)`` pair at the operators'
+# edges. The shape is data read from the operator and the vector; no
+# keyword, no environment variable, no operator's class name.
+_LANES = 128  # a TPU tile's minor extent: a narrower minor axis pads
+
+
+def _answers(got):
+    """``[(shape, why), ...]`` as one answer: the shapes as a list and
+    the first ``why`` of a vector left flat."""
+    return [s for s, _ in got], next((w for _, w in got if w), None)
+
+
+def _carry_shape(v: Vector, dims):
+    """``(shape, why)`` for one vector of a fused loop's carry: the
+    N-D shape to hold it in given what the operator declares for its
+    side, or ``None`` (flat, the program as it was) with the one word
+    ``solver.carry_select`` gives as ``why``:
+
+    - ``columns``: the vector has columns (``ndim == 2``);
+    - ``undeclared``: ``dims`` is not two or more axes that multiply to
+      the vector's length (a stacked vector wants one such tuple a
+      component);
+    - ``lanes``: folding trailing axes together until the minor extent
+      is a multiple of 128 lanes leaves one axis (``(1023, 4096, 16)``
+      folds to ``(1023, 65536)``; a 16-lane minor axis would pad 8 x);
+    - ``ragged``: a ``SCATTER`` vector whose shards are not the
+      balanced, unpadded split into whole leading rows (``BROADCAST``
+      vectors are whole on every shard).
+
+    A ``StackedDistributedArray`` answers by component, as a list."""
+    if isinstance(v, StackedDistributedArray):
+        by_part = (isinstance(dims, (tuple, list))
+                   and len(dims) == v.narrays
+                   and all(isinstance(d, (tuple, list)) for d in dims))
+        return _answers([_carry_shape(d, p) for d, p in zip(
+            v.distarrays, dims if by_part else (None,) * v.narrays)])
+    if v.ndim != 1:
+        return None, "columns"
+    if (not isinstance(dims, (tuple, list)) or len(dims) < 2
+            or not all(isinstance(d, (int, np.integer)) for d in dims)
+            or int(np.prod(dims, dtype=np.int64)) != v.size):
+        return None, "undeclared"
+    shape = tuple(int(d) for d in dims)
+    while len(shape) > 1 and shape[-1] % _LANES:
+        shape = shape[:-2] + (shape[-2] * shape[-1],)
+    if len(shape) == 1:
+        return None, "lanes"
+    if v.partition == Partition.SCATTER:
+        rows, rem = divmod(shape[0], v.n_shards)
+        if rem or v._axis_sizes != (rows * (v.size // shape[0]),) \
+                * v.n_shards:
+            return None, "ragged"
+    return shape, None
+
+
+def _shaped(shape) -> bool:
+    """Does an answer of ``_carry_shape`` (a shape, ``None``, or a list
+    of answers) hold any vector N-D?"""
+    if isinstance(shape, list):
+        return any(map(_shaped, shape))
+    return shape is not None
+
+
+def _carry_shapes(Op, vectors, sides):
+    """The one place that decides the shapes of a fused loop's carry:
+    for each of ``vectors`` (the loop's leading state, or the caller's
+    ``(x0, y)``) the answer of :func:`_carry_shape` against the
+    operator's ``dims`` (side ``"dims"``: model vectors) or ``dimsd``
+    (data vectors), and the first ``why`` of a vector left flat.
+    Decided a vector, not a side: ``x`` lies as the caller split it,
+    ``c`` and ``q`` as the operator does."""
+    return _answers([_carry_shape(v, getattr(Op, side, None))
+                     for v, side in zip(vectors, sides)])
+
+
+def _hold(v: Vector, shape) -> Vector:
+    """``v`` as the loop carries it: an N-D ``DistributedArray`` split
+    along axis 0 over the same shards (the reshape of a balanced flat
+    split into whole rows moves nothing), or ``v`` itself."""
+    if isinstance(v, StackedDistributedArray):
+        return StackedDistributedArray(
+            [_hold(d, s) for d, s in zip(v.distarrays, shape)])
+    if shape is None:
+        return v
+    nd = DistributedArray(global_shape=shape, mesh=v.mesh,
+                          partition=v.partition, axis=0, mask=v.mask,
+                          dtype=v.dtype)
+    nd[:] = v._arr.reshape(shape)
+    return nd
+
+
+def _unhold(v: Vector, shape) -> Vector:
+    """The flat vector the recurrence and every operator work on."""
+    if isinstance(v, StackedDistributedArray):
+        return StackedDistributedArray(
+            [_unhold(d, s) for d, s in zip(v.distarrays, shape)])
+    return v if shape is None else v.ravel()
+
+
+def _while_carried(solver: str, Op, cond, body, state, sides):
+    """The one rule that launches a fused loop: ``lax.while_loop`` over
+    ``state`` whose leading ``len(sides)`` entries are the vectors,
+    each held in the shape :func:`_carry_shapes` answers and handed to
+    the unchanged flat ``cond`` / ``body`` through ``flatten``. All
+    flat is ``lax.while_loop(cond, body, state)`` itself: the program
+    as it was, byte for byte. ``solver.carry_select`` (``solver``,
+    ``shaped`` 0/1, ``model`` and ``data`` — the shapes of ``x`` and of
+    the first data-side vector —, a one-word ``why`` for what stayed
+    flat) says which under ``PYLOPS_MPI_TPU_TRACE``, once a trace."""
+    n = len(sides)
+    shapes, why = _carry_shapes(Op, state[:n], sides)
+    data = [s for s, side in zip(shapes, sides) if side == "dimsd"]
+    _trace.event("solver.carry_select", cat="schedule", solver=solver,
+                 shaped=int(_shaped(shapes)), model=shapes[0],
+                 data=data[0] if data else None,
+                 **({"why": why} if why else {}))
+    if not _shaped(shapes):
+        return lax.while_loop(cond, body, state)
+
+    def shape(st):
+        return tuple(map(_hold, st[:n], shapes)) + tuple(st[n:])
+
+    def flatten(st):
+        return tuple(map(_unhold, st[:n], shapes)) + tuple(st[n:])
+
+    return flatten(lax.while_loop(lambda st: cond(flatten(st)),
+                                  lambda st: shape(body(flatten(st))),
+                                  shape(state)))
+
+
+_CG_SIDES = ("dims",) * 3   # CG's (x, r, c): a square operator's model
+
+
+def _carry_tag(Op, x0: Vector, y: Optional[Vector], fused: bool) -> str:
+    """The ``carry`` tag of the ``pmt.solver.cgls`` / ``cg`` spans:
+    ``shaped`` where the fused loop would hold the caller's vectors
+    N-D, else ``flat`` (the host's reading of the rule the traced
+    program applies; the classic fused loops', as ``normal`` is)."""
+    vectors, sides = ((x0,), ("dims",)) if y is None \
+        else ((x0, y), ("dims", "dimsd"))
+    return "shaped" if fused and _shaped(
+        _carry_shapes(Op, vectors, sides)[0]) else "flat"
 
 
 def _make_cg_body(Op, xdt, floors, *, M=None, guards=False,
@@ -510,7 +668,7 @@ def _cg_fused(Op, y: Vector, x0: Vector, tol, *, niter: int, M=None,
                     & (state[6] == _rstatus.RUNNING))
 
         x, r, c, kold, iiter, cost, status, _, _ = \
-            lax.while_loop(cond, body, state)
+            _while_carried("cg", Op, cond, body, state, _CG_SIDES)
         return x, iiter, cost, _resolve_status(status, kold, tol)
 
     def cond(state):
@@ -518,7 +676,8 @@ def _cg_fused(Op, y: Vector, x0: Vector, tol, *, niter: int, M=None,
         return (iiter < niter) & (jnp.max(kold) > tol)
 
     state = (x, r, c, kold, jnp.asarray(0), cost0)
-    x, r, c, kold, iiter, cost = lax.while_loop(cond, body, state)
+    x, r, c, kold, iiter, cost = _while_carried("cg", Op, cond, body,
+                                                state, _CG_SIDES)
     return x, iiter, cost
 
 
@@ -690,6 +849,26 @@ def _cgls_setup(Op, y: Vector, x0: Vector, damp, damp2, *, niter: int,
 def _cgls_fused_any(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
                     normal: bool, guards: bool, M=None, stall_n: int = 0,
                     fault=None):
+    """The fused CGLS program, both sweep schedules, guards on and off:
+    :func:`_cgls_setup`, then :func:`_make_cgls_body` under
+    ``lax.while_loop``. Flat vectors in, a flat ``x`` out, as
+    ``pmt.cgls`` promises.
+
+    Inside, the loop holds each of its four vectors in the shape the
+    operator declares for its side (:func:`_while_carried`:
+    ``(192, 1024, 1024)`` cubes in the stacked post-stack system,
+    ``(1023, 65536)`` under ``MPIMDC``, flat wherever
+    :func:`_carry_shape` says so): ONE reshape of ``head``'s vectors
+    after the set-up — on a TPU a relayout of each, once a solve — and
+    ONE flatten of ``x`` at the exit, in place of a relayout copy at
+    every operator's edge every iteration. The set-up, the body and
+    every operator's apply are the flat ones, unchanged (so
+    ``solvers/segmented.py``, which seeds the same carry and runs the
+    same body, still carries flat); only the order in which a
+    reduction's partial sums meet can differ from the flat program's.
+    A fresh ``x0`` is donated as before (see ``_DONATE_X0``); a
+    caller's is copied into the carry at entry (``_run_cgls_fused``),
+    which the entry's reshape now is."""
     damp2 = damp ** 2
     xdt = _vdtype(x0)
     head, floors, cost0, cost1_0 = _cgls_setup(Op, y, x0, damp, damp2,
@@ -697,6 +876,9 @@ def _cgls_fused_any(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
                                                M=M)
     body = _make_cgls_body(Op, xdt, damp2, floors, M=M, normal=normal,
                            guards=guards, stall_n=stall_n, fault=fault)
+    # head's vectors: (x, s, r, c) one-sweep, (x, s, c, q) classic
+    sides = (("dims", "dimsd", "dims", "dims") if normal
+             else ("dims", "dimsd", "dims", "dimsd"))
     if guards:
         from ..resilience import status as _rstatus
         kold0 = head[4]
@@ -707,7 +889,7 @@ def _cgls_fused_any(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
             return ((state[5] < niter) & (jnp.max(state[4]) > tol)
                     & (state[8] == _rstatus.RUNNING))
 
-        out = lax.while_loop(cond, body, state)
+        out = _while_carried("cgls", Op, cond, body, state, sides)
         x, kold, iiter, cost, cost1, status = (out[0], out[4], out[5],
                                                out[6], out[7], out[8])
         return (x, iiter, cost, cost1, kold,
@@ -717,7 +899,7 @@ def _cgls_fused_any(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
         return (state[5] < niter) & (jnp.max(state[4]) > tol)
 
     state = head + (jnp.asarray(0), cost0, cost1_0)
-    out = lax.while_loop(cond, body, state)
+    out = _while_carried("cgls", Op, cond, body, state, sides)
     return out[0], out[5], out[6], out[7], out[4]
 
 
@@ -950,6 +1132,7 @@ def cg(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
     with _trace.span("solver.cg", cat="solver", op=type(Op).__name__,
                      shape=Op.shape, dtype=_vdtype(x0), niter=niter,
                      tol=tol, fused=use_fused, guards=use_guards,
+                     carry=_carry_tag(Op, x0, None, use_fused),
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cg"):
         if use_fused:
@@ -977,6 +1160,7 @@ def cg_guarded(Op, y: Vector, x0: Optional[Vector] = None,
     with _trace.span("solver.cg", cat="solver", op=type(Op).__name__,
                      shape=Op.shape, dtype=_vdtype(x0), niter=niter,
                      tol=tol, fused=True, guards=True,
+                     carry=_carry_tag(Op, x0, None, True),
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cg"):
         return _run_cg_fused(Op, y, x0, x0_owned, niter, tol, True, M=M)
@@ -1192,6 +1376,7 @@ def cgls(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
                      shape=Op.shape, dtype=_vdtype(x0), niter=niter,
                      damp=damp, tol=tol, fused=use_fused,
                      normal=use_normal, guards=use_guards,
+                     carry=_carry_tag(Op, x0, y, use_fused),
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cgls"):
         if use_fused:
@@ -1221,6 +1406,7 @@ def cgls_guarded(Op, y: Vector, x0: Optional[Vector] = None,
                      shape=Op.shape, dtype=_vdtype(x0), niter=niter,
                      damp=damp, tol=tol, fused=True,
                      normal=use_normal, guards=True,
+                     carry=_carry_tag(Op, x0, y, True),
                      telemetry=telemetry.telemetry_enabled()), \
             _metrics.timer("solver.cgls"):
         return _run_cgls_fused(Op, y, x0, x0_owned, niter, damp, tol,

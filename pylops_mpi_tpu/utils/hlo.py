@@ -34,6 +34,7 @@ __all__ = ["collective_report", "assert_no_full_gather",
            "count_collectives", "assert_ring_schedule",
            "host_callback_lines", "count_host_callbacks",
            "assert_no_host_callbacks", "while_body_computations",
+           "while_body_instructions",
            "count_reductions", "assert_single_reduction"]
 
 # HLO opcode -> canonical name; bytes counted from the result shape
@@ -521,6 +522,12 @@ def _callees(lines: list) -> set:
     return out
 
 
+def _while_bodies(comps: Dict[str, list]) -> set:
+    """Names of the computations some ``while`` names as its body."""
+    return {m.group(1) for lines in comps.values() for line in lines
+            for m in [_WHILE_BODY_RE.search(line)] if m is not None}
+
+
 def while_body_computations(hlo: str) -> set:
     """Names of every while-loop body computation in the module plus
     everything those bodies transitively call. This is the scope the
@@ -528,15 +535,9 @@ def while_body_computations(hlo: str) -> set:
     ``kold0`` dot outside the loop) must not leak into a
     per-iteration count."""
     comps = _computations(hlo)
-    roots = set()
-    for lines in comps.values():
-        for line in lines:
-            m = _WHILE_BODY_RE.search(line)
-            if m is not None:
-                roots.add(m.group(1))
     # transitive closure over called computations
     seen = set()
-    stack = list(roots)
+    stack = list(_while_bodies(comps))
     while stack:
         name = stack.pop()
         if name in seen or name not in comps:
@@ -544,6 +545,16 @@ def while_body_computations(hlo: str) -> set:
         seen.add(name)
         stack.extend(_callees(comps[name]))
     return seen
+
+
+def while_body_instructions(hlo: str) -> list:
+    """The instruction lines of every while-loop body's OWN computation
+    (not of the fusions and reducers it calls): what runs as an
+    instruction of its own each iteration — where a relayout ``copy``
+    or ``reshape`` of a whole carry shows."""
+    comps = _computations(hlo)
+    return [line for name in sorted(_while_bodies(comps))
+            for line in comps.get(name, []) if "=" in line]
 
 
 _REDUCE_RE = re.compile(r"\ball-reduce(-start)?(?:\.\d+)?\(")

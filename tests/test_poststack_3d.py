@@ -377,6 +377,90 @@ def test_mdc_solver_v5e_makes_only_the_bins_it_keeps(mdc_v5e, what):
         assert not re.findall(r" fft\(", text)
 
 
+@pytest.fixture(scope="module")
+def poststack_v5e(v5e_devices):
+    """The stacked post-stack system's fused solver (the program
+    ``poststack_3d.reg_cgls`` runs: two-sweep CGLS with an ``x0``)
+    compiled for one described chip on a small cube of whole tiles
+    (``nx % 8 == 0``, ``nt0 % 128 == 0``), the kernels compiled as on
+    a TPU."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    ny, nx, nt0 = 8, 16, 256
+    V = ny * nx * nt0
+    real = pk._interpret
+    pk._interpret = lambda: False
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            mesh = Mesh(np.array(v5e_devices[:1]), ("sp",))
+            StackOp, _, _ = poststack_regularized(
+                WAV.astype(np.float32), nt0, (ny, nx), 100.0, mesh=mesh,
+                dtype=np.float32)
+            vec = lambda: DistributedArray.tree_unflatten(
+                (mesh, pmt.Partition.SCATTER, 0, (V,), pmt.local_split(
+                    (V,), 1, pmt.Partition.SCATTER, 0), None),
+                [jax.ShapeDtypeStruct((V,), jnp.float32,
+                                      sharding=NamedSharding(mesh,
+                                                             P("sp")))])
+            fn = jax.jit(lambda op, y, x0: basic._cgls_fused(
+                op, y, x0, jnp.float32(0), jnp.float32(0), niter=30))
+            return fn.lower(StackOp, StackedDistributedArray(
+                [vec(), vec()]), vec()).compile().as_text(), V
+    finally:
+        pk._interpret = real
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+def _whole_carry_relayouts(text, n):
+    """``copy`` / ``reshape`` / ``transpose`` instructions of the loop
+    body's own computation whose result has a whole carry's ``n``
+    elements or more: passes over HBM the algebra never asked for."""
+    out = []
+    for line in hlo.while_body_instructions(text):
+        m = re.match(r"\s*(?:ROOT )?%?([\w.\-]+) = \w+\[([\d,]*)\]\S* "
+                     r"(copy|reshape|transpose)\(", line)
+        if m and int(np.prod([int(d) for d in m.group(2).split(",")
+                              if d] or [1])) >= n:
+            out.append(m.group(1))
+    return out
+
+
+@pytest.mark.parametrize("cell", ["poststack_3d", "mdd_obc"])
+def test_the_v5e_loop_body_relayouts_no_carry(poststack_v5e, mdc_v5e,
+                                              cell):
+    """ISSUE 37: the fused CGLS loop holds its vectors in the
+    operator's own shape — ``(ny, nx, nt0)``; ``(1023, 65536)`` — so in
+    the loop's body no ``copy`` and no ``reshape`` has a result of a
+    whole carry (flat ``{T(1024)}`` carries against ``{T(8,128)}``
+    cubes cost seven an iteration in ``poststack_3d.reg_cgls`` and four
+    in ``mdd_obc.cgls_nv16``; PERF.md section 6, PR 37), and every
+    scope the device trace splits the solve by is still on an op
+    there."""
+    if cell == "poststack_3d":
+        text, n = poststack_v5e
+        scopes = ["pmt.local.Conv1D", "pmt.local.FirstDerivative",
+                  "pmt.MPILaplacian.matvec", "pmt.MPILaplacian.rmatvec",
+                  "pmt.MPIBlockDiag.matvec", "pmt.MPIBlockDiag.rmatvec",
+                  "pmt.MPIStackedVStack.matvec",
+                  "pmt._ScaledLinearOperator.matvec"]
+        assert text.count("pmt_laplacian") and text.count("pmt_conv1d")
+    else:
+        text, n = mdc_v5e.as_text(), 1023 * 4096 * 16
+        scopes = ["pmt.local.FFT", "pmt.MPIFredholm1.matvec",
+                  "pmt.MPIFredholm1.rmatvec",
+                  "pmt._ProductLinearOperator.matvec",
+                  "pmt._ProductLinearOperator.rmatvec"]
+    assert _whole_carry_relayouts(text, n) == []
+    inside = re.findall(r'op_name="([^"]*/while/body/[^"]*)"', text)
+    for scope in scopes + ["pmt.solver.step", "pmt.solver.direction",
+                           "pmt.solver.cost"]:
+        assert any(scope in name.split("/") for name in inside), scope
+    assert re.search(r'op_name="[^"]*pmt\.solver\.setup', text)
+
+
 def test_a_complex64_kernel_does_not_fit_a_v5e(v5e_devices):
     """What forced the planes: XLA splits a complex64 program argument
     into two float32 arrays at the program's entry, 8.59 GB of

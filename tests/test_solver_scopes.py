@@ -113,6 +113,23 @@ def test_own_scopes_are_provenance_only(monkeypatch, solver, normal,
     assert hlo.strip_provenance(named) == hlo.strip_provenance(plain)
 
 
+@pytest.mark.parametrize("solver,normal,guards", PROGRAMS)
+def test_flat_dims_keep_the_loop_as_it_was(monkeypatch, solver, normal,
+                                           guards):
+    """(ISSUE 37) ``MPIBlockDiag(MatrixMult)`` declares no N-D ``dims``:
+    the loop-launching rule answers flat and the program is the text
+    of ``lax.while_loop(cond, body, state)`` itself, the line the rule
+    replaced (the block solvers never go through it)."""
+    from jax import lax
+    now = _program(solver, normal, guards)
+    monkeypatch.setattr(
+        basic, "_while_carried",
+        lambda solver, Op, cond, body, state, sides: lax.while_loop(
+            cond, body, state))
+    assert hlo.strip_provenance(now) \
+        == hlo.strip_provenance(_program(solver, normal, guards))
+
+
 class _Recorder:
     """Stands in for ``jax.profiler.TraceAnnotation``: the opens and
     closes of every ``pmt.solver.*`` annotation, with the thread."""
