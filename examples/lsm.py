@@ -1,9 +1,14 @@
 """Least-squares migration — analog of the reference's
 ``tutorials/lsm.py``: Kirchhoff demigration blocks (one per shard's
 batch of sources) stacked with MPIVStack — model BROADCAST, data
-SCATTER, adjoint allreduce — inverted with CGLS. The Kirchhoff engine
-is jnp-native (``models/lsm.py``): constant-velocity straight rays,
-scatter-free one-hot spray."""
+SCATTER, adjoint allreduce — inverted with CGLS. The operator is
+PyLops' static Kirchhoff (``models/lsm.py``): constant-velocity
+straight rays, two interpolation taps a pixel (``floor`` of the travel
+time in samples and its fraction), no amplitude term; the per-pair
+tables are made and kept on the device and both applies are the Pallas
+kernels ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj`` (interpreted off a
+TPU). Not there: eikonal travel times, ``dynamic=True`` weights. Give
+``MPILSM`` at least a source a device."""
 import _setup  # noqa: F401
 import numpy as np
 import pylops_mpi_tpu as pmt

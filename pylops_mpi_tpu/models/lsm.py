@@ -6,103 +6,333 @@ demigration for its batch of sources) and the ranks are stacked with
 ``MPIVStack`` — model BROADCAST, data SCATTER over sources, adjoint
 sum-allreduce (ref ``pylops_mpi/basicoperators/VStack.py:135-150``).
 
-Here the Kirchhoff engine is jnp-native and deliberately scatter-free:
-the forward "spray" of each image point onto its travel-time sample is
-a per-shot-gather one-hot contraction (an MXU matmul), and the adjoint
-is a pure gather (``take_along_axis``) — no ``.at[].add`` anywhere (see
-the note in ``ops/pallas_kernels.py`` / the FirstDerivative operators on
-XLA scatter under GSPMD). Travel times are straight-ray constant-velocity
-(the reference's analytical mode); amplitudes use geometrical spreading
-``1/sqrt(d_s d_r)``.
+**What is computed** is PyLops' static Kirchhoff operator, as ``LSM``
+builds it by default: for a source-receiver pair ``p = (s, r)`` and a
+pixel ``x``, ``T = (t_s(x) + t_r(x)) / dt``, ``i = floor(T)``, ``tau =
+T - i``, and where ``0 <= i < nt - 1``
+
+    spray:   y[p, i] += (1 - tau) m[x];  y[p, i + 1] += tau m[x];  d = w * y
+    adjoint: m[x] = sum_p (1 - tau) z[p, i] + tau z[p, i + 1],  z = w (*) d
+
+two weighted taps a pixel (linear interpolation between samples), no
+amplitude term, straight-ray travel times in a constant velocity
+(``mode="analytic"``), the wavelet a :class:`~pylops_mpi_tpu.ops.local.
+Conv1D` along time.
+
+**How.** The per-pair tables ``(i, tau)`` — int32 and the operator's
+dtype, 8 bytes a pair-pixel in float32 — are the operator's memory and
+are STORED (as PyLops 2.0 did), made ON the device, in their stored
+dtypes, from the per-point travel times ``(ns + nr, npix)`` in one
+program: nothing pair-sized exists on the host. Both applies are one
+Pallas kernel each, ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj``
+(``ops/pallas_kernels.py``: the index becomes compares of a tile of
+1,024 pixels against the samples of the band its travel times span;
+compiled on a TPU, interpreted elsewhere — one form on every backend).
+So that a tile's band is short the tables hold the image's pixels in
+blocks of 32 x 32 (:class:`_BlockOrder` puts a model in that order, a
+2 MB transpose an apply). The tables are pytree children of the
+operator (``register_operator_arrays``): the fused solvers take them
+as ``jit`` arguments, never as constants of the compiled program.
+
+**Departures from upstream that remain**: travel times are analytic
+only (no eikonal, no user-supplied tables through ``MPILSM``; a
+:class:`TravelTimeSpray` takes any tables); ``dynamic=True`` (amplitude
+and obliquity weights) is not there; an ``MPIVStack`` of these blocks
+on several chips places every shard's tables on the default device
+(PERF.md section 7, the mix ``cgls_shots32_4chip``).
 """
 
 from __future__ import annotations
 
-from typing import Optional, Tuple
+from functools import partial
+from typing import Tuple
 
 import numpy as np
 import jax
 import jax.numpy as jnp
 from jax import lax
 
+from ..diagnostics import metrics as _metrics
+from ..diagnostics import trace as _trace
 from ..distributedarray import DistributedArray, Partition
+from ..linearoperator import register_operator_arrays
+from ..ops import pallas_kernels as _pk
 from ..ops.blockdiag import MPIBlockDiag  # noqa: F401  (re-export convenience)
 from ..ops.stack import MPIVStack
-from ..ops.local import Conv1D, LocalOperator
+from ..ops.local import Conv1D, LocalOperator, _scoped
 from ..solvers.basic import cgls
 
 __all__ = ["TravelTimeSpray", "KirchhoffDemigration", "MPILSM", "lsm"]
 
+_TILE = _pk.KIRCHHOFF_TILE
+_BLOCK = (32, 32)        # pixels of the image a tile of the tables holds
 
-def _straight_ray(points: np.ndarray, pix: np.ndarray, vel: float):
-    """(npts, npix) travel time + distance for straight rays in a
-    constant-velocity medium."""
-    d = np.sqrt(((points[:, None, :] - pix[None, :, :]) ** 2).sum(-1))
-    return d / vel, d
+
+@partial(jax.jit, static_argnames=("last",))
+def _pack(i, w, inside, last: int):
+    """Tables ``(pairs, npix)`` in the kernels' layout, the longest
+    band and the count of entries dropped (``i`` outside ``[0, last]``
+    at a pixel that is ``inside`` the image)."""
+    inrange = (i >= 0) & (i <= last)
+    it, wt, lohi = _pk.kirchhoff_pack(i, w, inside & inrange)
+    band = jnp.max(lohi[:, :, 1] - lohi[:, :, 0]) + 1
+    return it, wt, lohi, band, jnp.sum(inside & ~inrange)
 
 
 class TravelTimeSpray(LocalOperator):
-    """Spray image-point amplitudes onto travel-time samples of
-    source–receiver traces: ``y[p, itrav[p, i]] += amp[p, i] * m[i]``.
+    """Spray image points onto the samples of source-receiver traces
+    through stored tables ``(npairs, npix)``; the adjoint gathers.
 
-    Forward iterates shot gathers with ``lax.map``; each gather is an
-    ``(npix, nt)`` one-hot contraction so the hot op is a matmul, not a
-    scatter. Adjoint gathers ``y[p, itrav[p, i]]`` with
-    ``take_along_axis`` and reduces over traces.
-    """
+    - ``TravelTimeSpray(itrav, amp, nt)``: ONE weighted tap, ``y[p,
+      itrav[p, x]] += amp[p, x] m[x]`` where ``0 <= itrav < nt``;
+    - ``TravelTimeSpray(itrav, None, nt, frac=tau)``: TWO taps,
+      ``y[p, i] += (1 - tau) m[x]; y[p, i + 1] += tau m[x]`` where
+      ``0 <= i < nt - 1`` — PyLops' static Kirchhoff.
 
-    def __init__(self, itrav: np.ndarray, amp: np.ndarray, nt: int,
-                 dtype=np.float32):
+    Other entries are dropped. One form on every backend, chosen by
+    what the operator sees: the Pallas kernels ``pmt_kirchhoff`` /
+    ``pmt_kirchhoff_adj`` for real data whose spray accumulator fits
+    VMEM (``pallas_kernels.kirchhoff_legal``: 6,144 samples of
+    float32), else (``why``: ``dtype`` or ``nt``) a trace-by-trace
+    scatter-add / gather. Pixels are taken in the order given, 1,024 a
+    tile; a tile costs a few vector operations a sample of the BAND its
+    indices span, so neighbours in the table should be neighbours in
+    time. ``kirchhoff.path_select`` (``form``, ``pairs``, ``npix``,
+    ``nt``, ``tile``, ``band``, ``adjoint``, ``why``) says what a
+    traced apply took; the counters ``kirchhoff.pair_pixels`` and
+    ``kirchhoff.taps_dropped`` count the tables' entries at
+    construction. Measured on a TPU v5e: see :func:`_form`."""
+
+    @property
+    def whole(self):
+        """Off a TPU the kernels are interpreted and their loops run
+        over each tile's band, a count read from the tables: the
+        partitioner must not cut them (``LocalOperator.whole``)."""
+        return _pk._interpret()
+
+    def __init__(self, itrav, amp, nt: int, dtype=np.float32, frac=None):
+        if (amp is None) == (frac is None):
+            raise ValueError("TravelTimeSpray: give amp (one tap) or "
+                             "frac (two taps), not both")
         npairs, npix = itrav.shape
-        self.nt = int(nt)
-        valid = itrav < nt
-        self.itrav = jnp.asarray(np.where(valid, itrav, 0), dtype=jnp.int32)
-        self.amp = jnp.asarray(np.where(valid, amp, 0.0), dtype=dtype)
-        super().__init__(dims=npix, dimsd=(npairs, nt), dtype=dtype)
+        pad = -npix % _TILE
+        widths = ((0, 0), (0, pad))
+        w = jnp.pad(jnp.asarray(amp if frac is None else frac, dtype=dtype),
+                    widths)
+        self._init_packed(
+            _pack(jnp.pad(jnp.asarray(itrav, dtype=jnp.int32), widths), w,
+                  jnp.arange(npix + pad) < npix,
+                  last=int(nt) - (1 if frac is None else 2)),
+            npairs, npix, nt, 1 if frac is None else 2, dtype)
+
+    @classmethod
+    def _from_packed(cls, packed, npairs, npix, nt, taps, dtype):
+        self = cls.__new__(cls)
+        self._init_packed(packed, npairs, npix, nt, taps, dtype)
+        return self
+
+    def _init_packed(self, packed, npairs, npix, nt, taps, dtype):
+        self._it, self._wt, self._lohi, band, dropped = packed
+        self.nt, self.taps = int(nt), int(taps)
+        self.band = int(band) + self.taps - 1
+        self.dropped = int(dropped)
+        _metrics.inc("kirchhoff.pair_pixels", npairs * npix)
+        _metrics.inc("kirchhoff.taps_dropped", self.dropped)
+        LocalOperator.__init__(self, dims=npix, dimsd=(npairs, self.nt),
+                               dtype=dtype)
+
+    @property
+    def itrav(self) -> jax.Array:
+        """The stored indices as they lie, ``(npairs, ntiles, 8, 128)``
+        int32: pixel ``x`` at flat place ``x`` of its pair's row, the
+        pixels padded to whole tiles (and whole grid steps of the
+        kernels); a dropped entry is negative."""
+        return self._it
+
+    @property
+    def weight(self) -> jax.Array:
+        """The stored weights (``amp``, or ``frac`` for two taps), laid
+        as :attr:`itrav`; zero where the entry is dropped."""
+        return self._wt
+
+    @property
+    def table_bytes(self) -> int:
+        return sum(int(a.nbytes) for a in (self._it, self._wt, self._lohi))
+
+    def _form(self, dtype, adjoint: bool):
+        """``None`` where the kernels take the apply, else the one word
+        ``kirchhoff.path_select`` gives as ``why``; the event is
+        recorded here.
+
+        Measured on a TPU v5e (``chipbench/scratch/lsm_probe.py``,
+        PR 38; ms an apply, forward / adjoint, one shot of the
+        ``lsm_kirchhoff`` cell: 256 pairs, 524,288 pixels in 32 x 32
+        blocks, 1,024 samples, two taps, float32, tables 1.07 GB):
+
+        ==================================  ========  ========
+        form                                forward   adjoint
+        ==================================  ========  ========
+        ``pmt_kirchhoff`` / ``_adj``        20.2      13.0
+        scatter-add / gather, a trace       2,360     2,137
+        (the benchmark's plain forms:
+        scatter and index, 8 pairs a block  2,348     2,320
+        one-hot over a band of 40, jnp      100       70)
+        ==================================  ========  ========
+
+        (bands of 26 samples a tile on average, 38 the longest; the
+        kernels agree with the plain scatter to 9.9e-7 forward and
+        3.0e-7 adjoint, float32 sums in another order.) XLA's scatter
+        and gather cost 8.7 ns an entry on the chip, the kernels 0.08
+        and 0.05: the scatter form is for what the kernels cannot take
+        (complex data, a trace whose accumulator outgrows VMEM), never
+        for speed.
+
+        A rule in what the operator sees, the same on every backend, so
+        the CPU tests run the form the chip runs."""
+        why = None
+        if jnp.issubdtype(dtype, jnp.complexfloating) \
+                or np.dtype(self.dtype).kind != "f":
+            why = "dtype"
+        elif not _pk.kirchhoff_legal(self.nt, dtype):
+            why = "nt"
+        _trace.event("kirchhoff.path_select", cat="schedule",
+                     form="scatter" if why else "pmt_kirchhoff",
+                     pairs=self.dimsd[0], npix=self.dims[0], nt=self.nt,
+                     tile=_TILE, band=self.band, adjoint=int(adjoint),
+                     **({"why": why} if why else {}))
+        return why
+
+    def _flat_tables(self):
+        npairs = self.dimsd[0]
+        return (self._it.reshape(npairs, -1), self._wt.reshape(npairs, -1))
+
+    @_scoped
+    def _matvec(self, x):
+        npairs, nt = self.dimsd
+        npad = self._it.shape[1] * _TILE
+        m = jnp.pad(x.astype(jnp.result_type(x.dtype, self.dtype)),
+                    (0, npad - x.shape[0]))
+        if self._form(m.dtype, adjoint=False) is None:
+            return _pk.kirchhoff_spray(self._lohi, self._it, self._wt, m,
+                                       nt, self.taps).ravel()
+        it, wt = self._flat_tables()
+
+        def trace_of(tables):
+            i, w = tables                 # a dropped index is out of range
+            y = jnp.zeros(nt + 1, m.dtype)
+            if self.taps == 1:
+                return y.at[i].add(w * m, mode="drop")[:nt]
+            return y.at[i].add((1 - w) * m, mode="drop") \
+                    .at[i + 1].add(w * m, mode="drop")[:nt]
+        return lax.map(trace_of, (it, wt)).ravel()
+
+    @_scoped
+    def _rmatvec(self, x):
+        npairs, nt = self.dimsd
+        z = x.reshape(npairs, nt)
+        z = z.astype(jnp.result_type(z.dtype, self.dtype))
+        if self._form(z.dtype, adjoint=True) is None:
+            m = _pk.kirchhoff_gather(self._lohi, self._it, self._wt, z,
+                                     self.taps)
+            return m[:self.dims[0]]
+        it, wt = self._flat_tables()
+        wt = jnp.conj(wt)
+
+        def add_trace(acc, row):
+            zp, i, w = row
+            live = i >= 0
+            zp = jnp.pad(zp, (0, 1))
+            g0 = jnp.where(live, zp[jnp.clip(i, 0, nt)], 0)
+            if self.taps == 1:
+                return acc + w * g0, None
+            g1 = jnp.where(live, zp[jnp.clip(i + 1, 0, nt)], 0)
+            return acc + ((1 - w) * g0 + w * g1), None
+        acc, _ = lax.scan(add_trace, jnp.zeros(it.shape[1], z.dtype),
+                          (z, it, wt))
+        return acc[:self.dims[0]]
+
+
+class _BlockOrder(LocalOperator):
+    """An image ``(nz, nx)`` as its blocks of ``_BLOCK`` pixels one
+    after another, zero-padded to whole blocks: the order in which
+    :func:`KirchhoffDemigration` keeps its tables, so that the 1,024
+    pixels of a tile are neighbours in the image and their travel
+    times neighbours in time. A permutation (and a pad): the adjoint is
+    the way back."""
+
+    def __init__(self, dims, dtype=np.float32):
+        (nz, nx), (bz, bx) = dims, _BLOCK
+        self.padded = (-(-nz // bz) * bz, -(-nx // bx) * bx)
+        super().__init__(dims, int(np.prod(self.padded)), dtype=dtype)
+
+    def order(self, image):
+        """``image (nz, nx, ...)`` -> ``(npix_padded, ...)``; NumPy or
+        JAX."""
+        (nz, nx), (pz, px), (bz, bx) = self.dims, self.padded, _BLOCK
+        xp = jnp if isinstance(image, jax.Array) else np
+        rest = image.shape[2:]
+        v = xp.pad(image, ((0, pz - nz), (0, px - nx))
+                   + ((0, 0),) * len(rest))
+        v = v.reshape((pz // bz, bz, px // bx, bx) + rest)
+        return xp.swapaxes(v, 1, 2).reshape((pz * px,) + rest)
 
     def _matvec(self, x):
-        nt = self.nt
-        tgrid = jnp.arange(nt, dtype=jnp.int32)
-
-        def one_pair(args):
-            it, a = args                              # (npix,), (npix,)
-            onehot = (it[:, None] == tgrid[None, :]).astype(x.dtype)
-            return (x * a) @ onehot                   # (nt,)
-
-        y = lax.map(one_pair, (self.itrav, self.amp))
-        return y.ravel()
+        return self.order(x.reshape(self.dims))
 
     def _rmatvec(self, x):
-        y = x.reshape(self.dimsd)                     # (npairs, nt)
-        picked = jnp.take_along_axis(y, self.itrav, axis=1)  # (npairs, npix)
-        return (jnp.conj(self.amp) * picked).sum(axis=0)
+        (nz, nx), (pz, px), (bz, bx) = self.dims, self.padded, _BLOCK
+        v = x.reshape(pz // bz, px // bx, bz, bx)
+        return jnp.swapaxes(v, 1, 2).reshape(pz, px)[:nz, :nx].ravel()
+
+
+@partial(jax.jit, static_argnames=("nt",))
+def _tables(srcs, rcvs, pix, inside, vel, dt, nt: int):
+    """The packed per-pair tables of a batch of sources, made where
+    they are to lie: per-point straight-ray travel times ``(ns, npix)``
+    and ``(nr, npix)``, their sums over ``dt`` a pair, ``floor`` and
+    fraction — each table written once, in its stored dtype."""
+    def times(points):                                   # (n, npix)
+        d = points[:, None, :] - pix[None, :, :]
+        return jnp.sqrt(jnp.sum(d * d, axis=-1)) / vel
+    T = (times(srcs)[:, None, :] + times(rcvs)[None, :, :]) / dt
+    T = T.reshape(-1, pix.shape[0])
+    i = jnp.floor(T)
+    return _pack(i.astype(jnp.int32), T - i, inside, last=nt - 2)
 
 
 def KirchhoffDemigration(z: np.ndarray, x: np.ndarray, t: np.ndarray,
                          sources: np.ndarray, recs: np.ndarray, vel: float,
                          wav: np.ndarray, wavcenter: int,
                          dtype=np.float32) -> LocalOperator:
-    """Kirchhoff demigration ``d(s, r, t) = w(t) * Σ_x a(x) m(x)
-    δ(t − t_s(x) − t_r(x))`` for one batch of sources
-    (constant-velocity straight rays; jnp-native analog of the engine
-    inside ``pylops.waveeqprocessing.LSM`` the reference stacks,
-    ref ``tutorials/lsm.py``)."""
+    """Kirchhoff demigration ``d(s, r, t) = w(t) * sum_x m(x)
+    hat(t - t_s(x) - t_r(x))`` for one batch of sources (module
+    docstring: two taps a pixel, no amplitude, constant-velocity
+    straight rays; the engine inside ``pylops.waveeqprocessing.LSM``
+    the reference stacks, ref ``tutorials/lsm.py``): ``Conv1D *
+    TravelTimeSpray * _BlockOrder``. ``sources (2, ns)`` and ``recs
+    (2, nr)`` hold ``(x, z)``. The event ``lsm.tables`` (``pairs``,
+    ``npix``, ``stored``, ``table_bytes``, ``built_on``) says what was
+    made."""
+    nz, nx, nt = len(z), len(x), len(t)
+    order = _BlockOrder((nz, nx), dtype=dtype)
     zz, xx = np.meshgrid(z, x, indexing="ij")
-    pix = np.stack([xx.ravel(), zz.ravel()], axis=1)        # (npix, 2)
+    pix = order.order(np.stack([xx, zz], axis=-1))          # (npix_p, 2)
+    inside = order.order(np.ones((nz, nx), bool))
     srcs = np.asarray(sources, dtype=float).T               # (ns, 2)
     rcvs = np.asarray(recs, dtype=float).T                  # (nr, 2)
-    dt = float(t[1] - t[0])
-    nt = len(t)
-    ts, ds = _straight_ray(srcs, pix, vel)                  # (ns, npix)
-    tr, dr = _straight_ray(rcvs, pix, vel)                  # (nr, npix)
-    ttot = ts[:, None, :] + tr[None, :, :]                  # (ns, nr, npix)
-    amp = 1.0 / np.sqrt(ds[:, None, :] * dr[None, :, :] + 1e-10)
-    itrav = np.rint(ttot / dt).astype(np.int64).reshape(-1, pix.shape[0])
-    amp = amp.reshape(-1, pix.shape[0])
-    spray = TravelTimeSpray(itrav, amp, nt, dtype=dtype)
-    conv = Conv1D(spray.dimsd, wav.astype(dtype), axis=-1, offset=wavcenter,
-                  dtype=dtype)
-    return conv * spray
+    npairs = srcs.shape[0] * rcvs.shape[0]
+    real = np.dtype(dtype)
+    packed = _tables(*(jnp.asarray(a, dtype=real) for a in (srcs, rcvs, pix)),
+                     jnp.asarray(inside), real.type(vel),
+                     real.type(t[1] - t[0]), nt=nt)
+    spray = TravelTimeSpray._from_packed(packed, npairs, pix.shape[0], nt,
+                                         2, dtype)
+    _trace.event("lsm.tables", cat="setup", pairs=npairs, npix=nz * nx,
+                 stored="pair", table_bytes=spray.table_bytes,
+                 built_on=next(iter(spray._it.devices())).platform)
+    conv = Conv1D(spray.dimsd, np.asarray(wav, dtype=dtype), axis=-1,
+                  offset=wavcenter, dtype=dtype)
+    return conv * spray * order
 
 
 def MPILSM(z, x, t, sources, recs, vel, wav, wavcenter,
@@ -115,10 +345,12 @@ def MPILSM(z, x, t, sources, recs, vel, wav, wavcenter,
     P = int(mesh.devices.size)
     sources = np.asarray(sources, dtype=float)
     ns = sources.shape[1]
-    chunks = np.array_split(np.arange(ns), P)
+    if ns < P:
+        raise ValueError(f"MPILSM: {ns} source(s) cannot be dealt over a "
+                         f"mesh of {P} devices (every shard needs one)")
     ops = [KirchhoffDemigration(z, x, t, sources[:, c], recs, vel, wav,
                                 wavcenter, dtype=dtype)
-           for c in chunks if len(c)]
+           for c in np.array_split(np.arange(ns), P)]
     return MPIVStack(ops, mesh=mesh)
 
 
@@ -138,3 +370,10 @@ def lsm(z, x, t, sources, recs, vel, wav, wavcenter, refl: np.ndarray,
     minv, cost = out[0], out[5]
     return (np.asarray(minv.asarray()).reshape(len(z), len(x)),
             np.asarray(d.asarray()), np.asarray(cost))
+
+
+# the tables travel into jit as arguments (ops/stack.py registers the
+# generic MPIVStack's local operators, ops/local.py Conv1D and the
+# product); _BlockOrder holds no array
+register_operator_arrays(TravelTimeSpray, "_it", "_wt", "_lohi")
+register_operator_arrays(_BlockOrder)

@@ -45,6 +45,16 @@ def _scoped(apply):
 class LocalOperator:
     """Minimal pylops-like operator protocol over jnp arrays."""
 
+    # True where the apply must not be cut by the partitioner: an
+    # INTERPRETED Pallas kernel whose loops' trip counts are data (cut
+    # over a mesh, each shard's loop would hold collectives the others
+    # never reach). A distributed operator composed over such an
+    # operator keeps the operand and the result replicated around the
+    # apply (``MPIVStack._apply_local``); a product is whole where a
+    # factor is. A compiled kernel is opaque to the partitioner as it
+    # is, so on a TPU nothing answers True.
+    whole = False
+
     def __init__(self, dims, dimsd, dtype=None, name: str = "L"):
         self.dims = tuple(int(d) for d in np.ravel(dims))
         self.dimsd = tuple(int(d) for d in np.ravel(dimsd))
@@ -172,6 +182,10 @@ class _Product(LocalOperator):
             raise ValueError(f"shape mismatch {A.shape} @ {B.shape}")
         super().__init__(B.dims, A.dimsd, dtype=np.result_type(A.dtype, B.dtype))
         self.A, self.B = A, B
+
+    @property
+    def whole(self):
+        return self.A.whole or self.B.whole
 
     def _matvec(self, x):
         return self.A.matvec(self.B.matvec(x))
@@ -1018,3 +1032,14 @@ class NonStationaryConvolve1D(LocalOperator):
         for j in range(self.nh):
             out = out + jnp.conj(self.Hbank[:, j]) * vpad[:, j:j + n]
         return self._unbatch(out, shp)
+
+
+# local operators as pytree nodes: what a registered distributed
+# operator composed over them needs to travel into jit as an argument
+# (linearoperator.operator_is_jit_arg). The product exposes its
+# factors; Conv1D holds only its few taps, which stay with the
+# instance. A local operator that owns device arrays registers them
+# where it is defined (models/lsm.py::TravelTimeSpray).
+from ..linearoperator import register_operator_arrays  # noqa: E402
+register_operator_arrays(_Product, "A", "B")
+register_operator_arrays(Conv1D)

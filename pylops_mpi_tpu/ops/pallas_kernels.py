@@ -700,3 +700,239 @@ def laplacian_stencil(x: jax.Array, ghost_front: jax.Array,
         interpret=_interpret(),
         name="pmt_laplacian",
     )(jnp.asarray(base, jnp.int32).reshape(1), x, ghost_front, ghost_back)
+
+
+# -------------------------------------------------- indexed spray / gather
+# ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj`` (:func:`kirchhoff_spray`,
+# :func:`kirchhoff_gather`), added below everything else so that no
+# line of the kernels above moves (their compiled text carries their
+# line numbers). Live in an apply: the tables where they lie, a
+# trace-sized and an image-sized vector.
+#
+# ``models/lsm.py::TravelTimeSpray``: for a trace (a source-receiver
+# pair) ``p`` and a pixel ``x`` a stored sample index ``i[p, x]`` and a
+# stored weight ``w[p, x]``; one tap ``y[p, i] += w m[x]`` or two,
+# ``y[p, i] += (1 - w) m[x]; y[p, i + 1] += w m[x]`` (linear
+# interpolation between samples). Neither a GEMM nor a stencil, and the
+# chip has no scatter: the kernels turn the index into COMPARES on the
+# VPU. The pixels are cut into tiles of ``KIRCHHOFF_TILE`` = 1,024, one
+# ``(8, 128)`` register of indices; a tile's indices span a band of
+# samples ``[lo, hi]`` (stored a pair-tile beside the tables), and for
+# each sample ``t`` of the band ONE compare of the register against
+# ``t`` selects the pixels that land there:
+#
+# - spray: ``acc[t] += where(i == t, (1 - w) m, 0) + where(i == t - 1,
+#   w m, 0)`` into a VMEM accumulator of one register a sample, which
+#   is summed over its 1,024 places (sublanes on the VPU, lanes as a
+#   product with ones on the MXU, three bf16 parts) once a trace;
+# - gather: ``g0 = where(i == t, z[p, t], g0); g1 = where(i == t,
+#   z[p, t + 1], g1)`` with the trace's samples read as SCALARS from
+#   SMEM, then ``m[x] += (1 - w) g0 + w g1``.
+#
+# So an apply costs ``3-4`` vector operations a pair-TILE a sample of
+# its band, whatever the trace's length: pixels that are neighbours in
+# the image have travel times that are neighbours in time, and the
+# order of the pixels in the tables (``models/lsm.py`` cuts the image
+# into 32 x 32 blocks) keeps the bands short. Tables in any order give
+# the same answer with longer bands. An entry that is dropped (outside
+# the trace) holds an index no sample equals.
+
+__all__ += ["kirchhoff_spray", "kirchhoff_gather", "kirchhoff_pack",
+            "kirchhoff_legal", "KIRCHHOFF_TILE"]
+
+KIRCHHOFF_TILE = 1024          # pixels a tile: one (8, 128) register
+_KIR_UNROLL = 4                # samples a loop step
+_KIR_DROPPED = -(1 << 30)      # the index of a dropped entry
+_KIR_BLOCK_TILES = 64          # tiles a grid step (2 x 256 KiB of tables)
+_KIR_ACC_BYTES = 24 << 20      # the spray's accumulator: nt registers
+
+
+def kirchhoff_legal(nt: int, dtype) -> bool:
+    """Whether the kernels take traces of ``nt`` samples of ``dtype``:
+    real, and the spray's accumulator (one ``(8, 128)`` register a
+    sample) within its share of VMEM — 6,144 samples of float32."""
+    dtype = np.dtype(dtype)
+    return (dtype.kind == "f"
+            and (nt + 128 + _KIR_UNROLL) * KIRCHHOFF_TILE * dtype.itemsize
+            <= _KIR_ACC_BYTES)
+
+
+def kirchhoff_pack(i, w, valid):
+    """Tables ``(pairs, npix)`` (``npix`` whole tiles) in the kernels'
+    layout: ``it (pairs, ntiles, 8, 128)`` int32 with dropped entries
+    marked, ``wt`` likewise with zeros there, and the bands ``lohi
+    (pairs, nblk, 2, TB)`` int32 (an empty tile: ``lo > hi``), the
+    tiles padded to whole grid steps of ``TB``. Traceable: the caller's
+    program makes the tables where they are to lie."""
+    pairs, npix = i.shape
+    ntiles = npix // KIRCHHOFF_TILE
+    tb = min(_KIR_BLOCK_TILES, ntiles)
+    pad = -ntiles % tb
+    it = jnp.where(valid, i, _KIR_DROPPED).astype(jnp.int32)
+    wt = jnp.where(valid, w, 0)
+    tiles = it.reshape(pairs, ntiles, KIRCHHOFF_TILE)
+    lo = jnp.min(jnp.where(tiles == _KIR_DROPPED, 1 << 30, tiles), axis=-1)
+    hi = jnp.max(jnp.where(tiles == _KIR_DROPPED, -1, tiles), axis=-1)
+    if pad:
+        lo = jnp.pad(lo, ((0, 0), (0, pad)), constant_values=1 << 30)
+        hi = jnp.pad(hi, ((0, 0), (0, pad)), constant_values=-1)
+    lohi = jnp.stack([lo.reshape(pairs, -1, tb), hi.reshape(pairs, -1, tb)],
+                     axis=2)
+    shape = (pairs, ntiles, 8, 128)
+    it, wt = it.reshape(shape), wt.reshape(shape)
+    if pad:
+        widths = ((0, 0), (0, pad), (0, 0), (0, 0))
+        it = jnp.pad(it, widths, constant_values=_KIR_DROPPED)
+        wt = jnp.pad(wt, widths)
+    return it, wt, lohi
+
+
+def _kir_steps(lo, hi):
+    """Loop steps of ``_KIR_UNROLL`` samples that cover ``[lo, hi]``
+    (none for an empty band); the samples past ``hi`` a last step
+    visits match no index of the tile."""
+    return jnp.maximum(hi - lo + _KIR_UNROLL, 0) // _KIR_UNROLL
+
+
+def _kirchhoff_spray_kernel(lh_ref, i_ref, w_ref, m_ref, y_ref, acc_ref, *,
+                            taps: int, ntp: int):
+    """Grid ``(pairs, nblk)``: one trace, one block of ``TB`` tiles.
+    ``lh_ref (2, TB)`` in SMEM, ``i_ref`` / ``w_ref (1, TB, 8, 128)``,
+    ``m_ref (TB, 8, 128)``, ``y_ref (1, 1, ntp)`` written at the
+    trace's last block from ``acc_ref (ntp + unroll, 8, 128)``."""
+    j = pl.program_id(1)
+
+    @pl.when(j == 0)
+    def _():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def tile(k, carry):
+        i, w, m = i_ref[0, k], w_ref[0, k], m_ref[k]
+        a = w * m                  # two taps: (1 - w) m at i, w m at i + 1
+        b = (1 - w) * m            # (the gather's weights, bit for bit)
+        lo = lh_ref[0, k]
+
+        def step(s, c):
+            for q in range(_KIR_UNROLL):
+                t = lo + s * _KIR_UNROLL + q
+                if taps == 2:
+                    v = jnp.where(i == t, b, 0) + jnp.where(i == t - 1, a, 0)
+                else:
+                    v = jnp.where(i == t, a, 0)
+                acc_ref[t] = acc_ref[t] + v
+            return c
+        return jax.lax.fori_loop(
+            0, _kir_steps(lo, lh_ref[1, k] + (taps - 1)), step, carry)
+    jax.lax.fori_loop(0, i_ref.shape[1], tile, 0)
+
+    @pl.when(j == pl.num_programs(1) - 1)
+    def _():
+        ys = jnp.sum(acc_ref[0:ntp], axis=1)                  # (ntp, 128)
+        lanes = (((1,), (1,)), ((), ()))
+        if ys.dtype == jnp.float32:
+            ones = jnp.ones((8, 128), jnp.bfloat16)
+            y = reduce(jnp.add, (jax.lax.dot_general(
+                ones, p, lanes, preferred_element_type=jnp.float32,
+                precision=jax.lax.Precision.DEFAULT)
+                for p in reversed(_bf16_parts(ys, 3))))
+        else:           # wider than f32: interpreted only, a plain dot
+            y = jax.lax.dot_general(jnp.ones((8, 128), ys.dtype), ys, lanes)
+        y_ref[0] = y[0:1]
+
+
+def _kirchhoff_gather_kernel(lh_ref, z_ref, i_ref, w_ref, m_ref, *,
+                             taps: int):
+    """Grid ``(nblk, pairs)``: one block of ``TB`` tiles, one trace;
+    ``m_ref (TB, 8, 128)`` stays where it is over the traces and is
+    added to. ``z_ref (1, ntz)``: the trace's samples in SMEM, zeros
+    past its end."""
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        m_ref[...] = jnp.zeros_like(m_ref)
+
+    def tile(k, carry):
+        i, w = i_ref[0, k], w_ref[0, k]
+        lo = lh_ref[0, k]
+        zero = jnp.zeros(i.shape, m_ref.dtype)
+
+        def step(s, g):
+            g0, g1 = g
+            for q in range(_KIR_UNROLL):
+                t = lo + s * _KIR_UNROLL + q
+                here = i == t
+                g0 = jnp.where(here, z_ref[0, t], g0)
+                if taps == 2:
+                    g1 = jnp.where(here, z_ref[0, t + 1], g1)
+            return g0, g1
+        g0, g1 = jax.lax.fori_loop(0, _kir_steps(lo, lh_ref[1, k]), step,
+                                   (zero, zero))
+        m_ref[k] = m_ref[k] + ((1 - w) * g0 + w * g1 if taps == 2
+                             else w * g0)
+        return carry
+    jax.lax.fori_loop(0, i_ref.shape[1], tile, 0)
+
+
+def _kir_specs(lohi, swap: bool):
+    """Block specs of the bands and the two tables for a grid
+    ``(pairs, nblk)`` (``swap``: ``(nblk, pairs)``)."""
+    tb = lohi.shape[3]
+    at = (lambda j, p: (p, j, 0, 0)) if swap else (lambda p, j: (p, j, 0, 0))
+    table = pl.BlockSpec((1, tb, 8, 128), at)
+    return pl.BlockSpec((None, None, 2, tb), at,
+                        memory_space=pltpu.SMEM), table, table
+
+
+@partial(jax.jit, static_argnames=("nt", "taps"))
+def kirchhoff_spray(lohi, it, wt, m, nt: int, taps: int) -> jax.Array:
+    """``y (pairs, nt)``: the pixels ``m (ntiles * 1024,)`` sprayed
+    through the packed tables (:func:`kirchhoff_pack`), ``taps`` 1 or
+    2. Kernel ``pmt_kirchhoff`` (compiled on a TPU, interpreted
+    elsewhere). Gate on :func:`kirchhoff_legal`. Under ``jax.jit`` of
+    its own, as :func:`kirchhoff_gather`: an eager apply does not trace
+    the interpreted kernel anew, and the blocks of a stack that have
+    one shape share one lowering."""
+    pairs, nblk, _, tb = lohi.shape
+    ntp = -(-nt // 128) * 128
+    bands, ti, tw = _kir_specs(lohi, swap=False)
+    y = pl.pallas_call(
+        partial(_kirchhoff_spray_kernel, taps=taps, ntp=ntp),
+        grid=(pairs, nblk),
+        in_specs=[bands, ti, tw,
+                  pl.BlockSpec((tb, 8, 128), lambda p, j: (j, 0, 0))],
+        out_specs=pl.BlockSpec((1, 1, ntp), lambda p, j: (p, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((pairs, 1, ntp), m.dtype),
+        scratch_shapes=[pltpu.VMEM((ntp + _KIR_UNROLL, 8, 128), m.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+        name="pmt_kirchhoff",
+    )(lohi, it, wt, m.reshape(nblk * tb, 8, 128))
+    return y[:, 0, :nt]
+
+
+@partial(jax.jit, static_argnames=("taps",))
+def kirchhoff_gather(lohi, it, wt, z, taps: int) -> jax.Array:
+    """``m (ntiles * 1024,)``: the adjoint of :func:`kirchhoff_spray`
+    on traces ``z (pairs, nt)``, summed over the traces. Kernel
+    ``pmt_kirchhoff_adj``."""
+    pairs, nblk, _, tb = lohi.shape
+    nt = z.shape[1]
+    ntz = -(-(nt + _KIR_UNROLL + 1) // 128) * 128
+    bands, ti, tw = _kir_specs(lohi, swap=True)
+    m = pl.pallas_call(
+        partial(_kirchhoff_gather_kernel, taps=taps),
+        grid=(nblk, pairs),
+        in_specs=[bands,
+                  pl.BlockSpec((None, 1, ntz), lambda j, p: (p, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  ti, tw],
+        out_specs=pl.BlockSpec((tb, 8, 128), lambda j, p: (j, 0, 0)),
+        out_shape=jax.ShapeDtypeStruct((nblk * tb, 8, 128), z.dtype),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+        name="pmt_kirchhoff_adj",
+    )(lohi, jnp.pad(z, ((0, 0), (0, ntz - nt)))[:, None, :], it, wt)
+    return m.ravel()

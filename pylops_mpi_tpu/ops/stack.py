@@ -106,6 +106,11 @@ class MPIVStack(MPILinearOperator):
             from ._precision import default_compute_dtype
             self.compute_dtype = default_compute_dtype(dtype)
         self._batched, self._batched_adj = self._try_batch()
+        # the generic branch's local operators, as a pytree child: a
+        # registered local operator's arrays (a Kirchhoff block's
+        # tables) then reach the fused solvers as jit arguments
+        self._local = None if self._batched is not None \
+            else tuple(self.ops)
 
     def _try_batch(self):
         """Homogeneous matrix blocks → one stacked, block-sharded GEMM.
@@ -136,6 +141,22 @@ class MPIVStack(MPILinearOperator):
         from ..parallel.mesh import stack_sharded
         return stack_sharded(mats, self.mesh, self.compute_dtype), adjs[0]
 
+    def _apply_local(self, op, v, adjoint: bool):
+        """One local apply of the generic branch. A local operator that
+        is ``whole`` (an interpreted kernel with data-dependent loops,
+        see ``LocalOperator.whole``: never on a TPU) gets its operand
+        and its result replicated on a mesh of several devices, so the
+        partitioner does not carry the stack's row sharding into the
+        interpreter's loops."""
+        apply = op.rmatvec if adjoint else op.matvec
+        if not getattr(op, "whole", False) \
+                or int(self.mesh.devices.size) == 1:
+            return apply(v)
+        from jax.sharding import NamedSharding, PartitionSpec
+        rep = NamedSharding(self.mesh, PartitionSpec())
+        return jax.lax.with_sharding_constraint(
+            apply(jax.lax.with_sharding_constraint(v, rep)), rep)
+
     # block (column-batched) inputs add a trailing index to the SAME
     # batched einsums — one widened GEMM, no per-column Python loop
     accepts_block = True
@@ -162,7 +183,8 @@ class MPIVStack(MPILinearOperator):
             # heterogeneous rows: one compiled vmap over columns
             return self._apply_columns(x, forward=True)
         else:
-            arr = jnp.concatenate([op.matvec(xg) for op in self.ops])
+            arr = jnp.concatenate([self._apply_local(op, xg, False)
+                                   for op in self._local or self.ops])
         gshape = self.shape[0] if ncol is None else (self.shape[0], ncol)
         lsh = (self.local_shapes_n if ncol is None
                else tuple(tuple(s) + (ncol,) for s in self.local_shapes_n))
@@ -318,8 +340,10 @@ class MPIVStack(MPILinearOperator):
         else:
             offs = np.concatenate([[0], np.cumsum(self.nops)])
             acc = None
-            for op, lo, hi in zip(self.ops, offs[:-1], offs[1:]):
-                part = op.rmatvec(x.array[int(lo):int(hi)])
+            for op, lo, hi in zip(self._local or self.ops, offs[:-1],
+                                  offs[1:]):
+                part = self._apply_local(op, x.array[int(lo):int(hi)],
+                                         True)
                 acc = part if acc is None else acc + part
         gshape = self.shape[1] if ncol is None else (self.shape[1], ncol)
         y = DistributedArray(global_shape=gshape, mesh=self.mesh,
@@ -386,8 +410,11 @@ class MPIHStack(MPILinearOperator):
 
 
 # batched stacks travel into jit as pytree arguments (multi-process
-# arrays must not be closed over — see linearoperator.py registry)
+# arrays must not be closed over — see linearoperator.py registry); so
+# do the local operators of a generic stack, where their classes are
+# registered (an unregistered one is an opaque leaf: closure capture,
+# as before)
 from ..linearoperator import register_operator_arrays  # noqa: E402
-register_operator_arrays(MPIVStack, "_batched")
+register_operator_arrays(MPIVStack, "_batched", "_local")
 register_operator_arrays(MPIHStack, "vstack")
 register_operator_arrays(MPIStackedVStack, "ops")

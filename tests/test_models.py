@@ -79,7 +79,7 @@ def _lsm_geometry():
     nx, nz = 21, 16
     dx = 4.0
     x, z = np.arange(nx) * dx, np.arange(nz) * dx
-    nr, ns = 5, 4
+    nr, ns = 5, 8            # a source a device of the test mesh
     recs = np.vstack((np.linspace(2 * dx, (nx - 2) * dx, nr),
                       8 * np.ones(nr)))
     srcs = np.vstack((np.linspace(2 * dx, (nx - 2) * dx, ns),

@@ -660,3 +660,80 @@ def test_fused_cgls_leaves_a_callers_x0_alone_without_an_eager_copy(
     donated = sorted(bool(k[k.index("cgls") + 5]) for k in basic._FUSED_CACHE
                      if k[0] == id(Op))
     assert donated == [False, True]
+
+
+# ------------------------- the Kirchhoff kernels and their solver (PR 38)
+def _lsm_for_v5e(v5e_devices, what: str):
+    """Compile, for one described chip at ``lsm_kirchhoff.cgls_shots8``'s
+    full size (kept in THIS file because it holds the one topology
+    fixture of the suite), ``tables``: the program that makes the packed
+    per-pair tables; ``solver``: the fused CGLS of the stacked
+    demigration, the operator a pytree ARGUMENT whose tables are
+    abstract, as in the real program."""
+    import importlib
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+    from pylops_mpi_tpu.ops.stack import MPIVStack
+    M = importlib.import_module("pylops_mpi_tpu.models.lsm")
+    ns, nr, nz, nx, nt = 8, 256, 512, 1024, 1024
+    pairs, npix = ns * nr, nz * nx
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    try:
+        with jax.enable_x64(False):
+            mesh = Mesh(np.array(v5e_devices[:1]), ("sp",))
+            rep = NamedSharding(mesh, P())
+            S = lambda shape, dt=jnp.float32: jax.ShapeDtypeStruct(
+                shape, dt, sharding=rep)
+            if what == "tables":
+                return jax.jit(lambda s, r, pix, ok, v, dt: M._tables(
+                    s, r, pix, ok, v, dt, nt=nt)).lower(
+                        S((ns, 2)), S((nr, 2)), S((npix, 2)),
+                        S((npix,), bool), S(()), S(())).compile()
+            it, wt, lohi = (S(p.shape, p.dtype) for p in jax.eval_shape(
+                lambda i, w, ok: M._pack(i, w, ok, last=nt - 2),
+                S((pairs, npix), jnp.int32), S((pairs, npix)),
+                S((npix,), bool))[:3])
+            spray = M.TravelTimeSpray._from_packed(
+                (it, wt, lohi, 0, 0), pairs, npix, nt, 2, np.float32)
+            wav = ricker(np.arange(41) * 0.004, 20)[0].astype(np.float32)
+            conv = Conv1D(spray.dimsd, wav, axis=-1, offset=40,
+                          dtype=np.float32)
+            Op = MPIVStack([conv * spray * M._BlockOrder((nz, nx))],
+                           mesh=mesh)
+
+            def vec(n, part):
+                return DistributedArray.tree_unflatten(
+                    (mesh, part, 0, (n,), pmt.local_split((n,), 1, part, 0),
+                     None), [S((n,))])
+            fn = jax.jit(lambda op, y, x0: basic._cgls_fused(
+                op, y, x0, jnp.float32(0), jnp.float32(0), niter=10))
+            return fn.lower(Op, vec(pairs * nt, pmt.Partition.SCATTER),
+                            vec(npix, pmt.Partition.BROADCAST)).compile()
+    finally:
+        jax.config.update("jax_enable_compilation_cache", True)
+        cc.reset_cache()
+
+
+@pytest.mark.parametrize("what", ["tables", "solver"])
+def test_lsm_compiles_for_v5e(v5e_devices, monkeypatch, what):
+    """Mosaic accepts ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj`` at the
+    cell's widths; the tables (8.59 GB and their bands) are ARGUMENTS of
+    the solve with a few data vectors of temporaries beside them, and
+    the program that makes them holds no second table."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    c = _lsm_for_v5e(v5e_devices, what)
+    tables, vec = 8 * 2048 * 524288, 4 * 2048 * 1024
+    ma = c.memory_analysis()
+    if what == "tables":
+        assert tables <= ma.output_size_in_bytes <= 1.002 * tables
+        assert ma.temp_size_in_bytes <= tables // 8, ma
+        return
+    assert tables <= ma.argument_size_in_bytes <= 1.002 * tables + 4 * vec
+    assert ma.temp_size_in_bytes <= 4 * vec, ma
+    text = c.as_text()
+    for name in ("pmt_kirchhoff", "pmt_kirchhoff_adj", "pmt_conv1d"):
+        assert re.search(r'op_name="[^"]*/while/body/[^"]*%s' % name,
+                         text), name
+    assert re.search(r'op_name="[^"]*/while/body/[^"]*pmt.MPIVStack.matvec/'
+                     r'[^"]*pmt.local.TravelTimeSpray', text)
