@@ -26,8 +26,10 @@ dtypes, from the per-point travel times ``(ns + nr, npix)`` in one
 program: nothing pair-sized exists on the host. Both applies are one
 Pallas kernel each, ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj``
 (``ops/pallas_kernels.py``: the index becomes compares of a tile of
-1,024 pixels against the samples of the band its travel times span;
-compiled on a TPU, interpreted elsewhere — one form on every backend).
+1,024 pixels against the samples of the band its travel times span —
+in the adjoint, for a band that fits a 128-sample window of the trace,
+two lane gathers from that window; compiled on a TPU, interpreted
+elsewhere — one form on every backend).
 So that a tile's band is short the tables hold the image's pixels in
 blocks of 32 x 32 (:class:`_BlockOrder` puts a model in that order, a
 2 MB transpose an apply). The tables are pytree children of the
@@ -68,15 +70,20 @@ _TILE = _pk.KIRCHHOFF_TILE
 _BLOCK = (32, 32)        # pixels of the image a tile of the tables holds
 
 
-@partial(jax.jit, static_argnames=("last",))
-def _pack(i, w, inside, last: int):
-    """Tables ``(pairs, npix)`` in the kernels' layout, the longest
-    band and the count of entries dropped (``i`` outside ``[0, last]``
-    at a pixel that is ``inside`` the image)."""
+@partial(jax.jit, static_argnames=("last", "taps"))
+def _pack(i, w, inside, last: int, taps: int = 2):
+    """Tables ``(pairs, npix)`` of ``taps`` taps in the kernels' layout,
+    the longest band, the count of entries dropped (``i`` outside ``[0,
+    last]`` at a pixel that is ``inside`` the image) and the counts of
+    pair-tiles ``(not empty, read by lane gather)`` in the adjoint
+    (``pallas_kernels.kirchhoff_windowed``)."""
     inrange = (i >= 0) & (i <= last)
     it, wt, lohi = _pk.kirchhoff_pack(i, w, inside & inrange)
-    band = jnp.max(lohi[:, :, 1] - lohi[:, :, 0]) + 1
-    return it, wt, lohi, band, jnp.sum(inside & ~inrange)
+    lo, hi = lohi[:, :, 0], lohi[:, :, 1]
+    band = jnp.max(hi - lo) + 1
+    tiles = jnp.stack([jnp.sum(lo <= hi),
+                       jnp.sum(_pk.kirchhoff_windowed(lo, hi, taps))])
+    return it, wt, lohi, band, jnp.sum(inside & ~inrange), tiles
 
 
 class TravelTimeSpray(LocalOperator):
@@ -97,11 +104,18 @@ class TravelTimeSpray(LocalOperator):
     scatter-add / gather. Pixels are taken in the order given, 1,024 a
     tile; a tile costs a few vector operations a sample of the BAND its
     indices span, so neighbours in the table should be neighbours in
-    time. ``kirchhoff.path_select`` (``form``, ``pairs``, ``npix``,
-    ``nt``, ``tile``, ``band``, ``adjoint``, ``why``) says what a
-    traced apply took; the counters ``kirchhoff.pair_pixels`` and
+    time. The adjoint reads a tile whose band fits a 128-sample window
+    of the trace (up to 65 - taps samples: every tile of the
+    ``lsm_kirchhoff`` cell) by two lane gathers, whatever its length,
+    and walks any other. ``kirchhoff.path_select`` (``form``,
+    ``pairs``, ``npix``, ``nt``, ``tile``, ``band``, ``adjoint``,
+    ``why``; an adjoint's kernel also ``windowed``: the share of the
+    non-empty pair-tiles read by lane gather) says what a traced apply
+    took; the counters ``kirchhoff.pair_pixels`` and
     ``kirchhoff.taps_dropped`` count the tables' entries at
-    construction. Measured on a TPU v5e: see :func:`_form`."""
+    construction, ``kirchhoff.gather_tiles_windowed`` the pair-tiles
+    the adjoint reads by lane gather. Measured on a TPU v5e: see
+    :func:`_form`."""
 
     @property
     def whole(self):
@@ -122,7 +136,8 @@ class TravelTimeSpray(LocalOperator):
         self._init_packed(
             _pack(jnp.pad(jnp.asarray(itrav, dtype=jnp.int32), widths), w,
                   jnp.arange(npix + pad) < npix,
-                  last=int(nt) - (1 if frac is None else 2)),
+                  last=int(nt) - (1 if frac is None else 2),
+                  taps=1 if frac is None else 2),
             npairs, npix, nt, 1 if frac is None else 2, dtype)
 
     @classmethod
@@ -132,12 +147,17 @@ class TravelTimeSpray(LocalOperator):
         return self
 
     def _init_packed(self, packed, npairs, npix, nt, taps, dtype):
-        self._it, self._wt, self._lohi, band, dropped = packed
+        # a compile for a described chip packs abstract tables: no counts
+        self._it, self._wt, self._lohi, band, dropped = packed[:5]
         self.nt, self.taps = int(nt), int(taps)
         self.band = int(band) + self.taps - 1
         self.dropped = int(dropped)
+        self.tiles, self.windowed = (
+            (int(n) for n in np.asarray(packed[5])) if len(packed) > 5
+            else (0, 0))
         _metrics.inc("kirchhoff.pair_pixels", npairs * npix)
         _metrics.inc("kirchhoff.taps_dropped", self.dropped)
+        _metrics.inc("kirchhoff.gather_tiles_windowed", self.windowed)
         LocalOperator.__init__(self, dims=npix, dimsd=(npairs, self.nt),
                                dtype=dtype)
 
@@ -172,7 +192,8 @@ class TravelTimeSpray(LocalOperator):
         ==================================  ========  ========
         form                                forward   adjoint
         ==================================  ========  ========
-        ``pmt_kirchhoff`` / ``_adj``        20.2      13.0
+        ``pmt_kirchhoff`` / ``_adj``        20.2      3.46
+        (``_adj`` walking every band,                 13.0)
         scatter-add / gather, a trace       2,360     2,137
         (the benchmark's plain forms:
         scatter and index, 8 pairs a block  2,348     2,320
@@ -181,7 +202,14 @@ class TravelTimeSpray(LocalOperator):
 
         (bands of 26 samples a tile on average, 38 the longest; the
         kernels agree with the plain scatter to 9.9e-7 forward and
-        3.0e-7 adjoint, float32 sums in another order.) XLA's scatter
+        3.0e-7 adjoint, float32 sums in another order.) The adjoint
+        reads every tile of that shot by lane gather since PR 39
+        (``chip_probe/kirchhoff_gather_probe.py``: 16 tiles a step of
+        its loop 3.46 ms, 8 3.93, 4 5.12, a branch a tile 14.76, the
+        band loop 12.98; at 8 shots 21.9 against 102.1, 20.2 in the
+        cell's loop, 99.1 before; its window by a stride-0 broadcast
+        load 18.2, not taken: the interpreter has no such load; equal
+        to the band loop bit for bit). XLA's scatter
         and gather cost 8.7 ns an entry on the chip, the kernels 0.08
         and 0.05: the scatter form is for what the kernels cannot take
         (complex data, a trace whose accumulator outgrows VMEM), never
@@ -195,11 +223,14 @@ class TravelTimeSpray(LocalOperator):
             why = "dtype"
         elif not _pk.kirchhoff_legal(self.nt, dtype):
             why = "nt"
+        extra = {"why": why} if why else {}
+        if adjoint and not why:
+            extra["windowed"] = self.windowed / max(self.tiles, 1)
         _trace.event("kirchhoff.path_select", cat="schedule",
                      form="scatter" if why else "pmt_kirchhoff",
                      pairs=self.dimsd[0], npix=self.dims[0], nt=self.nt,
                      tile=_TILE, band=self.band, adjoint=int(adjoint),
-                     **({"why": why} if why else {}))
+                     **extra)
         return why
 
     def _flat_tables(self):

@@ -840,20 +840,75 @@ def _kirchhoff_spray_kernel(lh_ref, i_ref, w_ref, m_ref, y_ref, acc_ref, *,
         y_ref[0] = y[0:1]
 
 
-def _kirchhoff_gather_kernel(lh_ref, z_ref, i_ref, w_ref, m_ref, *,
-                             taps: int):
+# The gather by LANE GATHER (PR 39). The kernel also takes the trace as
+# overlapping WINDOWS of ``_KIR_WINDOW`` = 128 samples at a stride of
+# ``_KIR_STRIDE`` = 64 (window ``b``: samples ``[64 b, 64 b + 128)``,
+# zeros past the trace's end), one row of lanes each, in VMEM. A tile
+# whose band, with the ``taps - 1`` samples past it, lies in the window
+# that starts at or below its ``lo`` (:func:`kirchhoff_windowed`: every
+# band of up to 65 - taps samples does) broadcasts that row to a
+# register and takes each tap with ONE lane gather (``jnp.take_along_
+# axis`` along lanes, Mosaic's ``tpu.dynamic_gather``): ``g0 = row[i -
+# 64 b]``, ``g1 = row[i - 64 b + 1]``, whatever the band's length; an
+# empty tile, whose entries are all dropped, likewise. Any other tile
+# walks its band as the block above says. The loop takes
+# ``_KIR_GROUP`` tiles a step, without a branch between them where
+# every tile of the grid step is gathered (a flag a step, made from the
+# bands beside the kernel), so that their gathers overlap. The gathered
+# values are the ones the compares pick, the sum over the pairs keeps
+# its order, and both ways end in one expression after a ``lax.cond``:
+# the same result, bit for bit.
+
+_KIR_WINDOW = 128              # samples a window of the trace: a lane row
+_KIR_SHIFT = 6                 # a window starts every 2 ** 6 samples
+_KIR_STRIDE = 1 << _KIR_SHIFT
+_KIR_GROUP = 16                # tiles a step of the gather's loop
+
+__all__ += ["kirchhoff_windowed"]
+
+
+def kirchhoff_windowed(lo, hi, taps: int):
+    """Whether the band ``[lo, hi]`` of a tile (scalars, or arrays of
+    bands) is not empty and lies, with the ``taps - 1`` samples past
+    it, in the window of the trace that starts at ``64 * (lo // 64)``:
+    the tiles ``pmt_kirchhoff_adj`` reads by lane gather."""
+    start = (lo >> _KIR_SHIFT) << _KIR_SHIFT
+    return (lo <= hi) & (hi + (taps - 1) - start < _KIR_WINDOW)
+
+
+def _kir_gathered(lo, hi, taps: int):
+    """The tiles the gather reads by lane gather: windowed or empty."""
+    return kirchhoff_windowed(lo, hi, taps) | (lo > hi)
+
+
+def _kirchhoff_gather_kernel(fit_ref, lh_ref, z_ref, zw_ref, i_ref, w_ref,
+                             m_ref, *, taps: int, group: int):
     """Grid ``(nblk, pairs)``: one block of ``TB`` tiles, one trace;
     ``m_ref (TB, 8, 128)`` stays where it is over the traces and is
-    added to. ``z_ref (1, ntz)``: the trace's samples in SMEM, zeros
-    past its end."""
+    added to. ``fit_ref (1, nblk)`` in SMEM: whether each block's every
+    tile is gathered; ``z_ref (1, ntz)``: the trace's samples in SMEM,
+    zeros past its end, for the band loop; ``zw_ref (nwin, 1, 128)``:
+    its windows, for the lane gather."""
     @pl.when(pl.program_id(1) == 0)
     def _():
         m_ref[...] = jnp.zeros_like(m_ref)
 
-    def tile(k, carry):
-        i, w = i_ref[0, k], w_ref[0, k]
-        lo = lh_ref[0, k]
-        zero = jnp.zeros(i.shape, m_ref.dtype)
+    zero = jnp.zeros(i_ref.shape[2:], m_ref.dtype)
+
+    def lanes(k):
+        i = i_ref[0, k]
+        b = jnp.minimum(lh_ref[0, k] >> _KIR_SHIFT, zw_ref.shape[0] - 1)
+        row = jnp.broadcast_to(zw_ref[b], i.shape)
+        d = i - b * _KIR_STRIDE
+        live = d >= 0          # a dropped entry; any other is in the window
+        d = jnp.where(live, d, 0)
+        g = [jnp.where(live, jnp.take_along_axis(
+            row, d + s, axis=1, mode="promise_in_bounds"), 0)
+            for s in range(taps)]
+        return g[0], g[1] if taps == 2 else zero
+
+    def band(k):
+        i, lo = i_ref[0, k], lh_ref[0, k]
 
         def step(s, g):
             g0, g1 = g
@@ -864,12 +919,25 @@ def _kirchhoff_gather_kernel(lh_ref, z_ref, i_ref, w_ref, m_ref, *,
                 if taps == 2:
                     g1 = jnp.where(here, z_ref[0, t + 1], g1)
             return g0, g1
-        g0, g1 = jax.lax.fori_loop(0, _kir_steps(lo, lh_ref[1, k]), step,
-                                   (zero, zero))
-        m_ref[k] = m_ref[k] + ((1 - w) * g0 + w * g1 if taps == 2
-                             else w * g0)
+        return jax.lax.fori_loop(0, _kir_steps(lo, lh_ref[1, k]), step,
+                                 (zero, zero))
+
+    def either(k):
+        return jax.lax.cond(_kir_gathered(lh_ref[0, k], lh_ref[1, k], taps),
+                            lanes, band, k)
+
+    fit = fit_ref[0, pl.program_id(0)] != 0
+
+    def tiles(s, carry):
+        ks = [s * group + u for u in range(group)]
+        gs = jax.lax.cond(fit, lambda: [lanes(k) for k in ks],
+                          lambda: [either(k) for k in ks])
+        for k, (g0, g1) in zip(ks, gs):
+            w = w_ref[0, k]
+            m_ref[k] = m_ref[k] + ((1 - w) * g0 + w * g1 if taps == 2
+                                 else w * g0)
         return carry
-    jax.lax.fori_loop(0, i_ref.shape[1], tile, 0)
+    jax.lax.fori_loop(0, i_ref.shape[1] // group, tiles, 0)
 
 
 def _kir_specs(lohi, swap: bool):
@@ -915,17 +983,30 @@ def kirchhoff_spray(lohi, it, wt, m, nt: int, taps: int) -> jax.Array:
 def kirchhoff_gather(lohi, it, wt, z, taps: int) -> jax.Array:
     """``m (ntiles * 1024,)``: the adjoint of :func:`kirchhoff_spray`
     on traces ``z (pairs, nt)``, summed over the traces. Kernel
-    ``pmt_kirchhoff_adj``."""
+    ``pmt_kirchhoff_adj``: a tile whose band fits a window of the trace
+    (:func:`kirchhoff_windowed`) by lane gather, any other by a walk
+    over its band."""
     pairs, nblk, _, tb = lohi.shape
     nt = z.shape[1]
     ntz = -(-(nt + _KIR_UNROLL + 1) // 128) * 128
+    nwin = -(-nt // _KIR_STRIDE)     # the last starts at or below nt - 1
+    zs = jnp.pad(z, ((0, 0), (0, (nwin + 1) * _KIR_STRIDE - nt))).reshape(
+        pairs, nwin + 1, _KIR_STRIDE)
+    windows = jnp.concatenate([zs[:, :-1], zs[:, 1:]], axis=2)[:, :, None]
+    fit = jnp.all(_kir_gathered(lohi[:, :, 0], lohi[:, :, 1], taps),
+                  axis=-1).astype(jnp.int32)[:, None, :]
     bands, ti, tw = _kir_specs(lohi, swap=True)
     m = pl.pallas_call(
-        partial(_kirchhoff_gather_kernel, taps=taps),
+        partial(_kirchhoff_gather_kernel, taps=taps,
+                group=int(np.gcd(_KIR_GROUP, tb))),
         grid=(nblk, pairs),
-        in_specs=[bands,
+        in_specs=[pl.BlockSpec((None, 1, nblk), lambda j, p: (p, 0, 0),
+                               memory_space=pltpu.SMEM),
+                  bands,
                   pl.BlockSpec((None, 1, ntz), lambda j, p: (p, 0, 0),
                                memory_space=pltpu.SMEM),
+                  pl.BlockSpec((None, nwin, 1, _KIR_WINDOW),
+                               lambda j, p: (p, 0, 0, 0)),
                   ti, tw],
         out_specs=pl.BlockSpec((tb, 8, 128), lambda j, p: (j, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((nblk * tb, 8, 128), z.dtype),
@@ -934,5 +1015,6 @@ def kirchhoff_gather(lohi, it, wt, z, taps: int) -> jax.Array:
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
         interpret=_interpret(),
         name="pmt_kirchhoff_adj",
-    )(lohi, jnp.pad(z, ((0, 0), (0, ntz - nt)))[:, None, :], it, wt)
+    )(fit, lohi, jnp.pad(z, ((0, 0), (0, ntz - nt)))[:, None, :], windows,
+      it, wt)
     return m.ravel()

@@ -345,6 +345,94 @@ def test_the_scatter_form_is_the_same_operator(rng, monkeypatch, adjoint):
         == [("scatter", "nt", adjoint)]
 
 
+# --------------------------- the adjoint's lane gather (PR 39)
+def _banded(rng, taps, nt, ntiles, long=None, pairs=2):
+    """Indices ``(pairs, ntiles * 1024)`` whose every tile spans a band
+    of at most 40 samples at a random place, tile 0's reaching the
+    trace's last tap (``nt - taps``), 5 % of the entries dropped (-7),
+    tile 1 all dropped (an empty tile) and tile ``long``'s band samples
+    40 to 139: 100 wide, and in no window; and weights in ``[0, 1)``."""
+    i = np.empty((pairs, ntiles * 1024), np.int64)
+    ends = []
+    for t in range(ntiles):
+        width = 100 if t == long else int(rng.integers(1, 41))
+        lo = 40 if t == long else nt - taps + 1 - width if t == 0 else int(
+            rng.integers(0, nt - taps + 2 - width))
+        i[:, t * 1024:(t + 1) * 1024] = lo + rng.integers(
+            0, width, (pairs, 1024))
+        ends.append((t * 1024, lo, lo + width - 1))
+    i[rng.random(i.shape) < 0.05] = -7
+    for x, lo, hi in ends:                      # the bands as drawn
+        i[:, x], i[:, x + 1] = lo, hi
+    i[:, 1024:2048] = -7
+    return i, rng.uniform(0, 1, i.shape).astype(np.float32)
+
+
+def _spray(i, w, nt, taps, dtype=np.float32):
+    return (TravelTimeSpray(i, None, nt, dtype=dtype, frac=w) if taps == 2
+            else TravelTimeSpray(i, w, nt, dtype=dtype))
+
+
+def _windowed_tiles(i, nt, taps):
+    """Non-empty pair-tiles, and those whose band fits a window."""
+    t = i.reshape(i.shape[0], -1, 1024)
+    ok = (t >= 0) & (t <= nt - taps)
+    lo = np.where(ok, t, 1 << 30).min(-1)
+    hi = np.where(ok, t, -1).max(-1)
+    fits = (lo <= hi) & (hi + taps - 1 - lo // 64 * 64 <= 127)
+    return int((lo <= hi).sum()), int(fits.sum())
+
+
+@pytest.mark.parametrize("taps", [1, 2])
+@pytest.mark.parametrize("ntiles, long", [(6, None), (6, 3), (70, 66)],
+                         ids=["windowed", "one-long-band", "two-blocks"])
+def test_the_lane_gather_is_the_band_loop_bit_for_bit(rng, monkeypatch,
+                                                       taps, ntiles, long):
+    """``pmt_kirchhoff_adj`` (interpreted) against a copy of the band
+    loop it replaces (``chip_probe/kirchhoff_gather_probe.py``), bit for
+    bit: one and two taps, float32, an index on the trace's last tap,
+    dropped entries, an empty tile; one tile of a 100-sample band,
+    which the band loop takes (``two-blocks``: the tables' second grid
+    step, its first every tile gathered); the counter and the adjoint's
+    event count only the tiles that fit a window."""
+    from chip_probe.kirchhoff_gather_probe import band_loop_gather
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_METRICS", "on")
+    metrics.clear_metrics()
+    trace.clear_events()
+    nt = 300
+    i, w = _banded(rng, taps, nt, ntiles, long)
+    op = _spray(i, w, nt, taps)
+    nonempty, fits = _windowed_tiles(i, nt, taps)
+    assert (op.tiles, op.windowed) == (nonempty, fits)
+    assert fits == nonempty - (0 if long is None else 2)
+    assert metrics.snapshot()["counters"][
+        "kirchhoff.gather_tiles_windowed"] == fits
+    z = jnp.asarray(rng.standard_normal((2, nt)), jnp.float32)
+    got = np.asarray(op.rmatvec(z.ravel()))
+    want = np.asarray(band_loop_gather(op._lohi, op.itrav, op.weight, z,
+                                       taps))[:i.shape[1]]
+    assert np.array_equal(got, want), np.abs(got - want).max()
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "kirchhoff.path_select"]
+    assert [a["windowed"] for a in ev] == [fits / nonempty]
+
+
+@pytest.mark.parametrize("taps", [1, 2])
+def test_the_gather_is_the_spray_s_adjoint_across_both_ways(rng, taps):
+    """The dot test in float64 on tables that send some tiles through
+    the lane gather and one through the band loop."""
+    nt = 300
+    i, w = _banded(rng, taps, nt, 6, long=3)
+    op = _spray(i, w.astype(np.float64), nt, taps, dtype=np.float64)
+    assert 0 < op.windowed < op.tiles
+    m = rng.standard_normal(op.shape[1])
+    z = rng.standard_normal(op.shape[0])
+    lhs = np.asarray(op.matvec(jnp.asarray(m))) @ z
+    rhs = m @ np.asarray(op.rmatvec(jnp.asarray(z)))
+    np.testing.assert_allclose(lhs, rhs, rtol=1e-12)
+
+
 # ------------------------------------------------- the shares add up
 def test_the_four_shares_add_up_to_the_whole_line(case):
     """The deployment deals the line's shots over four chips: the

@@ -715,6 +715,40 @@ def _lsm_for_v5e(v5e_devices, what: str):
         cc.reset_cache()
 
 
+def _mosaic(text, name):
+    """Kernel ``name``'s Mosaic module in a compiled program's text: a
+    digest of its ops (locations left out) and one of the lines and
+    columns of ``ops/pallas_kernels.py`` up to the end of
+    ``_kirchhoff_spray_kernel`` that its locations name (the kernel's
+    own; its callers' move with any line below it)."""
+    import base64
+    import hashlib
+    import inspect
+    from jax._src.lib.mlir import ir
+    line = next(ln for ln in text.split("\n") if "tpu_custom_call" in ln
+                and re.search(r"%%%s(\.\d+)? = " % name, ln))
+    body = base64.b64decode(re.search(r'"body":"([^"]+)"', line).group(1))
+    ctx = ir.Context()
+    ctx.allow_unregistered_dialects = True
+    with ctx:
+        module = ir.Module.parse(body)
+        ops = module.operation.get_asm(enable_debug_info=False)
+        located = module.operation.get_asm(enable_debug_info=True)
+    src, first = inspect.getsourcelines(pk._kirchhoff_spray_kernel)
+    spans = sorted(set(
+        m.group(0) for m in re.finditer(
+            r'pallas_kernels\.py":(\d+):\d+ to [\d:]*\d+', located)
+        if int(m.group(1)) < first + len(src)))
+    digest = lambda t: hashlib.sha256(t.encode()).hexdigest()[:16]
+    return digest(ops), digest("\n".join(spans))
+
+
+# ``_mosaic(..., "pmt_kirchhoff")`` of the solver's compile below at the
+# commit before PR 39, with this container's JAX (0.9.0): the spray is
+# left as it was, byte for byte but for its callers' lines
+SPRAY_MOSAIC = ("4f83131e4cda861e", "1f9aaa546ffae31e")
+
+
 @pytest.mark.parametrize("what", ["tables", "solver"])
 def test_lsm_compiles_for_v5e(v5e_devices, monkeypatch, what):
     """Mosaic accepts ``pmt_kirchhoff`` / ``pmt_kirchhoff_adj`` at the
@@ -737,3 +771,5 @@ def test_lsm_compiles_for_v5e(v5e_devices, monkeypatch, what):
                          text), name
     assert re.search(r'op_name="[^"]*/while/body/[^"]*pmt.MPIVStack.matvec/'
                      r'[^"]*pmt.local.TravelTimeSpray', text)
+    if jax.__version__ == "0.9.0":
+        assert _mosaic(text, "pmt_kirchhoff") == SPRAY_MOSAIC
