@@ -569,7 +569,8 @@ asmpilinearoperator = aslinearoperator
 # and on multi-host pods, replacing the reference's per-rank operator
 # state (each rank owning only its local block).
 
-OP_ARRAY_PYTREES = set()
+# registered class -> the names of its children (``register_operator_arrays``)
+OP_ARRAY_PYTREES = {}
 
 
 def register_operator_arrays(cls, *attrs: str) -> None:
@@ -591,7 +592,7 @@ def register_operator_arrays(cls, *attrs: str) -> None:
         return new
 
     jax.tree_util.register_pytree_node(cls, _flatten, _unflatten)
-    OP_ARRAY_PYTREES.add(cls)
+    OP_ARRAY_PYTREES[cls] = attrs
 
 
 def operator_is_jit_arg(Op) -> bool:

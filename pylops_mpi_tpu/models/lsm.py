@@ -36,12 +36,20 @@ blocks of 32 x 32 (:class:`_BlockOrder` puts a model in that order, a
 operator (``register_operator_arrays``): the fused solvers take them
 as ``jit`` arguments, never as constants of the compiled program.
 
+**On several devices** ``MPILSM`` makes shard ``c``'s tables on mesh
+device ``c`` (``KirchhoffDemigration(device=)``): no table crosses a
+chip or the host. The blocks are alike, so ``MPIVStack`` takes its
+sharded form (``ops/stack.py``): the tables of all shards are laid into
+one stack sharded over the mesh with no copy, each device applies its
+own block under ``shard_map``, and the adjoint's partial images meet in
+one ``psum`` of the image (``pmt.collective.stack_reduce``). A shot
+count that is no multiple of the devices gives blocks of two sizes: the
+replicated form, every table on the default device as before.
+
 **Departures from upstream that remain**: travel times are analytic
 only (no eikonal, no user-supplied tables through ``MPILSM``; a
 :class:`TravelTimeSpray` takes any tables); ``dynamic=True`` (amplitude
-and obliquity weights) is not there; an ``MPIVStack`` of these blocks
-on several chips places every shard's tables on the default device
-(PERF.md section 7, the mix ``cgls_shots32_4chip``).
+and obliquity weights) is not there.
 """
 
 from __future__ import annotations
@@ -116,6 +124,11 @@ class TravelTimeSpray(LocalOperator):
     construction, ``kirchhoff.gather_tiles_windowed`` the pair-tiles
     the adjoint reads by lane gather. Measured on a TPU v5e: see
     :func:`_form`."""
+
+    # the blocks of one stack differ in these counts of their tables
+    # alone; a sharded stack reports the longest band and the totals
+    shard_merge = {"band": max, "dropped": sum, "tiles": sum,
+                   "windowed": sum}
 
     @property
     def whole(self):
@@ -334,14 +347,15 @@ def _tables(srcs, rcvs, pix, inside, vel, dt, nt: int):
 def KirchhoffDemigration(z: np.ndarray, x: np.ndarray, t: np.ndarray,
                          sources: np.ndarray, recs: np.ndarray, vel: float,
                          wav: np.ndarray, wavcenter: int,
-                         dtype=np.float32) -> LocalOperator:
+                         dtype=np.float32, device=None) -> LocalOperator:
     """Kirchhoff demigration ``d(s, r, t) = w(t) * sum_x m(x)
     hat(t - t_s(x) - t_r(x))`` for one batch of sources (module
     docstring: two taps a pixel, no amplitude, constant-velocity
     straight rays; the engine inside ``pylops.waveeqprocessing.LSM``
     the reference stacks, ref ``tutorials/lsm.py``): ``Conv1D *
     TravelTimeSpray * _BlockOrder``. ``sources (2, ns)`` and ``recs
-    (2, nr)`` hold ``(x, z)``. The event ``lsm.tables`` (``pairs``,
+    (2, nr)`` hold ``(x, z)``. The tables are made on ``device`` (JAX's
+    default where ``None``). The event ``lsm.tables`` (``pairs``,
     ``npix``, ``stored``, ``table_bytes``, ``built_on``) says what was
     made."""
     nz, nx, nt = len(z), len(x), len(t)
@@ -353,8 +367,9 @@ def KirchhoffDemigration(z: np.ndarray, x: np.ndarray, t: np.ndarray,
     rcvs = np.asarray(recs, dtype=float).T                  # (nr, 2)
     npairs = srcs.shape[0] * rcvs.shape[0]
     real = np.dtype(dtype)
-    packed = _tables(*(jnp.asarray(a, dtype=real) for a in (srcs, rcvs, pix)),
-                     jnp.asarray(inside), real.type(vel),
+    packed = _tables(*(jnp.asarray(a, dtype=real, device=device)
+                       for a in (srcs, rcvs, pix)),
+                     jnp.asarray(inside, device=device), real.type(vel),
                      real.type(t[1] - t[0]), nt=nt)
     spray = TravelTimeSpray._from_packed(packed, npairs, pix.shape[0], nt,
                                          2, dtype)
@@ -369,8 +384,10 @@ def KirchhoffDemigration(z: np.ndarray, x: np.ndarray, t: np.ndarray,
 def MPILSM(z, x, t, sources, recs, vel, wav, wavcenter,
            mesh=None, dtype=np.float32) -> MPIVStack:
     """Distributed LSM operator: sources split over shards, one
-    Kirchhoff demigration block per shard, stacked with ``MPIVStack``
-    (model BROADCAST, data SCATTER — ref ``tutorials/lsm.py``)."""
+    Kirchhoff demigration block per shard, its tables made on the
+    shard's device, stacked with ``MPIVStack`` (model BROADCAST, data
+    SCATTER — ref ``tutorials/lsm.py``; module docstring: the sharded
+    form)."""
     from ..parallel.mesh import default_mesh
     mesh = mesh if mesh is not None else default_mesh()
     P = int(mesh.devices.size)
@@ -379,9 +396,12 @@ def MPILSM(z, x, t, sources, recs, vel, wav, wavcenter,
     if ns < P:
         raise ValueError(f"MPILSM: {ns} source(s) cannot be dealt over a "
                          f"mesh of {P} devices (every shard needs one)")
+    # dealt evenly, shard c's tables are made on device c; blocks of
+    # two sizes keep the replicated form, whose tables lie together
+    devices = mesh.devices.flat if ns % P == 0 else [None] * P
     ops = [KirchhoffDemigration(z, x, t, sources[:, c], recs, vel, wav,
-                                wavcenter, dtype=dtype)
-           for c in np.array_split(np.arange(ns), P)]
+                                wavcenter, dtype=dtype, device=dev)
+           for c, dev in zip(np.array_split(np.arange(ns), P), devices)]
     return MPIVStack(ops, mesh=mesh)
 
 

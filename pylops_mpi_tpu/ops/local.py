@@ -54,6 +54,10 @@ class LocalOperator:
     # factor is. A compiled kernel is opaque to the partitioner as it
     # is, so on a TPU nothing answers True.
     whole = False
+    # attributes that report on the operator rather than define its
+    # apply, each with how a sharded ``MPIVStack`` merges it over its
+    # blocks (``ops/stack.py``): blocks that differ only there are alike
+    shard_merge: dict = {}
 
     def __init__(self, dims, dimsd, dtype=None, name: str = "L"):
         self.dims = tuple(int(d) for d in np.ravel(dims))

@@ -5,8 +5,10 @@ Rebuild of ``pylops_mpi/basicoperators/VStack.py:21-203`` and
 model, every rank computes its own row-block (no comm), output is
 SCATTER; adjoint computes per-rank partials ``Lᵢᴴ xᵢ`` then
 sum-allreduces into a BROADCAST result (ref ``VStack.py:135-150``).
-Here the partials are a static slice-apply chain whose final sum the XLA
-partitioner lowers to the same allreduce over ICI.
+Here alike blocks run one rank a device under ``shard_map`` and their
+partials meet in one ``psum``; other blocks are a static slice-apply
+chain whose final sum the XLA partitioner lowers to the same allreduce
+over ICI (``MPIVStack``'s docstring).
 """
 
 from __future__ import annotations
@@ -17,6 +19,7 @@ import numpy as np
 import jax
 import jax.numpy as jnp
 
+from ..diagnostics import trace as _trace
 from ..distributedarray import DistributedArray, Partition
 from ..stacked import StackedDistributedArray
 from ..linearoperator import MPILinearOperator
@@ -25,6 +28,46 @@ from ._precision import check_compute_dtype, einsum_narrow
 from .local import LocalOperator
 
 __all__ = ["MPIVStack", "MPIStackedVStack", "MPIHStack"]
+
+
+def _nodes(treedef, registry) -> list:
+    """``(class, aux, children)`` of every node of a pytree structure,
+    depth first: for a registered operator class the aux is the operator
+    and ``children`` the names of its pytree children, else ``None``."""
+    data = treedef.node_data()
+    if data is None:
+        return []
+    out = [data + (registry.get(data[0]),)]
+    for c in treedef.children():
+        out += _nodes(c, registry)
+    return out
+
+
+def _alike(a, b) -> bool:
+    """Two nodes of :func:`_nodes` are one class with equal state, up
+    to their children and the reports the class merges
+    (``shard_merge``)."""
+    (ca, na, kids), (cb, nb, _) = a, b
+    if ca is not cb:
+        return False
+    if kids is None:
+        return _same(na, nb)
+    skip = set(kids) | set(getattr(na, "shard_merge", {}))
+    va, vb = ({k: v for k, v in vars(n).items() if k not in skip}
+              for n in (na, nb))
+    return va.keys() == vb.keys() and all(_same(va[k], vb[k]) for k in va)
+
+
+def _same(a, b) -> bool:
+    if isinstance(a, (np.ndarray, jax.Array)) \
+            or isinstance(b, (np.ndarray, jax.Array)):
+        return np.shape(a) == np.shape(b) \
+            and np.result_type(a) == np.result_type(b) \
+            and bool(np.array_equal(np.asarray(a), np.asarray(b)))
+    try:
+        return bool(a == b)
+    except Exception:       # noqa: BLE001 - incomparable: not alike
+        return a is b
 
 
 class MPIVStack(MPILinearOperator):
@@ -58,6 +101,34 @@ class MPIVStack(MPILinearOperator):
     ``hier_all_gather``): the inner ICI stage shrinks the payload
     ``P_ici``-fold before anything touches DCN. With ``hierarchical``
     off a hybrid mesh keeps the bulk einsum-then-psum path.
+
+    **Any other blocks** are applied one by one, in one of two forms
+    read from what the stack sees (``form``):
+
+    - ``sharded``, on a mesh of several devices, where the local
+      operators are registered (``register_operator_arrays``), alike
+      (one tree of classes, equal leaf shapes and dtypes, equal
+      non-array state; the reports a class names in ``shard_merge`` are
+      merged, not compared) and as many as a multiple of the devices.
+      Their array leaves are laid one after another along their leading
+      axis, sharded over the mesh (``parallel/mesh.py::concat_sharded``:
+      a block's arrays made on the device that owns it ARE that
+      device's shard, uncopied), and both applies run under
+      ``shard_map``, each device applying its own blocks only. The
+      forward takes the replicated model and leaves its rows
+      ``SCATTER`` with no collective; the adjoint sums the device's
+      partials and ONE ``psum`` over the mesh gives the ``BROADCAST``
+      result (named scope ``pmt.collective.stack_reduce``, around it
+      alone) — the reference's sum-allreduce, one rank a device;
+    - ``replicated`` otherwise (``why``: ``one_device``, ``ragged``,
+      ``unregistered``, ``mixed``): every local apply on the global
+      operands, cut by the partitioner where it can (``_apply_local``).
+
+    The event ``stack.placement`` (``form`` = ``sharded``,
+    ``replicated`` or ``batched``; ``blocks``, ``shards``,
+    ``bytes_a_shard`` = the array bytes of the blocks one device
+    applies; for ``replicated`` a one-word ``why``) says which, once a
+    stack.
     """
 
     def __init__(self, ops: Sequence[LocalOperator],
@@ -106,11 +177,98 @@ class MPIVStack(MPILinearOperator):
             from ._precision import default_compute_dtype
             self.compute_dtype = default_compute_dtype(dtype)
         self._batched, self._batched_adj = self._try_batch()
-        # the generic branch's local operators, as a pytree child: a
+        self._sharded = self._template = None
+        why = None if self._batched is not None else self._shard()
+        self.form = "batched" if self._batched is not None else \
+            "sharded" if why is None else "replicated"
+        # the replicated form's local operators, as a pytree child: a
         # registered local operator's arrays (a Kirchhoff block's
-        # tables) then reach the fused solvers as jit arguments
-        self._local = None if self._batched is not None \
-            else tuple(self.ops)
+        # tables) then reach the fused solvers as jit arguments; the
+        # sharded form's arrays are its stacked leaves, ``_sharded``
+        self._local = tuple(self.ops) if self.form == "replicated" \
+            else None
+        P_ = int(self.mesh.devices.size)
+        held = sum(int(a.nbytes) for a in jax.tree_util.tree_leaves(
+            (self._batched, self._sharded, self._local))
+            if hasattr(a, "nbytes"))
+        _trace.event("stack.placement", cat="schedule", form=self.form,
+                     blocks=len(self.ops), shards=P_,
+                     bytes_a_shard=held if self.form == "replicated"
+                     else held // P_, **({"why": why} if why else {}))
+
+    def _shard(self):
+        """Take the sharded form (class docstring) where the blocks
+        allow it: set ``_sharded`` (the stacked leaves) and
+        ``_template`` (the first block's tree, its ``shard_merge``
+        reports merged over the blocks) and return ``None``; else the
+        one word why not."""
+        from ..linearoperator import OP_ARRAY_PYTREES, operator_is_jit_arg
+        from ..parallel.mesh import concat_sharded
+        P_ = int(self.mesh.devices.size)
+        if P_ == 1:
+            return "one_device"
+        if len(self.ops) % P_ or len({op.shape for op in self.ops}) != 1:
+            return "ragged"
+        if not all(operator_is_jit_arg(op) for op in self.ops):
+            return "unregistered"
+        flat = [jax.tree_util.tree_flatten(op) for op in self.ops]
+        nodes = [_nodes(tdef, OP_ARRAY_PYTREES) for _, tdef in flat]
+        leaves = [[(np.shape(a), np.result_type(a)) for a in lv]
+                  for lv, _ in flat]
+        if any(lv != leaves[0] for lv in leaves) or any(
+                len(n) != len(nodes[0])
+                or not all(map(_alike, n, nodes[0])) for n in nodes):
+            return "mixed"
+        # the template: fresh copies of the first block's nodes
+        # (unflatten copies every node), its reports merged
+        tmpl = jax.tree_util.tree_unflatten(flat[0][1], flat[0][0])
+        self._template = jax.tree_util.tree_structure(tmpl)
+        for i, (_, node, _) in enumerate(_nodes(self._template,
+                                                OP_ARRAY_PYTREES)):
+            for k, merge in getattr(node, "shard_merge", {}).items():
+                setattr(node, k, merge(getattr(n[i][1], k) for n in nodes))
+        self._shapes = [s for s, _ in leaves[0]]
+        self._sharded = tuple(
+            concat_sharded([lv[i] for lv, _ in flat], self.mesh)
+            for i in range(len(self._shapes)))
+        return None
+
+    def _sharded_apply(self, v: jax.Array, adjoint: bool) -> jax.Array:
+        """One apply of the sharded form: ``shard_map`` over the mesh,
+        each device its own blocks (the template with the device's
+        leaves put in); the adjoint's partials reduced by one ``psum``
+        under ``pmt.collective.stack_reduce``."""
+        from jax import lax, shard_map
+        from jax.sharding import PartitionSpec as PSpec
+        names = self.mesh.axis_names
+        ax = names[0] if len(names) == 1 else tuple(names)
+        P_ = int(self.mesh.devices.size)
+        k = len(self.ops) // P_
+
+        def blocks(leaves):
+            return [jax.tree_util.tree_unflatten(self._template, [
+                a.reshape(s) if k == 1 else a.reshape((k,) + s)[j]
+                for a, s in zip(leaves, self._shapes)]) for j in range(k)]
+
+        def forward(leaves, x):
+            return jnp.concatenate([b.matvec(x) for b in blocks(leaves)])
+
+        def adjoint_(leaves, y):
+            rows = y.reshape(k, -1)
+            part = sum(b.rmatvec(rows[j])
+                       for j, b in enumerate(blocks(leaves)))
+            with _trace.span("collective.stack_reduce", cat="collective",
+                             shape=part.shape, dtype=part.dtype, axis=ax,
+                             n_shards=P_):
+                return lax.psum(part, ax)
+
+        spec = PSpec(ax)
+        return shard_map(
+            adjoint_ if adjoint else forward, mesh=self.mesh,
+            in_specs=((spec,) * len(self._sharded),
+                      spec if adjoint else PSpec()),
+            out_specs=PSpec() if adjoint else spec,
+            check_vma=False)(self._sharded, v)
 
     def _try_batch(self):
         """Homogeneous matrix blocks → one stacked, block-sharded GEMM.
@@ -182,6 +340,8 @@ class MPIVStack(MPILinearOperator):
         elif ncol is not None:
             # heterogeneous rows: one compiled vmap over columns
             return self._apply_columns(x, forward=True)
+        elif self._sharded is not None:
+            arr = self._sharded_apply(xg, adjoint=False)
         else:
             arr = jnp.concatenate([self._apply_local(op, xg, False)
                                    for op in self._local or self.ops])
@@ -337,6 +497,8 @@ class MPIVStack(MPILinearOperator):
                                     self.compute_dtype, self.dtype)
         elif ncol is not None:
             return self._apply_columns(x, forward=False)
+        elif self._sharded is not None:
+            acc = self._sharded_apply(x.array, adjoint=True)
         else:
             offs = np.concatenate([[0], np.cumsum(self.nops)])
             acc = None
@@ -411,10 +573,10 @@ class MPIHStack(MPILinearOperator):
 
 # batched stacks travel into jit as pytree arguments (multi-process
 # arrays must not be closed over — see linearoperator.py registry); so
-# do the local operators of a generic stack, where their classes are
-# registered (an unregistered one is an opaque leaf: closure capture,
-# as before)
+# do a sharded stack's stacked leaves, and the local operators of a
+# replicated stack where their classes are registered (an unregistered
+# one is an opaque leaf: closure capture, as before)
 from ..linearoperator import register_operator_arrays  # noqa: E402
-register_operator_arrays(MPIVStack, "_batched", "_local")
+register_operator_arrays(MPIVStack, "_batched", "_local", "_sharded")
 register_operator_arrays(MPIHStack, "vstack")
 register_operator_arrays(MPIStackedVStack, "ops")

@@ -33,6 +33,7 @@ __all__ = [
     "local_device_count",
     "best_grid_2d",
     "stack_sharded",
+    "concat_sharded",
 ]
 
 # The default axis name for 1-D sharding ("shard-parallel"); mirrors the
@@ -231,4 +232,32 @@ def stack_sharded(mats: Sequence, mesh: Mesh, dtype=None) -> jax.Array:
     for dev, idx in sharding.addressable_devices_indices_map(shape).items():
         part = np.stack(mats[idx[0]], dtype=dtype, casting="unsafe")
         shards.append(jax.device_put(part, dev))
+    return jax.make_array_from_single_device_arrays(shape, sharding, shards)
+
+
+def concat_sharded(parts: Sequence, mesh: Mesh) -> jax.Array:
+    """``concatenate(parts)`` along their leading axis (a 0-d part counts
+    as one element) as one array sharded over ``mesh`` along it, each
+    device's shard made of the contiguous parts it owns (``len(parts)``
+    divisible by the device count, every part of one shape).
+
+    A part that is alone on its device and already lies there IS that
+    device's shard: no copy is made, so blocks made where they are to
+    lie (gigabytes of tables a chip) are assembled in place. Any other
+    shard is concatenated on its own device."""
+    import jax.numpy as jnp
+    k = len(parts) // int(mesh.devices.size)
+    lead = tuple(np.shape(parts[0])) or (1,)
+    shape = (len(parts) * lead[0],) + lead[1:]
+    sharding = axis_sharding(mesh, len(shape), 0)
+    shards = []
+    for dev, idx in sharding.addressable_devices_indices_map(shape).items():
+        c = (idx[0].start or 0) // (k * lead[0])
+        own = parts[c * k:(c + 1) * k]
+        if k == 1 and isinstance(own[0], jax.Array) \
+                and own[0].shape == lead and own[0].devices() == {dev}:
+            shards.append(own[0])
+        else:
+            shards.append(jnp.concatenate([
+                jnp.reshape(jax.device_put(p, dev), lead) for p in own]))
     return jax.make_array_from_single_device_arrays(shape, sharding, shards)
