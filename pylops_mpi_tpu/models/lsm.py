@@ -84,13 +84,23 @@ def _pack(i, w, inside, last: int, taps: int = 2):
     the longest band, the count of entries dropped (``i`` outside ``[0,
     last]`` at a pixel that is ``inside`` the image) and the counts of
     pair-tiles ``(not empty, read by lane gather)`` in the adjoint
-    (``pallas_kernels.kirchhoff_windowed``)."""
+    (``pallas_kernels.kirchhoff_windowed``) and of the spray's loop
+    steps ``(walked, of the pairs' own bands)``: its groups of
+    ``pallas_kernels.kirchhoff_group`` traces walk each tile's union
+    band once a trace."""
     inrange = (i >= 0) & (i <= last)
     it, wt, lohi = _pk.kirchhoff_pack(i, w, inside & inrange)
     lo, hi = lohi[:, :, 0], lohi[:, :, 1]
     band = jnp.max(hi - lo) + 1
+    pairs = lo.shape[0]
+    g = _pk.kirchhoff_group(pairs, last + taps, w.dtype)
+    union = (jnp.min(lo.reshape(pairs // g, g, *lo.shape[1:]), axis=1),
+             jnp.max(hi.reshape(pairs // g, g, *hi.shape[1:]), axis=1))
     tiles = jnp.stack([jnp.sum(lo <= hi),
-                       jnp.sum(_pk.kirchhoff_windowed(lo, hi, taps))])
+                       jnp.sum(_pk.kirchhoff_windowed(lo, hi, taps)),
+                       g * jnp.sum(_pk._kir_steps(union[0],
+                                                  union[1] + taps - 1)),
+                       jnp.sum(_pk._kir_steps(lo, hi + taps - 1))])
     return it, wt, lohi, band, jnp.sum(inside & ~inrange), tiles
 
 
@@ -112,23 +122,30 @@ class TravelTimeSpray(LocalOperator):
     scatter-add / gather. Pixels are taken in the order given, 1,024 a
     tile; a tile costs a few vector operations a sample of the BAND its
     indices span, so neighbours in the table should be neighbours in
-    time. The adjoint reads a tile whose band fits a 128-sample window
+    time. The spray takes ``G`` consecutive traces a grid step
+    (``pallas_kernels.kirchhoff_group``, a rule in ``npairs``, ``nt``
+    and the dtype: 4 in the ``lsm_kirchhoff`` cell), a tile walking the
+    union of their bands, so neighbouring traces should be neighbours
+    in time too (shot-major pairs are). The adjoint reads a tile whose
+    band fits a 128-sample window
     of the trace (up to 65 - taps samples: every tile of the
     ``lsm_kirchhoff`` cell) by two lane gathers, whatever its length,
     and walks any other. ``kirchhoff.path_select`` (``form``,
     ``pairs``, ``npix``, ``nt``, ``tile``, ``band``, ``adjoint``,
-    ``why``; an adjoint's kernel also ``windowed``: the share of the
+    ``why``; a forward's kernel also ``group``: ``G``, and ``walk``:
+    the samples its union bands walk over those of the pairs' own
+    bands; an adjoint's kernel ``windowed``: the share of the
     non-empty pair-tiles read by lane gather) says what a traced apply
     took; the counters ``kirchhoff.pair_pixels`` and
     ``kirchhoff.taps_dropped`` count the tables' entries at
     construction, ``kirchhoff.gather_tiles_windowed`` the pair-tiles
-    the adjoint reads by lane gather. Measured on a TPU v5e: see
-    :func:`_form`."""
+    the adjoint reads by lane gather, ``kirchhoff.spray_group`` the
+    spray's ``G``. Measured on a TPU v5e: see :func:`_form`."""
 
     # the blocks of one stack differ in these counts of their tables
     # alone; a sharded stack reports the longest band and the totals
     shard_merge = {"band": max, "dropped": sum, "tiles": sum,
-                   "windowed": sum}
+                   "windowed": sum, "steps_walked": sum, "steps_banded": sum}
 
     @property
     def whole(self):
@@ -165,12 +182,14 @@ class TravelTimeSpray(LocalOperator):
         self.nt, self.taps = int(nt), int(taps)
         self.band = int(band) + self.taps - 1
         self.dropped = int(dropped)
-        self.tiles, self.windowed = (
+        self.tiles, self.windowed, self.steps_walked, self.steps_banded = (
             (int(n) for n in np.asarray(packed[5])) if len(packed) > 5
-            else (0, 0))
+            else (0, 0, 0, 0))
+        self.group = _pk.kirchhoff_group(npairs, self.nt, dtype)
         _metrics.inc("kirchhoff.pair_pixels", npairs * npix)
         _metrics.inc("kirchhoff.taps_dropped", self.dropped)
         _metrics.inc("kirchhoff.gather_tiles_windowed", self.windowed)
+        _metrics.inc("kirchhoff.spray_group", self.group)
         LocalOperator.__init__(self, dims=npix, dimsd=(npairs, self.nt),
                                dtype=dtype)
 
@@ -205,8 +224,9 @@ class TravelTimeSpray(LocalOperator):
         ==================================  ========  ========
         form                                forward   adjoint
         ==================================  ========  ========
-        ``pmt_kirchhoff`` / ``_adj``        20.2      3.46
-        (``_adj`` walking every band,                 13.0)
+        ``pmt_kirchhoff`` / ``_adj``        9.82      3.46
+        (``pmt_kirchhoff`` a trace a step   20.2
+        ``_adj`` walking every band,                  13.0)
         scatter-add / gather, a trace       2,360     2,137
         (the benchmark's plain forms:
         scatter and index, 8 pairs a block  2,348     2,320
@@ -215,7 +235,15 @@ class TravelTimeSpray(LocalOperator):
 
         (bands of 26 samples a tile on average, 38 the longest; the
         kernels agree with the plain scatter to 9.9e-7 forward and
-        3.0e-7 adjoint, float32 sums in another order.) The adjoint
+        3.0e-7 adjoint, float32 sums in another order.) The spray takes
+        4 traces a grid step (``chip_probe/kirchhoff_spray_probe.py``,
+        that shot: one trace
+        a step 20.23 ms, the same with a step's loads before its stores
+        16.85, 2 traces 12.07, 4 9.82; at 8 shots 160.16, 133.63, 93.42
+        and **75.00**, ``walk`` 1.04 at 2 and 1.11 at 4; 8 traces do not
+        fit VMEM at 1,024 samples, and at 512 read 51.02 against 4's
+        56.74 and one trace's 126.88; equal to one trace a step bit for
+        bit). The adjoint
         reads every tile of that shot by lane gather since PR 39
         (``chip_probe/kirchhoff_gather_probe.py``: 16 tiles a step of
         its loop 3.46 ms, 8 3.93, 4 5.12, a branch a tile 14.76, the
@@ -239,6 +267,10 @@ class TravelTimeSpray(LocalOperator):
         extra = {"why": why} if why else {}
         if adjoint and not why:
             extra["windowed"] = self.windowed / max(self.tiles, 1)
+        elif not why:
+            extra["group"] = _pk.kirchhoff_group(self.dimsd[0], self.nt,
+                                                 dtype)
+            extra["walk"] = self.steps_walked / max(self.steps_banded, 1)
         _trace.event("kirchhoff.path_select", cat="schedule",
                      form="scatter" if why else "pmt_kirchhoff",
                      pairs=self.dimsd[0], npix=self.dims[0], nt=self.nt,

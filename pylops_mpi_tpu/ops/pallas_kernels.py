@@ -736,15 +736,27 @@ def laplacian_stencil(x: jax.Array, ghost_front: jax.Array,
 # into 32 x 32 blocks) keeps the bands short. Tables in any order give
 # the same answer with longer bands. An entry that is dropped (outside
 # the trace) holds an index no sample equals.
+#
+# The spray takes G consecutive traces a grid step
+# (:func:`kirchhoff_group`): pairs are shot-major and neighbouring
+# receivers' travel times differ by a sample or two, so the G traces'
+# bands of one tile nearly coincide. A tile walks the UNION of their
+# bands once, and a loop step loads the G accumulators' rows of its
+# samples, updates them and stores them: G x unroll independent chains
+# where one trace's tiles, which share one accumulator, give one.
 
 __all__ += ["kirchhoff_spray", "kirchhoff_gather", "kirchhoff_pack",
-            "kirchhoff_legal", "KIRCHHOFF_TILE"]
+            "kirchhoff_legal", "kirchhoff_group", "KIRCHHOFF_TILE"]
 
 KIRCHHOFF_TILE = 1024          # pixels a tile: one (8, 128) register
 _KIR_UNROLL = 4                # samples a loop step
 _KIR_DROPPED = -(1 << 30)      # the index of a dropped entry
 _KIR_BLOCK_TILES = 64          # tiles a grid step (2 x 256 KiB of tables)
 _KIR_ACC_BYTES = 24 << 20      # the spray's accumulator: nt registers
+_KIR_GROUPS = (8, 4, 2, 1)     # traces a grid step of the spray, in order
+# the spray's accumulators, table blocks and the reduction's
+# temporaries: the limit less the image's and the output's blocks
+_KIR_SPRAY_VMEM = _VMEM_LIMIT_BYTES - (2 << 20)
 
 
 def kirchhoff_legal(nt: int, dtype) -> bool:
@@ -755,6 +767,23 @@ def kirchhoff_legal(nt: int, dtype) -> bool:
     return (dtype.kind == "f"
             and (nt + 128 + _KIR_UNROLL) * KIRCHHOFF_TILE * dtype.itemsize
             <= _KIR_ACC_BYTES)
+
+
+def kirchhoff_group(pairs: int, nt: int, dtype) -> int:
+    """Traces a grid step of ``pmt_kirchhoff`` for ``pairs`` traces of
+    ``nt`` samples of ``dtype``: the largest of ``_KIR_GROUPS`` that
+    divides ``pairs`` and whose accumulators (one ``(8, 128)`` register
+    a sample a trace) and double-buffered table blocks fit the spray's
+    share of VMEM beside the three accumulator-sized temporaries that
+    Mosaic gives the final reduction (compiled for a v5e: 18, 23, 33
+    and 53 MiB at 1, 2, 4 and 8 traces of 1,024 float32 samples); 1
+    for a trace that fits alone (:func:`kirchhoff_legal`). 4 for the
+    ``lsm_kirchhoff`` cell's 2,048 traces of 1,024 float32 samples."""
+    itemsize = np.dtype(dtype).itemsize
+    acc = (-(-nt // 128) * 128 + _KIR_UNROLL) * KIRCHHOFF_TILE * itemsize
+    tables = 2 * _KIR_BLOCK_TILES * KIRCHHOFF_TILE * (4 + itemsize)
+    return next(g for g in _KIR_GROUPS if pairs % g == 0 and (
+        g == 1 or (g + 3) * acc + g * tables <= _KIR_SPRAY_VMEM))
 
 
 def kirchhoff_pack(i, w, valid):
@@ -794,50 +823,68 @@ def _kir_steps(lo, hi):
     return jnp.maximum(hi - lo + _KIR_UNROLL, 0) // _KIR_UNROLL
 
 
-def _kirchhoff_spray_kernel(lh_ref, i_ref, w_ref, m_ref, y_ref, acc_ref, *,
+def _kirchhoff_spray_kernel(lh_ref, i_ref, w_ref, m_ref, y_ref, *accs,
                             taps: int, ntp: int):
-    """Grid ``(pairs, nblk)``: one trace, one block of ``TB`` tiles.
-    ``lh_ref (2, TB)`` in SMEM, ``i_ref`` / ``w_ref (1, TB, 8, 128)``,
-    ``m_ref (TB, 8, 128)``, ``y_ref (1, 1, ntp)`` written at the
-    trace's last block from ``acc_ref (ntp + unroll, 8, 128)``."""
+    """Grid ``(pairs // G, nblk)``: ``G`` consecutive traces, one block
+    of ``TB`` tiles. ``lh_ref (G, 2, TB)`` in SMEM, ``i_ref`` / ``w_ref
+    (G, TB, 8, 128)``, ``m_ref (TB, 8, 128)``, ``y_ref (G, 1, ntp)``
+    written at the group's last block from ``accs``: ``G`` buffers
+    ``(ntp + unroll, 8, 128)``, one a trace. A tile walks the union of
+    its ``G`` bands; a sample outside a trace's own band adds ``+0.0``
+    to its accumulator, which is never ``-0.0`` (it starts at ``+0.0``),
+    so each trace's sums are the one-trace kernel's, bit for bit."""
     j = pl.program_id(1)
 
     @pl.when(j == 0)
     def _():
-        acc_ref[...] = jnp.zeros_like(acc_ref)
+        for acc in accs:
+            acc[...] = jnp.zeros_like(acc)
 
     def tile(k, carry):
-        i, w, m = i_ref[0, k], w_ref[0, k], m_ref[k]
-        a = w * m                  # two taps: (1 - w) m at i, w m at i + 1
-        b = (1 - w) * m            # (the gather's weights, bit for bit)
-        lo = lh_ref[0, k]
+        m, group = m_ref[k], range(len(accs))
+        rows = []
+        for g in group:
+            i, w = i_ref[g, k], w_ref[g, k]
+            a = w * m              # two taps: (1 - w) m at i, w m at i + 1
+            b = (1 - w) * m        # (the gather's weights, bit for bit)
+            rows.append((i, a, b))
+        lo = reduce(jnp.minimum, [lh_ref[g, 0, k] for g in group])
+        hi = reduce(jnp.maximum, [lh_ref[g, 1, k] for g in group])
 
         def step(s, c):
-            for q in range(_KIR_UNROLL):
-                t = lo + s * _KIR_UNROLL + q
-                if taps == 2:
-                    v = jnp.where(i == t, b, 0) + jnp.where(i == t - 1, a, 0)
-                else:
-                    v = jnp.where(i == t, a, 0)
-                acc_ref[t] = acc_ref[t] + v
+            ts = [lo + s * _KIR_UNROLL + q for q in range(_KIR_UNROLL)]
+            new = [[acc[t] for t in ts] for acc in accs]   # loads first
+            for (i, a, b), row in zip(rows, new):
+                for q, t in enumerate(ts):
+                    if taps == 2:
+                        v = jnp.where(i == t, b, 0) + jnp.where(i == t - 1, a,
+                                                                0)
+                    else:
+                        v = jnp.where(i == t, a, 0)
+                    row[q] = row[q] + v
+            for acc, row in zip(accs, new):                # then stores
+                for t, r in zip(ts, row):
+                    acc[t] = r
             return c
-        return jax.lax.fori_loop(
-            0, _kir_steps(lo, lh_ref[1, k] + (taps - 1)), step, carry)
+        return jax.lax.fori_loop(0, _kir_steps(lo, hi + (taps - 1)), step,
+                                 carry)
     jax.lax.fori_loop(0, i_ref.shape[1], tile, 0)
 
     @pl.when(j == pl.num_programs(1) - 1)
     def _():
-        ys = jnp.sum(acc_ref[0:ntp], axis=1)                  # (ntp, 128)
         lanes = (((1,), (1,)), ((), ()))
-        if ys.dtype == jnp.float32:
-            ones = jnp.ones((8, 128), jnp.bfloat16)
-            y = reduce(jnp.add, (jax.lax.dot_general(
-                ones, p, lanes, preferred_element_type=jnp.float32,
-                precision=jax.lax.Precision.DEFAULT)
-                for p in reversed(_bf16_parts(ys, 3))))
-        else:           # wider than f32: interpreted only, a plain dot
-            y = jax.lax.dot_general(jnp.ones((8, 128), ys.dtype), ys, lanes)
-        y_ref[0] = y[0:1]
+        for g, acc in enumerate(accs):
+            ys = jnp.sum(acc[0:ntp], axis=1)                  # (ntp, 128)
+            if ys.dtype == jnp.float32:
+                ones = jnp.ones((8, 128), jnp.bfloat16)
+                y = reduce(jnp.add, (jax.lax.dot_general(
+                    ones, p, lanes, preferred_element_type=jnp.float32,
+                    precision=jax.lax.Precision.DEFAULT)
+                    for p in reversed(_bf16_parts(ys, 3))))
+            else:       # wider than f32: interpreted only, a plain dot
+                y = jax.lax.dot_general(jnp.ones((8, 128), ys.dtype), ys,
+                                        lanes)
+            y_ref[g] = y[0:1]
 
 
 # The gather by LANE GATHER (PR 39). The kernel also takes the trace as
@@ -950,26 +997,26 @@ def _kir_specs(lohi, swap: bool):
                         memory_space=pltpu.SMEM), table, table
 
 
-@partial(jax.jit, static_argnames=("nt", "taps"))
-def kirchhoff_spray(lohi, it, wt, m, nt: int, taps: int) -> jax.Array:
-    """``y (pairs, nt)``: the pixels ``m (ntiles * 1024,)`` sprayed
-    through the packed tables (:func:`kirchhoff_pack`), ``taps`` 1 or
-    2. Kernel ``pmt_kirchhoff`` (compiled on a TPU, interpreted
-    elsewhere). Gate on :func:`kirchhoff_legal`. Under ``jax.jit`` of
-    its own, as :func:`kirchhoff_gather`: an eager apply does not trace
-    the interpreted kernel anew, and the blocks of a stack that have
-    one shape share one lowering."""
+def _spray_call(lohi, it, wt, m, nt: int, taps: int, group: int):
+    """``pmt_kirchhoff`` at ``group`` traces a grid step ``(pairs //
+    group, nblk)``: the bands' block ``(group, 2, TB)``, the tables'
+    ``(group, TB, 8, 128)``."""
     pairs, nblk, _, tb = lohi.shape
     ntp = -(-nt // 128) * 128
-    bands, ti, tw = _kir_specs(lohi, swap=False)
+
+    def at(p, j):
+        return p, j, 0, 0
+    table = pl.BlockSpec((group, tb, 8, 128), at)
     y = pl.pallas_call(
         partial(_kirchhoff_spray_kernel, taps=taps, ntp=ntp),
-        grid=(pairs, nblk),
-        in_specs=[bands, ti, tw,
+        grid=(pairs // group, nblk),
+        in_specs=[pl.BlockSpec((group, None, 2, tb), at,
+                               memory_space=pltpu.SMEM), table, table,
                   pl.BlockSpec((tb, 8, 128), lambda p, j: (j, 0, 0))],
-        out_specs=pl.BlockSpec((1, 1, ntp), lambda p, j: (p, 0, 0)),
+        out_specs=pl.BlockSpec((group, 1, ntp), lambda p, j: (p, 0, 0)),
         out_shape=jax.ShapeDtypeStruct((pairs, 1, ntp), m.dtype),
-        scratch_shapes=[pltpu.VMEM((ntp + _KIR_UNROLL, 8, 128), m.dtype)],
+        scratch_shapes=[pltpu.VMEM((ntp + _KIR_UNROLL, 8, 128), m.dtype)
+                        for _ in range(group)],
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary"),
             vmem_limit_bytes=_VMEM_LIMIT_BYTES),
@@ -977,6 +1024,20 @@ def kirchhoff_spray(lohi, it, wt, m, nt: int, taps: int) -> jax.Array:
         name="pmt_kirchhoff",
     )(lohi, it, wt, m.reshape(nblk * tb, 8, 128))
     return y[:, 0, :nt]
+
+
+@partial(jax.jit, static_argnames=("nt", "taps"))
+def kirchhoff_spray(lohi, it, wt, m, nt: int, taps: int) -> jax.Array:
+    """``y (pairs, nt)``: the pixels ``m (ntiles * 1024,)`` sprayed
+    through the packed tables (:func:`kirchhoff_pack`), ``taps`` 1 or
+    2. Kernel ``pmt_kirchhoff`` (compiled on a TPU, interpreted
+    elsewhere), :func:`kirchhoff_group` traces a grid step. Gate on
+    :func:`kirchhoff_legal`. Under ``jax.jit`` of its own, as
+    :func:`kirchhoff_gather`: an eager apply does not trace the
+    interpreted kernel anew, and the blocks of a stack that have one
+    shape share one lowering."""
+    return _spray_call(lohi, it, wt, m, nt, taps,
+                       kirchhoff_group(lohi.shape[0], nt, m.dtype))
 
 
 @partial(jax.jit, static_argnames=("taps",))

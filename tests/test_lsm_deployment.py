@@ -433,6 +433,92 @@ def test_the_gather_is_the_spray_s_adjoint_across_both_ways(rng, taps):
     np.testing.assert_allclose(lhs, rhs, rtol=1e-12)
 
 
+# ------------------------------------- the spray's pair groups
+def _steps(lo, hi, taps):
+    return np.maximum(hi + taps - 1 - lo + pk._KIR_UNROLL, 0) \
+        // pk._KIR_UNROLL
+
+
+def _shifted(rng, taps, nt, pairs):
+    """``_banded``'s tables with each pair's live entries moved by ``p
+    % 3`` samples (neighbouring traces: the bands of a group overlap
+    and differ), entries moved past the trace's last tap dropped, and
+    pair 1's tile 4 all dropped: a group with an empty row beside live
+    ones; tile 1 stays empty in every pair, tile 3 holds a band of 100
+    samples, in no window."""
+    i, w = _banded(rng, taps, nt, 6, long=3, pairs=pairs)
+    shift = (np.arange(pairs) % 3)[:, None]
+    i = np.where(i >= 0, i + shift, i)
+    i[i > nt - taps] = -7
+    i[1, 4 * 1024:5 * 1024] = -7
+    return i, w
+
+
+@pytest.mark.parametrize("taps", [1, 2])
+@pytest.mark.parametrize("pairs, group", [(8, 8), (12, 4), (6, 2), (3, 1)])
+def test_the_pair_groups_are_the_one_pair_spray_bit_for_bit(
+        rng, monkeypatch, taps, pairs, group):
+    """``pmt_kirchhoff`` (interpreted) at the rule's ``G`` traces a grid
+    step against a copy of the one-trace kernel it replaces
+    (``chip_probe/kirchhoff_spray_probe.py``), bit for bit: one and two
+    taps, pair counts that 8, only 4, only 2 and nothing divide, a group
+    with an empty row, dropped entries, a band in no window; the
+    forward's event and the counter read the rule's ``G`` and the
+    tables' ``walk``."""
+    from chip_probe.kirchhoff_spray_probe import one_pair_spray
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_METRICS", "on")
+    metrics.clear_metrics()
+    trace.clear_events()
+    nt = 300
+    i, w = _shifted(rng, taps, nt, pairs)
+    op = _spray(i, w, nt, taps)
+    assert op.group == pk.kirchhoff_group(pairs, nt, np.float32) == group
+    assert metrics.snapshot()["counters"]["kirchhoff.spray_group"] == group
+    m = jnp.asarray(rng.standard_normal(op.shape[1]), jnp.float32)
+    got = np.asarray(op.matvec(m)).reshape(pairs, nt)
+    want = np.asarray(one_pair_spray(
+        op._lohi, op.itrav, op.weight,
+        jnp.pad(m, (0, op.itrav.shape[1] * 1024 - m.size)), nt, taps))
+    assert np.array_equal(got, want), np.abs(got - want).max()
+    # the walk, from the tables as drawn
+    t = i.reshape(pairs, -1, 1024)
+    ok = t >= 0
+    lo = np.where(ok, t, 1 << 30).min(-1)
+    hi = np.where(ok, t, -1).max(-1)
+    union = _steps(lo.reshape(-1, group, lo.shape[1]).min(1),
+                   hi.reshape(-1, group, hi.shape[1]).max(1), taps)
+    own = _steps(lo, hi, taps)
+    assert (op.steps_walked, op.steps_banded) == (group * union.sum(),
+                                                  own.sum())
+    assert (op.steps_walked > op.steps_banded) == (group > 1)
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "kirchhoff.path_select"]
+    assert [(a["adjoint"], a["group"], a["walk"]) for a in ev] == [
+        (0, group, group * union.sum() / own.sum())]
+    assert "windowed" not in ev[0]
+
+
+def test_the_spray_group_follows_pairs_nt_and_dtype():
+    """The rule: the largest of 8, 4, 2 that divides the pairs and whose
+    accumulators fit the spray's VMEM share beside the reduction's
+    temporaries, else 1; ``kirchhoff_legal`` as before."""
+    g = pk.kirchhoff_group
+    f32 = np.float32
+    assert g(2048, 1024, f32) == 4                  # the cell: 8 needs 53 MiB
+    assert g(2048, 512, f32) == 8
+    assert (g(2052, 1024, f32), g(2050, 1024, f32), g(2049, 1024, f32)) \
+        == (4, 2, 1)
+    assert (g(2048, 2048, f32), g(2048, 2600, f32)) == (2, 1)
+    assert (g(2048, 1024, np.float64), g(2048, 1024, jnp.bfloat16)) \
+        == (2, 8)
+    # a trace that fits alone takes the kernel, one trace a step
+    assert pk.kirchhoff_legal(6012, f32) and g(2048, 6012, f32) == 1
+    assert not pk.kirchhoff_legal(6013, f32)
+    assert pk.kirchhoff_legal(1024, np.float64)
+    assert not pk.kirchhoff_legal(1024, np.complex64)
+
+
 # ------------------------------------------------- the shares add up
 def test_the_four_shares_add_up_to_the_whole_line(case):
     """The deployment deals the line's shots over four chips: the

@@ -354,3 +354,23 @@ def test_the_stack_reports_the_longest_band_and_the_totals(monkeypatch):
     assert [(a["band"], a["pairs"]) for a in ev] == [
         (tmpl.band, SIZES["ns"] // 4 * SIZES["nr"])]
     assert all(s.band <= tmpl.band for s in sprays)
+
+
+def test_the_stack_reports_the_spray_s_walk_over_its_shards(monkeypatch):
+    """The spray's loop steps are merged as totals too: the forward's
+    event gives the line's ``walk`` and the shards' common ``group``."""
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    mesh = pmt.make_mesh(4)
+    op = _op(mesh)
+    sprays = [b.A.B for b in op.ops]
+    tmpl = jax.tree_util.tree_unflatten(op._template, op._sharded).A.B
+    walked, banded = (sum(getattr(s, k) for s in sprays)
+                      for k in ("steps_walked", "steps_banded"))
+    assert (tmpl.steps_walked, tmpl.steps_banded) == (walked, banded)
+    assert walked >= banded > 0
+    trace.clear_events()
+    op.matvec(_vec(np.ones(op.shape[1]), mesh, Partition.BROADCAST))
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "kirchhoff.path_select"]
+    assert [(a["adjoint"], a["group"], a["walk"]) for a in ev] == [
+        (0, sprays[0].group, walked / banded)]

@@ -715,12 +715,12 @@ def _lsm_for_v5e(v5e_devices, what: str):
         cc.reset_cache()
 
 
-def _mosaic(text, name):
+def _mosaic(text, name, kernel):
     """Kernel ``name``'s Mosaic module in a compiled program's text: a
     digest of its ops (locations left out) and one of the lines and
-    columns of ``ops/pallas_kernels.py`` up to the end of
-    ``_kirchhoff_spray_kernel`` that its locations name (the kernel's
-    own; its callers' move with any line below it)."""
+    columns of ``ops/pallas_kernels.py`` up to the end of the function
+    ``kernel`` that its locations name (the kernel's own; its callers'
+    move with any line below it)."""
     import base64
     import hashlib
     import inspect
@@ -734,7 +734,7 @@ def _mosaic(text, name):
         module = ir.Module.parse(body)
         ops = module.operation.get_asm(enable_debug_info=False)
         located = module.operation.get_asm(enable_debug_info=True)
-    src, first = inspect.getsourcelines(pk._kirchhoff_spray_kernel)
+    src, first = inspect.getsourcelines(kernel)
     spans = sorted(set(
         m.group(0) for m in re.finditer(
             r'pallas_kernels\.py":(\d+):\d+ to [\d:]*\d+', located)
@@ -743,10 +743,13 @@ def _mosaic(text, name):
     return digest(ops), digest("\n".join(spans))
 
 
-# ``_mosaic(..., "pmt_kirchhoff")`` of the solver's compile below at the
-# commit before PR 39, with this container's JAX (0.9.0): the spray is
-# left as it was, byte for byte but for its callers' lines
-SPRAY_MOSAIC = ("4f83131e4cda861e", "1f9aaa546ffae31e")
+# ``_mosaic`` of the solver's compile below under JAX 0.9.0, for each
+# Kirchhoff kernel and the function that ends its own lines. The spray
+# was changed on purpose when it took G traces a grid step over their
+# union band (its ops read "4f83131e4cda861e" at one trace a step); the
+# gather's ops are those of its lane-gather form, pinned as the spray is
+SPRAY_MOSAIC = ("46aa58075873c589", "0ae4dfd4749198a1")
+GATHER_MOSAIC = ("fca9030e5296c61e", "be92d8ae16fb6816")
 
 
 @pytest.mark.parametrize("what", ["tables", "solver"])
@@ -772,4 +775,7 @@ def test_lsm_compiles_for_v5e(v5e_devices, monkeypatch, what):
     assert re.search(r'op_name="[^"]*/while/body/[^"]*pmt.MPIVStack.matvec/'
                      r'[^"]*pmt.local.TravelTimeSpray', text)
     if jax.__version__ == "0.9.0":
-        assert _mosaic(text, "pmt_kirchhoff") == SPRAY_MOSAIC
+        assert _mosaic(text, "pmt_kirchhoff",
+                       pk._kirchhoff_spray_kernel) == SPRAY_MOSAIC
+        assert _mosaic(text, "pmt_kirchhoff_adj",
+                       pk._kirchhoff_gather_kernel) == GATHER_MOSAIC
