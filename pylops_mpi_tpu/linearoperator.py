@@ -189,6 +189,13 @@ class MPILinearOperator:
         recurrence worth its extra carry. The generic pair never is."""
         return False
 
+    # ``(q, adjoint) = fresh_normal_matvec(c, s)``: ``q = Op c`` and
+    # ``adjoint(t) = Opᴴ (s − t q)`` from one memory pass — what the
+    # fresh-residual CGLS body needs (``solvers/basic.py``). Operators
+    # that can make it define the method and set ``has_fresh_normal``
+    # (``ops/mdc.py``'s chain); no other operator offers it.
+    has_fresh_normal = False
+
     # ----------------------------------------------------------- algebra
     def dot(self, x):
         """Operator-operator, operator-scalar or operator-vector product

@@ -269,6 +269,102 @@ class MPIFredholm1(MPILinearOperator):
     def _rmatvec(self, x: DistributedArray) -> DistributedArray:
         return self._apply(x, adjoint=True)
 
+    # ------------------------------------------- the plane-pair normal product
+    def normal_form(self, columns: bool = False):
+        """``(form, why, tile)`` of :meth:`normal_planes`: ``one_sweep``
+        — the kernel ``pmt_normal_planes``, compiled, both products from
+        one read of the planes — or ``pair``, the forward and the
+        adjoint product, a plane ``einsum`` sweep each (the caller's to
+        run), with the one word that says why: ``planar`` (no complex
+        plane pair: a real kernel, or the planar engine), ``columns``
+        (the vector has columns), ``interpret`` (no TPU: the kernel would
+        be interpreted, a trap inside a solver's loop), ``tile`` (no
+        Mosaic-legal row tile that the chip has shown to pay, a mesh
+        that is not 1-D or slices that do not divide it), ``cols`` (more
+        columns than the chip has shown to pay:
+        ``pallas_kernels.plane_pair_cols_pay``). ``tile`` is the row tile
+        the kernel takes, 0 where none is legal. A rule in what the
+        operator stores and is handed; no keyword, no variable."""
+        from . import pallas_kernels as pk
+        tile = pk.plane_pair_tile(self.G) if self._planes else None
+        if self.planar or not self._planes:
+            why = "planar"
+        elif columns:
+            why = "columns"
+        elif pk._interpret():
+            why = "interpret"
+        elif (tile is None or len(self.mesh.axis_names) != 1
+              or self.nsl % self._ndev
+              or not pk._tile_beats_two_sweeps(tile, self.ny,
+                                               self.G.dtype.itemsize)):
+            why = "tile"
+        elif not pk.plane_pair_cols_pay(2 * self.nz):
+            why = "cols"
+        else:
+            why = None
+        return ("pair" if why else "one_sweep"), why, int(tile or 0)
+
+    def normal_planes(self, v: DistributedArray, s: DistributedArray):
+        """``(Q, Z1, Z2) = (G v, Gᴴ M Q, Gᴴ s)`` for a model-side
+        spectrum ``v`` and a data-side one ``s`` (the operator's own
+        vectors), ``M`` zeroing the imaginary part of slice 0: on
+        ``MPIMDC``'s kept bins ``F1 F1ᴴ = M`` where they hold no Nyquist
+        bin, so ``Z1`` is what ``F1ᴴ Q`` brought back through ``F1``
+        would be. The kernel ``pmt_normal_planes`` makes all three from
+        ONE read of the planes, each shard its own slices under
+        ``shard_map`` (:meth:`normal_form` says where that pays). Under
+        ``pmt.MPIFredholm1.normal_matvec``."""
+        from ..diagnostics import trace
+        with trace.op_span(self, "normal_matvec"):
+            shape = lambda n: (self.nsl, n, self.nz)
+            if self.planar:
+                vr, vi = v.array.reshape((2,) + shape(self.ny))
+                sr, si = s.array.reshape((2,) + shape(self.nx))
+            else:
+                vr, vi = (f(v.array.reshape(shape(self.ny)))
+                          for f in (jnp.real, jnp.imag))
+                sr, si = (f(s.array.reshape(shape(self.nx)))
+                          for f in (jnp.real, jnp.imag))
+            parts = self._normal_sweep(vr, vi, sr, si)
+            out = []
+            for (re, im), like, inner in zip(parts, (s, v, v),
+                                             (self.nx, self.ny, self.ny)):
+                arr = jnp.stack([re, im]) if self.planar \
+                    else jax.lax.complex(re, im)
+                out.append(self._wrap(arr, like, like.global_shape[0],
+                                      inner))
+            return tuple(out)
+
+    def _normal_sweep(self, vr, vi, sr, si):
+        """:meth:`normal_planes`' one sweep: the spectra's parts as rows
+        (each part's columns padded to whole groups of
+        ``PLANE_PAIR_ROWS``), the kernel per shard, the parts back in the
+        operator's layout."""
+        from jax import shard_map
+        from jax.sharding import PartitionSpec as P
+        from . import pallas_kernels as pk
+        nz = self.nz
+        nzp = -(-nz // pk.PLANE_PAIR_ROWS) * pk.PLANE_PAIR_ROWS
+        dt = _real_dtype(self.dtype)
+
+        def rows(re, im):
+            pad = ((0, 0), (0, 0), (0, nzp - nz))
+            return jnp.swapaxes(jnp.concatenate(
+                [jnp.pad(re, pad), jnp.pad(im, pad)], -1), 1, 2).astype(dt)
+        axis = self.mesh.axis_names[0]
+        nloc = self.nsl // self._ndev
+
+        def kernel(G, C, S):
+            first = jax.lax.axis_index(axis) * nloc
+            return pk.plane_pair_normal(G, C, S, first)
+        Q, Z = shard_map(kernel, mesh=self.mesh,
+                         in_specs=(P(None, axis), P(axis), P(axis)),
+                         out_specs=(P(axis), P(axis)),
+                         check_vma=False)(self.G, rows(vr, vi), rows(sr, si))
+        cols = lambda A, k: jnp.swapaxes(A[:, k * nzp:k * nzp + nz], 1, 2)
+        return ((cols(Q, 0), cols(Q, 1)), (cols(Z, 0), cols(Z, 2)),
+                (cols(Z, 1), cols(Z, 3)))
+
 
 # the frequency-sharded kernel travels into jit as a pytree child
 # (multi-process arrays must not be closed over — linearoperator.py)

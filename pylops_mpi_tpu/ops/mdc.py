@@ -42,7 +42,10 @@ import numpy as np
 import jax.numpy as jnp
 
 from ..diagnostics import trace as _trace
-from ..linearoperator import MPILinearOperator, aslinearoperator
+from ..distributedarray import DistributedArray
+from ..linearoperator import (MPILinearOperator, _ProductLinearOperator,
+                              _ScaledLinearOperator, aslinearoperator,
+                              register_operator_arrays)
 from . import dft
 from .fredholm import MPIFredholm1
 from .local import FFT as _LocalFFT
@@ -153,6 +156,105 @@ def MPIMDC(G, nt: int, nv: int, nfreq: Optional[int] = None, dt: float = 1.0,
     if not prescaled:
         # on the spectrum, not on the kernel: no second kernel is made
         Frop = Frop * rdtype.type(dr * dt * np.sqrt(nt))
-    MDCop = F1op.H * Frop * Fop
+    MDCop = _MDCChain(F1op.H * Frop, Fop)
     MDCop.dtype = rdtype
     return MDCop
+
+
+class _MDCChain(_ProductLinearOperator):
+    """``F1ᴴ · (a · Fredholm1) · F``, ``MPIMDC``'s operator: the product
+    chain as it was (``matvec`` / ``rmatvec`` unchanged), which knows its
+    three factors and so offers what ONE read of the kernel gives CGLS
+    (:meth:`fresh_normal_matvec`; ``solvers/basic.py``'s fresh-residual
+    body). No other chain is one: ``_ProductLinearOperator`` and
+    ``_ScaledLinearOperator`` keep their generic behaviour everywhere
+    else. The factors are read from ``args`` at each call, so an
+    operator that travels into ``jit`` as a pytree reads its traced
+    kernel, never the one it was built with."""
+
+    def _factors(self):
+        """``(F1, Fredholm1, a, F)``: ``a`` is ``None`` where the chain
+        was built ``prescaled``; the core is whatever stands between the
+        transforms (a ``conj`` chain's is a wrapper)."""
+        inner, F = self.args
+        F1H, core = inner.args
+        a = None
+        if isinstance(core, _ScaledLinearOperator):
+            core, a = core.args
+        return F1H.A, core, a, F
+
+    @property
+    def has_fresh_normal(self) -> bool:
+        """The core is the product on a kernel held as its plane pair
+        itself (:class:`MPIFredholm1`, either engine; not a ``conj``
+        wrapper, not a real kernel): only then does
+        :meth:`fresh_normal_matvec` exist for the chain."""
+        core = self._factors()[1]
+        return isinstance(core, MPIFredholm1) and core._planes
+
+    def normal_select(self, x):
+        """``(form, why, tile)`` of :meth:`fresh_normal_matvec` for the
+        vector ``x``: :meth:`MPIFredholm1.normal_form`'s answer (the
+        vector has columns where it is no one ``DistributedArray``), but
+        ``pair`` with the ``why`` ``nyquist`` where the kept bins hold
+        the Nyquist bin of an even ``nt`` (``twosided=False``, the whole
+        half spectrum): ``F1ᴴ`` drops that bin's imaginary part too, so
+        ``F1 F1ᴴ`` is not the kernel's mask."""
+        F1, core = self._factors()[:2]
+        form, why, tile = core.normal_form(
+            not isinstance(x, DistributedArray) or x.ndim != 1)
+        fft = F1.Op
+        if form == "one_sweep" and fft.nfft % 2 == 0 \
+                and fft.nfkeep == fft.nfft // 2 + 1:
+            return "pair", "nyquist", tile
+        return form, why, tile
+
+    def prefers_fused_normal(self, x) -> bool:
+        """``cgls(normal=None)``'s question: yes exactly where the chain's
+        product would be the compiled one-sweep kernel for ``x`` — a
+        TPU, the ``complex`` engine, one vector with no columns, a row
+        tile and a column count at which the chip has shown one sweep of
+        the planes faster than two, no Nyquist bin kept
+        (:meth:`normal_select`)."""
+        return self.has_fresh_normal and self.normal_select(x)[0] \
+            == "one_sweep"
+
+    def fresh_normal_matvec(self, c: DistributedArray, s: DistributedArray):
+        """``(q, adjoint)`` with ``q = Op c`` and ``adjoint(t) = Opᴴ (s −
+        t q)`` — the normal residual's adjoint after a step ``t`` along
+        ``c``. Where :meth:`normal_select` says ``one_sweep``, from ONE
+        read of the kernel: ``F c`` and ``F1 s``, then
+        :meth:`MPIFredholm1.normal_planes` gives ``Q = G F c``, ``Z1 =
+        Gᴴ M Q`` and ``Z2 = Gᴴ F1 s``; ``q = F1ᴴ (a Q)``, and since ``F1
+        F1ᴴ = M`` on the kept bins (bin 0's imaginary part is the one
+        that ``F1ᴴ`` drops), ``Opᴴ (s − t q) = Fᴴ (a (Z2 − t a Z1))``: one
+        adjoint transform, of the spectrum combined once the step is
+        known. Each transform is the chain's own (``pmt.local.FFT``), the
+        product under ``pmt.MPIFredholm1.normal_matvec``. Where it says
+        ``pair``: the chain's own ``matvec`` and ``rmatvec``, a read of
+        the kernel each. ``mdc.normal_select`` (``form``, ``cols`` = the
+        forward columns ``2 nv``, ``tile``, for ``pair`` a one-word
+        ``why``: ``interpret``, ``planar``, ``columns``, ``tile``,
+        ``cols``, ``nyquist``) says which under ``PYLOPS_MPI_TPU_TRACE``,
+        one a traced apply."""
+        F1, core, a, F = self._factors()
+        form, why, tile = self.normal_select(c)
+        _trace.event("mdc.normal_select", cat="schedule", form=form,
+                     cols=2 * core.nz, tile=tile,
+                     **({"why": why} if why else {}))
+        if form == "pair":
+            q = self.matvec(c)
+            return q, lambda t: self.rmatvec(s - q * t)
+        with _trace.op_span(self, "fresh_normal_matvec"):
+            Q, Z1, Z2 = core.normal_planes(F.matvec(c), F1.matvec(s))
+            q = F1.rmatvec(Q if a is None else Q * a)
+
+        def adjoint(t):
+            with _trace.op_span(self, "fresh_normal_matvec"):
+                ta = t if a is None else t * a
+                z = Z2 - Z1 * ta
+                return F.rmatvec(z if a is None else z * a)
+        return q, adjoint
+
+
+register_operator_arrays(_MDCChain, "args")

@@ -1079,3 +1079,164 @@ def kirchhoff_gather(lohi, it, wt, z, taps: int) -> jax.Array:
     )(fit, lohi, jnp.pad(z, ((0, 0), (0, ntz - nt)))[:, None, :], windows,
       it, wt)
     return m.ravel()
+
+
+# ------------------------------------------------- plane-pair normal product
+# ``pmt_normal_planes`` (:func:`plane_pair_normal`), added below
+# everything else so that no line of the kernels above moves. For a
+# complex kernel stored as its real (re, im) planes, ``G = Gr + i Gi``,
+# a batch of ``nsl`` frequency blocks ``(m, n)``, and two spectra with
+# ``nz`` columns a block — ``C (n, nz)`` on the model side and ``S (m,
+# nz)`` on the data side — the kernel makes, from ONE read of each
+# plane,
+#
+#     Q = G C,    Z = Gᴴ [M Q | S]     (``Gᴴ = Grᵀ − i Giᵀ``),
+#
+# ``M`` zeroing the imaginary part of global block 0 and nothing else:
+# everything an iteration of CGLS on ``MPIMDC``'s chain ``F1ᴴ (a G) F``
+# needs from the kernel (``ops/mdc.py``: on the kept bins ``F1 F1ᴴ`` is
+# ``M``). Within a block's row tile ``i`` the plane pair ``(Gr, Gi)[i]``
+# comes into VMEM once; the forward makes the tile's rows of ``Q`` as
+# the four real products on ``[Cr; Ci]`` against each plane (the
+# columns on sublanes, as ``pmt_normal``'s), and the adjoint adds the
+# tile's share of ``Z`` — ``[M Q_i | S_i]`` against each plane — to an
+# accumulator that stays resident over the row tiles. Every product is
+# ``highest`` by hand (``_dot_highest``): a plane's three bf16 parts
+# are made once a tile and serve both products. A part of the
+# spectrum's columns is padded to whole sublane groups of 8, so every
+# slice in the kernel is a whole group.
+
+__all__ += ["plane_pair_normal", "plane_pair_tile", "plane_pair_cols_pay",
+            "PLANE_PAIR_ROWS"]
+
+PLANE_PAIR_ROWS = 8            # a part's columns are padded to whole groups
+
+
+def plane_pair_cols_pay(K: int) -> bool:
+    """Whether the chip has shown ``pmt_normal_planes`` with ``K``
+    forward columns a block (``2 nz``: the spectrum's two parts; the
+    adjoint carries ``2 K``) faster than the two plane ``einsum``s it
+    replaces. v5e, ``mdd_obc``'s 64 blocks of 4,096^2 complex64 as
+    f32 planes (8.59 GB), ms a product (median of 10), on BROADCAST
+    spectra: ``one`` is ``MPIFredholm1.normal_planes``, the spectra's
+    relayouts to and from the kernel's rows included, 256-row tiles;
+    ``pair`` the operator's ``matvec`` and ``rmatvec``, ``nz`` columns
+    each, as a classic CGLS iteration runs them
+    (``chip_probe/fredholm_normal_probe.py`` on a TPU v5e; PERF.md
+    section 6):
+
+    ======= ======= ======= ======= =====
+    nz      K       one     pair    ratio
+    ======= ======= ======= ======= =====
+    1       2       12.59   24.09   1.91
+    16      32      20.78   28.76   1.38
+    32      64      32.58   31.83   0.98
+    ======= ======= ======= ======= =====
+
+    The kernel alone at 16 columns a part: 16.93 ms at 128-row tiles,
+    16.32 at 256 (:func:`plane_pair_tile`). The MXU's six passes over
+    ``K`` forward and ``2 K`` adjoint columns show from 32 columns on,
+    as in the real kernel's table (``_cols_beat_two_sweeps``), and at
+    64 the one sweep no longer pays; more than 32 forward columns stay
+    with the pair."""
+    return K <= 32
+
+
+def plane_pair_tile(P: jax.Array):
+    """The row tile of ``pmt_normal_planes`` for the planes ``P (2, nsl,
+    m, n)``: ``pmt_normal``'s, each plane's tile within the 4 MiB
+    budget (256 rows at ``n`` = 4,096 f32: compiled for a v5e, 256 rows
+    and 32 columns a part fit the 48 MiB limit, 512 rows do not; alone
+    on the chip 16.32 ms a sweep of ``mdd_obc``'s planes at 16 columns
+    a part against 16.90 at 128 rows, measured on a v5e), which,
+    compiled, has to be whole 128-lane groups or all of ``m`` (the tile
+    is the lane extent of ``Q``'s and ``S``'s blocks); ``None`` where no
+    tile is legal."""
+    _, _, m, n = P.shape
+    tm = _pick_tile(m, n, max(P.dtype.itemsize, 4),
+                    min_sublane=_min_sublane(P.dtype))
+    if tm is None or (not _interpret() and tm % 128 and tm != m):
+        return None
+    return tm
+
+
+def _plane_pair_kernel(first_ref, g_ref, c_ref, s_ref, q_ref, z_ref, *,
+                       nz: int):
+    """Block ``b``, row tile ``i``: ``g_ref (2, 1, tm, n)`` the planes'
+    rows, ``c_ref (1, 2 nz, n)`` = ``[Cr; Ci]``, ``s_ref (1, 2 nz, tm)``
+    = ``[Sr; Si]`` of the tile's rows, ``q_ref (1, 2 nz, tm)`` =
+    ``[Qr; Qi]``, ``z_ref (1, 4 nz, n)`` = ``[Re Gᴴ MQ; Re Gᴴ S; Im Gᴴ
+    MQ; Im Gᴴ S]``, resident over ``i``. ``first_ref[0]``: the global
+    index of the shard's first block. Wider than f32 (interpreted only):
+    plain dots."""
+    nt, nn = (((1,), (1,)), ((), ())), (((1,), (0,)), ((), ()))
+    acc = jnp.promote_types(g_ref.dtype, jnp.float32)
+    planes = (g_ref[0, 0], g_ref[1, 0])                      # (tm, n)
+    if acc == jnp.float32:
+        parts = [[g] if g.dtype == jnp.bfloat16 else
+                 _bf16_parts(g.astype(jnp.float32), 3) for g in planes]
+
+        def dot(lhs, plane, dims):
+            return _dot_highest(lhs, parts[plane], dims)
+    else:
+        wide = [g.astype(acc) for g in planes]
+
+        def dot(lhs, plane, dims):
+            return jax.lax.dot_general(lhs, wide[plane], dims,
+                                       preferred_element_type=acc)
+    c = c_ref[0].astype(acc)                                 # (2 nz, n)
+    tr, ti = dot(c, 0, nt), dot(c, 1, nt)                    # (2 nz, tm)
+    qr = tr[:nz] - ti[nz:]                      # Gr Cr − Gi Ci
+    qi = tr[nz:] + ti[:nz]                      # Gr Ci + Gi Cr
+    q_ref[0] = jnp.concatenate([qr, qi]).astype(q_ref.dtype)
+    qi = jnp.where(first_ref[0] + pl.program_id(0) == 0, 0.0, qi)
+    s = s_ref[0].astype(acc)
+    v = jnp.concatenate([qr, s[:nz], qi, s[nz:]])            # (4 nz, tm)
+    a, b = dot(v, 0, nn), dot(v, 1, nn)                      # (4 nz, n)
+    h = 2 * nz                  # Re: Vrᵀ Gr + Viᵀ Gi; Im: Viᵀ Gr − Vrᵀ Gi
+    z = jnp.concatenate([a[:h] + b[h:], a[h:] - b[:h]])
+
+    @pl.when(pl.program_id(1) == 0)
+    def _():
+        z_ref[...] = jnp.zeros_like(z_ref)
+
+    z_ref[0] += z.astype(z_ref.dtype)
+
+
+def plane_pair_normal(P: jax.Array, C: jax.Array, S: jax.Array, first=0,
+                      tm=None):
+    """``(Q, Z)`` of the block above for the planes ``P (2, nsl, m,
+    n)`` and the spectra's parts as rows: ``C (nsl, 2 nz, n)`` =
+    ``[Cr; Ci]``, ``S (nsl, 2 nz, m)`` = ``[Sr; Si]``, ``nz`` a whole
+    number of ``PLANE_PAIR_ROWS``. Returns ``Q (nsl, 2 nz, m)`` =
+    ``[Qr; Qi]`` and ``Z (nsl, 4 nz, n)`` = ``[Re Z1; Re Z2; Im Z1; Im
+    Z2]`` (``Z1 = Gᴴ M Q``, ``Z2 = Gᴴ S``) at ``C``'s dtype. ``first``:
+    the global index of ``P``'s first block (an int32 scalar, traced or
+    not: a shard's). Kernel ``pmt_normal_planes``, compiled on a TPU,
+    interpreted elsewhere; ``tm`` (the row tile) defaults to
+    :func:`plane_pair_tile`'s. Call per shard."""
+    _, nsl, m, n = P.shape
+    nz = C.shape[1] // 2
+    tm = plane_pair_tile(P) if tm is None else int(tm)
+    if tm is None or nz % PLANE_PAIR_ROWS or m % tm:
+        raise ValueError(f"pmt_normal_planes: no legal row tile for blocks "
+                         f"of {m}x{n} with {nz} columns a part; gate on "
+                         "plane_pair_tile()")
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=1, grid=(nsl, m // tm),
+        in_specs=[pl.BlockSpec((2, 1, tm, n), lambda b, i, f: (0, b, i, 0)),
+                  pl.BlockSpec((1, 2 * nz, n), lambda b, i, f: (b, 0, 0)),
+                  pl.BlockSpec((1, 2 * nz, tm), lambda b, i, f: (b, 0, i))],
+        out_specs=[pl.BlockSpec((1, 2 * nz, tm), lambda b, i, f: (b, 0, i)),
+                   pl.BlockSpec((1, 4 * nz, n), lambda b, i, f: (b, 0, 0))])
+    return pl.pallas_call(
+        partial(_plane_pair_kernel, nz=nz),
+        grid_spec=grid_spec,
+        out_shape=[jax.ShapeDtypeStruct((nsl, 2 * nz, m), C.dtype),
+                   jax.ShapeDtypeStruct((nsl, 4 * nz, n), C.dtype)],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("parallel", "arbitrary"),
+            vmem_limit_bytes=_VMEM_LIMIT_BYTES),
+        interpret=_interpret(),
+        name="pmt_normal_planes",
+    )(jnp.asarray(first, jnp.int32).reshape(1), P, C, S)

@@ -682,15 +682,29 @@ def _cg_fused(Op, y: Vector, x0: Vector, tol, *, niter: int, M=None,
 
 
 def _make_cgls_body(Op, xdt, damp2, floors, *, M=None, normal=False,
-                    guards=False, carry_status=False, stall_n=0,
-                    fault=None):
-    """CGLS loop body (classic two-sweep or fused-normal) over the
-    carry ``(x, s, c, q, ...)`` / ``(x, s, r, c, ...)`` — shared by the
-    single-shot loops, the guarded variants and the segmented epoch
-    program (solvers/segmented.py). ``M`` preconditions the NORMAL
-    equations (PCGLS): it should approximate ``(OpᴴOp + damp²)⁻¹``;
-    applied to the normal residual in both sweep schedules, carries
-    unchanged, ``M=None`` bit-identical (see :func:`_make_cg_body`)."""
+                    fresh=False, guards=False, carry_status=False,
+                    stall_n=0, fault=None):
+    """CGLS loop body (classic two-sweep, fused-normal, or fresh) over
+    the carry ``(x, s, c, q, ...)`` / ``(x, s, r, c, ...)`` / ``(x, s,
+    c, ...)`` — shared by the single-shot loops, the guarded variants
+    and the segmented epoch program (solvers/segmented.py). ``M``
+    preconditions the NORMAL equations (PCGLS): it should approximate
+    ``(OpᴴOp + damp²)⁻¹``; applied to the normal residual in every
+    schedule, carries unchanged, ``M=None`` bit-identical (see
+    :func:`_make_cg_body`).
+
+    ``fresh`` (the one-sweep schedule on an operator that offers
+    ``fresh_normal_matvec``, ``has_fresh_normal``): the normal residual
+    is not carried but made anew each iteration, ``r = Opᴴ s_{k+1} −
+    damp² x_{k+1}``, with ``Opᴴ s_{k+1} = Opᴴ s_k − a Opᴴ q_k`` taken
+    from the SAME read of the operator that gave ``q_k = Op c_k``. In
+    exact arithmetic it is the fused-normal body's ``r ← r − a(u +
+    damp² c)``; in float32 that recurrence accumulates its rounding
+    iteration after iteration, and on an operator whose singular values
+    span the band of a wavelet (``MPIMDC``: 4e-4 to 0.89) drifts
+    2.9e-5 from the plain reference's iterate in 30 iterations, where
+    this body stays at the classic schedule's 4e-7 (PERF.md section
+    6)."""
     from ..resilience import faults as _faults
     nan_at, stall_at = _fault_sites(guards, fault)
 
@@ -806,6 +820,61 @@ def _make_cgls_body(Op, xdt, damp2, floors, *, M=None, normal=False,
             return (x, s, r, c, k, iiter, cost, cost1, status)
         return (x, s, r, c, k, iiter, cost, cost1)
 
+    def body_fresh(state):
+        if guards:
+            x, s, c, kold, iiter, cost, cost1, status, bestk, stall = state
+        elif carry_status:
+            x, s, c, kold, iiter, cost, cost1, status = state
+        else:
+            x, s, c, kold, iiter, cost, cost1 = state
+        done = kold <= floors
+        q, adjoint = Op.fresh_normal_matvec(c, s)
+        with _trace.span("solver.step"):
+            if nan_at is not None:
+                q = _faults.inject_nan(q, iiter, nan_at)
+            a = _abs(kold / (_rdot(q, q) + damp2 * _rdot(c, c)))
+            a = jnp.where(done, jnp.zeros_like(a), a)
+            if stall_at is not None:
+                a = _faults.inject_stall(a, iiter, stall_at)
+            xn = x + c * _step_scalar(a, xdt)
+            sn_ = s - q * _step_scalar(a, xdt)
+        r = adjoint(_step_scalar(a, xdt))       # Opᴴ sn_, from this sweep
+        with _trace.span("solver.direction"):
+            r = r - xn * damp2
+        z = _precond_apply(M, r, xdt)
+        with _trace.span("solver.direction"):
+            k = _rdot(r, z)
+            k = jnp.where(done, kold, k)
+            b = jnp.where(done, jnp.zeros_like(k), k / kold)
+            cn = z + c * _step_scalar(b, xdt)
+            if guards:
+                bad = (jnp.any(~jnp.isfinite(a))
+                       | jnp.any(~jnp.isfinite(k))
+                       | jnp.any(~jnp.isfinite(b)))
+                x = _reject(bad, x, xn)
+                s = _reject(bad, s, sn_)
+                c = _reject(bad, c, cn)
+                k = jnp.where(bad, kold, k)
+                status, bestk, stall = _guard_update(
+                    status, bestk, stall, bad, k, done, stall_n)
+            else:
+                x, s, c = xn, sn_, cn
+        with _trace.span("solver.cost"):
+            iiter = iiter + 1
+            sn = jnp.asarray(s.norm())
+            cost = lax.dynamic_update_index_in_dim(cost, sn, iiter, 0)
+            r2 = jnp.sqrt(sn ** 2 + damp2 * _rdot(x, x))
+            cost1 = lax.dynamic_update_index_in_dim(cost1, r2, iiter, 0)
+        # no-op unless telemetry is enabled (see _make_cg_body note)
+        telemetry.iteration("cgls", iiter, resid=sn, k=k, alpha=a)
+        if guards:
+            return (x, s, c, k, iiter, cost, cost1, status, bestk, stall)
+        if carry_status:
+            return (x, s, c, k, iiter, cost, cost1, status)
+        return (x, s, c, k, iiter, cost, cost1)
+
+    if fresh:
+        return body_fresh
     return body_normal if normal else body_classic
 
 
@@ -814,12 +883,23 @@ def _cgls_setup(Op, y: Vector, x0: Vector, damp, damp2, *, niter: int,
     """Shared CGLS prologue: residuals, first direction, recurrence
     norm, machine-precision floor and the cost buffers — used by the
     single-shot fused loops here and the segmented driver
-    (solvers/segmented.py), which must seed the exact same carry."""
+    (solvers/segmented.py), which must seed the exact same carry.
+    Where the fresh-residual body follows (:func:`_fresh`), ``s = y −
+    Op x`` and ``Opᴴ s`` come from one read of the operator too:
+    ``fresh_normal_matvec(x, y)``, its adjoint at a unit step; the
+    carry's head is then ``(x, s, c, kold)``: no ``r`` to carry."""
     x = x0  # donated: carry aliases the caller's buffer (see _DONATE_X0)
-    s = Op.matvec(x)
-    with _trace.span("solver.setup"):
-        s = y - s
-    rq = Op.rmatvec(s)
+    fresh = _fresh(Op, normal)
+    if fresh:
+        q, adjoint = Op.fresh_normal_matvec(x, y)
+        with _trace.span("solver.setup"):
+            s = y - q
+        rq = adjoint(jnp.ones((), np.dtype(_vdtype(x0))))
+    else:
+        s = Op.matvec(x)
+        with _trace.span("solver.setup"):
+            s = y - s
+        rq = Op.rmatvec(s)
     with _trace.span("solver.setup"):
         # ref's un-squared setup damp (see module doc) seeds only the
         # first direction, as in the classic path
@@ -831,7 +911,7 @@ def _cgls_setup(Op, y: Vector, x0: Vector, damp, damp2, *, niter: int,
     with _trace.span("solver.setup"):
         kold = _rdot(rq, z)
         floors = _mp_floor(kold)
-        if normal:
+        if normal and not fresh:
             # the recurrence tracks the true gradient r = Opᴴs − damp²x,
             # so it must start from the damp²-form, not the quirked one
             r = rq + x * (damp - damp2)
@@ -841,6 +921,8 @@ def _cgls_setup(Op, y: Vector, x0: Vector, damp, damp2, *, niter: int,
         cost1_0 = lax.dynamic_update_index_in_dim(
             jnp.zeros_like(cost0),
             jnp.sqrt(sn0 ** 2 + damp2 * _rdot(x, x)), 0, 0)
+    if fresh:
+        return (x, s, c, kold), floors, cost0, cost1_0
     if normal:
         return (x, s, r, c, kold), floors, cost0, cost1_0
     return (x, s, c, q, kold), floors, cost0, cost1_0
@@ -871,36 +953,42 @@ def _cgls_fused_any(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
     which the entry's reshape now is."""
     damp2 = damp ** 2
     xdt = _vdtype(x0)
+    fresh = _fresh(Op, normal)
     head, floors, cost0, cost1_0 = _cgls_setup(Op, y, x0, damp, damp2,
                                                niter=niter, normal=normal,
                                                M=M)
     body = _make_cgls_body(Op, xdt, damp2, floors, M=M, normal=normal,
-                           guards=guards, stall_n=stall_n, fault=fault)
-    # head's vectors: (x, s, r, c) one-sweep, (x, s, c, q) classic
-    sides = (("dims", "dimsd", "dims", "dims") if normal
+                           fresh=fresh, guards=guards, stall_n=stall_n,
+                           fault=fault)
+    # head's vectors: (x, s, r, c) one-sweep, (x, s, c) fresh, (x, s, c,
+    # q) classic; kold follows them
+    sides = (("dims", "dimsd", "dims") if fresh
+             else ("dims", "dimsd", "dims", "dims") if normal
              else ("dims", "dimsd", "dims", "dimsd"))
+    n = len(sides)
     if guards:
         from ..resilience import status as _rstatus
-        kold0 = head[4]
+        kold0 = head[n]
         state = head + (jnp.asarray(0), cost0, cost1_0,
                         _i32(_rstatus.RUNNING), jnp.max(kold0), _i32(0))
 
         def cond(state):
-            return ((state[5] < niter) & (jnp.max(state[4]) > tol)
-                    & (state[8] == _rstatus.RUNNING))
+            return ((state[n + 1] < niter) & (jnp.max(state[n]) > tol)
+                    & (state[n + 4] == _rstatus.RUNNING))
 
         out = _while_carried("cgls", Op, cond, body, state, sides)
-        x, kold, iiter, cost, cost1, status = (out[0], out[4], out[5],
-                                               out[6], out[7], out[8])
+        x, kold, iiter, cost, cost1, status = (out[0], out[n], out[n + 1],
+                                               out[n + 2], out[n + 3],
+                                               out[n + 4])
         return (x, iiter, cost, cost1, kold,
                 _resolve_status(status, kold, tol))
 
     def cond(state):
-        return (state[5] < niter) & (jnp.max(state[4]) > tol)
+        return (state[n + 1] < niter) & (jnp.max(state[n]) > tol)
 
     state = head + (jnp.asarray(0), cost0, cost1_0)
     out = _while_carried("cgls", Op, cond, body, state, sides)
-    return out[0], out[5], out[6], out[7], out[4]
+    return out[0], out[n + 1], out[n + 2], out[n + 3], out[n]
 
 
 def _cgls_fused(Op, y: Vector, x0: Vector, damp, tol, *, niter: int,
@@ -920,7 +1008,10 @@ def _cgls_fused_normal(Op, y: Vector, x0: Vector, damp, tol, *,
     ``r ← r − a (u + damp² c)``, which is algebraically identical to the
     textbook ``r = Opᴴ s − damp² x`` (s-update substituted). Halves HBM
     traffic on memory-bound matvecs; enabled when
-    ``Op.has_fused_normal``."""
+    ``Op.has_fused_normal``. On an operator that offers
+    ``fresh_normal_matvec`` (``has_fresh_normal``: ``MPIMDC``'s chain)
+    the same sweep also gives ``Opᴴ s``, and the body makes ``r`` anew
+    each iteration instead (:func:`_make_cgls_body`'s ``fresh``)."""
     return _cgls_fused_any(Op, y, x0, damp, tol, niter=niter,
                            normal=True, guards=guards, M=M,
                            stall_n=stall_n, fault=fault)
@@ -1174,19 +1265,30 @@ def _resolve_normal(Op, x0: Vector, normal: Optional[bool],
     ``normal_matvec`` would run a compiled one-sweep kernel that pays
     for the model vector (or ``(rows, K)`` block of columns) at hand
     (``MPILinearOperator.prefers_fused_normal``) — never off the fused
-    path, which has no one-sweep body."""
+    path, which has no one-sweep body. Which one-sweep body runs is the
+    operator's to say too (:func:`_fresh`)."""
     if normal is not None:
         return bool(normal)
     ask = getattr(Op, "prefers_fused_normal", None)
     return bool(use_fused and ask is not None and ask(x0))
 
 
+def _fresh(Op, normal: bool) -> bool:
+    """Whether a one-sweep CGLS on ``Op`` runs the fresh-residual body
+    (:func:`_make_cgls_body`): decided by what the operator is — one
+    that offers ``fresh_normal_matvec`` (``has_fresh_normal``) — and by
+    nothing else."""
+    return bool(normal and getattr(Op, "has_fresh_normal", False))
+
+
 def _count_cgls_solve(iiter: int, use_normal: bool,
-                      solver: str = "cgls") -> None:
+                      solver: str = "cgls", fresh: bool = False) -> None:
     _metrics.inc(f"solver.{solver}.solves")
     _metrics.inc(f"solver.{solver}.iterations", iiter)
     if use_normal:
         _metrics.inc(f"solver.{solver}.one_sweep")
+    if fresh:
+        _metrics.inc(f"solver.{solver}.fresh_residual")
 
 
 def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
@@ -1217,9 +1319,12 @@ def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
     from . import ca as _ca
     _ca_mode = _ca.resolve_mode(Op, "cgls")
     if _ca_mode != "off":
-        out = _ca.run_cgls_fused(Op, y, x0, x0_owned, niter, damp,
-                                 tol, use_normal, guards, M=M,
-                                 mode=_ca_mode)
+        # the CA engine has no fresh-residual body: on an operator that
+        # offers one, its one-sweep product is not what CA would run, so
+        # the solve is classic there and counted so (docs/ca.md)
+        out = _ca.run_cgls_fused(Op, y, x0, x0_owned, niter, damp, tol,
+                                 use_normal and not _fresh(Op, use_normal),
+                                 guards, M=M, mode=_ca_mode)
         return out + (float(jnp.max(out[4])),)
     # The wrapper's two host phases tile it, inside the caller's
     # ``pmt.solver.<name>`` span: ``launch`` until the fused program's
@@ -1271,7 +1376,8 @@ def _run_cgls_fused(Op, y: Vector, x0: Vector, x0_owned: bool,
         if guards:
             code = int(status[0])
             _rstatus.record("cgls", code, iiter)
-        _count_cgls_solve(iiter, use_normal)
+        _count_cgls_solve(iiter, use_normal,
+                          fresh=_fresh(Op, use_normal))
         return (x, iiter, cost[:iiter + 1], cost1[:iiter + 1], kold, code,
                 float(np.max(kmax)))
 
@@ -1319,10 +1425,18 @@ def cgls(Op, y: Vector, x0: Optional[Vector] = None, niter: int = 10,
     ``normal_matvec`` would run a compiled one-sweep kernel that beats
     two sweeps for this vector — today a batched ``MPIBlockDiag`` of
     real blocks on a 1-D mesh, on a TPU (Mosaic), a real model of the
-    blocks' accumulation dtype, a row tile the chip has shown fast;
-    everything else, and every operator on the CPU, compiles the
-    classic program. The recurrence carries rounding of its own: in
-    f32 its error to the true model stayed within 1.04 × the classic
+    blocks' accumulation dtype, a row tile the chip has shown fast; and
+    ``MPIMDC``'s chain, whose plane-pair kernel ``pmt_normal_planes``
+    gives ``Op c`` and ``Opᴴ s`` from one read of the planes
+    (``ops/mdc.py``), for one vector on a TPU; everything else, and
+    every operator on the CPU, compiles the classic program. An
+    operator that offers ``fresh_normal_matvec`` (``MPIMDC``) runs the
+    one-sweep schedule with the normal residual made anew each
+    iteration, ``r = Opᴴ s − damp² x``, whose error stays at the
+    classic schedule's (the recurrence below drifts to 2e-5 on that
+    operator; :func:`_make_cgls_body`). The recurrence carries rounding
+    of its own: in f32 its error to the true model stayed within 1.04 ×
+    the classic
     schedule's at cond 3 / 100 / 1000 over 30–400 iterations on evenly
     spaced spectra; sitting on the f32 floor of a log-spaced one (cond
     100, 400 iterations) single seeds scatter 0.85–1.27 × to both

@@ -225,17 +225,17 @@ def fused_hlo():
 
 @pytest.mark.parametrize("scope", [
     "pmt.local.FFT", "pmt.MPIFredholm1.matvec", "pmt.MPIFredholm1.rmatvec",
-    "pmt._ProductLinearOperator.matvec",
-    "pmt._ProductLinearOperator.rmatvec"])
+    "pmt._MDCChain.matvec", "pmt._MDCChain.rmatvec"])
 def test_scopes_in_the_fused_solver(fused_hlo, scope):
     """The names the device trace splits the solve by survive on the
     ops inside the fused ``while_loop``; the local FFT sits inside the
-    product chain's scope."""
+    MDC chain's scope (``_MDCChain``: the product chain that knows its
+    factors)."""
     names = [ln for ln in fused_hlo.split("\n")
              if "op_name=" in ln and "/while/body/" in ln and scope in ln]
     assert names, scope
     if scope == "pmt.local.FFT":
-        assert all("pmt._ProductLinearOperator." in ln for ln in names)
+        assert all("pmt._MDCChain." in ln for ln in names)
 
 
 def test_the_fused_solver_holds_no_second_kernel(fused_hlo):
@@ -585,3 +585,347 @@ def test_the_configuration_is_what_these_tests_run():
         cfg = json.load(f)
     small = dict(cfg["sizes"], **cfg["rehearse"])
     assert small == SIZES
+
+
+# ------------------------------- one read of the kernel an iteration
+from pylops_mpi_tpu.diagnostics import metrics          # noqa: E402
+from pylops_mpi_tpu.ops import fredholm, mdc            # noqa: E402
+from pylops_mpi_tpu.ops import pallas_kernels as pk     # noqa: E402
+
+
+def _spectra(rng, Fred, dtype):
+    """A model-side and a data-side spectrum of ``Fred`` as its own
+    vectors, bin 0's imaginary part not zero."""
+    cdt = np.result_type(dtype, np.complex64)
+
+    def one(inner):
+        n = Fred.nsl * inner * Fred.nz
+        a = rng.standard_normal(n) + 1j * rng.standard_normal(n)
+        return DistributedArray.to_dist(a.astype(cdt), mesh=Fred.mesh,
+                                        partition=Partition.BROADCAST)
+    return one(Fred.ny), one(Fred.nx)
+
+
+@pytest.mark.parametrize("ndev", [1, 2, 4])
+@pytest.mark.parametrize("dtype", [np.float32, np.float64])
+def test_the_plane_pair_kernel_is_the_two_sweeps(rng, dtype, ndev):
+    """``pmt_normal_planes`` (interpreted) makes ``(Q, Gᴴ M Q, Gᴴ S)``
+    as the two ``_contract_planes`` sweeps (the operator's ``matvec``
+    and ``rmatvec``) do, within the dtype's rounding, each shard's
+    slices under ``shard_map``; bin 0's block is not zero and its
+    spectra have an imaginary part, so the mask ``M`` (bin 0's
+    imaginary part of ``Q`` zeroed, global bin 0 only) is exercised,
+    and both agree with NumPy's complex product."""
+    nsl, ns, nr, nz = 4, 64, 48, 3
+    P = rng.standard_normal((2, nsl, ns, nr)).astype(dtype)
+    Fred = pmt.MPIFredholm1(P, nz, mesh=pmt.make_mesh(ndev),
+                            dtype=np.result_type(dtype, np.complex64))
+    v, s = _spectra(rng, Fred, dtype)
+    one = [np.asarray(t.asarray()) for t in Fred.normal_planes(v, s)]
+    Q = np.asarray(Fred.matvec(v).asarray()).reshape(nsl, ns, nz)
+    MQ = Q.copy()
+    MQ[0] = MQ[0].real
+    MQ = DistributedArray.to_dist(MQ.ravel(), mesh=Fred.mesh,
+                                  partition=Partition.BROADCAST)
+    two = [Q.ravel()] + [np.asarray(Fred.rmatvec(t).asarray())
+                         for t in (MQ, s)]
+    G = P[0].astype(np.float64) + 1j * P[1]
+    V = np.asarray(v.asarray()).reshape(nsl, nr, nz)
+    S = np.asarray(s.asarray()).reshape(nsl, ns, nz)
+    Q = np.einsum("kxy,kyz->kxz", G, V)
+    MQ = Q.copy()
+    MQ[0] = MQ[0].real
+    want = [Q, np.einsum("kxy,kxz->kyz", G.conj(), MQ),
+            np.einsum("kxy,kxz->kyz", G.conj(), S)]
+    tol = 1e-6 if dtype == np.float32 else 1e-13
+    for a, b, w in zip(one, two, want):
+        assert a.dtype == b.dtype == Fred.dtype
+        assert _rel(a, b) < tol
+        assert _rel(a, w.ravel()) < tol
+    # the mask touches bin 0 alone
+    Z1 = one[1].reshape(nsl, nr, nz)
+    unmasked = np.einsum("kxy,kxz->kyz", G.conj(), Q)
+    assert _rel(Z1[1:], unmasked[1:]) < tol
+    assert _rel(Z1[0], unmasked[0]) > 1e-2
+
+
+@pytest.mark.parametrize("shift", [True, False])
+def test_the_kept_bins_round_trip_is_the_mask(shift):
+    """``Wᵀ W`` of the cell's ``_truncated_dft_matrix`` (``nt`` 1,023,
+    64 bins kept; ``F`` shifts, ``F1`` does not), made in float32 and
+    multiplied in float64, is the identity but for a zero at bin 0's
+    imaginary part: on the kept bins ``F1 F1ᴴ = M``, the mask the
+    plane-pair kernel applies."""
+    nt, nf = 1023, 64
+    W = local._truncated_dft_matrix(nt, nt, nf, shift,
+                                    "float32").astype(np.float64)
+    want = np.eye(2 * nf)
+    want[nf, nf] = 0.0
+    WtW = W.T @ W
+    assert np.abs(np.diag(WtW) - np.diag(want)).max() < 2.5e-8
+    assert np.abs(WtW - np.diag(np.diag(WtW))).max() < 2e-8
+    assert WtW[nf, nf] == 0.0
+
+
+def _force(monkeypatch, form):
+    """``normal_form``'s answer forced to ``form`` (the kernel runs
+    interpreted where ``one_sweep`` is forced off a TPU)."""
+    real = fredholm.MPIFredholm1.normal_form
+
+    def forced(self, columns=False):
+        got = real(self, columns)
+        return (form, None if form == "one_sweep" else got[1], got[2])
+    monkeypatch.setattr(fredholm.MPIFredholm1, "normal_form", forced)
+
+
+@pytest.mark.parametrize("engine", ["complex", "planar"])
+@pytest.mark.parametrize("ndev", [1, 2])
+@pytest.mark.parametrize("form", ["one_sweep", "pair"])
+def test_the_chain_gives_u_q_g_from_one_product(case, monkeypatch, form,
+                                                ndev, engine):
+    """``(q, adjoint) = Op.fresh_normal_matvec(c, s)``: ``q = Op c``,
+    ``adjoint(0) = Opᴴ s`` and ``adjoint(0) − adjoint(1) = OpᴴOp c``,
+    each to the chain's own ``matvec`` / ``rmatvec``; ``adjoint(t)``
+    at a step is ``Opᴴ (s − t q)``. Both engines: the planar one's
+    spectra are plane pairs, its mask plane 1's bin 0."""
+    _force(monkeypatch, form)
+    mesh = pmt.make_mesh(ndev)
+    Op = _mdc(case["P"], mesh, engine=engine)
+    assert isinstance(Op, mdc._MDCChain) and Op.has_fresh_normal
+    c, s = _vec(case["x"], mesh), _vec(case["u"], mesh)
+    q, g0, g1, gt = jax.jit(lambda op, c_, s_: (lambda q_, f: (
+        q_, f(jnp.float32(0)), f(jnp.float32(1)), f(jnp.float32(0.37))))(
+            *op.fresh_normal_matvec(c_, s_)))(Op, c, s)
+    Ac = Op.matvec(c)
+    assert _rel(q.asarray(), Ac.asarray()) < 2e-6
+    assert _rel(g0.asarray(), Op.rmatvec(s).asarray()) < 2e-6
+    assert _rel((g0 - g1).asarray(), Op.rmatvec(Ac).asarray()) < 2e-6
+    step = s - Ac * np.float32(0.37)
+    assert _rel(gt.asarray(), Op.rmatvec(step).asarray()) < 2e-6
+
+
+def _x0(Op, mesh):
+    return DistributedArray(global_shape=Op.shape[1], mesh=mesh,
+                            partition=Partition.BROADCAST, dtype=np.float32)
+
+
+@pytest.mark.parametrize("form", ["one_sweep", "pair"])
+def test_one_sweep_cgls_on_mdc_keeps_the_classic_accuracy(
+        case, monkeypatch, form):
+    """``pmt.cgls(normal=True)`` on an ``MPIMDC`` takes the fresh-residual
+    body because the operator offers ``fresh_normal_matvec`` (counted,
+    and the product's event says which form ran, in the set-up and in
+    the loop's body): its answer stays
+    within 1e-6 of the classic schedule's and of the plain reference's,
+    where the fused-normal body — the residual by recurrence — drifts
+    to ~2e-5 on this operator (2.8e-5–2.9e-5 on the CPU at these
+    sizes)."""
+    _force(monkeypatch, form)
+    mesh = pmt.make_mesh(1)
+    Op = _mdc(case["P"], mesh)
+    d = _vec(case["d"], mesh)
+    monkeypatch.setenv("PYLOPS_MPI_TPU_METRICS", "on")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    metrics.clear_metrics()
+    trace.clear_events()
+    basic.clear_fused_cache()
+    fresh = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                     normal=True)[0].asarray()
+    counters = metrics.snapshot()["counters"]
+    assert counters["solver.cgls.fresh_residual"] == 1
+    assert counters["solver.cgls.one_sweep"] == 1
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "mdc.normal_select"]
+    assert [e["form"] for e in ev] == [form] * 2      # set-up and body
+    classic = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                       normal=False)[0].asarray()
+    assert metrics.snapshot()["counters"]["solver.cgls.fresh_residual"] == 1
+    assert _rel(fresh, classic) < 1e-6
+    assert _rel(fresh, np.ravel(case["xref"])) < 1e-6
+    # the one-sweep body by recurrence, on the same operator
+    monkeypatch.setattr(mdc._MDCChain, "has_fresh_normal", False)
+    basic.clear_fused_cache()
+    drift = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                     normal=True)[0].asarray()
+    assert _rel(drift, classic) > 1e-5
+
+
+@pytest.mark.parametrize("why", ["interpret", "planar", "columns", "tile",
+                                 "cols", None])
+def test_prefers_fused_normal_is_the_compiled_kernel_only(monkeypatch, why):
+    """``cgls(normal=None)`` asks the chain: yes only where its product
+    is the compiled ``pmt_normal_planes`` for one vector — so every
+    program off a TPU, on the ``planar`` engine or for columns stays the
+    classic one. Shapes whose plane tile is 2 MiB (the chip's row
+    table says tiles of 512 KiB and more pay)."""
+    sizes = dict(SIZES, nfmax=2, ns=512, nr=1024, nt=33, nv=16)
+    P = np.zeros((2, 2, 512, 1024), np.float32)
+    if why != "interpret":
+        monkeypatch.setattr(pk, "_interpret", lambda: False)
+    if why == "tile":
+        monkeypatch.setattr(pk, "_tile_beats_two_sweeps", lambda *a: False)
+    if why == "cols":
+        sizes["nv"] = 17                  # 34 forward columns: past 32
+    Op = _mdc(P, pmt.make_mesh(1), sizes,
+              **({"engine": "planar"} if why == "planar" else {}))
+    x = np.zeros(Op.shape[1], np.float32)
+    v = DistributedArray.to_dist(np.stack([x, x], 1) if why == "columns"
+                                 else x, partition=Partition.BROADCAST,
+                                 mesh=pmt.make_mesh(1))
+    form, got, tile = Op.normal_select(v)
+    assert (form, got) == (("pair", why) if why else ("one_sweep", None))
+    assert tile == 512
+    assert Op.prefers_fused_normal(v) is (why is None)
+    assert basic._resolve_normal(Op, v, None) is (why is None)
+
+
+def test_mdc_normal_select_event(case, monkeypatch):
+    """One ``mdc.normal_select`` a traced apply of the chain's product:
+    ``form``, ``cols`` (the forward's ``2 nv``), ``tile``, and off a TPU
+    ``why`` = ``interpret``."""
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    Op = _mdc(case["P"], pmt.make_mesh(1))
+    c = _vec(case["x"], pmt.make_mesh(1))
+    s = _vec(case["d"], pmt.make_mesh(1))
+    trace.clear_events()
+    jax.jit(lambda op, a, b: op.fresh_normal_matvec(a, b)[0])(Op, c, s)
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "mdc.normal_select"]
+    assert len(ev) == 1
+    assert (ev[0]["form"], ev[0]["why"], ev[0]["cols"], ev[0]["tile"]) == (
+        "pair", "interpret", 2 * SIZES["nv"], SIZES["ns"])
+
+
+def test_no_other_chain_offers_the_fresh_product():
+    """``_ProductLinearOperator`` and ``_ScaledLinearOperator`` keep
+    their generic behaviour: a chain of the same factors built by hand,
+    a ``conj`` MDC and one on a real kernel offer no fresh product and
+    run the classic or the fused-normal body as before."""
+    P = _planes(WIDE)
+    Op = _mdc(P, None, WIDE, conj=True)
+    assert not Op.has_fresh_normal
+    assert not basic._fresh(Op, True)
+    real = _mdc(np.asarray(P[0]), None, WIDE)        # a real kernel
+    assert not real.has_fresh_normal and not real.prefers_fused_normal(
+        _vec(np.zeros(real.shape[1]), None))
+    F = Op.args[1]
+    plain = Op.args[0] * F
+    assert type(plain).__name__ == "_ProductLinearOperator"
+    assert not plain.has_fresh_normal and not basic._fresh(plain, True)
+    assert not basic._fresh(_mdc(P, None, WIDE), False)
+
+
+def test_the_guarded_fresh_body_is_the_unguarded_one(case):
+    """``cgls_guarded(normal=True)`` on an ``MPIMDC`` runs the fresh body
+    with its guard carry: the same answer as the unguarded solve, the
+    status ``MAXITER`` after the 30 iterations; a NaN injected into
+    ``q`` at an iteration is a ``BREAKDOWN`` that keeps the last finite
+    iterate."""
+    from pylops_mpi_tpu.resilience import faults, status
+    mesh = pmt.make_mesh(1)
+    Op = _mdc(case["P"], mesh)
+    d = _vec(case["d"], mesh)
+    basic.clear_fused_cache()
+    x = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                 normal=True)[0].asarray()
+    xg, iiter, *_, code = basic.cgls_guarded(
+        Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0, normal=True)
+    assert (iiter, code) == (NITER, status.MAXITER)
+    assert _rel(xg.asarray(), x) < 1e-6
+    faults.arm("nan", 5)
+    xb, iiter, *_, code = basic.cgls_guarded(
+        Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0, normal=True)
+    assert code == status.BREAKDOWN and iiter < NITER
+    assert np.all(np.isfinite(xb.asarray()))
+
+
+# an even nt, one-sided, the whole half spectrum kept: the Nyquist bin is
+# one of the kept bins, and F1ᴴ drops its imaginary part as it does bin 0's
+NYQ = dict(SIZES, nt=64, nfmax=33, ns=16, nr=16, nv=2)
+
+
+def _nyq_mdc(P, mesh=None, sizes=NYQ):
+    return pmt.MPIMDC(P, nt=sizes["nt"], nv=sizes["nv"], dt=sizes["dt"],
+                      dr=sizes["dr"], twosided=False, mesh=mesh)
+
+
+def test_a_kept_nyquist_bin_is_dropped_by_the_round_trip():
+    """``Wᵀ W`` at an even ``nt`` with the whole half spectrum kept is
+    the identity but for zeros at bin 0's AND the Nyquist bin's
+    imaginary parts: there ``F1 F1ᴴ`` is not the kernel's mask ``M``,
+    which zeroes bin 0's alone."""
+    nt = NYQ["nt"]
+    nf = nt // 2 + 1
+    W = local._truncated_dft_matrix(nt, nt, nf, False,
+                                    "float32").astype(np.float64)
+    want = np.eye(2 * nf)
+    want[nf, nf] = want[2 * nf - 1, 2 * nf - 1] = 0.0
+    assert np.abs(W.T @ W - want).max() < 1e-6
+
+
+@pytest.mark.parametrize("nt", [2, 3])
+def test_a_kept_nyquist_bin_takes_the_pair(monkeypatch, nt):
+    """Where the core would run the kernel, a chain that keeps the
+    Nyquist bin of an even ``nt`` says ``pair`` with the ``why``
+    ``nyquist``, and ``cgls(normal=None)`` stays classic; at an odd
+    ``nt`` with as many bins the same chain takes the kernel."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    P = np.zeros((2, 2, 512, 1024), np.float32)
+    Op = pmt.MPIMDC(P, nt=nt, nv=16, twosided=False, mesh=pmt.make_mesh(1))
+    v = _vec(np.zeros(Op.shape[1]), pmt.make_mesh(1))
+    want = ("pair", "nyquist", 512) if nt == 2 else ("one_sweep", None, 512)
+    assert Op.normal_select(v) == want
+    assert Op.has_fresh_normal
+    assert Op.prefers_fused_normal(v) is (nt == 3)
+    assert basic._resolve_normal(Op, v, None) is (nt == 3)
+
+
+def test_fresh_cgls_with_a_kept_nyquist_bin_is_the_classic(monkeypatch):
+    """``pmt.cgls(normal=True)`` on a chain that keeps the Nyquist bin:
+    with the core forced to answer ``one_sweep``, the chain's product
+    is still the pair (``why`` = ``nyquist``: the kernel's mask would
+    leave the Nyquist bin's imaginary part in), and the fresh body's
+    answer is the classic one's."""
+    _force(monkeypatch, "one_sweep")
+    mesh = pmt.make_mesh(1)
+    Op = _nyq_mdc(_planes(NYQ), mesh)
+    x = jax.random.normal(jax.random.key(4), (Op.shape[1],), jnp.float32)
+    d = Op.matvec(_vec(np.asarray(x), mesh))
+    monkeypatch.setenv("PYLOPS_MPI_TPU_METRICS", "on")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_TRACE", "spans")
+    metrics.clear_metrics()
+    trace.clear_events()
+    basic.clear_fused_cache()
+    fresh = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                     normal=True)[0].asarray()
+    assert metrics.snapshot()["counters"]["solver.cgls.fresh_residual"] == 1
+    ev = [e["args"] for e in trace.get_events()
+          if e["name"] == "mdc.normal_select"]
+    assert [(e["form"], e["why"]) for e in ev] == [("pair", "nyquist")] * 2
+    classic = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                       normal=False)[0].asarray()
+    assert _rel(fresh, classic) < 1e-6
+
+
+def test_the_ca_engine_runs_mdc_classic(case, monkeypatch):
+    """Under ``PYLOPS_MPI_TPU_CA`` the engine has no fresh-residual body:
+    a one-sweep solve of an ``MPIMDC`` runs the pipelined engine's
+    classic body and is counted as classic — no ``one_sweep``, no
+    ``fresh_residual`` — with the answer of the pipelined classic
+    solve."""
+    mesh = pmt.make_mesh(1)
+    Op = _mdc(case["P"], mesh)
+    d = _vec(case["d"], mesh)
+    monkeypatch.setenv("PYLOPS_MPI_TPU_CA", "pipelined")
+    monkeypatch.setenv("PYLOPS_MPI_TPU_METRICS", "on")
+    metrics.clear_metrics()
+    basic.clear_fused_cache()
+    one = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                   normal=True)[0].asarray()
+    counters = metrics.snapshot()["counters"]
+    assert counters["solver.cgls.solves"] == 1
+    assert "solver.cgls.one_sweep" not in counters
+    assert "solver.cgls.fresh_residual" not in counters
+    two = pmt.cgls(Op, d, x0=_x0(Op, mesh), niter=NITER, tol=0.0,
+                   normal=False)[0].asarray()
+    assert np.array_equal(one, two)

@@ -290,16 +290,23 @@ def test_pmt_laplacian_compiles_for_v5e(v5e_devices, monkeypatch, chips,
     assert c.memory_analysis().temp_size_in_bytes <= 2.05 * 4 * V / chips
 
 
-def _mdc_solver_for_v5e(v5e_devices, kernel_as: str):
+def _mdc_solver_for_v5e(v5e_devices, kernel_as: str, normal=False):
     """Compile ``mdd_obc.cgls_nv16``'s fused solver for one described
     chip at the cell's full size (PR 34; kept in THIS file because it
     holds the one topology fixture of the suite): the operator built
     inside the traced function from an abstract kernel — ``planes``:
     float32 ``(2, 64, 4096, 4096)``, what the cell hands over;
-    ``complex``: complex64 ``(64, 4096, 4096)``."""
+    ``complex``: complex64 ``(64, 4096, 4096)``. ``normal``: the
+    one-sweep schedule, the kernels compiled as on a TPU — the program
+    the cell runs (``cgls(normal=None)`` asks the chain,
+    which answers yes on a TPU)."""
     from jax.experimental.compilation_cache import compilation_cache as cc
     from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
     nf, n, nt, nv = 64, 4096, 1023, 16
+    solve = basic._cgls_fused_normal if normal else basic._cgls_fused
+    real = pk._interpret
+    if normal:
+        pk._interpret = lambda: False
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
     try:
@@ -317,12 +324,13 @@ def _mdc_solver_for_v5e(v5e_devices, kernel_as: str):
                     (V,), 1, pmt.Partition.BROADCAST, 0), None),
                 [jax.ShapeDtypeStruct((V,), jnp.float32,
                                       sharding=NamedSharding(mesh, P()))])
-            fn = jax.jit(lambda g, y, x0: basic._cgls_fused(
+            fn = jax.jit(lambda g, y, x0: solve(
                 pmt.MPIMDC(g, nt=nt, nv=nv, dt=0.004, dr=12.5,
                            twosided=True, mesh=mesh),
                 y, x0, jnp.float32(0), jnp.float32(0), niter=30))
             return fn.lower(G, vec(), vec()).compile()
     finally:
+        pk._interpret = real
         jax.config.update("jax_enable_compilation_cache", True)
         cc.reset_cache()
 
@@ -330,6 +338,44 @@ def _mdc_solver_for_v5e(v5e_devices, kernel_as: str):
 @pytest.fixture(scope="module")
 def mdc_v5e(v5e_devices):
     return _mdc_solver_for_v5e(v5e_devices, "planes")
+
+
+@pytest.fixture(scope="module")
+def mdc_one_sweep_v5e(v5e_devices):
+    return _mdc_solver_for_v5e(v5e_devices, "planes", normal=True)
+
+
+def test_mdc_one_sweep_solver_reads_the_kernel_once(mdc_one_sweep_v5e,
+                                                    mdc_v5e):
+    """The cell's one-sweep program at full size: the loop's body holds the
+    plane-pair kernel ``pmt_normal_planes`` under
+    ``pmt.MPIFredholm1.normal_matvec`` and NO plane ``einsum`` (a fusion
+    that reads the ``(2, 64, 4096, 4096)`` planes: the classic body has
+    two, its set-up two more; the set-up here reads the planes once
+    through the same kernel); the four truncated DFT products are still under
+    ``pmt.local.FFT``; arguments are the kernel and two vectors, and the
+    compiler's peak is the classic program's to a MiB (10,469,256,704
+    bytes against 10,469,255,680: no vector-sized buffer is added)."""
+    c = mdc_one_sweep_v5e
+    text = c.as_text()
+    kernel, vec = 8 * 64 * 4096 * 4096, 4 * 1023 * 4096 * 16
+    ma = c.memory_analysis()
+    assert ma.argument_size_in_bytes <= kernel + 2.01 * vec
+    assert ma.peak_memory_in_bytes <= \
+        mdc_v5e.memory_analysis().peak_memory_in_bytes + (1 << 20)
+    calls = [ln for ln in text.split("\n")
+             if re.search(r"%pmt_normal_planes(\.\d+)? = ", ln)]
+    assert len(calls) == 2             # the set-up's and the loop's
+    assert sum(bool(re.search(
+        r'op_name="[^"]*/while/body/[^"]*pmt\.MPIFredholm1\.normal_matvec/'
+        r'pmt_normal_planes', ln)) for ln in calls) == 1
+    assert not re.findall(
+        r"^%fused_computation[\w.\-]* \([^)]*f32\[2,64,4096,4096\]",
+        text, re.M)
+    inside = re.findall(r'op_name="([^"]*/while/body/[^"]*)"', text)
+    for scope in ("pmt.local.FFT", "pmt._MDCChain.fresh_normal_matvec",
+                  "pmt.solver.step", "pmt.solver.direction"):
+        assert any(scope in name.split("/") for name in inside), scope
 
 
 def test_mdc_solver_compiles_for_v5e(mdc_v5e):
@@ -450,9 +496,8 @@ def test_the_v5e_loop_body_relayouts_no_carry(poststack_v5e, mdc_v5e,
     else:
         text, n = mdc_v5e.as_text(), 1023 * 4096 * 16
         scopes = ["pmt.local.FFT", "pmt.MPIFredholm1.matvec",
-                  "pmt.MPIFredholm1.rmatvec",
-                  "pmt._ProductLinearOperator.matvec",
-                  "pmt._ProductLinearOperator.rmatvec"]
+                  "pmt.MPIFredholm1.rmatvec", "pmt._MDCChain.matvec",
+                  "pmt._MDCChain.rmatvec"]
     assert _whole_carry_relayouts(text, n) == []
     inside = re.findall(r'op_name="([^"]*/while/body/[^"]*)"', text)
     for scope in scopes + ["pmt.solver.step", "pmt.solver.direction",
@@ -750,6 +795,27 @@ def _mosaic(text, name, kernel):
 # gather's ops are those of its lane-gather form, pinned as the spray is
 SPRAY_MOSAIC = ("46aa58075873c589", "0ae4dfd4749198a1")
 GATHER_MOSAIC = ("fca9030e5296c61e", "be92d8ae16fb6816")
+# ``pmt_normal``'s, at the flagship cells' blocks (4,096^2 f32, 256-row
+# tiles, one column: ``solve_k1``), unchanged since the plane-pair
+# kernel was added beside it: the flagship cells run the kernel they ran
+NORMAL_MOSAIC = ("9ce8715d9e83b727", "9e26709bd413b5a0")
+
+
+def test_pmt_normal_is_the_flagships_kernel(one_chip, monkeypatch):
+    """The one-sweep kernel of ``MPIBlockDiag`` compiled for a described
+    v5e at the flagship's block: its Mosaic module and its source lines
+    are pinned (a second one-sweep kernel beside it, ``pmt_normal_planes``,
+    changed nothing of it)."""
+    monkeypatch.setattr(pk, "_interpret", lambda: False)
+    A = jax.ShapeDtypeStruct((2, 4096, 4096), jnp.float32, sharding=one_chip)
+    X = jax.ShapeDtypeStruct((2, 1, 4096), jnp.float32, sharding=one_chip)
+    assert pk._tile_args(A) == (256, False)
+    with jax.enable_x64(False):
+        text = jax.jit(pk.batched_normal_matvec).lower(A, X).compile(
+        ).as_text()
+    if jax.__version__ == "0.9.0":
+        assert _mosaic(text, "pmt_normal", pk._normal_kernel) \
+            == NORMAL_MOSAIC
 
 
 @pytest.mark.parametrize("what", ["tables", "solver"])
