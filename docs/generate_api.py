@@ -45,8 +45,7 @@ PAGES = {
           "reshard_budget", "plan_reshard", "reshard", "place_replica",
           "reshard_raw"]),
         ("Host-RAM spill tier", "pylops_mpi_tpu.parallel.spill",
-         ["HostArray", "to_host", "reshard_from_host", "run_spilled",
-          "chunk_hint_spill", "overlap_hint_spill", "record_spill_plan"]),
+         ["HostArray", "to_host", "reshard_from_host", "run_spilled"]),
         ("Fabric topology", "pylops_mpi_tpu.parallel.topology",
          ["fabric_override", "axis_fabric", "mesh_fabrics", "is_hybrid",
           "hybrid_axes", "topology_key", "collective_fabric", "slice_map",
@@ -204,8 +203,7 @@ PAGES = {
     "tuning": [
         ("Plan seam", "pylops_mpi_tpu.tuning.plan",
          ["Plan", "get_plan", "tune_mode", "tune_enabled", "plan_key",
-          "shape_bucket", "chunk_hint", "record_chunk_plan",
-          "applied_provenance", "cached_batch_widths"]),
+          "shape_bucket", "applied_provenance"]),
         ("Tuning spaces", "pylops_mpi_tpu.tuning.space",
          ["Axis", "TuningSpace", "register_space", "space_for",
           "candidates", "rank", "default_params"]),
@@ -214,7 +212,7 @@ PAGES = {
           "tune_margin"]),
         ("Plan cache", "pylops_mpi_tpu.tuning.cache",
          ["cache_path", "lookup", "store", "load_plans",
-          "cached_keys", "clear_memory"]),
+          "clear_memory"]),
     ],
     "serving": [
         ("Warm-executable pool", "pylops_mpi_tpu.serving.engine",

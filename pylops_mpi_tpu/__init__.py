@@ -67,7 +67,7 @@ from . import aot
 
 # process-wide fallback compile tier: point JAX's persistent
 # compilation cache at PYLOPS_MPI_TPU_COMPILE_CACHE (no-op unset) so
-# every entry point — tests, bench, supervised workers, the serving
+# every entry point — tests, scripts, supervised workers, the serving
 # daemon — shares the job's cache without per-call wiring (docs/aot.md)
 aot.maybe_enable_compile_cache()
 

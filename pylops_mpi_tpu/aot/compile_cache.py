@@ -15,7 +15,7 @@ Where the cache lives is decided here and nowhere else, by one rule:
 2. else ``PYLOPS_MPI_TPU_COMPILE_CACHE=<dir>`` (CI legs share a per-job
    dir, the tier-1 command keeps one under ``/tmp``);
 3. else the ``default`` the caller passes — the repository's entry
-   scripts (``chip_smoke.py``, ``bench.py``, ``benchmarks/*``) pass
+   scripts (``chip_smoke.py``, ``benchmarks/*``) pass
    ``<checkout>/.jax_cache``; the package import passes none, so a
    library user with neither variable set gets no cache.
 

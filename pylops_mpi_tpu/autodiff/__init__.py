@@ -15,8 +15,8 @@ shard_map collective or unroll a ``lax.while_loop`` tape:
   reusing the ``_get_fused`` executables, tuned plans, CA mode, the
   ``M=`` preconditioner seam and the AOT bank.
 - :mod:`unrolled` — reverse-differentiable fixed-iteration (scan-tape)
-  CG/CGLS oracles, used by the tests and the bench gradient race as
-  the "what everyone else does" baseline.
+  CG/CGLS oracles, used by the tests as the "what everyone else
+  does" baseline.
 - :mod:`fit` — a minimal ``value_and_grad`` training driver
   (grad-of-``batched_solve`` over an operator family = minibatch
   training of a learned regularizer).

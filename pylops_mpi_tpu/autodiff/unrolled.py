@@ -1,7 +1,7 @@
 """Reverse-differentiable fixed-iteration CG/CGLS (scan tape).
 
-The oracle the implicit rules are checked against, and the baseline
-the bench gradient race times: a ``lax.scan`` over exactly ``niter``
+The oracle the implicit rules are checked against, and their
+baseline: a ``lax.scan`` over exactly ``niter``
 iterations is what a user without implicit diff would write —
 reverse-differentiable because scan saves the per-iteration carry as
 a tape, which is precisely its cost: O(niter · n) activation memory

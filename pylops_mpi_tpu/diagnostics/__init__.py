@@ -14,8 +14,7 @@ time, bytes and iterations actually go. Four modules make the folklore first-cla
 - :mod:`~pylops_mpi_tpu.diagnostics.costmodel` — per-op cost registry
   (FLOPs, HBM bytes, ICI bytes per apply) generalizing the comm-volume
   model previously private to ``ops/matrixmult.py``'s auto-select,
-  plus the per-chip peak tables and a roofline predictor
-  (``bench.py`` stamps predicted-vs-measured on every row).
+  plus the per-chip peak tables and a roofline predictor.
 - :mod:`~pylops_mpi_tpu.diagnostics.telemetry` — per-iteration
   convergence telemetry captured from INSIDE the fused solver
   ``while_loop``\\ s via ``jax.debug.callback``; off by default, with
@@ -23,8 +22,7 @@ time, bytes and iterations actually go. Four modules make the folklore first-cla
   donated/fused hot path carries zero host callbacks when disabled.
 - :mod:`~pylops_mpi_tpu.diagnostics.profiler` — the deadline-aware
   stage runner and the central per-stage wall-budget table (tuner
-  searches, benchmark components, watched multi-host phases, serving
-  batches).
+  searches, watched multi-host phases, serving batches).
 
 Fleet observability (ISSUE 10) adds the cross-process half:
 

@@ -18,8 +18,8 @@ so it runs on a login node or in CI without touching an accelerator):
     ``job_report.json``) as one combined per-worker table.
 
 Output contract: progress goes to stderr; the LAST stdout line is one
-compact JSON summary (the ``bench._run_json_cmd`` salvage convention
-shared with ``python -m pylops_mpi_tpu.tuning``). Exit is nonzero only
+compact JSON summary (the convention shared with
+``python -m pylops_mpi_tpu.tuning``). Exit is nonzero only
 on usage errors — tolerant loading is the whole point of a post-mortem
 tool.
 """

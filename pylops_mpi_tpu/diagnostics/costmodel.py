@@ -16,13 +16,13 @@ module makes both first-class instead of bench-script folklore:
 - :func:`summa_comm_volume` — the per-device communication-volume
   model that ``ops/matrixmult.py``'s ``schedule="auto"`` previously
   kept private (it now calls this function), exposed so tests can
-  hand-check it and bench rows can cite it;
-- the per-chip peak tables (dense-matmul TFLOP/s, HBM GB/s —
-  the figures ``bench.py`` has carried since rounds 2/7 — plus an
+  hand-check it;
+- the per-chip peak tables (dense-matmul TFLOP/s, HBM GB/s — public
+  spec-sheet figures, held equal to the benchmark's
+  ``chipbench/peaks.json`` by ``tests/test_diagnostics.py`` — plus an
   APPROXIMATE aggregate ICI GB/s per chip) and :func:`roofline`,
   which converts an :class:`OpCost` + peaks into a predicted time and
-  a bound ("compute" / "hbm" / "ici") so ``bench.py`` stamps
-  predicted-vs-measured on every row.
+  a bound ("compute" / "hbm" / "ici").
 
 Counting conventions (what the hand-count tests pin):
 
@@ -34,7 +34,7 @@ Counting conventions (what the hand-count tests pin):
   once per apply (matrices at their STORAGE dtype — the
   ``compute_dtype`` lever halves this — vectors at theirs). On-chip
   (VMEM) residency makes the true figure smaller; the model is an
-  upper bound, exactly like the bench's ``hbm_pct`` qualifier.
+  upper bound.
 - ICI bytes: bytes RECEIVED per device per apply. An all-gather over
   ``P`` devices of a result of ``B`` bytes receives ``B·(P-1)/P``;
   a tiled all-to-all moves ``B·(P-1)/P`` of the local block; a psum
@@ -62,7 +62,7 @@ __all__ = ["OpCost", "estimate", "register_cost", "roofline",
 # Dense matmul peak per chip, TFLOP/s (bf16 inputs, f32 accumulation on
 # the MXU) — public spec-sheet numbers; most-specific key first. The
 # f32 peak under the package's `highest` matmul-precision pin is bf16/6
-# (3 products x 2 operand splits — bench.py round-4 correction).
+# (3 products x 2 operand splits).
 PEAK_TFLOPS = [
     ("v6e", 918.0), ("v6 lite", 918.0), ("v6", 918.0),
     ("v5p", 459.0), ("v5e", 197.0), ("v5 lite", 197.0), ("v5", 459.0),
@@ -292,8 +292,7 @@ def summa_comm_volume_split(N: int, K: int, M: int,
     fabric's bytes. A topology-blind schedule gets the conservative
     charge instead: with no pinned axis→fabric assignment, every
     collective may ride the slow fabric, so the whole total is
-    DCN-attributed (how the flat baseline of the ``hierarchical_vs_flat``
-    bench row and the ≥3x acceptance ratio are counted)."""
+    DCN-attributed."""
     pr, pc = int(grid[0]), int(grid[1])
     Np = pr * math.ceil(N / pr)
     Kp_r = pr * math.ceil(K / pr)
@@ -608,7 +607,7 @@ def _cost_derivative(op, direction: str) -> OpCost:
 
 
 # dotted-name -> model; resolved lazily so this module imports clean
-# from scripts (bench.py children) without pulling the operator stack
+# without pulling the operator stack
 _BUILTIN = [
     ("pylops_mpi_tpu.ops.matrixmult:_MPIBlockMatrixMult",
      _cost_block_matmul),

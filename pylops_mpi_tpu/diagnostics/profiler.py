@@ -1,9 +1,9 @@
 """The deadline-aware stage runner.
 
 :class:`DeadlineRunner` + :data:`STAGE_BUDGETS` — the central
-per-stage wall-budget table (tuner searches, benchmark components,
-watched multi-host phases, serving batches) and a runner that (a)
-caps each stage's timeout at ``min(budget, window remaining)``, (b)
+per-stage wall-budget table (tuner searches, watched multi-host
+phases, serving batches) and a runner that (a) caps each stage's
+timeout at ``min(budget, window remaining)``, (b)
 records whether a killed stage still BANKED a partial artifact, and
 (c) SKIPS stages the remaining window cannot fit.
 
@@ -30,15 +30,13 @@ __all__ = ["STAGE_BUDGETS", "stage_budget", "DeadlineRunner",
 
 
 # ------------------------------------------------------------ budget table
-# Per-stage wall budgets, seconds. Env override names:
-# PROBE_<STAGE>_TIMEOUT (BENCH_COMPONENT_TIMEOUT for "component").
+# Per-stage wall budgets, seconds. Env override name:
+# PROBE_<STAGE>_TIMEOUT.
 STAGE_BUDGETS: Dict[str, int] = {
     # the autotuner sweep (python -m pylops_mpi_tpu.tuning); also the
     # per-search budget tuning.search enforces in-process
     # (PYLOPS_MPI_TPU_TUNE_BUDGET overrides for a single search)
     "tune":           600,
-    # per-config cap of benchmarks/bench_components.py
-    "component":      150,
     # elastic-runtime watched phases (resilience/elastic.py
     # watched_call deadlines; PYLOPS_MPI_TPU_WATCHDOG_TIMEOUT
     # overrides globally, PROBE_<STAGE>_TIMEOUT per stage):
@@ -55,22 +53,17 @@ STAGE_BUDGETS: Dict[str, int] = {
     "serve_smoke":     900,
 }
 
-def _env_name(stage: str) -> str:
-    if stage == "component":
-        return "BENCH_COMPONENT_TIMEOUT"
-    return "PROBE_" + stage.upper() + "_TIMEOUT"
-
 
 def stage_budget(stage: str, env: Optional[Dict] = None) -> int:
     """Wall budget (seconds) for one stage: the env override
-    (``PROBE_<STAGE>_TIMEOUT`` / ``BENCH_COMPONENT_TIMEOUT``) when set
-    and parseable, else the table entry. Unknown stages raise — a
-    typo'd stage name must not silently get some default."""
+    (``PROBE_<STAGE>_TIMEOUT``) when set and parseable, else the table
+    entry. Unknown stages raise — a typo'd stage name must not
+    silently get some default."""
     if stage not in STAGE_BUDGETS:
         raise KeyError(f"unknown stage {stage!r}; known: "
                        f"{sorted(STAGE_BUDGETS)}")
     env = os.environ if env is None else env
-    raw = env.get(_env_name(stage))
+    raw = env.get("PROBE_" + stage.upper() + "_TIMEOUT")
     if raw is not None:
         try:
             return int(raw)

@@ -171,7 +171,6 @@ class _MPIBaseFFTND(MPILinearOperator):
         want_chunks = comm_chunks is None and not comm_chunks_env_pinned()
         want_hier = (hierarchical is None
                      and not hierarchical_env_pinned())
-        self._chunks_from_user = not want_chunks
         if want_overlap or want_chunks or want_hier:
             from ..tuning import plan as _tuneplan
             tplan = _tuneplan.get_plan(
@@ -262,8 +261,7 @@ class _MPIBaseFFTND(MPILinearOperator):
             return 1
         from ..parallel.collectives import resolve_chunks
         return resolve_chunks(width, P, self._comm_chunks,
-                              where=f"{type(self).__name__} pencil",
-                              allow_plan=not self._chunks_from_user)
+                              where=f"{type(self).__name__} pencil")
 
     def _shift_axes(self, flags) -> Tuple[int, ...]:
         return tuple(int(ax) for ax, f in zip(self.axes, flags) if f)

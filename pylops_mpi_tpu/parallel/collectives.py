@@ -94,7 +94,7 @@ _logger = logging.getLogger("pylops_mpi_tpu.collectives")
 # The CPU-sim mesh has ~zero all-reduce latency, so the
 # communication-avoiding solver tier (solvers/ca.py) has nothing to win
 # against on CI: every reduction completes in the time of a local sum.
-# reduce_stall() is the bench/chaos seam that restores a pod-fabric
+# reduce_stall() is the test/chaos seam that restores a pod-fabric
 # latency profile — it chains an N-step SERIAL scalar recurrence (each
 # step depends on the previous one, so XLA cannot parallelize or fold
 # it) onto a reduction result, seeded FROM that result (so it cannot be
@@ -561,31 +561,15 @@ def ring_halo_ghosts(block, axis_name: str, n_shards: int,
 
 
 def resolve_chunks(width: int, n_shards: int, chunks: int,
-                   where: str = "pencil transpose",
-                   allow_plan: bool = False) -> int:
+                   where: str = "pencil transpose") -> int:
     """Usable chunk count for streaming a length-``width`` axis through
     tiled all-to-alls over ``n_shards`` devices: every chunk must carry
     at least one row per shard, so the count caps at
     ``width // n_shards``. A request that doesn't fit falls back (to
     the cap, or to 1 = the bulk schedule) with a logged note instead of
     erroring — the chunked path must degrade, never break, on small
-    axes.
-
-    ``allow_plan``: a DEFAULT-sourced ``chunks`` (not a user kwarg —
-    the caller asserts this) may be replaced by a measured
-    chunk-count plan from the autotuner cache
-    (``tuning.plan.chunk_hint``; inert when ``PYLOPS_MPI_TPU_TUNE`` is
-    off). Explicit ``comm_chunks=`` kwargs never pass ``True`` here,
-    so a hand-pinned count always wins."""
+    axes."""
     chunks = int(chunks)
-    if allow_plan:
-        from ..tuning.plan import chunk_hint
-        hint = chunk_hint(where, int(width), int(n_shards))
-        if hint is not None and hint != chunks:
-            _trace.event("tuning.chunk_plan", cat="tuning", where=where,
-                         width=int(width), n_shards=int(n_shards),
-                         requested=chunks, planned=int(hint))
-            chunks = int(hint)
     if chunks <= 1 or n_shards <= 1:
         return 1
     cap = max(1, int(width) // int(n_shards))

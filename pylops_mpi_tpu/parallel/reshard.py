@@ -31,8 +31,7 @@ Three layers:
   (shrink/grow, host replicas) transfers one chunk at a time.
 - accounting — the whole move runs under a ``collective.reshard`` span
   with per-step ``collective.reshard.step`` events, bytes split
-  ici/dcn when the mesh spans slices, and the chunk count registered
-  in the round-5 tuning space (op ``"reshard"``). The
+  ici/dcn when the mesh spans slices. The
   :func:`~pylops_mpi_tpu.resilience.faults.maybe_kill_reshard` seam
   fires between steps so chaos tests can kill a worker mid-plan.
 
@@ -350,8 +349,7 @@ def plan_reshard(global_shape: Sequence[int], itemsize: int,
                              kind, rows, row_bytes, budget, chunks,
                              src_host, dst_host, topo_note)
 
-    hint = _chunk_hint(rows, max(src.n_shards, dst.n_shards))
-    n_chunks = min(rows, max(c_budget, int(chunks or 1), int(hint or 1)))
+    n_chunks = min(rows, max(c_budget, int(chunks or 1)))
     width = -(-rows // n_chunks)
     n_chunks = -(-rows // width)    # drop empty tail chunks
 
@@ -410,8 +408,7 @@ def _plan_spilled(global_shape, itemsize, src: Layout, dst: Layout,
             row_bytes)
     w_max = rows if budget is None else max(1, int(budget) // row_bytes)
     c_budget = -(-rows // w_max)
-    hint = _chunk_hint_spilled(rows, max(src.n_shards, dst.n_shards))
-    n_chunks = min(rows, max(c_budget, int(chunks or 1), int(hint or 1)))
+    n_chunks = min(rows, max(c_budget, int(chunks or 1)))
     width = -(-rows // n_chunks)
     n_chunks = -(-rows // width)    # drop empty tail chunks
     if dst.is_scatter and dst.sizes:
@@ -443,31 +440,6 @@ def _plan_spilled(global_shape, itemsize, src: Layout, dst: Layout,
                        row_bytes, budget, spilled=True, host_dst=host_dst,
                        nbytes_h2d=h2d, nbytes_d2h=d2h,
                        dst_device_bytes=dst_device_bytes)
-
-
-def _chunk_hint_spilled(width: int, n_shards: int) -> Optional[int]:
-    """Tuned chunk count for a spilled plan: the max of the op
-    ``"reshard"`` and op ``"spill"`` hints — a chunk count banked for
-    the device planner still means "stream this width finer", and the
-    spill space can override it upward."""
-    hints = [_chunk_hint(width, n_shards)]
-    try:
-        from . import spill as _spill
-        hints.append(_spill.chunk_hint_spill(width, n_shards))
-    except Exception:
-        pass
-    vals = [int(h) for h in hints if h]
-    return max(vals) if vals else None
-
-
-def _chunk_hint(width: int, n_shards: int) -> Optional[int]:
-    """Tuned chunk count for op ``"reshard"`` (None when tuning is off
-    or no plan is cached — off mode must stay bit-identical)."""
-    from ..tuning import plan as _tplan
-    try:
-        return _tplan.chunk_hint("reshard", width, n_shards, op="reshard")
-    except Exception:
-        return None
 
 
 # ------------------------------------------------------------- executor

@@ -18,8 +18,7 @@ schedules by staging chunks through host RAM:
   ``overlap="on"`` (the default) chunk ``k`` drains to the host buffer
   on a one-slot worker thread while the main thread carves chunk
   ``k+1``, so the D2H copy and the carve genuinely overlap (both sides
-  release the GIL); ``overlap="off"`` serializes every chunk (the A/B
-  baseline the bench ratio is measured against);
+  release the GIL); ``overlap="off"`` serializes every chunk;
 - :class:`HostArray`, a host-resident stand-in for
   :class:`~pylops_mpi_tpu.DistributedArray`: the logical (unpadded)
   value in host RAM plus the full layout metadata, so
@@ -35,10 +34,11 @@ floor remains: a budget below one chunk row (``min_budget =
 row_bytes``) still raises, because even the host path stages one row
 at a time.
 
-Chunk counts and the overlap choice live in the round-5 tuning space
-under op ``"spill"``; H2D/D2H bytes are accounted per step in trace
-events and per move in the metrics registry (``bytes_h2d`` /
-``bytes_d2h`` next to the ici/dcn split). The
+The chunk count comes from the budget (or the ``chunks`` kwarg) and
+the overlap choice from the ``overlap`` kwarg (default ``"on"``).
+H2D/D2H bytes are accounted per step in trace events and per move in
+the metrics registry (``bytes_h2d`` / ``bytes_d2h`` next to the
+ici/dcn split). The
 :func:`~pylops_mpi_tpu.resilience.faults.maybe_kill_spill` seam fires
 once per staged chunk so chaos tests can kill a worker mid-spill.
 """
@@ -62,9 +62,6 @@ __all__ = [
     "run_spilled",
     "to_host",
     "reshard_from_host",
-    "chunk_hint_spill",
-    "overlap_hint_spill",
-    "record_spill_plan",
 ]
 
 
@@ -162,81 +159,18 @@ class HostArray:
                 f"axis={self.axis}, n_shards={self.n_shards})")
 
 
-# -------------------------------------------------- tuned spill params
-
-def _spill_cached_params(width: int, n_shards: int) -> Optional[dict]:
-    """Cached params for op ``"spill"`` (``comm_chunks`` + ``overlap``),
-    or ``None`` when tuning is off / no plan banked / stale params —
-    same cache-only discipline as the reshard chunk hint."""
-    try:
-        from ..tuning import plan as _tplan
-        from ..tuning import cache as _tcache
-        from ..tuning import space as _tspace
-        if _tplan.tune_mode() == "off":
-            return None
-        key = _tplan.plan_key("spill", (int(width),), None, int(n_shards),
-                              None)
-        entry = _tcache.lookup(key)
-        if entry is None:
-            return None
-        sp = _tspace.space_for("spill")
-        params = entry.get("params")
-        if not (isinstance(params, dict) and sp is not None
-                and sp.validate(params)):
-            return None
-        return dict(params)
-    except Exception:
-        return None
-
-
-def chunk_hint_spill(width: int, n_shards: int) -> Optional[int]:
-    """Tuned ``comm_chunks`` for a spilled plan (None = no hint)."""
-    params = _spill_cached_params(width, n_shards)
-    if not params:
-        return None
-    k = int(params.get("comm_chunks", 0))
-    return k if k >= 1 else None
-
-
-def overlap_hint_spill(width: int, n_shards: int) -> Optional[str]:
-    """Tuned overlap choice (``"on"``/``"off"``) for a spilled plan."""
-    params = _spill_cached_params(width, n_shards)
-    if not params:
-        return None
-    ov = params.get("overlap")
-    return ov if ov in ("on", "off") else None
-
-
-def record_spill_plan(width: int, n_shards: int, chunks: int,
-                      overlap: str = "on", trials=None,
-                      path: Optional[str] = None) -> str:
-    """Bank a measured spill schedule (chunk count + overlap choice)
-    under op ``"spill"``. Returns the cache key."""
-    from ..tuning import plan as _tplan
-    from ..tuning import cache as _tcache
-    key = _tplan.plan_key("spill", (int(width),), None, int(n_shards), None)
-    _tcache.store(key, {"params": {"comm_chunks": int(chunks),
-                                   "overlap": str(overlap)},
-                        "provenance": "tuned",
-                        "trials": list(trials or [])}, path=path)
-    return key
-
-
-def _resolve_overlap(overlap, width: int, n_shards: int) -> str:
-    """Kwarg beats the tuned hint beats the default (``"on"``) — the
-    same explicit-beats-tuner rule as every other plan seam."""
-    if overlap is not None:
-        s = str(overlap).strip().lower()
-        if s in ("1", "true"):
-            s = "on"
-        if s in ("0", "false"):
-            s = "off"
-        if s not in ("on", "off"):
-            raise ValueError(
-                f"overlap={overlap!r}: expected 'on' or 'off'")
-        return s
-    hint = overlap_hint_spill(width, n_shards)
-    return hint if hint is not None else "on"
+def _resolve_overlap(overlap) -> str:
+    """The ``overlap`` kwarg, else ``"on"``."""
+    if overlap is None:
+        return "on"
+    s = str(overlap).strip().lower()
+    if s in ("1", "true"):
+        s = "on"
+    if s in ("0", "false"):
+        s = "off"
+    if s not in ("on", "off"):
+        raise ValueError(f"overlap={overlap!r}: expected 'on' or 'off'")
+    return s
 
 
 # ------------------------------------------------------------ executor
@@ -277,9 +211,7 @@ def run_spilled(plan, *, dst=None, host_out=None, src=None,
             host_value = src.value
         src = None
     move = plan.move_axis
-    rows = plan.global_shape[move] if plan.global_shape else 0
-    ov = _resolve_overlap(overlap, rows,
-                          max(plan.src.n_shards, plan.dst.n_shards))
+    ov = _resolve_overlap(overlap)
 
     def _seams_and_event(st):
         _faults.maybe_kill_reshard()

@@ -5,8 +5,8 @@ The serving subsystem turns the library's one-shot solvers into a
 long-lived daemon for single-RHS traffic:
 
 - :mod:`.engine` — :class:`WarmPool`: compiled block-CG/CGLS programs
-  per (operator family, K bucket), pre-warmed from the tuning plan
-  cache so first-request latency is compile-free; ragged fills are
+  per (operator family, K bucket), pre-warmed at startup so
+  first-request latency is compile-free; ragged fills are
   zero-padded to the bucket (exact, by per-column freeze).
 - :mod:`.queue` — :class:`AdmissionQueue` (bounded, rejecting —
   backpressure) + :class:`Dispatcher` (continuous batcher: full

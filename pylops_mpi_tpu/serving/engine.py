@@ -17,11 +17,9 @@ request that first needs it. The :class:`WarmPool` removes that cliff:
   iteration 0, and the padded program is the same compiled executable
   the full bucket uses — so K distinct fills share one program instead
   of K programs.
-- **Prewarm** — at startup the pool consults the tuning plan cache
-  (:func:`pylops_mpi_tpu.tuning.plan.cached_batch_widths`) for the
-  block widths real traffic measured plans at, and compiles those
-  (falling back to every configured bucket when there is no history) by
-  running a zero-RHS solve per (family, K): zero data means zero
+- **Prewarm** — at startup the pool compiles the widths it is given,
+  else every configured bucket, by running a zero-RHS solve per
+  (family, K): zero data means zero
   initial residual, the fused ``while_loop`` condition is false at
   entry, and the call compiles the program without executing a single
   iteration.
@@ -304,14 +302,11 @@ class WarmPool:
                 widths: Optional[Sequence[int]] = None) -> Dict:
         """Compile (family, bucket) programs before traffic arrives.
 
-        Bucket choice per family, in order: the explicit ``widths``
-        argument; else the plan cache's banked block widths for the
-        operator's family name (``tuning.plan.cached_batch_widths`` —
-        a width that earned a measured plan is a width traffic used),
-        rounded up to configured buckets; else EVERY configured bucket
-        (no history → assume any fill can arrive). Each compile is a
-        zero-RHS solve: the loop condition is false at entry, so the
-        cost is exactly one compilation, zero iterations. Returns
+        Buckets per family: the explicit ``widths``, each rounded up
+        to a configured bucket; else EVERY configured bucket (any fill
+        can arrive). Each compile is a zero-RHS solve: the loop
+        condition is false at entry, so the cost is exactly one
+        compilation, zero iterations. Returns
         ``{family: [buckets compiled]}``.
 
         Prewarm is keyed on the family SIGNATURE (shape/dtype/solver
@@ -326,21 +321,15 @@ class WarmPool:
         genuinely requires the recompile and the zero-RHS solve runs
         as before.) With a banked AOT cache on disk, the zero-RHS
         solves themselves load serialized executables in milliseconds
-        instead of compiling — the cold-start path the bench
-        ``cold_start`` row measures."""
-        from ..tuning.plan import cached_batch_widths
+        instead of compiling."""
         from ..aot import aot_enabled
         report: Dict[str, list] = {}
+        if widths is not None:
+            want = [bucket_for(w, self._buckets) for w in widths]
+        else:
+            want = list(self._buckets)
         for name in (names if names is not None else self.families()):
             spec = self.family(name)
-            if widths is not None:
-                want = [bucket_for(w, self._buckets) for w in widths]
-            else:
-                hist = cached_batch_widths(type(spec.operator).__name__)
-                want = [bucket_for(w, self._buckets)
-                        for w in hist if w <= self.k_max]
-                if not want:
-                    want = list(self._buckets)
             sig = spec.signature() if aot_enabled() else None
             done = []
             for b in sorted(set(want)):

@@ -27,8 +27,7 @@ instead of crash-looping the fleet. The supervisor's ``on_relaunch``
 hook calls this between attempts (see ``serving/service.py``).
 
 No locks, no daemons, no network: multiple workers on one spool
-coordinate purely through rename atomicity, the same way the tuning
-cache and heartbeat files already do.
+coordinate purely through rename atomicity.
 """
 
 from __future__ import annotations

@@ -22,14 +22,14 @@ offline and banks a cache artifact. See ``docs/tuning.md``.
 """
 
 from .plan import (Plan, get_plan, tune_mode, tune_enabled, plan_key,
-                   shape_bucket, chunk_hint, applied_provenance)
+                   shape_bucket, applied_provenance)
 from .space import (Axis, TuningSpace, space_for, register_space,
                     candidates, rank, default_params)
 from .search import measure_candidates
 from . import cache
 
 __all__ = ["Plan", "get_plan", "tune_mode", "tune_enabled", "plan_key",
-           "shape_bucket", "chunk_hint", "applied_provenance",
+           "shape_bucket", "applied_provenance",
            "Axis", "TuningSpace", "space_for", "register_space",
            "candidates", "rank", "default_params",
            "measure_candidates", "cache"]

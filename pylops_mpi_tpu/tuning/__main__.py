@@ -253,11 +253,6 @@ def _tune_one(fam, shape, mesh, n_dev, platform, sp, out_path,
     cache.store(key, {"params": params, "provenance": provenance,
                       "trials": trials, "created_s": time.time()},
                 path=out_path)
-    if fam == "fft" and params.get("comm_chunks"):
-        # bank the standalone transpose-chunking plan resolve_chunks
-        # consults for default-sourced chunk counts
-        plan.record_chunk_plan(shape[-1], n_dev,
-                               params["comm_chunks"], path=out_path)
     return {"family": fam, "shape": list(shape), "key": key,
             "params": params, "provenance": provenance,
             "n_trials": sum(1 for t in trials if t.get("ok"))}

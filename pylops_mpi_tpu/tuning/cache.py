@@ -38,7 +38,7 @@ from ..diagnostics import metrics as _metrics
 from ..diagnostics import trace as _trace
 
 __all__ = ["SCHEMA_VERSION", "cache_path", "lookup", "store",
-           "load_plans", "cached_keys", "clear_memory"]
+           "load_plans", "clear_memory"]
 
 SCHEMA_VERSION = 1
 
@@ -96,17 +96,6 @@ def load_plans(path: Optional[str] = None) -> Dict[str, dict]:
         _cache_error(path, "missing 'plans' table")
         return {}
     return {str(k): v for k, v in plans.items() if isinstance(v, dict)}
-
-
-def cached_keys(path: Optional[str] = None) -> list:
-    """Every plan key currently known — the union of the in-memory
-    store and the cache file, sorted. The serving warm pool consults
-    this at startup to decide WHICH (family, K-bucket) programs earned
-    a measured plan and should be compiled before traffic arrives."""
-    with _LOCK:
-        keys = set(_MEM)
-    keys.update(load_plans(path))
-    return sorted(keys)
 
 
 def lookup(key: str, path: Optional[str] = None) -> Optional[dict]:

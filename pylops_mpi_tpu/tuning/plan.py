@@ -2,8 +2,8 @@
 
 ``get_plan()`` is the ONE entry point the operator stack calls
 (``ops/matrixmult.py``, ``ops/fft.py``, ``ops/blockdiag.py``,
-``ops/stack.py``, ``ops/derivatives.py``, ``ops/halo.py``, and
-``parallel/collectives.resolve_chunks`` through :func:`chunk_hint`).
+``ops/stack.py``, ``ops/derivatives.py``, ``ops/halo.py``,
+``ops/sparse.py``).
 Resolution order:
 
 1. ``PYLOPS_MPI_TPU_TUNE=off`` (the default) → ``None``: the caller
@@ -53,9 +53,8 @@ from . import cache as _cache
 from . import space as _space
 
 __all__ = ["Plan", "tune_mode", "tune_enabled", "plan_key",
-           "shape_bucket", "get_plan", "chunk_hint",
-           "record_chunk_plan", "applied_provenance", "reset_applied",
-           "cached_batch_widths"]
+           "shape_bucket", "get_plan", "applied_provenance",
+           "reset_applied"]
 
 _MODES = ("off", "on", "auto")
 _warned_mode = False
@@ -65,8 +64,7 @@ _warned_mode = False
 # this is the belt to that suspender)
 _tls = threading.local()
 
-# last applied provenance per op family — bench.py stamps this as the
-# `plan=` column on headline rows
+# last applied provenance per op family
 _APPLIED: Dict[str, str] = {}
 _APPLIED_LOCK = threading.Lock()
 
@@ -171,27 +169,6 @@ def plan_key(op: str, shape, dtype=None, n_dev: Optional[int] = None,
     return key
 
 
-def cached_batch_widths(op: str, path: Optional[str] = None) -> list:
-    """Block widths K with a banked plan for operator family ``op``
-    (sorted, deduped; ``1`` for keys without a ``|b{K}`` segment). The
-    serving warm pool's startup consult: a width that earned a measured
-    plan is a width real traffic used, so its (family, K) program is
-    compiled before the first request instead of on it. An unparseable
-    segment is skipped — a foreign cache entry must not break serving
-    bring-up."""
-    widths = set()
-    prefix = op + "|"
-    for key in _cache.cached_keys(path):
-        if not key.startswith(prefix):
-            continue
-        k = 1
-        for seg in key.split("|")[1:]:
-            if len(seg) > 1 and seg[0] == "b" and seg[1:].isdigit():
-                k = int(seg[1:])
-        widths.add(k)
-    return sorted(widths)
-
-
 def _context(op: str, shape, dtype, n_dev, axes, extra) -> Dict:
     platform, chip = _chip_kind()
     return {"op": op, "shape": tuple(int(s) for s in np.atleast_1d(shape)),
@@ -207,8 +184,8 @@ def _note_applied(op: str, provenance: str) -> None:
 
 def applied_provenance(op: Optional[str] = None, default: str = "default"):
     """Provenance of the last plan applied for ``op`` this process
-    (``"default"`` when the tuner never ran — the ``plan=`` column
-    bench.py stamps). Without ``op``: the whole table (a copy)."""
+    (``"default"`` when the tuner never ran). Without ``op``: the
+    whole table (a copy)."""
     with _APPLIED_LOCK:
         if op is None:
             return dict(_APPLIED)
@@ -291,40 +268,3 @@ def get_plan(op: str, *, shape, dtype=None, mesh=None,
     _trace.event("tuning.plan", cat="tuning", op=op, key=key,
                  provenance="costmodel", params=params)
     return plan
-
-
-def chunk_hint(where: str, width: int, n_shards: int, *,
-               op: str = "pencil_transpose") -> Optional[int]:
-    """Cached chunk-count plan for one streamed collective —
-    ``parallel.collectives.resolve_chunks`` consults this for
-    default-sourced chunk counts (explicit ``comm_chunks=`` kwargs
-    never reach here), and the round-13 resharding planner with
-    ``op="reshard"``. Cache-only by design: there is no analytic
-    reason to move off the env default without a measurement."""
-    if tune_mode() == "off" or getattr(_tls, "active", False):
-        return None
-    key = plan_key(op, (int(width),), None, int(n_shards), None)
-    entry = _cache.lookup(key)
-    if entry is None:
-        return None
-    sp = _space.space_for(op)
-    params = entry.get("params")
-    if not (isinstance(params, dict) and sp is not None
-            and sp.validate(params)):
-        return None
-    k = int(params.get("comm_chunks", 0))
-    return k if k >= 1 else None
-
-
-def record_chunk_plan(width: int, n_shards: int, chunks: int,
-                      trials: Optional[List[Dict]] = None,
-                      path: Optional[str] = None, *,
-                      op: str = "pencil_transpose") -> str:
-    """Bank a measured chunk count for one transpose/reshard width
-    (used by the offline CLI after an FFT-family sweep). Returns the
-    key."""
-    key = plan_key(op, (int(width),), None, int(n_shards), None)
-    _cache.store(key, {"params": {"comm_chunks": int(chunks)},
-                       "provenance": "tuned",
-                       "trials": list(trials or [])}, path=path)
-    return key

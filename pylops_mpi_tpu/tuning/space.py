@@ -97,10 +97,9 @@ class TuningSpace:
 # ------------------------------------------------------------- cost seeds
 def _peaks(context: Dict) -> Dict:
     """Roofline peaks for the seed: spec-sheet per-chip numbers on
-    TPU; the bench's assumed stream bandwidth carved across virtual
+    TPU; an assumed 30 GB/s stream bandwidth carved across virtual
     devices on the CPU sim (the point is ORDERING candidates, not
-    absolute prediction — same convention as bench.py's roofline
-    rows)."""
+    absolute prediction)."""
     nd = max(1, int(context.get("n_dev") or 1))
     if context.get("platform") == "tpu":
         from ..diagnostics import costmodel
@@ -507,33 +506,6 @@ register_space(TuningSpace(
     cost=_cost_halo_family,
     note="repack from the pre-exchange block (select-merged) vs the "
          "post-exchange extended block"))
-
-register_space(TuningSpace(
-    op="pencil_transpose",
-    axes=(Axis("comm_chunks", (1, 2, 4, 8)),),
-    cost=None,
-    note="standalone chunk-count plans consumed by "
-         "collectives.resolve_chunks for default-chunked transposes"))
-
-register_space(TuningSpace(
-    op="reshard",
-    axes=(Axis("comm_chunks", (1, 2, 4, 8)),),
-    cost=None,
-    note="chunk counts for the bounded-memory resharding planner "
-         "(parallel/reshard.py); the budget sets the floor, a banked "
-         "plan can only stream finer"))
-
-register_space(TuningSpace(
-    op="spill",
-    axes=(Axis("comm_chunks", (1, 2, 4, 8)),
-          Axis("overlap", ("on", "off"))),
-    cost=None,
-    note="host-staging schedules of the spill tier "
-         "(parallel/spill.py): chunk counts for the budget-sized "
-         "device_get/device_put stream and the double-buffer overlap "
-         "choice (on = fetch of chunk k+1 rides behind the placement "
-         "of chunk k); the budget stays the floor on chunk counts"))
-
 
 def _cost_ca(context: Dict, params: Dict) -> Optional[float]:
     """Latency-aware (α–β) seed for the communication-avoiding solver

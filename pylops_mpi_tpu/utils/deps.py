@@ -170,7 +170,7 @@ KNOBS = [
      "default epoch length of the segmented fused solvers "
      "(checkpoint cadence)"),
     ("PYLOPS_MPI_TPU_RETRIES", "int>=0", "3",
-     "resilience/retry.py (parallel/mesh.py, benchmarks)",
+     "resilience/retry.py (parallel/mesh.py)",
      "bounded retries for transient host-side faults (multihost "
      "init)"),
     ("PYLOPS_MPI_TPU_RETRY_BACKOFF", "seconds", "0.5",
@@ -187,7 +187,7 @@ KNOBS = [
      "diagnostics/telemetry.py",
      "in-loop solver telemetry gate under TRACE=full"),
     ("PYLOPS_MPI_TPU_TUNE", "off|on|auto", "off",
-     "tuning/plan.py (ops/*, parallel/collectives.py)",
+     "tuning/plan.py (ops/*)",
      "autotuner seam: on replays cached/cost-model plans, auto also "
      "measures on cache miss"),
     ("PYLOPS_MPI_TPU_TUNE_CACHE", "path", "unset (memory-only)",
@@ -199,9 +199,9 @@ KNOBS = [
     ("PYLOPS_MPI_TPU_TUNE_MARGIN", "float", "0.02", "tuning/search.py",
      "fractional win required to move off the default plan"),
     ("PYLOPS_MPI_TPU_BATCH", "int>=1", "1",
-     "utils/deps.py (benchmarks, tuning contexts)",
-     "default RHS-column count K of the batched solve paths (block "
-     "solvers' bench race width; carried into plan-cache keys)"),
+     "utils/deps.py (tuning contexts)",
+     "default RHS-column count K of the batched solve paths (carried "
+     "into plan-cache keys)"),
     ("PYLOPS_MPI_TPU_TEST_DEVICES", "int", "8",
      "tests/conftest.py, .github/workflows/build.yml",
      "virtual-device count of the CPU-sim test mesh"),
@@ -322,8 +322,8 @@ KNOBS = [
      "applies; the monomial-basis conditioning guard falls back to "
      "the pipelined engine on breakdown"),
     ("PYLOPS_MPI_TPU_REDUCE_STALL", "int>=0", "unset (off)",
-     "parallel/collectives.py (solvers, bench.py)",
-     "bench/chaos seam: chain an N-step serial scalar dependency "
+     "parallel/collectives.py (solvers)",
+     "test/chaos seam: chain an N-step serial scalar dependency "
      "onto every solver reduction result so the CPU sim becomes "
      "latency-dominated like a pod fabric; unset/0 traces "
      "bit-identical programs"),
@@ -653,9 +653,8 @@ def comm_chunks_env_pinned() -> bool:
 def batch_default() -> int:
     """Default RHS-column count ``K`` of the batched solve paths
     (``PYLOPS_MPI_TPU_BATCH``, default 1 = single-RHS; floored at 1).
-    Consumed by the benchmark's batched-throughput race and forwarded
-    into plan-cache contexts (``extra["batch"]``) so a plan measured
-    at one block width is never replayed at another."""
+    Forwarded into plan-cache contexts (``extra["batch"]``) so a plan
+    measured at one block width is never replayed at another."""
     try:
         v = int(os.environ.get("PYLOPS_MPI_TPU_BATCH", "1"))
     except ValueError:

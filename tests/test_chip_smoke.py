@@ -135,40 +135,6 @@ def test_chip_smoke_without_a_chip_exits_nonzero_naming_the_platform():
     assert p.stdout.strip() == ""          # no result line
 
 
-def test_bench_without_a_chip_exits_nonzero(monkeypatch, capsys):
-    """In this process JAX runs on the CPU; unless JAX_PLATFORMS=cpu
-    ASKED for that, bench.py refuses to measure."""
-    import bench
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    assert bench.main() == 2
-    out = capsys.readouterr()
-    assert "platform 'cpu'" in out.err and out.out == ""
-
-
-def test_bench_components_without_a_chip_exits_nonzero(monkeypatch,
-                                                       capsys):
-    monkeypatch.syspath_prepend(os.path.join(ROOT, "benchmarks"))
-    import bench_components
-    monkeypatch.delenv("JAX_PLATFORMS", raising=False)
-    assert bench_components.main() == 2
-    out = capsys.readouterr()
-    assert "platform 'cpu'" in out.err and out.out == ""
-
-
-def test_cpu_rows_carry_no_device_metric():
-    """Asked-for CPU runs keep counts and correctness only."""
-    import bench
-    assert bench._device_metrics(iters_per_sec=1.0, wall_s=2.0) == {}
-    line = bench._compact_line({
-        "metric": "m", "value": None, "unit": "iters/s",
-        "platform": "cpu", "device_metrics": "not measured (platform cpu)",
-        "f32": {"mode": "f32 two-sweep", "rel_err": "1e-6",
-                "status": "maxiter", "platform": "cpu"}})
-    assert "value" not in line and "mfu" not in line
-    assert line["platform"] == "cpu"
-    assert "iters_per_sec" not in line["f32"]
-
-
 # ------------------------------------------------ one process per chip
 MODULES = ("pylops_mpi_tpu", "pylops_mpi_tpu.resilience",
            "pylops_mpi_tpu.serving")
@@ -198,20 +164,12 @@ def test_import_initialises_no_backend(backends_after_import, module):
     assert backends_after_import[module] == []
 
 
-def test_bench_module_imports_without_jax():
-    """bench.py's one child — the NumPy baseline — imports bench and
-    nothing of JAX, so it never asks for the chip its parent holds."""
-    p = _run(["-c", "import sys, bench; assert 'jax' not in sys.modules"])
-    assert p.returncode == 0, p.stderr[-2000:]
-
-
 def test_entry_scripts_start_no_jax_children():
-    """No entry script that initialises a backend starts a process,
-    except bench.py's jax-free NumPy baseline."""
+    """No entry script that initialises a backend starts a process."""
     spawners = ("subprocess", "multiprocessing", "os.fork", "Popen",
                 "os.system")
     found = {}
-    scripts = ["chip_smoke.py", "bench.py", "__graft_entry__.py"] + [
+    scripts = ["chip_smoke.py", "__graft_entry__.py"] + [
         os.path.join("benchmarks", f)
         for f in sorted(os.listdir(os.path.join(ROOT, "benchmarks")))
         if f.endswith(".py")]
@@ -221,7 +179,7 @@ def test_entry_scripts_start_no_jax_children():
         hits = [s for s in spawners if s in src]
         if hits:
             found[rel] = hits
-    assert found == {"bench.py": ["subprocess"]}
+    assert found == {}
 
 
 # ------------------------------------------- construction and placement

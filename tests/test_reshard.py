@@ -265,28 +265,3 @@ def test_raw_non_divisible_traced(ndev, rng):
 
     got = jax.jit(f)(v)
     np.testing.assert_array_equal(np.asarray(got), v)
-
-
-def test_tuning_space_registered():
-    from pylops_mpi_tpu.tuning.space import space_for
-    sp = space_for("reshard")
-    assert sp is not None
-    assert [a.name for a in sp.axes] == ["comm_chunks"]
-
-
-def test_chunk_hint_consulted(monkeypatch, tmp_path, ndev):
-    """A recorded reshard plan raises the chunk count the planner
-    picks (the budget stays the floor, a banked plan streams finer)."""
-    from pylops_mpi_tpu.tuning import plan as tplan
-    from pylops_mpi_tpu.tuning import cache as tcache
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE", "on")
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE_CACHE",
-                       str(tmp_path / "plans.json"))
-    tcache.clear_memory()
-    # keyed on (rows, max-world) — the planner consults (45, 8) here
-    tplan.record_chunk_plan(45, 8, 4, op="reshard")
-    src = R.Layout.scatter(_sizes(45, 8))
-    dst = R.Layout.scatter(_sizes(45, 4))
-    plan = R.plan_reshard((45,), F64, src, dst)
-    assert plan.chunks >= 4
-    tcache.clear_memory()

@@ -34,8 +34,6 @@ from pylops_mpi_tpu.serving.queue import SolveRequest
 from pylops_mpi_tpu.solvers import batched_cache_info, batched_solve
 from pylops_mpi_tpu.solvers.basic import _FUSED_CACHE
 from pylops_mpi_tpu.solvers.block import _BATCHED_CACHE
-from pylops_mpi_tpu.tuning import cache as tuning_cache
-from pylops_mpi_tpu.tuning.plan import cached_batch_widths, plan_key
 from pylops_mpi_tpu.utils.deps import KNOBS
 
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
@@ -170,42 +168,17 @@ def test_prewarm_compiles_before_traffic(rng):
         "first request recompiled despite prewarm"
 
 
-def test_prewarm_consults_plan_cache(rng, tmp_path, monkeypatch):
-    """With banked plans for the operator family, prewarm compiles
-    only the widths traffic measured (rounded up to buckets), not
-    every configured bucket."""
-    monkeypatch.delenv("PYLOPS_MPI_TPU_TUNE_CACHE", raising=False)
-    tuning_cache.clear_memory()
-    path = str(tmp_path / "plans.json")
-    op_name = "MPIBlockDiag"
-    key = plan_key(op_name, (48,), np.float32, 8, ("sp",),
-                   {"batch": 3})
-    tuning_cache.store(key, {"plan": {}}, path=path)
-    assert cached_batch_widths(op_name, path=path) == [3]
+def test_prewarm_without_widths_compiles_every_bucket(rng):
+    """No ``widths``: prewarm compiles every configured bucket. Given
+    widths round up to their buckets and are compiled alone."""
+    pool = WarmPool(buckets=(1, 2, 4))
+    pool.register(_make_family(rng))
+    assert pool.prewarm() == {"fam": [1, 2, 4]}
+    assert {("fam", b) for b in (1, 2, 4)} <= pool.warmed
     pool = WarmPool(buckets=(2, 4))
     pool.register(_make_family(rng))
-    monkeypatch.setattr(
-        "pylops_mpi_tpu.tuning.plan.cached_batch_widths",
-        lambda op, path=None: [3] if op == op_name else [])
-    report = pool.prewarm()
-    assert report == {"fam": [4]}    # 3 rounds up to the 4-bucket
-    tuning_cache.clear_memory()
-
-
-def test_cached_batch_widths_parsing(tmp_path, monkeypatch):
-    monkeypatch.delenv("PYLOPS_MPI_TPU_TUNE_CACHE", raising=False)
-    tuning_cache.clear_memory()
-    path = str(tmp_path / "plans.json")
-    for key in ("OpA|s64|f32|mesh[sp]x8|cpu:host",
-                "OpA|s64|f32|mesh[sp]x8|cpu:host|b8",
-                "OpA|s64|f32|mesh[sp]x8|cpu:host|b16|thybrid",
-                "OpB|s64|f32|mesh[sp]x8|cpu:host|b4",
-                "OpA|s64|f32|mesh[sp]x8|cpu:host|bbad"):
-        tuning_cache.store(key, {"plan": {}}, path=path)
-    assert cached_batch_widths("OpA", path=path) == [1, 8, 16]
-    assert cached_batch_widths("OpB", path=path) == [4]
-    assert cached_batch_widths("OpC", path=path) == []
-    tuning_cache.clear_memory()
+    assert pool.prewarm(widths=[3]) == {"fam": [4]}
+    assert pool.warmed == {("fam", 4)}
 
 
 # ---------------------------------------------------- admission + queue

@@ -154,28 +154,6 @@ def test_spilled_host_dst_resolution():
     assert from_host.nbytes_h2d == 45 * F64
 
 
-def test_spill_chunk_hint_consulted(monkeypatch, tmp_path):
-    """A banked op="spill" plan streams finer, and a banked
-    op="reshard" plan still applies to the spilled schedule (the max
-    of both hints wins)."""
-    from pylops_mpi_tpu.tuning import plan as tplan
-    from pylops_mpi_tpu.tuning import cache as tcache
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE", "on")
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE_CACHE",
-                       str(tmp_path / "plans.json"))
-    tcache.clear_memory()
-    src = R.Layout.scatter(_sizes(45, 8))
-    dst = R.Layout.scatter(_sizes(45, 4))
-    tplan.record_chunk_plan(45, 8, 4, op="reshard")
-    plan = R.plan_reshard((45,), F64, src, dst, spill="on")
-    assert plan.chunks >= 4
-    S.record_spill_plan(45, 8, 8, overlap="off")
-    plan = R.plan_reshard((45,), F64, src, dst, spill="on")
-    assert plan.chunks >= 8
-    assert S.overlap_hint_spill(45, 8) == "off"
-    tcache.clear_memory()
-
-
 # ---------------------------------------- spill-forced mirror matrix
 @pytest.mark.parametrize("world", [2, 4, 8])
 def test_spill_round_trip_worlds(world, ndev):

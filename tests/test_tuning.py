@@ -447,27 +447,6 @@ def test_plan_key_batch_axis():
     assert k16 != base and k16.endswith("|b16")
 
 
-# ----------------------------------------------- resolve_chunks planning
-def test_chunk_hint_consulted_only_when_allowed(monkeypatch):
-    from pylops_mpi_tpu.parallel.collectives import resolve_chunks
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE", "on")
-    tplan.record_chunk_plan(256, 8, 8)
-    # default-sourced count: plan wins (then the cap still applies)
-    assert resolve_chunks(256, 8, 4, allow_plan=True) == 8
-    # explicit user kwarg path: plan never consulted
-    assert resolve_chunks(256, 8, 4, allow_plan=False) == 4
-    # tuner off: inert
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE", "off")
-    assert resolve_chunks(256, 8, 4, allow_plan=True) == 4
-
-
-def test_chunk_hint_still_capped(monkeypatch):
-    from pylops_mpi_tpu.parallel.collectives import resolve_chunks
-    monkeypatch.setenv("PYLOPS_MPI_TPU_TUNE", "on")
-    tplan.record_chunk_plan(32, 8, 8)  # 8 chunks cannot fit 32/8 rows
-    assert resolve_chunks(32, 8, 4, allow_plan=True) == 4  # cap 32//8
-
-
 # ------------------------------------------------------- knob registry
 def test_knob_registry_covers_every_package_read():
     """Grep the package for PYLOPS_MPI_TPU_* reads; every knob must
